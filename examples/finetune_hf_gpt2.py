@@ -3,24 +3,18 @@
 Loads HF weights via smp.from_hf, trains under tp, saves a full
 checkpoint back in HF naming (loadable by transformers).
     python examples/finetune_hf_gpt2.py
+Runs on the devices JAX finds. With no accelerator (or fewer devices than
+the degrees below need), run it on eight virtual CPU devices:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/finetune_hf_gpt2.py
 """
 
 import os
 import sys
 
-if not os.environ.get("SMP_EXAMPLE_ON_TPU"):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if not os.environ.get("SMP_EXAMPLE_ON_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 import optax

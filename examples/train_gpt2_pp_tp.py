@@ -1,27 +1,18 @@
 """Train GPT-2 with pipeline + tensor parallelism on synthetic data.
 
-Run on any host (uses an 8-virtual-device CPU mesh when no TPUs):
     python examples/train_gpt2_pp_tp.py
-On a TPU slice, drop the platform overrides and scale the degrees.
+Runs on the devices JAX finds. With no accelerator (or fewer devices than
+the degrees below need), run it on eight virtual CPU devices:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/train_gpt2_pp_tp.py
 """
 
 import os
 import sys
 
-if not os.environ.get("SMP_EXAMPLE_ON_TPU"):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if not os.environ.get("SMP_EXAMPLE_ON_TPU"):
-    # The env var alone is not enough on hosts whose TPU plugin pins the
-    # platform; force it at the config level too.
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -25,20 +25,6 @@ Typical use::
     optimizer.step()
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "set_mesh"):
-    # jax < 0.5 compat: the step/model/generation engines (and the test
-    # suite / graft entry points) bind the mesh at jit call sites via
-    # ``with jax.set_mesh(mesh):``. On older jax the Mesh object itself is
-    # the context manager with the same scoping semantics (the explicit
-    # NamedShardings those engines compute do the real work). Deliberately
-    # patched onto the jax namespace — callers outside this package need it
-    # too. Limitation: newer jax also allows STATEMENT-style global
-    # ``jax.set_mesh(m)``; under this shim that form is a no-op, so only
-    # the with-block form is supported on old jax.
-    _jax.set_mesh = lambda mesh: mesh
-
 from smdistributed_modelparallel_tpu.backend.config import ModelParallelConfig
 from smdistributed_modelparallel_tpu.backend.collectives import (
     CollectiveCommunicator,

@@ -49,9 +49,9 @@ class ModelParallelCore:
         self.cfg = cfg
         telemetry.set_phase("init/distributed")
         self._maybe_init_distributed()
-        # The first device enumeration is the probe that wedges when the
-        # accelerator transport is down (BENCH_r05): guard it so an armed
-        # watchdog dumps instead of hanging smp.init silently.
+        # The first device enumeration is where a backend that cannot come
+        # up stalls: guard it so an armed watchdog dumps instead of hanging
+        # smp.init silently.
         telemetry.set_phase("init/topology")
         with watchdog.guard("init/topology"):
             # Rank identity first (inside the guard: process_index() itself
@@ -266,7 +266,11 @@ class ModelParallelCore:
         return 0
 
     def local_size(self):
-        return jax.local_device_count()
+        """This process's devices IN THE MESH — a mesh built from a subset
+        of the host's devices (``smp.init(cfg, devices=...)``) does not
+        count the rest, like ``_default_rank``."""
+        self._check()
+        return len(self.topology.mesh.local_devices)
 
     def _flat_devices(self):
         """Cached rank -> device list (per topology: large pods shouldn't

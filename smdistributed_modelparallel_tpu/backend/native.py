@@ -17,6 +17,7 @@ toolchain; every caller must tolerate ``load() is None`` (no toolchain, or
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -38,33 +39,46 @@ _NATIVE_DIR = os.path.join(
     "native",
 )
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libsmptpu.so")
+_HASH_PATH = _LIB_PATH + ".srchash"
 
 _lock = threading.Lock()
 _lib = None
 _load_attempted = False
 
 
-def _stale():
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
+def _source_hash():
+    """sha256 over the Makefile and ``src/*.cc`` — the rebuild key. File
+    times say nothing in a fresh copy of the tree, so the key is content."""
+    h = hashlib.sha256()
     src_dir = os.path.join(_NATIVE_DIR, "src")
+    paths = [os.path.join(_NATIVE_DIR, "Makefile")] + sorted(
+        os.path.join(src_dir, f) for f in os.listdir(src_dir)
+        if f.endswith(".cc")
+    )
+    for path in paths:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
+
+
+def _stale():
+    """True when the library is missing or was built from other sources
+    than the ones on disk (the hash of what it was built from sits beside
+    it in ``libsmptpu.so.srchash``)."""
     try:
-        return any(
-            os.path.getmtime(os.path.join(src_dir, f)) > lib_mtime
-            for f in os.listdir(src_dir)
-            if f.endswith(".cc")
-        )
+        with open(_HASH_PATH) as fh:
+            built_from = fh.read().strip()
     except OSError:
-        return False
+        return True
+    return not os.path.exists(_LIB_PATH) or built_from != _source_hash()
 
 
 def _build():
     """Build libsmptpu.so under an inter-process file lock, into a temp
     name, installed by atomic rename — N processes hit smp.init (and so
     this builder) simultaneously on one host, and an unlocked in-place make
-    can hand a half-written .so to a peer's dlopen (worse: the corrupt file
-    ends up newer than the sources, so _stale() never rebuilds it)."""
+    can hand a half-written .so to a peer's dlopen."""
     import fcntl
 
     lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
@@ -74,6 +88,7 @@ def _build():
             fcntl.flock(lock_fh, fcntl.LOCK_EX)
             if not _stale():  # a peer built it while we waited
                 return True
+            src_hash = _source_hash()
             subprocess.run(
                 ["make", "-C", _NATIVE_DIR, f"LIB={tmp_name}"],
                 check=True,
@@ -81,6 +96,9 @@ def _build():
                 timeout=120,
             )
             os.replace(os.path.join(_NATIVE_DIR, tmp_name), _LIB_PATH)
+            with open(_HASH_PATH + ".tmp", "w") as fh:
+                fh.write(src_hash + "\n")
+            os.replace(_HASH_PATH + ".tmp", _HASH_PATH)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         logger.warning("native build failed (%s); using pure-Python fallbacks.", e)

@@ -569,7 +569,14 @@ class DistributedTransformerOutputLayer(nn.Module):
             record_fused_kernel_dispatch,
         )
 
-        ok = bias_gelu_ok(self.activation)
+        # The kernel sees the LOCAL feature width (nn/utils.fused_bias_gelu
+        # hands it the tp shard when the width divides).
+        from smdistributed_modelparallel_tpu.nn.utils import tp_size
+
+        F, tp = self.intermediate_size, tp_size()
+        ok = bias_gelu_ok(
+            self.activation, features=F // tp if F % tp == 0 else F
+        )
         record_fused_kernel_dispatch(
             "bias_gelu", "pallas" if ok else "fallback"
         )

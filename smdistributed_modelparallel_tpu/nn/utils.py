@@ -141,11 +141,10 @@ def _fused_bias_gelu_region(mesh, ndim, interpret):
     from smdistributed_modelparallel_tpu.parallel.sharding import (
         single_axis_spec,
     )
-    from smdistributed_modelparallel_tpu.utils.jax_compat import shard_map
 
     h_spec = single_axis_spec(ndim, ndim - 1, TP_AXIS)
     b_spec = single_axis_spec(1, 0, TP_AXIS)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda h, b: bias_gelu(h, b, interpret),
         mesh=mesh, in_specs=(h_spec, b_spec), out_specs=h_spec,
         axis_names={TP_AXIS}, check_vma=False,

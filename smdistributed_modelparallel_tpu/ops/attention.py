@@ -152,17 +152,13 @@ def attention_core(
         # attention_in_fp32 / qk_compensation need no special handling: the
         # kernel's score math is always fp32 (N8 parity, and then some).
     ):
-        from smdistributed_modelparallel_tpu.ops.pallas_attention import (
-            flash_attention,
-        )
-
         qq, kernel_scale, seed, rate = _fold_scale_and_seed(
             q, scale, dropout_rate, dropout_rng
         )
         # Block sizes resolve inside the kernel entry (explicit arg ->
         # pallas_attn_block_{q,k} config -> default).
-        return flash_attention(
-            qq, k, v, kpad, seed, None, kernel_scale, causal, window, rate
+        return _flash_on_mesh(
+            qq, k, v, kpad, seed, kernel_scale, causal, window, rate
         )
 
     T, S = q.shape[1], k.shape[1]
@@ -207,6 +203,77 @@ def attention_core(
         probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
     probs = probs.astype(v.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
+
+
+def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
+    """Run the flash kernel where the operands live.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"): under a jit over more than one device the bare
+    ``pallas_call`` does not lower at all. So on a multi-device mesh the
+    kernel runs in a FULL-manual ``shard_map`` region over the mesh (a
+    partial-manual region is refused the same way): batch split over the
+    data axes and heads over tp, each only when it divides — a dim that
+    does not divide stays whole and is computed replicated. Sequence and
+    head_dim stay whole. On one device this is the bare call.
+    """
+    from smdistributed_modelparallel_tpu.backend.state import state
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    mesh = state.mesh if state.initialized else None
+    if mesh is None or mesh.devices.size == 1:
+        return flash_attention(
+            q, k, v, kpad, seed, None, scale, causal, window, rate
+        )
+    from jax.sharding import PartitionSpec as P
+
+    from smdistributed_modelparallel_tpu.backend.topology import (
+        EP_AXIS,
+        RDP_AXIS,
+        TP_AXIS,
+    )
+
+    B, H = q.shape[0], q.shape[2]
+    data = tuple(a for a in (RDP_AXIS, EP_AXIS) if mesh.shape[a] > 1)
+    n_data = int(np.prod([mesh.shape[a] for a in data])) if data else 1
+    b_axes = data if data and B % n_data == 0 else None
+    tp = mesh.shape[TP_AXIS]
+    h_axis = TP_AXIS if tp > 1 and H % tp == 0 else None
+    qkv_spec = P(b_axes, None, h_axis, None)
+
+    operands, specs = [q, k, v], [qkv_spec] * 3
+    if kpad is not None:
+        operands.append(kpad)
+        specs.append(P(b_axes, None))
+    if seed is not None:
+        operands.append(seed)
+        specs.append(P())
+
+    def body(q, k, v, *rest):
+        rest = list(rest)
+        kpad_l = rest.pop(0) if kpad is not None else None
+        seed_l = head0 = None
+        if seed is not None:
+            # Dropout hashes the GLOBAL head index, and every batch shard
+            # draws from its own stream.
+            seed_l = rest.pop(0)
+            if h_axis is not None:
+                head0 = jax.lax.axis_index(h_axis) * q.shape[2]
+            if b_axes is not None:
+                seed_l = seed_l + jax.lax.axis_index(b_axes).astype(
+                    seed_l.dtype
+                ) * jnp.asarray(-1640531535, seed_l.dtype)
+        return flash_attention(
+            q, k, v, kpad_l, seed_l, head0, scale, causal, window, rate,
+            head_total=H,
+        )
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(specs), out_specs=qkv_spec,
+        check_vma=False,
+    )(*operands)
 
 
 def _as_key_padding_bias(mask, mask_value):

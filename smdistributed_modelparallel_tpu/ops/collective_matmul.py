@@ -64,9 +64,7 @@ by. Revisit if a tp>4 profile shows the tail hop contending.
 Multi-axis caveat: the ring regions currently own ONLY the tp axis —
 the entry constraints spec tp alone (non-tp dims pinned replicated) and
 the in/out specs name no batch axes, so on a dp x tp mesh activations
-replicate over dp around every ring matmul, on every jax version (the
-jax-0.4 full-manual shard_map fallback, utils/jax_compat.py, gathers
-the unnamed axes at region entry too). On a pure-tp mesh (the tp=2
+replicate over dp around every ring matmul. On a pure-tp mesh (the tp=2
 parity/golden tier) this is exact and free; on multi-axis meshes it is
 semantically correct but pays dp gather traffic + replicated activation
 memory — making the rings batch-sharded (lead-dim axes in the specs and
@@ -82,10 +80,6 @@ import jax.numpy as jnp
 
 from smdistributed_modelparallel_tpu.backend.state import state
 from smdistributed_modelparallel_tpu.backend.topology import TP_AXIS
-from smdistributed_modelparallel_tpu.utils.jax_compat import (
-    ensure_optimization_barrier_rules,
-    shard_map,
-)
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 
 from smdistributed_modelparallel_tpu.parallel.sharding import (
@@ -244,7 +238,6 @@ def _build_ag(mesh, tp, x_ndim, w_ndim, w_tp_dim, has_bias, use_pallas,
     w: [D, *out] with tp on ``w_tp_dim``; bias (optional): w.shape[1:]
     with tp on ``w_tp_dim - 1``. Output [*lead, S, *out], tp on the out
     dim. See module docstring for the decomposition."""
-    ensure_optimization_barrier_rules()
     perm = [(i, (i + 1) % tp) for i in range(tp)]
     seq_dim = x_ndim - 2
 
@@ -334,14 +327,14 @@ def _build_ag(mesh, tp, x_ndim, w_ndim, w_tp_dim, has_bias, use_pallas,
     b_spec = single_axis_spec(w_ndim - 1, w_tp_dim - 1, axis_name)
 
     fwd_specs = (x_spec, w_spec) + ((b_spec,) if has_bias else ())
-    fwd_fn = shard_map(
+    fwd_fn = jax.shard_map(
         (lambda x, w, b: fwd_body(x, w, b)) if has_bias
         else (lambda x, w: fwd_body(x, w, None)),
         mesh=mesh, in_specs=fwd_specs, out_specs=out_spec,
         axis_names={axis_name}, check_vma=False,
     )
     bwd_out = (x_spec, w_spec) + ((b_spec,) if has_bias else ())
-    bwd_fn = shard_map(
+    bwd_fn = jax.shard_map(
         bwd_body, mesh=mesh, in_specs=(x_spec, w_spec, out_spec),
         out_specs=bwd_out, axis_names={axis_name}, check_vma=False,
     )
@@ -414,7 +407,6 @@ def _build_rs(mesh, tp, x_ndim, n_contract, x_tp_dim, w_ndim,
     with tp on ``x_tp_dim`` (a contract dim); w: [*contract, *out] with
     tp on the matching dim. Output [*lead, S, *out] sequence-sharded
     over tp."""
-    ensure_optimization_barrier_rules()
     perm = [(i, (i + 1) % tp) for i in range(tp)]
     seq_dim = x_ndim - n_contract - 1
     w_tp_dim = x_tp_dim - seq_dim - 1      # position inside w's contract dims
@@ -488,11 +480,11 @@ def _build_rs(mesh, tp, x_ndim, n_contract, x_tp_dim, w_ndim,
     out_ndim = seq_dim + 1 + (w_ndim - n_contract)
     out_spec = single_axis_spec(out_ndim, seq_dim, axis_name)
 
-    fwd_fn = shard_map(
+    fwd_fn = jax.shard_map(
         fwd_body, mesh=mesh, in_specs=(x_spec, w_spec),
         out_specs=out_spec, axis_names={axis_name}, check_vma=False,
     )
-    bwd_fn = shard_map(
+    bwd_fn = jax.shard_map(
         bwd_body, mesh=mesh, in_specs=(x_spec, w_spec, out_spec),
         out_specs=(x_spec, w_spec), axis_names={axis_name},
         check_vma=False,

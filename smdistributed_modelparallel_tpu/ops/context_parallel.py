@@ -39,7 +39,6 @@ from smdistributed_modelparallel_tpu.backend.state import state
 from smdistributed_modelparallel_tpu.backend.topology import CP_AXIS
 from smdistributed_modelparallel_tpu.ops.pallas_attention import _dropout_keep
 from smdistributed_modelparallel_tpu.utils.exceptions import SMPValidationError
-from smdistributed_modelparallel_tpu.utils.jax_compat import shard_map
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 
 NEG_INF = -1e30
@@ -845,7 +844,7 @@ def _build_cp_call(body_fn, body_kw_items, mesh, spec, has_kp, has_seed):
         sd = next(it) if has_seed else None
         return body(q, k, v, kp, sd)
 
-    shard_fn = shard_map(
+    shard_fn = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),

@@ -597,6 +597,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
             ),
             jax.ShapeDtypeStruct((B * H, 1, t_pad), jnp.float32),
         ],
+        name="smp_flash_fwd",
         interpret=interpret or FORCE_INTERPRET,
     )(qt, kt, vt, *extra)
     o = out[:, :T, :hd].reshape(B, H, T, hd).transpose(0, 2, 1, 3)
@@ -655,6 +656,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         out_shape=jax.ShapeDtypeStruct(
             (B * H, t_pad, hd_pad), jnp.float32 if has_ids else q.dtype
         ),
+        name="smp_flash_bwd_dq",
         interpret=interpret or FORCE_INTERPRET,
     )(qt, kt, vt, gt, lse, delta, *extra)
 
@@ -684,6 +686,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
                 (B * H, s_pad, hd_pad), jnp.float32 if has_ids else v.dtype
             ),
         ],
+        name="smp_flash_bwd_dkv",
         interpret=interpret or FORCE_INTERPRET,
     )(qt, kt, vt, gt, lse, delta, *extra)
 

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from smdistributed_modelparallel_tpu.utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -210,6 +209,7 @@ def _fused_ce_fwd_impl(x, w, targets, block_n, block_v, interpret,
             jax.ShapeDtypeStruct((1, n_pad), jnp.float32)
             for _ in range(n_out)
         ],
+        name="smp_ce_fwd",
         interpret=interpret or FORCE_INTERPRET,
     )(xp, wp, tp)
     m, l, tgt = outs[0], outs[1], outs[2]
@@ -245,6 +245,7 @@ def _fused_ce_bwd_impl(x, w, targets, lse, g, block_n, block_v, interpret,
         # fp32 accumulator: the block is revisited across the vocab sweep;
         # accumulating ~V/block_v partial sums in bf16 would round.
         out_shape=jax.ShapeDtypeStruct((n_pad, D), jnp.float32),
+        name="smp_ce_bwd_dx",
         interpret=interp,
     )(xp, wp, tp, lsep, gp)
 
@@ -263,6 +264,7 @@ def _fused_ce_bwd_impl(x, w, targets, lse, g, block_n, block_v, interpret,
         ],
         out_specs=pl.BlockSpec((block_v, D), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((v_pad, D), jnp.float32),
+        name="smp_ce_bwd_dw",
         interpret=interp,
     )(xp, wp, tp, lsep, gp)
     return dx[:N].astype(x.dtype), dw[:V].astype(w.dtype)
@@ -350,7 +352,7 @@ def make_vocab_parallel_fused_ce(mesh, v_global, block_n, block_v,
             sum_l = jnp.zeros_like(lse_l)
         return lse_l[None], tgt_l[None], sum_l[None]   # [1, N] per shard
 
-    stats_fn = shard_map(
+    stats_fn = jax.shard_map(
         stats_body, mesh=mesh,
         in_specs=(P(), P(axis_name, None), P()),
         out_specs=(P(axis_name, None),) * 3,
@@ -369,7 +371,7 @@ def make_vocab_parallel_fused_ce(mesh, v_global, block_n, block_v,
         dx = jax.lax.psum(dx_l.astype(jnp.float32), axis_name)
         return dx, dw_l
 
-    bwd_fn = shard_map(
+    bwd_fn = jax.shard_map(
         bwd_body, mesh=mesh,
         in_specs=(P(), P(axis_name, None), P(), P(), P()),
         out_specs=(P(), P(axis_name, None)),
@@ -406,14 +408,20 @@ def _step_bytes(D, block_n, block_v):
                 + max(block_n, block_v) * D)
 
 
-_VMEM_BUDGET = 12 * 2**20
+# Budget for ``_step_bytes``, held against the chip's compiler: the v5e
+# allows a kernel 16 MiB of scoped VMEM, and the dw backward kernel asks
+# about 1.45x what ``_step_bytes`` counts (16.6-17.8 MiB where the count
+# was 11.5-12.0). 10.5 MiB keeps the largest accepted blocks near 15 MiB.
+_VMEM_BUDGET = int(10.5 * 2**20)
 
 # Preference order: large vocab blocks amortize the row re-reads; shrink
 # block_v first (it multiplies D in three of the four VMEM terms), then
 # block_n, so wide models (large D) still get a fitting configuration
-# instead of losing the kernel entirely.
+# instead of losing the kernel entirely. block_n stops at 128: the
+# per-row vectors (targets, lse) are [1, N] blocks whose last dim must be
+# a multiple of 128 lanes, so narrower row blocks do not lower on the TPU.
 _BLOCK_CANDIDATES = (
-    (256, 1024), (256, 512), (128, 512), (128, 256), (64, 256), (32, 128),
+    (256, 1024), (256, 512), (128, 512), (128, 256), (128, 128),
 )
 
 

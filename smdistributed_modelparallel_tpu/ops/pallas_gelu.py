@@ -33,9 +33,43 @@ FORCE_INTERPRET = False
 _SQRT_2_OVER_PI = float(np.sqrt(2.0 / np.pi))
 _COEFF = 0.044715
 
-# Rows per grid step; the feature dim stays whole (bias broadcasts over
-# rows, and intermediate dims are at most a few k * 4 bytes per row).
+# Rows per grid step.
 _BLOCK_ROWS = 256
+# Widest feature tile. Whole rows do not fit the chip's scoped VMEM at real
+# MLP widths (v5e compiler: 25-48 MiB asked at F=6400 / 16384 against a
+# 16 MiB limit), so the feature dim is tiled too: a (256, 1024) tile is at
+# most 1 MiB per fp32 buffer whatever F is.
+_BLOCK_COLS = 1024
+# An F with no 128-multiple divisor cannot be tiled (a block's last dim is
+# a multiple of 128 lanes or the whole dim) and keeps whole rows; it is
+# refused when a tile of them would not fit. 32 bytes per element is the
+# most the v5e compiler was seen to allocate for the backward kernel
+# (double-buffered x, g, dpre plus fp32 temporaries).
+_VMEM_BUDGET = 12 * 2**20
+_BYTES_PER_ELEMENT = 32
+
+
+def _feature_block(F):
+    """Widest tile of the feature dim: the largest divisor of ``F`` that is
+    a multiple of 128 and at most ``_BLOCK_COLS``; ``F`` itself when there
+    is none."""
+    if F % 128:
+        return F
+    return max(
+        d for d in range(128, min(F, _BLOCK_COLS) + 1, 128) if F % d == 0
+    )
+
+
+def _fits(F):
+    return 8 * _feature_block(F) * _BYTES_PER_ELEMENT <= _VMEM_BUDGET
+
+
+def _row_block(N, bf):
+    """Rows per tile: ``_BLOCK_ROWS``, shrunk (in multiples of 8) until a
+    tile fits the VMEM budget — only an untileable wide F ever shrinks
+    it."""
+    fit = _VMEM_BUDGET // (bf * _BYTES_PER_ELEMENT) // 8 * 8
+    return max(8, min(_BLOCK_ROWS, fit, -(-N // 8) * 8))
 
 
 def _gelu_tanh(u):
@@ -69,22 +103,24 @@ def _pad_rows(x, n):
     return jnp.pad(x, ((0, n - x.shape[0]), (0, 0)))
 
 
-def _call_rowwise(kernel, outs_dtype, interpret, x2d, b, *extra):
+def _call_tiled(kernel, name, outs_dtype, interpret, x2d, b, *extra):
     N, F = x2d.shape
-    bn = min(_BLOCK_ROWS, max(8, N))
+    bf = _feature_block(F)
+    bn = _row_block(N, bf)
     n_pad = -(-N // bn) * bn
-    row = pl.BlockSpec((bn, F), lambda i: (i, 0))
+    tile = pl.BlockSpec((bn, bf), lambda i, j: (i, j))
     args = [_pad_rows(x2d, n_pad), b.reshape(1, F)]
-    in_specs = [row, pl.BlockSpec((1, F), lambda i: (0, 0))]
+    in_specs = [tile, pl.BlockSpec((1, bf), lambda i, j: (0, j))]
     for e in extra:
         args.append(_pad_rows(e, n_pad))
-        in_specs.append(row)
+        in_specs.append(tile)
     out = pl.pallas_call(
         kernel,
-        grid=(n_pad // bn,),
+        grid=(n_pad // bn, F // bf),
         in_specs=in_specs,
-        out_specs=row,
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((n_pad, F), outs_dtype),
+        name=name,
         interpret=interpret or FORCE_INTERPRET,
     )(*args)
     return out[:N]
@@ -93,8 +129,8 @@ def _call_rowwise(kernel, outs_dtype, interpret, x2d, b, *extra):
 def _bias_gelu_impl(x, b, interpret):
     lead = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
-    return _call_rowwise(
-        _fwd_kernel, x.dtype, interpret, x2d, b
+    return _call_tiled(
+        _fwd_kernel, "smp_bias_gelu_fwd", x.dtype, interpret, x2d, b
     ).reshape(lead + (x.shape[-1],))
 
 
@@ -113,8 +149,8 @@ def _bg_bwd(interpret, res, g):
     x, b = res
     lead = x.shape[:-1]
     F = x.shape[-1]
-    dpre = _call_rowwise(
-        _bwd_kernel, jnp.float32, interpret,
+    dpre = _call_tiled(
+        _bwd_kernel, "smp_bias_gelu_bwd", jnp.float32, interpret,
         x.reshape(-1, F), b, g.reshape(-1, F),
     )
     dx = dpre.astype(x.dtype).reshape(lead + (F,))
@@ -125,11 +161,14 @@ def _bg_bwd(interpret, res, g):
 bias_gelu.defvjp(_bg_fwd, _bg_bwd)
 
 
-def bias_gelu_ok(activation):
+def bias_gelu_ok(activation, features=None):
     """Dispatch precondition: the tanh-GELU family (the reference's
-    fused bias_gelu polynomial) on the kernel's target backend (TPU, or
-    interpret-mode testing)."""
+    fused bias_gelu polynomial), a feature dim (``features``, the LOCAL
+    width under tp) whose tiles fit VMEM, and the kernel's target backend
+    (TPU, or interpret-mode testing)."""
     if activation not in ("gelu", "gelu_new"):
+        return False
+    if features is not None and not _fits(features):
         return False
     return jax.default_backend() == "tpu" or FORCE_INTERPRET
 

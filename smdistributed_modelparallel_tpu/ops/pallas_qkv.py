@@ -112,6 +112,7 @@ def _matmul_bias_impl(x, w, b, interpret):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), x.dtype),
+        name="smp_matmul_bias",
         interpret=interpret or FORCE_INTERPRET,
     )(*args)
     return y[:N, :F]
@@ -200,6 +201,7 @@ def matmul_bias_fp8(x8, w8, *, interpret=False):
         ],
         out_specs=pl.BlockSpec((bn, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
+        name="smp_matmul_fp8",
         interpret=interpret or FORCE_INTERPRET,
     )(xp, wp)
     return y[:N, :F]

@@ -305,9 +305,8 @@ def zero3_enabled(cfg=None):
 def zero3_manual_grads_supported(cfg=None):
     """True when the explicit per-slice-grad + bucketed reduce-scatter
     path applies: the rdp axis must be the ONLY nontrivial mesh axis (the
-    reduce buckets run in a full-manual shard_map region on this jax —
-    see utils/jax_compat.py — which would gather the other axes at region
-    entry). Other compositions (pp x zero3, tp x zero3) keep sharded
+    reduce buckets run in a full-manual shard_map region, which would
+    gather the other axes at region entry). Other compositions (pp x zero3, tp x zero3) keep sharded
     params + just-in-time gathers and leave the gradient reduction to
     GSPMD."""
     cfg = cfg if cfg is not None else state.cfg
@@ -413,7 +412,6 @@ def zero3_grad_reduce(pgrads, params, model, name="step"):
     by rdp — the per-microbatch gradient is the MEAN of the slice
     gradients, matching the plain path's mean-over-batch loss.
     """
-    from smdistributed_modelparallel_tpu.utils.jax_compat import shard_map
     from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
 
     cfg = state.cfg
@@ -498,7 +496,7 @@ def zero3_grad_reduce(pgrads, params, model, name="step"):
             ))
             for i in bucket
         )
-        reduced = shard_map(
+        reduced = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )(*(g_leaves[i] for i in bucket))
@@ -666,12 +664,6 @@ def zero3_prefetch_scan(apply_layer, h, stacked_params, num_layers,
     transpose loop, so per-device live gathered params stay at two layers
     in forward and one in backward.
     """
-    from smdistributed_modelparallel_tpu.utils.jax_compat import (
-        ensure_optimization_barrier_rules,
-    )
-
-    ensure_optimization_barrier_rules()
-
     def gather(tree):
         return jax.tree_util.tree_map(
             jax.lax.with_sharding_constraint, tree, gather_specs

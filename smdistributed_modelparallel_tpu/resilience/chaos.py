@@ -44,9 +44,9 @@ Faults:
   failover path must absorb a death there too.
 - ``corrupt_weights@version=N[:rank=R]`` — perturb the parameter tree a
   serving replica adopts as weights version ``N`` (every float leaf
-  mapped to ``x * 1.01 + 0.01`` — deterministic, and the affine shift
-  breaks greedy token parity even where a pure rescale would preserve
-  every argmax): the canary's token-parity gate must catch it and the
+  rolled by one along its leading axis, then mapped to
+  ``x * 1.01 + 0.01`` — deterministic; the roll breaks greedy token
+  parity where the affine map alone can preserve every argmax): the canary's token-parity gate must catch it and the
   controller auto-roll back, latching ``smp_canary_rollback_total`` and
   one forensics bundle.
 - ``bus_drop@seq=N[:rank=R][:dest=D]`` — silently drop this process's
@@ -314,9 +314,12 @@ class ChaosInjector:
         """serving/engine.py seam: called with the parameter tree a
         replica is about to adopt as weights version ``version``. Rule
         ``corrupt_weights@version=N`` returns a perturbed copy (every
-        float leaf mapped to ``x * 1.01 + 0.01``) — silently wrong
-        weights the canary's token-parity gate must catch. Returns
-        ``params`` untouched otherwise."""
+        float leaf rolled by one along its leading axis — a shard read at
+        the wrong offset: embedding rows and stacked layers land one
+        place over — then mapped to ``x * 1.01 + 0.01``) — silently wrong
+        weights the canary's token-parity gate must catch. The affine map
+        alone can leave every greedy token of a small model where it
+        was; the roll cannot. Returns ``params`` untouched otherwise."""
         if not os.environ.get(CHAOS_ENV):
             return params
         for r in self._sync():
@@ -331,12 +334,15 @@ class ChaosInjector:
             record_chaos("corrupt_weights", f"version={version}")
             logger.warning(
                 "chaos: corrupting weights version %s (float leaves "
-                "-> x*1.01 + 0.01)", version,
+                "-> roll(x, 1, axis 0) * 1.01 + 0.01)", version,
             )
             import jax  # lazy: chaos must import without a backend
+            import jax.numpy as jnp
 
             def _perturb(x):
                 if hasattr(x, "dtype") and "float" in str(x.dtype):
+                    if getattr(x, "ndim", 0) >= 1:
+                        x = jnp.roll(x, 1, axis=0)
                     return x * 1.01 + 0.01
                 return x
 

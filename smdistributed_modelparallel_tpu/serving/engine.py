@@ -378,7 +378,9 @@ class ServingEngine:
             for leaf in jax.tree_util.tree_leaves(self._cache)
             if nb in getattr(leaf, "shape", ())
         ) // nb
-        self._chips = max(len(jax.local_devices()), 1)
+        # The chips this engine runs on: its mesh's, or the one default
+        # device of a process-local engine — not every chip of the host.
+        self._chips = self._mesh.devices.size if self._mesh is not None else 1
         # Metrics time-series snapshotter (the autoscaler feed):
         # SMP_TIMESERIES_INTERVAL=0 (the default) constructs NOTHING —
         # no ring, no thread. When armed, the engine also polls it from
@@ -560,8 +562,10 @@ class ServingEngine:
         }
 
         def shape_fn(p):
+            # Cast as the programs do: the pools take the dtype of the K/V
+            # the programs will write (bf16 under ``bf16: True``).
             return self.decode_mod.apply(
-                {"params": self._deq_params(p)},
+                {"params": self._half_params(self._deq_params(p))},
                 jnp.zeros((1, 1), jnp.int32), paged=paged0,
                 mutable=["cache"],
             )[1]["cache"]

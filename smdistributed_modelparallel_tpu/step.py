@@ -29,6 +29,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from smdistributed_modelparallel_tpu.backend.split import (
@@ -560,8 +561,16 @@ class StepFunction:
 
                 def mb_args(mb, _sv=tuple(scan_vals), _sm=tuple(scan_meta),
                             _bv=tuple(bcast_vals), _rc=reconstruct):
+                    # Restack on the host: the dispatched inputs are
+                    # batch-sharded over the data axes, and an eager
+                    # reshape of such an array to [num_mb, mb, ...] has
+                    # no sharding to give its result.
                     leaves = [
-                        stack_leaf(v, *m)[mb] for v, m in zip(_sv, _sm)
+                        stack_leaf(
+                            np.asarray(v) if v.is_fully_addressable else v,
+                            *m,
+                        )[mb]
+                        for v, m in zip(_sv, _sm)
                     ]
                     return _rc(leaves, list(_bv))
 

@@ -373,8 +373,12 @@ def load(name, key_hash, module_sha=None, params=None,
         payload, in_tree, out_tree = pickle.loads(raw)
         from jax.experimental import serialize_executable
 
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
         compiled = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in meta["device_ids"]],
         )
     except Exception as e:  # corrupt/truncated/undeserializable entry
         logger.warning(
@@ -521,6 +525,12 @@ def store(name, key_hash, compiled, module_sha=None, audit=None,
 
         payload, in_tree, out_tree = serialize_executable.serialize(compiled)
         raw = pickle.dumps((payload, in_tree, out_tree))
+        # The devices the program was compiled for: a reload executes on
+        # these and no others (left to itself it takes every device of the
+        # backend and then wants one shard per device).
+        device_ids = [
+            int(d.id) for d in compiled.runtime_executable().local_devices()
+        ]
     except Exception as e:
         logger.warning("[exec_cache] %s: executable not serializable on "
                        "this backend (%s); entry not written.", name, e)
@@ -537,6 +547,7 @@ def store(name, key_hash, compiled, module_sha=None, audit=None,
         "knobs": _knob_facts(),
         "payload_sha256": hashlib.sha256(raw).hexdigest(),
         "payload_bytes": len(raw),
+        "device_ids": device_ids,
         "module_sha": module_sha,
         "compile_seconds": compile_seconds,
         "audit": audit.fingerprint if audit is not None else None,

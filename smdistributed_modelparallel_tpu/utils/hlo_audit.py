@@ -31,8 +31,12 @@ inspection a first-class, structured pass over EVERY compiled step:
    shapes, contraction dims, source location) of an earlier instruction,
    FLOP-weighted. Exact for double-forward recompute (activation remat,
    the ZB split-backward's B+W forward re-runs); an upper bound when a
-   transpose dot is structurally identical to its forward. Static census:
-   multiplicities are per compiled program, not per loop trip.
+   transpose dot is structurally identical to its forward, or when the
+   compiler left a dot without its source location (its ``stack_frame_id``;
+   XLA:CPU keeps one on few dots) — same-shape dots of different layers
+   then count as duplicates. Total and unique FLOPs do not depend on the
+   key. Static census: multiplicities are per compiled program, not per
+   loop trip.
 
 4. **Memory breakdown** — XLA buffer assignment by class (arguments /
    outputs / temps / aliased / generated code) from ``memory_analysis``.
@@ -106,9 +110,24 @@ _CONTRACT_RE = re.compile(
     r"lhs_contracting_dims=\{([0-9,]*)\}, rhs_contracting_dims=\{([0-9,]*)\}"
 )
 _METADATA_RE = re.compile(r"metadata=\{[^}]*\}")
-_SOURCE_RE = re.compile(r'source_file="([^"]*)" source_line=(\d+)')
+# The module header's source tables (file names, function names, line and
+# column of every location, stack frames), up to the first computation.
+_SOURCE_TABLES_RE = re.compile(
+    r"^FileNames\n.*?^StackFrames\n.*?\n\n", re.DOTALL | re.MULTILINE
+)
+# An instruction's source location is its stack frame in those tables.
+_SOURCE_RE = re.compile(r"stack_frame_id=(\d+)")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _WHILE_RE = re.compile(r"%?([\w.\-]+)\s*=\s*(\([^=]*?\))\s+while\(")
+
+def strip_source_metadata(hlo_text):
+    """HLO text without what only says where in the source an instruction
+    came from: per-instruction ``metadata={...}`` and the module header's
+    file / function / line-and-column / stack-frame tables. Two programs
+    that differ only by an edit above the traced function compare (and
+    hash) equal after this."""
+    return _METADATA_RE.sub("", _SOURCE_TABLES_RE.sub("", hlo_text))
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -1370,7 +1389,7 @@ def audit_compiled(name, compiled, key=None, params=None,
     except Exception:
         pass
     hlo_sha = hashlib.sha256(
-        _METADATA_RE.sub("", text).encode()
+        strip_source_metadata(text).encode()
     ).hexdigest()
     audit = ProgramAudit(
         name, key, census, remat, memory, findings, flops, bytes_accessed,

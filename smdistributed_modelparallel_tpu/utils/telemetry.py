@@ -20,8 +20,8 @@ Exports: ``smp.telemetry.report()`` (plain dict), ``render_prometheus()``
 ``scripts/telemetry_report.py`` pretty-prints the dump.
 
 The **watchdog** (``SMP_WATCHDOG_TIMEOUT`` seconds; unset/0 = off) turns
-silent wedges (a stalled collective, a hung device probe — see BENCH_r05's
-eight silent 150 s probe hangs) into actionable dumps: when a guarded
+silent wedges (a stalled collective, a hung device probe) into actionable
+dumps: when a guarded
 operation overruns the timeout, the full registry state, the per-rank
 last-known phase, and every thread's stack are written to stderr and to
 ``SMP_WATCHDOG_PATH`` (default ``smp_watchdog_dump.json``). Pollable waits

@@ -1,0 +1,164 @@
+"""The Pallas kernels of the main path, compiled by the chip's own compiler.
+
+Interpret mode accepts kernels the TPU compiler refuses (too much scoped
+VMEM, an unaligned slice). The compiler is installed here and compiles for a
+chip that is described, not attached — nothing runs, nothing is timed. The
+shapes are the three widths ``BASELINE.json`` names (GPT-2-124M, GPT-2-1.5B,
+GPT-J-6B).
+
+Only one process at a time may load the TPU library, so the topology is
+described inside a fixture (never at import) and everything compiles in the
+test's own process; keep these tests in this one file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A sharding on one described v5e chip. While the module runs the
+    compile cache is off (a described-chip entry cannot be read back and the
+    next compile would warn) and the matmul precision is JAX's default, as on
+    the chip: ``conftest.py`` pins "highest" for the CPU parity tests, under
+    which Mosaic refuses the bf16 dots or asks for more VMEM."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """HLO text of ``fn`` compiled for the described chip. ``shapes`` are
+    bf16 shape tuples, or ``(shape, dtype)`` pairs."""
+    args = []
+    for s in shapes:
+        shape, dtype = s if isinstance(s[0], tuple) else (s, jnp.bfloat16)
+        args.append(jax.ShapeDtypeStruct(shape, dtype, sharding=sharding))
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sum32(x):
+    return jnp.sum(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize(
+    "shape", [(2, 1024, 12, 64), (1, 2048, 16, 256)],
+    ids=["gpt2_124m", "gptj_6b"],
+)
+def test_flash_attention_forward_backward(one_chip, shape):
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, causal=True))
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip, shape, shape, shape
+    )
+    # forward, dq and dk/dv passes
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert name in text
+
+
+@pytest.mark.parametrize(
+    "d_model,vocab", [(768, 50257), (1600, 50257), (4096, 50400)],
+    ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],
+)
+def test_fused_ce_forward_backward(one_chip, d_model, vocab):
+    """``auto_blocks`` sizes the blocks from a VMEM budget that was held
+    against this compiler: the dw backward kernel asks ~1.45x what the
+    budget formula counts."""
+    from smdistributed_modelparallel_tpu.ops import pallas_ce
+
+    rows = 2048
+    bn, bv = pallas_ce.auto_blocks(d_model)
+
+    def loss(x, w, t):
+        return jnp.sum(
+            pallas_ce.fused_lm_head_ce(x, w, t, bn, bv, False, 0.0)
+        )
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1)), one_chip,
+        (rows, d_model), (vocab, d_model), ((rows,), jnp.int32),
+    )
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("smp_ce_fwd", "smp_ce_bwd_dx", "smp_ce_bwd_dw"):
+        assert name in text
+
+
+@pytest.mark.parametrize(
+    "d_in,d_out", [(768, 2304), (4096, 12288)], ids=["gpt2_124m", "gptj_6b"]
+)
+def test_matmul_bias(one_chip, d_in, d_out):
+    from smdistributed_modelparallel_tpu.ops.pallas_qkv import matmul_bias
+
+    text = _compile(
+        lambda x, w, b: matmul_bias(x, w, b), one_chip,
+        (2048, d_in), (d_in, d_out), (d_out,),
+    )
+    assert "tpu_custom_call" in text and "smp_matmul_bias" in text
+
+
+@pytest.mark.parametrize(
+    "features", [3072, 6400, 16384],
+    ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],
+)
+def test_bias_gelu_forward_backward(one_chip, features):
+    """Whole-row blocks asked 25-48 MiB of scoped VMEM at the two wide
+    sizes (limit 16 MiB); the kernel tiles the feature dim now."""
+    from smdistributed_modelparallel_tpu.ops import pallas_gelu
+
+    assert pallas_gelu._fits(features)
+
+    def loss(x, b):
+        return _sum32(pallas_gelu.bias_gelu(x, b))
+
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+        (2048, features), (features,),
+    )
+    assert text.count("tpu_custom_call") >= 2
+    assert "smp_bias_gelu_fwd" in text and "smp_bias_gelu_bwd" in text
+
+
+def test_bias_gelu_refuses_what_cannot_fit():
+    """An untileable width (no 128-multiple divisor) keeps whole rows; past
+    the VMEM budget ``bias_gelu_ok`` says no instead of the compiler."""
+    from smdistributed_modelparallel_tpu.ops import pallas_gelu
+
+    assert pallas_gelu._feature_block(6400) == 640
+    assert pallas_gelu._feature_block(16384) == 1024
+    assert pallas_gelu._feature_block(100) == 100
+    wide_odd = 128 * 1024 + 8
+    assert pallas_gelu._feature_block(wide_odd) == wide_odd
+    assert not pallas_gelu._fits(wide_odd)
+    pallas_gelu.FORCE_INTERPRET, was = True, pallas_gelu.FORCE_INTERPRET
+    try:
+        assert pallas_gelu.bias_gelu_ok("gelu", features=6400)
+        assert not pallas_gelu.bias_gelu_ok("gelu", features=wide_odd)
+    finally:
+        pallas_gelu.FORCE_INTERPRET = was

@@ -289,11 +289,7 @@ class TestCpRealModelFeatures:
             back = cp._zig_exit(z, me, n, CP_AXIS)
             return back, z
 
-        from smdistributed_modelparallel_tpu.utils.jax_compat import (
-            shard_map,
-        )
-
-        shard_fn = shard_map(
+        shard_fn = jax.shard_map(
             body, mesh=state.mesh,
             in_specs=P(None, CP_AXIS),
             out_specs=(P(None, CP_AXIS), P(None, CP_AXIS)),

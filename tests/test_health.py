@@ -25,7 +25,7 @@ import optax
 
 import smdistributed_modelparallel_tpu as smp
 from smdistributed_modelparallel_tpu.backend.state import state
-from smdistributed_modelparallel_tpu.utils import health
+from smdistributed_modelparallel_tpu.utils import health, hlo_audit
 from smdistributed_modelparallel_tpu.utils import telemetry as tel
 from smdistributed_modelparallel_tpu.utils.flight_recorder import flight_recorder
 
@@ -102,10 +102,9 @@ class TestModeAndNoOp:
         plain = jax.jit(make(False)).lower(x).compile().as_text()
         tagged = jax.jit(make(True)).lower(x).compile().as_text()
 
-        def strip(text):
-            return re.sub(r"metadata=\{[^}]*\}", "", text)
-
+        strip = hlo_audit.strip_source_metadata
         assert strip(tagged) == strip(plain)
+        assert "line=" not in strip(plain)
 
     def test_off_mode_step_has_no_sentinel(self, monkeypatch):
         monkeypatch.delenv("SMP_HEALTH_CHECK", raising=False)

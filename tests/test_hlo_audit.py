@@ -154,7 +154,7 @@ class TestRematCensus:
         "%dot.{i} = f32[4,16]{{1,0}} dot(f32[4,8]{{1,0}} %a, "
         "f32[8,16]{{1,0}} %b), lhs_contracting_dims={{1}}, "
         "rhs_contracting_dims={{0}}, metadata={{op_name=\"jit(f)/dot\" "
-        "source_file=\"m.py\" source_line={line}}}\n"
+        "stack_frame_id={line}}}\n"
     )
 
     def test_duplicates_counted_as_recompute(self):

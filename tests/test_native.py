@@ -316,3 +316,19 @@ def test_python_timeline_uses_native(tmp_path, monkeypatch):
     with open(path) as f:
         names = [e["name"] for e in json.load(f)["traceEvents"]]
     assert "phase" in names and "step_3_begin" in names
+
+
+def test_rebuild_is_keyed_on_source_content_not_file_times(monkeypatch):
+    """A fresh copy of the tree gives every file a new time; what says the
+    library is stale is the hash of the sources it was built from."""
+    import os
+
+    assert native.available()      # built (or found current) on first load
+    assert not native._stale()
+    src = os.path.join(native._NATIVE_DIR, "src", "timeline.cc")
+    past = os.path.getmtime(native._LIB_PATH) - 10_000
+    os.utime(native._LIB_PATH, (past, past))   # library "older" than sources
+    os.utime(src)                              # sources "newer"
+    assert not native._stale()
+    monkeypatch.setattr(native, "_source_hash", lambda: "0" * 64)
+    assert native._stale()                     # other sources: rebuild

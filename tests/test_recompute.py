@@ -401,11 +401,19 @@ class TestKnobPlumbing:
 class TestCensusGate:
     def test_gate_pp2_mb8_v2_stash_weight(self):
         """THE acceptance gate: at (pp=2, mb=8, v=2, zero_bubble,
-        stash_weight) the compiled program's FLOP-weighted remat census
-        reads <= 0.35 — vs the committed 0.79-class golden for `full` —
-        with losses/grads allclose to the `full` run and to the pp=1
-        baseline at the existing tolerances. The stash plan's rings must
-        match the planner prediction (machine-checked memory bound)."""
+        stash_weight) the compiled program holds at most half the dot
+        FLOPs of the `full` program at the same config, and the same
+        unique ones — what went is recompute — with losses/grads allclose
+        to the `full` run and to the pp=1 baseline at the existing
+        tolerances. The stash plan's rings must match the planner
+        prediction (machine-checked memory bound).
+
+        The census's FRACTION is held relatively, not to an absolute
+        bound: jax 0.9.0's compiled CPU HLO keeps a source location on few
+        dots (3 of 34 here), so same-shape dots of different layers
+        collide and the fraction is an upper bound (0.50 here where
+        0.4.37 read 0.35, and 0.83 for `full`). Totals and unique FLOPs
+        do not depend on the key."""
         stash, stash_grads, step_fn = _train({
             "pipeline_parallel_degree": 2, "microbatches": 8, "ddp": True,
             "pipeline": "zero_bubble", "virtual_pipeline_degree": 2,
@@ -414,7 +422,6 @@ class TestCensusGate:
         audit = hlo_audit.of_step_function(step_fn)
         if audit is None:
             pytest.skip("AOT step executable unavailable on this backend")
-        assert audit.remat["fraction"] <= 0.35, audit.remat
         # The fingerprint carries the plan; the plan matches the
         # machine-checked ring sizes.
         blk = audit.fingerprint.get("recompute")
@@ -428,16 +435,19 @@ class TestCensusGate:
         plan = remat_plan.plans["zb"]
         assert plan.res_ring_slots == rings["b_to_w"]
         assert plan.stash_bytes == blk["stash_bytes"]
-        # vs the committed `full` golden: the census moved by > 2x.
-        from tests.conftest import golden_hlo_fingerprint
-
-        full_golden = golden_hlo_fingerprint("zero_bubble_pp2_mb4")
-        assert full_golden["remat"]["fraction"] >= 2 * audit.remat["fraction"]
-
-        full, full_grads, _ = _train({
+        full, full_grads, full_fn = _train({
             "pipeline_parallel_degree": 2, "microbatches": 8, "ddp": True,
             "pipeline": "zero_bubble", "virtual_pipeline_degree": 2,
         })
+        # vs the `full` program at the same config.
+        stash_r = audit.remat
+        full_r = hlo_audit.of_step_function(full_fn).remat
+        assert stash_r["flops"] <= 0.5 * full_r["flops"], (stash_r, full_r)
+        assert (
+            stash_r["flops"] - stash_r["recomputed_flops"]
+            == full_r["flops"] - full_r["recomputed_flops"]
+        ), (stash_r, full_r)
+        assert full_r["fraction"] >= 1.5 * stash_r["fraction"]
         base, base_grads, _ = _train({"microbatches": 8})
         _assert_parity(stash, full, stash_grads, full_grads)
         _assert_parity(stash, base, stash_grads, base_grads)

@@ -543,3 +543,38 @@ class TestProbeAndLedgerTools:
             str(tmp_path), max_cold_recoveries=0
         )
         assert gated["problems"] == [], gated["problems"]
+
+
+class TestBf16AndChips:
+    """Found bringing the engine up on the chip (PR 21): nothing had run it
+    under ``bf16: True``, and it counted the host's chips as its own."""
+
+    def test_bf16_engine_matches_generate(self):
+        """The pools take the dtype of the K/V the programs write (the
+        cache used to be shaped from un-cast fp32 parameters, and the bf16
+        programs then failed to trace)."""
+        smp.init({"bf16": True}, devices=jax.devices()[:1])
+        mod = _zoo()
+        params = mod.init(
+            jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+        engine = ServingEngine(
+            mod, params=params, max_slots=2, block_tokens_override=4,
+            prefill_chunk=4,
+        )
+        pools = [
+            leaf for leaf in jax.tree_util.tree_leaves(engine._cache)
+            if leaf.ndim == 5
+        ]
+        assert pools and all(p.dtype == jnp.bfloat16 for p in pools)
+        p = _prompt(50, 6)
+        res = engine.run([ServeRequest("x", p, 4)], timeout_s=300)
+        assert list(res["x"]) == _generate_ref(mod, params, p, 4)
+
+    @pytest.mark.parametrize("n", [1, 2])
+    def test_chips_are_the_mesh_devices(self, n):
+        smp.init({}, devices=jax.devices()[:n])
+        mod = _zoo()
+        params = mod.init(
+            jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+        engine = ServingEngine(mod, params=params, max_slots=2)
+        assert engine._chips == n

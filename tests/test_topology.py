@@ -223,3 +223,14 @@ def test_public_surface_queries():
     )
     with pytest.raises(SMPValidationError):
         smp.get_partition(123)
+
+
+def test_rank_and_size_queries_follow_the_mesh_not_the_host():
+    """A mesh over a subset of the host's devices (the four-chip smoke pins
+    four of the test host's eight): sizes count the mesh's devices."""
+    smp.reset()
+    smp.init({"pipeline_parallel_degree": 2, "tensor_parallel_degree": 2,
+              "ddp": True, "microbatches": 2}, devices=jax.devices()[4:8])
+    assert smp.size() == 4 and smp.local_size() == 4
+    assert smp.rank() == 0 and smp.rdp_size() == 1
+    assert [d.id for d in smp.get_mesh().devices.flat] == [4, 5, 6, 7]
