@@ -1,0 +1,11 @@
+"""Share of device 0's busy time in ops of the backward pass: self time of
+the traced ops whose record in the compiled step's op index
+(``hlo_audit.op_index``) says ``phase == "backward"``."""
+
+from benchmark import loader
+
+_scopes = loader.load_sibling(__file__, "_scopes")
+
+
+def read(ctx):
+    return _scopes.phase_share(ctx, "backward")

@@ -147,7 +147,7 @@ def render(report, out=sys.stdout):
     # -- throughput -----------------------------------------------------
     steps = _value(report, "smp_step_total", 0)
     tokens = _value(report, "smp_step_tokens_total")
-    disp_sum, disp_count = _hist_totals(report, "smp_step_dispatch_seconds")
+    disp_sum, disp_count = _hist_totals(report, "smp_step_time_seconds")
     w("\n-- throughput --\n")
     w(f"steps: {int(steps or 0)}   tokens: {_fmt_num(tokens)}\n")
     if disp_count:

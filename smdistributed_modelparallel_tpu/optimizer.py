@@ -138,7 +138,8 @@ class DistributedOptimizer:
         (``torch/optimizers/optimizer.py:355-391``) — sharded update then
         param allgather; under XLA both emerge from the sharding specs.
         """
-        with profiling.region("optimizer/step"):
+        # It belongs to the step whose call has just returned.
+        with profiling.region("optimizer/step", step=state.step_count - 1):
             self._step_impl()
 
     def _step_impl(self):

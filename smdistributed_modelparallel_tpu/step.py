@@ -102,7 +102,79 @@ class StepFunction:
     def __call__(self, *args, **kwargs):
         if state.cfg is None:
             raise StepUsageError("Call smp.init(config) before invoking an @smp.step function.")
+        # One parent span round the whole call; its children (prepare,
+        # lookup, place, dispatch, install, bookkeeping) cover it, so the
+        # host time a step spends outside its executable has a name.
+        with profiling.region("step", step=state.step_count):
+            return self._call(args, kwargs)
+
+    def _call(self, args, kwargs):
         cfg = state.cfg
+        with profiling.region("step/prepare"):
+            model, stacked_args, stacked_kwargs, bucket_state = (
+                self._prepare(cfg, args, kwargs)
+            )
+            tl = state.timeline
+            telemetry.set_phase(f"step_{state.step_count}")
+            flight_recorder.record_step("begin", state.step_count)
+            # On-demand profiler capture (SMP_PROFILE=steps=N:M / SIGUSR2):
+            # starts exactly at this step's begin edge when armed; a single
+            # attribute test otherwise.
+            profiling.capture.on_step_begin(state.step_count)
+        t_step = time.perf_counter()
+        if tl is not None and tl.enabled:
+            tl.start_step(state.step_count)
+            with tl.span(f"step_{state.step_count}"):
+                grads, outputs = self._run_compiled(
+                    model, stacked_args, stacked_kwargs, bucket_state
+                )
+                with profiling.region("step/fetch"):
+                    jax.block_until_ready(outputs)
+            tl.end_step(state.step_count)
+            tl.flush()
+        else:
+            grads, outputs = self._run_compiled(
+                model, stacked_args, stacked_kwargs, bucket_state
+            )
+        with profiling.region("step/bookkeeping"):
+            # Dispatch wall time: exact when the timeline blocked above,
+            # otherwise a lower bound (async dispatch returns before the
+            # device finishes) — still enough for compile-vs-steady-state
+            # attribution. Log-bucketed, so a tail step stays visible.
+            record_step_time(time.perf_counter() - t_step)
+            # Goodput ledger tick (publish + sentinel window at most once
+            # per tick interval): one attribute test while disarmed.
+            goodput.on_step_edge(state.step_count)
+            profiling.capture.on_step_end(state.step_count, outputs=outputs)
+            flight_recorder.record_step("end", state.step_count)
+            telemetry.counter("smp_step_total", "step invocations").inc()
+            if state.memory_metrics is not None:
+                state.memory_metrics.record_step(state.step_count)
+            from smdistributed_modelparallel_tpu.utils.metrics import (
+                record_device_memory_telemetry,
+            )
+
+            record_device_memory_telemetry()
+            state.step_count += 1
+            # Step edge: the only point where every rank is at a known,
+            # identical position in the program — chaos faults land here
+            # deterministically, and a pending preemption (SIGTERM,
+            # sentinel file, peer notice) turns into the coordinated
+            # emergency checkpoint before the next step's work begins.
+            # Both are single-flag no-ops when disarmed, and the
+            # failure-recovery supervisor's edge hook (close a pending
+            # recovery's MTTR, raise typed on a detected peer failure
+            # before the next dispatch can hang on it) is ONE attribute
+            # test when SMP_SUPERVISOR=off.
+            chaos.on_step_edge(state.step_count)
+            preemption.maybe_emergency_save()
+            if supervisor.active:
+                supervisor.on_step_edge()
+        return StepOutput(outputs)
+
+    def _prepare(self, cfg, args, kwargs):
+        """Model extraction, shape bucketing, microbatch stacking and (on
+        a model's first call) the init / backward-discovery pass."""
         model, clean_args, clean_kwargs = self._extract_model(args, kwargs)
         splitter = TensorSplitter(
             cfg.microbatches, self.non_split_inputs, self.input_split_axes
@@ -137,83 +209,7 @@ class StepFunction:
                 )
 
                 maybe_auto_partition(model)
-
-        tl = state.timeline
-        telemetry.set_phase(f"step_{state.step_count}")
-        flight_recorder.record_step("begin", state.step_count)
-        # On-demand profiler capture (SMP_PROFILE=steps=N:M / SIGUSR2):
-        # starts exactly at this step's begin edge when armed; a single
-        # attribute test otherwise.
-        profiling.capture.on_step_begin(state.step_count)
-        t_step = time.perf_counter()
-        exact_time = False
-        if tl is not None and tl.enabled:
-            tl.start_step(state.step_count)
-            with tl.span(f"step_{state.step_count}"):
-                grads, outputs = self._run_compiled(
-                    model, stacked_args, stacked_kwargs, bucket_state
-                )
-                with profiling.region("step/fetch"):
-                    jax.block_until_ready(outputs)
-            tl.end_step(state.step_count)
-            tl.flush()
-            exact_time = True
-        else:
-            grads, outputs = self._run_compiled(
-                model, stacked_args, stacked_kwargs, bucket_state
-            )
-            if profiling.should_sample_step(state.step_count):
-                # Roofline sample: block on this step's outputs so the
-                # measured time covers device execution. Without it the
-                # async-dispatch time is a lower bound and smp_mfu would
-                # overreport (possibly >1). ~1/16 steps; cost is one
-                # drained dispatch queue.
-                with profiling.region("step/fetch"):
-                    jax.block_until_ready(outputs)
-                exact_time = True
-        # Dispatch wall time: exact when a block happened above, otherwise
-        # a lower bound (async dispatch returns before the device
-        # finishes) — still enough for compile-vs-steady-state attribution.
-        t_step = time.perf_counter() - t_step
-        telemetry.histogram(
-            "smp_step_dispatch_seconds", "host wall time per step dispatch"
-        ).observe(t_step)
-        # Log-bucketed distribution + p50/p90/p99 gauges: the coarse
-        # dispatch histogram above keeps its legacy buckets; this one
-        # resolves tail steps (a p99 blowup is invisible in the mean).
-        record_step_time(t_step)
-        # Goodput ledger tick (publish + sentinel window at most once per
-        # tick interval): one attribute test while disarmed.
-        goodput.on_step_edge(state.step_count)
-        profiling.capture.on_step_end(state.step_count, outputs=outputs)
-        if exact_time:
-            # smp_mfu / smp_roofline_* gauges for this program, from its
-            # cached cost analysis + this step's exact wall time.
-            profiling.record_step_roofline(self._last_runner, t_step)
-        flight_recorder.record_step("end", state.step_count)
-        telemetry.counter("smp_step_total", "step invocations").inc()
-        if state.memory_metrics is not None:
-            state.memory_metrics.record_step(state.step_count)
-        from smdistributed_modelparallel_tpu.utils.metrics import (
-            record_device_memory_telemetry,
-        )
-
-        record_device_memory_telemetry()
-        state.step_count += 1
-        # Step edge: the only point where every rank is at a known,
-        # identical position in the program — chaos faults land here
-        # deterministically, and a pending preemption (SIGTERM, sentinel
-        # file, peer notice) turns into the coordinated emergency
-        # checkpoint before the next step's work begins. Both are
-        # single-flag no-ops when disarmed, and the failure-recovery
-        # supervisor's edge hook (close a pending recovery's MTTR, raise
-        # typed on a detected peer failure before the next dispatch can
-        # hang on it) is ONE attribute test when SMP_SUPERVISOR=off.
-        chaos.on_step_edge(state.step_count)
-        preemption.maybe_emergency_save()
-        if supervisor.active:
-            supervisor.on_step_edge()
-        return StepOutput(outputs)
+        return model, stacked_args, stacked_kwargs, bucket_state
 
     # ------------------------------------------------------------------
 
@@ -281,350 +277,352 @@ class StepFunction:
 
     def _run_compiled(self, model, stacked_args, stacked_kwargs,
                       bucket_state=None):
-        # Chaos seam: `wedge@step=N:ms=M` hangs HERE — inside dispatch,
-        # after the step-begin edge, before the compiled program runs —
-        # so the rank keeps heartbeating (detector thread) while its
-        # reported step edge stalls: the peers' supervisors must classify
-        # it wedged, not dead. One env lookup when disarmed.
-        chaos.on_step_dispatch(state.step_count)
-        cfg = state.cfg
-        mesh = state.mesh
-        num_mb = cfg.microbatches
+        with profiling.region("step/lookup"):
+            # Chaos seam: `wedge@step=N:ms=M` hangs HERE — inside dispatch,
+            # after the step-begin edge, before the compiled program runs —
+            # so the rank keeps heartbeating (detector thread) while its
+            # reported step edge stalls: the peers' supervisors must classify
+            # it wedged, not dead. One env lookup when disarmed.
+            chaos.on_step_dispatch(state.step_count)
+            cfg = state.cfg
+            mesh = state.mesh
+            num_mb = cfg.microbatches
 
-        # Partition the arg tree into scan leaves (DeferredSplit: restacked
-        # to [num_mb, ...] inside the compiled program), broadcast array
-        # leaves, and static leaves.
-        tree = (stacked_args, stacked_kwargs)
-        leaves, treedef = jax.tree_util.tree_flatten(
-            tree, is_leaf=lambda x: isinstance(x, (NonSplit, _ModelRef, DeferredSplit))
-        )
-        scan_idx, bcast_idx, static = [], [], {}
-        scan_vals, bcast_vals, scan_meta = [], [], []
-        for i, leaf in enumerate(leaves):
-            if isinstance(leaf, _ModelRef):
-                static[i] = leaf
-            elif isinstance(leaf, DeferredSplit):
-                scan_idx.append(i)
-                scan_vals.append(leaf.value)
-                scan_meta.append((leaf.axis, leaf.num_mb, leaf.stacked))
-            elif isinstance(leaf, NonSplit):
-                if _is_jax_type(leaf.value):
-                    bcast_idx.append(i)
-                    bcast_vals.append(leaf.value)
-                else:
-                    static[i] = leaf.value
-            else:  # untracked array leaf: broadcast
-                bcast_idx.append(i)
-                bcast_vals.append(leaf)
-
-        # Fused optimizer update (TPU extension, cfg.fused_optimizer_step):
-        # compile the optax update into the step program so a full training
-        # iteration is ONE device launch. Disabled under fp16 loss scaling
-        # (the overflow-skip decision lives in the scaler on the host).
-        opt = state.optimizer
-        fused = (
-            getattr(cfg, "fused_optimizer_step", False)
-            and opt is not None
-            and opt.model is model
-            and state.loss_scaler is None
-            and getattr(self, "_has_backward", True)
-        )
-        if fused:
-            opt._ensure_state()
-
-        # state.generation pins the entry to the topology it was compiled
-        # under: smp.reset()/re-init with a different cfg or mesh must not
-        # serve a stale program whose shapes/flags happen to collide. The
-        # health mode is part of the key: the sentinel reduces live inside
-        # the program, so flipping SMP_HEALTH_CHECK recompiles. The
-        # pipeline shape tuple (pp, schedule, virtual degree, microbatch
-        # math) is keyed explicitly as well: the baked 1F1B schedule and
-        # chunk layout depend on all four, and the key must not rely on
-        # every config change also bumping the generation.
-        hmode = health.mode()
-        # Shape bucketing: a masked (microbatch-weighted) program differs
-        # from the exact-shape program even at identical input shapes, so
-        # the mask flag is part of the key. The weight VECTOR is a device
-        # input — every occupancy of one bucket shares one executable.
-        masked = bucket_state is not None
-        pipe_key = (cfg.pipeline_parallel_degree, cfg.pipeline,
-                    getattr(cfg, "virtual_pipeline_degree", 1),
-                    num_mb, cfg.active_microbatches)
-        # ZeRO knobs change the built program (param sharding layout,
-        # slice-grad restructuring, bucket boundaries) without moving any
-        # shape component — key them explicitly so a knob flip can never
-        # warm-hit a stale executable. Mirrored in the exec-cache's
-        # verified knob facts (utils/exec_cache.py) for the disk entries.
-        # Sub-knobs that cannot affect the program under the current mode
-        # (bucket/prefetch without zero3, the persistence threshold
-        # without any ZeRO param sharding) are canonicalized out so an
-        # idle env var never spuriously invalidates caches.
-        zero3 = cfg.zero3_enabled
-        zero_key = (getattr(cfg, "sharded_params", "none"),
-                    getattr(cfg, "zero3_bucket_mb", 0) if zero3 else 0,
-                    cfg.sdp_param_persistence_threshold
-                    if (zero3 or cfg.zero2d_enabled) else 0,
-                    cfg.sharded_data_parallel_degree,
-                    # Prefetch flips between the transfer-register scan
-                    # and the lifted scan at identical shapes.
-                    zero_mod.prefetch_knob() if zero3 else "-")
-        # Recompute-planner knob: a stash mode rebuilds the pipeline
-        # executors (and the checkpoint policy) at identical shapes, so
-        # the knob must be keyed. Canonicalized so idle values never
-        # move the key: the default ("full") contributes NOTHING — the
-        # key (and the disk key every stored entry and golden hashes)
-        # stays byte-identical to pre-knob builds regardless of stray
-        # budget env vars — and the budget is keyed only under "auto"
-        # (the only mode that reads it).
-        from smdistributed_modelparallel_tpu.parallel import remat_plan
-        rmode = remat_plan.resolve(cfg)
-        # Under "auto", an UNSET budget (-1: planner falls back to the
-        # last audit's temp bytes or its own ring bound) is a different
-        # program than an explicit 0 (degrade everything) — keep them
-        # distinct. The audit-derived default itself is deliberately not
-        # keyed (it is a volatile registry value); a plan drift under the
-        # same key is caught by the disk cache's lowered-module content
-        # hash, costing a verified miss, never a wrong program.
-        _rbudget = getattr(cfg, "recompute_budget_mb", None)
-        recompute_key = (
-            () if rmode == "full"
-            else ((rmode,
-                   (-1 if _rbudget is None else int(_rbudget))
-                   if rmode == "auto" else 0),)
-        )
-        # Overlapped-tp knobs: the ring decomposition and the fused QKV
-        # kernel rebuild the program at identical shapes. Canonicalized
-        # the recompute way: the defaults (mode "off" via
-        # collective_matmul.tp_overlap_mode — which also folds in the
-        # tp<=1 / cp>1 inertness — and fused_qkv False) contribute
-        # NOTHING, so default keys stay byte-identical to pre-knob
-        # builds. Mirrored in the exec-cache knob facts.
-        from smdistributed_modelparallel_tpu.ops.collective_matmul import (
-            fused_qkv_effective,
-            tp_overlap_mode,
-        )
-        tmode = tp_overlap_mode(cfg)
-        _fused_qkv = fused_qkv_effective(cfg)
-        tp_overlap_key = (
-            () if tmode == "off" and not _fused_qkv
-            else ((tmode, _fused_qkv),)
-        )
-        # Low-precision knob, canonicalized the same way: the default
-        # ("bf16", also the pp>1/zero3 fallback via
-        # quant.matmul_precision_mode) contributes NOTHING — default
-        # keys and the committed goldens stay byte-identical — while
-        # fp8 rebuilds the program (quantized seams, the QuantState
-        # input/output) at identical shapes. Mirrored in the exec-cache
-        # knob facts.
-        from smdistributed_modelparallel_tpu import quant as quant_mod
-        qmode = quant_mod.matmul_precision_mode(cfg)
-        quant_key = () if qmode == "bf16" else ((qmode,),)
-        key_pre = (pipe_key, zero_key) + recompute_key + tp_overlap_key + quant_key + (
-                   treedef, tuple(scan_idx), tuple(bcast_idx),
-                   tuple((i, _static_key(v)) for i, v in sorted(static.items())),
-                   tuple((v.shape, str(v.dtype)) for v in scan_vals),
-                   tuple(scan_meta),
-                   tuple((v.shape, str(v.dtype)) for v in bcast_vals),
-                   getattr(self, "_has_backward", True), fused)
-        key_post = (model.training if model is not None else None,
-                    hmode, masked)
-        key = ((state.generation,) + key_pre
-               + (opt._serial if fused else None,) + key_post)
-        # Disk-cache key: generation and optimizer serial are per-process
-        # instance counters that can never match across a restart — the
-        # disk entry drops both and relies on the lowered-module hash
-        # (verified at load) to catch any content difference they guarded.
-        disk_key_src = key_pre + (None,) + key_post
-        compiled = self._cache.get(key)
-        cache_events = telemetry.counter(
-            "smp_step_compile_cache_total",
-            "compiled-step cache lookups by outcome",
-        )
-        if compiled is None:
-            cache_events.labels(event="miss").inc()
-            # Prior-generation entries are unreachable (their key[0] can
-            # never match again) — evict them so re-init cycles don't
-            # accumulate dead compiled executables.
-            stale = [k for k in self._cache if k[0] != state.generation]
-            for k in stale:
-                del self._cache[k]
-            telemetry.set_phase(f"step_{state.step_count}/trace")
-            t_build = time.perf_counter()
-            with profiling.region("step/trace"):
-                compiled = self._build(
-                    model, treedef, scan_idx, bcast_idx, static, num_mb,
-                    scan_meta, opt.build_update_fn() if fused else None,
-                    masked=masked,
-                )
-            t_build = time.perf_counter() - t_build
-            telemetry.histogram(
-                "smp_step_trace_seconds", "step program build/trace wall time"
-            ).observe(t_build)
-            flight_recorder.record_compile("trace", "step", t_build)
-            # The X-ray fingerprint is keyed by this cache key: one audit
-            # per distinct compiled program, re-identifiable across runs.
-            compiled.audit_key = hlo_audit.cache_key_hash(key)
-            compiled.disk_key = exec_cache.stable_key_hash(disk_key_src)
-            self._cache[key] = compiled
-        else:
-            cache_events.labels(event="hit").inc()
-        self._last_runner = compiled
-        tokens = _count_tokens(scan_vals, scan_meta)
-        if tokens:
-            telemetry.counter(
-                "smp_step_tokens_total",
-                "input tokens consumed by step invocations",
-            ).inc(tokens)
-
-        # Device placement: params already sharded; shard batch over data axes
-        # (replicate arrays whose dims don't divide the mesh axes, e.g. tiny
-        # test batches). Skip the dispatch when the leaf already sits on the
-        # target sharding (the steady-state case).
-        scan_vals = [
-            _place(v, _input_sharding(mesh, cfg, v, meta))
-            for v, meta in zip(scan_vals, scan_meta)
-        ]
-        rng = state.step_rng
-        if rng is None:
-            rng = state.rng_manager.next_key("step")
-        loss_scale = _cached_scalar(
-            state.loss_scaler.loss_scale if state.loss_scaler else 1.0
-        )
-        opt_state = opt._opt_state if fused else ()
-        has_backward = getattr(self, "_has_backward", True)
-        if model is not None:
-            # Forgot-optimizer.step() detector (both paths): a pending
-            # fused update OR unconsumed grads with params untouched since
-            # the previous step means the last step's work is being
-            # discarded. Once is normal (an eval step in between);
-            # repeatedly means the model silently never learns. Counter is
-            # per-model (multi-model loops warn for the forgotten one) and
-            # reset by that model's optimizer.step(). Eval-only steps (no
-            # backward) neither produce nor consume updates — a train step
-            # followed by N eval steps before optimizer.step() is a normal
-            # loop shape, so they don't count.
-            stale = model._pending_update is not None or (
-                model._grads_store is not None
-                and model._params is getattr(model, "_params_at_step", None)
+            # Partition the arg tree into scan leaves (DeferredSplit: restacked
+            # to [num_mb, ...] inside the compiled program), broadcast array
+            # leaves, and static leaves.
+            tree = (stacked_args, stacked_kwargs)
+            leaves, treedef = jax.tree_util.tree_flatten(
+                tree, is_leaf=lambda x: isinstance(x, (NonSplit, _ModelRef, DeferredSplit))
             )
-            if (stale and has_backward
-                    and not getattr(cfg, "fused_step_donation", False)):
-                n = getattr(model, "_dropped_updates", 0) + 1
-                model._dropped_updates = n
-                if n == 3:
-                    logger.warning(
-                        "3 training steps ran without optimizer.step(): "
-                        "parameter updates are computed and then "
-                        "discarded, so the model is NOT learning. Call "
-                        "optimizer.step() after each step (or enable "
-                        "fused_step_donation to auto-install updates)."
+            scan_idx, bcast_idx, static = [], [], {}
+            scan_vals, bcast_vals, scan_meta = [], [], []
+            for i, leaf in enumerate(leaves):
+                if isinstance(leaf, _ModelRef):
+                    static[i] = leaf
+                elif isinstance(leaf, DeferredSplit):
+                    scan_idx.append(i)
+                    scan_vals.append(leaf.value)
+                    scan_meta.append((leaf.axis, leaf.num_mb, leaf.stacked))
+                elif isinstance(leaf, NonSplit):
+                    if _is_jax_type(leaf.value):
+                        bcast_idx.append(i)
+                        bcast_vals.append(leaf.value)
+                    else:
+                        static[i] = leaf.value
+                else:  # untracked array leaf: broadcast
+                    bcast_idx.append(i)
+                    bcast_vals.append(leaf)
+
+            # Fused optimizer update (TPU extension, cfg.fused_optimizer_step):
+            # compile the optax update into the step program so a full training
+            # iteration is ONE device launch. Disabled under fp16 loss scaling
+            # (the overflow-skip decision lives in the scaler on the host).
+            opt = state.optimizer
+            fused = (
+                getattr(cfg, "fused_optimizer_step", False)
+                and opt is not None
+                and opt.model is model
+                and state.loss_scaler is None
+                and getattr(self, "_has_backward", True)
+            )
+            if fused:
+                opt._ensure_state()
+
+            # state.generation pins the entry to the topology it was compiled
+            # under: smp.reset()/re-init with a different cfg or mesh must not
+            # serve a stale program whose shapes/flags happen to collide. The
+            # health mode is part of the key: the sentinel reduces live inside
+            # the program, so flipping SMP_HEALTH_CHECK recompiles. The
+            # pipeline shape tuple (pp, schedule, virtual degree, microbatch
+            # math) is keyed explicitly as well: the baked 1F1B schedule and
+            # chunk layout depend on all four, and the key must not rely on
+            # every config change also bumping the generation.
+            hmode = health.mode()
+            # Shape bucketing: a masked (microbatch-weighted) program differs
+            # from the exact-shape program even at identical input shapes, so
+            # the mask flag is part of the key. The weight VECTOR is a device
+            # input — every occupancy of one bucket shares one executable.
+            masked = bucket_state is not None
+            pipe_key = (cfg.pipeline_parallel_degree, cfg.pipeline,
+                        getattr(cfg, "virtual_pipeline_degree", 1),
+                        num_mb, cfg.active_microbatches)
+            # ZeRO knobs change the built program (param sharding layout,
+            # slice-grad restructuring, bucket boundaries) without moving any
+            # shape component — key them explicitly so a knob flip can never
+            # warm-hit a stale executable. Mirrored in the exec-cache's
+            # verified knob facts (utils/exec_cache.py) for the disk entries.
+            # Sub-knobs that cannot affect the program under the current mode
+            # (bucket/prefetch without zero3, the persistence threshold
+            # without any ZeRO param sharding) are canonicalized out so an
+            # idle env var never spuriously invalidates caches.
+            zero3 = cfg.zero3_enabled
+            zero_key = (getattr(cfg, "sharded_params", "none"),
+                        getattr(cfg, "zero3_bucket_mb", 0) if zero3 else 0,
+                        cfg.sdp_param_persistence_threshold
+                        if (zero3 or cfg.zero2d_enabled) else 0,
+                        cfg.sharded_data_parallel_degree,
+                        # Prefetch flips between the transfer-register scan
+                        # and the lifted scan at identical shapes.
+                        zero_mod.prefetch_knob() if zero3 else "-")
+            # Recompute-planner knob: a stash mode rebuilds the pipeline
+            # executors (and the checkpoint policy) at identical shapes, so
+            # the knob must be keyed. Canonicalized so idle values never
+            # move the key: the default ("full") contributes NOTHING — the
+            # key (and the disk key every stored entry and golden hashes)
+            # stays byte-identical to pre-knob builds regardless of stray
+            # budget env vars — and the budget is keyed only under "auto"
+            # (the only mode that reads it).
+            from smdistributed_modelparallel_tpu.parallel import remat_plan
+            rmode = remat_plan.resolve(cfg)
+            # Under "auto", an UNSET budget (-1: planner falls back to the
+            # last audit's temp bytes or its own ring bound) is a different
+            # program than an explicit 0 (degrade everything) — keep them
+            # distinct. The audit-derived default itself is deliberately not
+            # keyed (it is a volatile registry value); a plan drift under the
+            # same key is caught by the disk cache's lowered-module content
+            # hash, costing a verified miss, never a wrong program.
+            _rbudget = getattr(cfg, "recompute_budget_mb", None)
+            recompute_key = (
+                () if rmode == "full"
+                else ((rmode,
+                       (-1 if _rbudget is None else int(_rbudget))
+                       if rmode == "auto" else 0),)
+            )
+            # Overlapped-tp knobs: the ring decomposition and the fused QKV
+            # kernel rebuild the program at identical shapes. Canonicalized
+            # the recompute way: the defaults (mode "off" via
+            # collective_matmul.tp_overlap_mode — which also folds in the
+            # tp<=1 / cp>1 inertness — and fused_qkv False) contribute
+            # NOTHING, so default keys stay byte-identical to pre-knob
+            # builds. Mirrored in the exec-cache knob facts.
+            from smdistributed_modelparallel_tpu.ops.collective_matmul import (
+                fused_qkv_effective,
+                tp_overlap_mode,
+            )
+            tmode = tp_overlap_mode(cfg)
+            _fused_qkv = fused_qkv_effective(cfg)
+            tp_overlap_key = (
+                () if tmode == "off" and not _fused_qkv
+                else ((tmode, _fused_qkv),)
+            )
+            # Low-precision knob, canonicalized the same way: the default
+            # ("bf16", also the pp>1/zero3 fallback via
+            # quant.matmul_precision_mode) contributes NOTHING — default
+            # keys and the committed goldens stay byte-identical — while
+            # fp8 rebuilds the program (quantized seams, the QuantState
+            # input/output) at identical shapes. Mirrored in the exec-cache
+            # knob facts.
+            from smdistributed_modelparallel_tpu import quant as quant_mod
+            qmode = quant_mod.matmul_precision_mode(cfg)
+            quant_key = () if qmode == "bf16" else ((qmode,),)
+            key_pre = (pipe_key, zero_key) + recompute_key + tp_overlap_key + quant_key + (
+                       treedef, tuple(scan_idx), tuple(bcast_idx),
+                       tuple((i, _static_key(v)) for i, v in sorted(static.items())),
+                       tuple((v.shape, str(v.dtype)) for v in scan_vals),
+                       tuple(scan_meta),
+                       tuple((v.shape, str(v.dtype)) for v in bcast_vals),
+                       getattr(self, "_has_backward", True), fused)
+            key_post = (model.training if model is not None else None,
+                        hmode, masked)
+            key = ((state.generation,) + key_pre
+                   + (opt._serial if fused else None,) + key_post)
+            # Disk-cache key: generation and optimizer serial are per-process
+            # instance counters that can never match across a restart — the
+            # disk entry drops both and relies on the lowered-module hash
+            # (verified at load) to catch any content difference they guarded.
+            disk_key_src = key_pre + (None,) + key_post
+            compiled = self._cache.get(key)
+            cache_events = telemetry.counter(
+                "smp_step_compile_cache_total",
+                "compiled-step cache lookups by outcome",
+            )
+            if compiled is None:
+                cache_events.labels(event="miss").inc()
+                # Prior-generation entries are unreachable (their key[0] can
+                # never match again) — evict them so re-init cycles don't
+                # accumulate dead compiled executables.
+                stale = [k for k in self._cache if k[0] != state.generation]
+                for k in stale:
+                    del self._cache[k]
+                telemetry.set_phase(f"step_{state.step_count}/trace")
+                t_build = time.perf_counter()
+                with profiling.region("step/trace"):
+                    compiled = self._build(
+                        model, treedef, scan_idx, bcast_idx, static, num_mb,
+                        scan_meta, opt.build_update_fn() if fused else None,
+                        masked=masked,
                     )
-            # An eval-only step must not clobber the pending train-step
-            # state either: the fused update tuple and the fp16
-            # grads-finite flag belong to the preceding train step and
-            # are consumed by the upcoming optimizer.step().
-            if has_backward:
-                model._params_at_step = model._params
-                model._pending_update = None
-        in_params = model.params
-        extra = ()
-        if masked:
-            extra = (_cached_mb_weights(
-                num_mb, bucket_state["active_mb"], mesh
-            ),)
-        if qmode == "fp8":
-            # The delayed-scaling state rides the step like the fp16
-            # loss scale: last step's scales enter as a program input,
-            # the rolled history + refreshed scales come back as the
-            # program's quant output, absorbed below.
-            extra = extra + (quant_mod.ensure_state().arrays(),)
+                t_build = time.perf_counter() - t_build
+                telemetry.histogram(
+                    "smp_step_trace_seconds", "step program build/trace wall time"
+                ).observe(t_build)
+                flight_recorder.record_compile("trace", "step", t_build)
+                # The X-ray fingerprint is keyed by this cache key: one audit
+                # per distinct compiled program, re-identifiable across runs.
+                compiled.audit_key = hlo_audit.cache_key_hash(key)
+                compiled.disk_key = exec_cache.stable_key_hash(disk_key_src)
+                self._cache[key] = compiled
+            else:
+                cache_events.labels(event="hit").inc()
+            self._last_runner = compiled
+            tokens = _count_tokens(scan_vals, scan_meta)
+            if tokens:
+                telemetry.counter(
+                    "smp_step_tokens_total",
+                    "input tokens consumed by step invocations",
+                ).inc(tokens)
+        with profiling.region("step/place"):
+            # Device placement: params already sharded; shard batch over data axes
+            # (replicate arrays whose dims don't divide the mesh axes, e.g. tiny
+            # test batches). Skip the dispatch when the leaf already sits on the
+            # target sharding (the steady-state case).
+            scan_vals = [
+                _place(v, _input_sharding(mesh, cfg, v, meta))
+                for v, meta in zip(scan_vals, scan_meta)
+            ]
+            rng = state.step_rng
+            if rng is None:
+                rng = state.rng_manager.next_key("step")
+            loss_scale = _cached_scalar(
+                state.loss_scaler.loss_scale if state.loss_scaler else 1.0
+            )
+            opt_state = opt._opt_state if fused else ()
+            has_backward = getattr(self, "_has_backward", True)
+            if model is not None:
+                # Forgot-optimizer.step() detector (both paths): a pending
+                # fused update OR unconsumed grads with params untouched since
+                # the previous step means the last step's work is being
+                # discarded. Once is normal (an eval step in between);
+                # repeatedly means the model silently never learns. Counter is
+                # per-model (multi-model loops warn for the forgotten one) and
+                # reset by that model's optimizer.step(). Eval-only steps (no
+                # backward) neither produce nor consume updates — a train step
+                # followed by N eval steps before optimizer.step() is a normal
+                # loop shape, so they don't count.
+                stale = model._pending_update is not None or (
+                    model._grads_store is not None
+                    and model._params is getattr(model, "_params_at_step", None)
+                )
+                if (stale and has_backward
+                        and not getattr(cfg, "fused_step_donation", False)):
+                    n = getattr(model, "_dropped_updates", 0) + 1
+                    model._dropped_updates = n
+                    if n == 3:
+                        logger.warning(
+                            "3 training steps ran without optimizer.step(): "
+                            "parameter updates are computed and then "
+                            "discarded, so the model is NOT learning. Call "
+                            "optimizer.step() after each step (or enable "
+                            "fused_step_donation to auto-install updates)."
+                        )
+                # An eval-only step must not clobber the pending train-step
+                # state either: the fused update tuple and the fp16
+                # grads-finite flag belong to the preceding train step and
+                # are consumed by the upcoming optimizer.step().
+                if has_backward:
+                    model._params_at_step = model._params
+                    model._pending_update = None
+            in_params = model.params
+            extra = ()
+            if masked:
+                extra = (_cached_mb_weights(
+                    num_mb, bucket_state["active_mb"], mesh
+                ),)
+            if qmode == "fp8":
+                # The delayed-scaling state rides the step like the fp16
+                # loss scale: last step's scales enter as a program input,
+                # the rolled history + refreshed scales come back as the
+                # program's quant output, absorbed below.
+                extra = extra + (quant_mod.ensure_state().arrays(),)
         (grads, outputs, grads_finite, next_rng, fused_out, health_word,
          quant_out) = (
             compiled(in_params, opt_state, scan_vals, bcast_vals, rng,
                      loss_scale, *extra)
         )
-        if qmode == "fp8" and quant_out:
-            quant_mod.ensure_state().absorb(quant_out)
-        state.step_rng = next_rng
-        schema = list(getattr(compiled, "health_schema", ()) or ())
-        if schema:
-            # Submit the still-on-device health word: the PREVIOUS step's
-            # word is decoded now (its step has finished — no sync on the
-            # step just dispatched). The bisector retains references to the
-            # exact dispatched inputs so a trip can re-run this step
-            # eagerly with per-module checkpoints.
-            bisect_fn = None
-            if model is not None and model._output_aval is not None:
-                reconstruct = self._make_reconstruct(
-                    model, treedef, scan_idx, bcast_idx, static
-                )
-
-                def mb_args(mb, _sv=tuple(scan_vals), _sm=tuple(scan_meta),
-                            _bv=tuple(bcast_vals), _rc=reconstruct):
-                    # Restack on the host: the dispatched inputs are
-                    # batch-sharded over the data axes, and an eager
-                    # reshape of such an array to [num_mb, mb, ...] has
-                    # no sharding to give its result.
-                    leaves = [
-                        stack_leaf(
-                            np.asarray(v) if v.is_fully_addressable else v,
-                            *m,
-                        )[mb]
-                        for v, m in zip(_sv, _sm)
-                    ]
-                    return _rc(leaves, list(_bv))
-
-                # in_params: the exact tree this step consumed. Retaining
-                # it for one step keeps bisection honest when an optimizer
-                # update lands before the word is decoded (it is dropped
-                # with the pending entry; donated trees are detected and
-                # fall back to the live params).
-                bisect_fn = health.make_bisector(
-                    model, self.fn, mb_args, num_mb, rng, has_backward,
-                    step_params=in_params,
-                )
-            health.monitor.submit(
-                state.step_count, health_word, schema, hmode, bisect_fn
-            )
-        if model is not None and has_backward:
-            model._grads_finite = grads_finite
-            if grads is not None:
-                raw_div = getattr(compiled, "raw_divisor", None)
-                if raw_div:
-                    if masked:
-                        # The raw accumulator holds only the active
-                        # microbatches (padding carries zero weight); the
-                        # lazy mean divides by the live active count.
-                        raw_div = bucket_state["active_mb"]
-                    model._set_raw_grads(grads, raw_div)
-                else:
-                    model._grads = grads
-            if fused:
-                if getattr(cfg, "fused_step_donation", False):
-                    # Donated inputs are gone: install the update NOW and
-                    # leave a self-consistent pending tuple so a following
-                    # optimizer.step() no-ops instead of re-applying.
-                    model.params = fused_out[0]
-                    opt._opt_state = fused_out[1]
-                    model._pending_update = (
-                        grads, fused_out[0], fused_out[1],
-                        fused_out[0], fused_out[1],
+        with profiling.region("step/install"):
+            if qmode == "fp8" and quant_out:
+                quant_mod.ensure_state().absorb(quant_out)
+            state.step_rng = next_rng
+            schema = list(getattr(compiled, "health_schema", ()) or ())
+            if schema:
+                # Submit the still-on-device health word: the PREVIOUS step's
+                # word is decoded now (its step has finished — no sync on the
+                # step just dispatched). The bisector retains references to the
+                # exact dispatched inputs so a trip can re-run this step
+                # eagerly with per-module checkpoints.
+                bisect_fn = None
+                if model is not None and model._output_aval is not None:
+                    reconstruct = self._make_reconstruct(
+                        model, treedef, scan_idx, bcast_idx, static
                     )
-                else:
-                    # Tokens of the exact inputs the fused update consumed:
-                    # optimizer.step() installs the precomputed result only
-                    # if neither grads, params, nor opt_state were replaced
-                    # since.
-                    model._pending_update = (
-                        grads, fused_out[0], fused_out[1], in_params,
-                        opt_state,
+
+                    def mb_args(mb, _sv=tuple(scan_vals), _sm=tuple(scan_meta),
+                                _bv=tuple(bcast_vals), _rc=reconstruct):
+                        # Restack on the host: the dispatched inputs are
+                        # batch-sharded over the data axes, and an eager
+                        # reshape of such an array to [num_mb, mb, ...] has
+                        # no sharding to give its result.
+                        leaves = [
+                            stack_leaf(
+                                np.asarray(v) if v.is_fully_addressable else v,
+                                *m,
+                            )[mb]
+                            for v, m in zip(_sv, _sm)
+                        ]
+                        return _rc(leaves, list(_bv))
+
+                    # in_params: the exact tree this step consumed. Retaining
+                    # it for one step keeps bisection honest when an optimizer
+                    # update lands before the word is decoded (it is dropped
+                    # with the pending entry; donated trees are detected and
+                    # fall back to the live params).
+                    bisect_fn = health.make_bisector(
+                        model, self.fn, mb_args, num_mb, rng, has_backward,
+                        step_params=in_params,
                     )
-        if masked and bucket_state["active_mb"] < num_mb:
-            # Padded microbatches computed garbage under a zero weight;
-            # the user-visible StepOutput carries only the real ones
-            # (padding is whole trailing microbatches by construction).
-            act = bucket_state["active_mb"]
-            outputs = jax.tree_util.tree_map(lambda x: x[:act], outputs)
+                health.monitor.submit(
+                    state.step_count, health_word, schema, hmode, bisect_fn
+                )
+            if model is not None and has_backward:
+                model._grads_finite = grads_finite
+                if grads is not None:
+                    raw_div = getattr(compiled, "raw_divisor", None)
+                    if raw_div:
+                        if masked:
+                            # The raw accumulator holds only the active
+                            # microbatches (padding carries zero weight); the
+                            # lazy mean divides by the live active count.
+                            raw_div = bucket_state["active_mb"]
+                        model._set_raw_grads(grads, raw_div)
+                    else:
+                        model._grads = grads
+                if fused:
+                    if getattr(cfg, "fused_step_donation", False):
+                        # Donated inputs are gone: install the update NOW and
+                        # leave a self-consistent pending tuple so a following
+                        # optimizer.step() no-ops instead of re-applying.
+                        model.params = fused_out[0]
+                        opt._opt_state = fused_out[1]
+                        model._pending_update = (
+                            grads, fused_out[0], fused_out[1],
+                            fused_out[0], fused_out[1],
+                        )
+                    else:
+                        # Tokens of the exact inputs the fused update consumed:
+                        # optimizer.step() installs the precomputed result only
+                        # if neither grads, params, nor opt_state were replaced
+                        # since.
+                        model._pending_update = (
+                            grads, fused_out[0], fused_out[1], in_params,
+                            opt_state,
+                        )
+            if masked and bucket_state["active_mb"] < num_mb:
+                # Padded microbatches computed garbage under a zero weight;
+                # the user-visible StepOutput carries only the real ones
+                # (padding is whole trailing microbatches by construction).
+                act = bucket_state["active_mb"]
+                outputs = jax.tree_util.tree_map(lambda x: x[:act], outputs)
         return grads, outputs
 
     @staticmethod
@@ -702,7 +700,8 @@ class StepFunction:
             # loop-invariant, and differentiating w.r.t. the half params is
             # numerically identical (the astype VJP is an exact bf16->fp32
             # upcast of the cotangent, applied below at accumulation).
-            run_params = half_cast_util(params, half)
+            with profiling.named_region("smp/step/cast_params"):
+                run_params = half_cast_util(params, half)
             if has_backward:
                 def scaled_fwd(run_params, mb_leaves, bcast_leaves, key):
                     loss, out = mb_forward(run_params, mb_leaves, bcast_leaves, key)
@@ -809,9 +808,7 @@ class StepFunction:
                             lambda g: wmb.astype(g.dtype) * g, grads
                         )
                         loss_v = loss_v * wmb
-                    acc = jax.tree_util.tree_map(
-                        lambda a, g: a + g.astype(a.dtype), acc, grads
-                    )
+                    acc = _accumulate(acc, grads)
                     ys = (out, loss_v) if hc is not None else out
                     return acc, ys
 
@@ -840,9 +837,7 @@ class StepFunction:
                             lambda g: wmb.astype(g.dtype) * g, grads
                         )
                         loss_v = loss_v * wmb
-                    acc = jax.tree_util.tree_map(
-                        lambda a, g: a + g.astype(a.dtype), acc, grads
-                    )
+                    acc = _accumulate(acc, grads)
                     # Health sentinel: the per-microbatch loss rides out of
                     # the scan so the word records the FIRST bad microbatch.
                     ys = (out, loss_v) if hc is not None else out
@@ -1367,6 +1362,16 @@ def _make_runner(step_impl, name, scan_meta, fused_update, model,
     run.raw_divisor = raw_divisor if fused_update is not None else None
     run.health_schema = schema_box
     return run
+
+
+def _accumulate(acc, grads):
+    """One microbatch's gradients added into the accumulator, in the
+    accumulator's dtype (under a scope of its own: the device trace then
+    says what accumulation costs)."""
+    with profiling.named_region("smp/step/accumulate"):
+        return jax.tree_util.tree_map(
+            lambda a, g: a + g.astype(a.dtype), acc, grads
+        )
 
 
 def _count_tokens(scan_vals, scan_meta):

@@ -286,37 +286,168 @@ def _attribute_pairs(pairs, maps, use_global_ids):
     return axes[axis_hit] if axis_hit is not None else "unattributed"
 
 
+# ----------------------------------------------------------------------
+# Op index: one record per instruction, from the one walk over the text
+# ----------------------------------------------------------------------
+
+#: The phases an instruction can belong to (``phase_of``), in the order
+#: the rules are tried.
+PHASES = ("optimizer", "recompute", "backward", "forward", "other")
+
+_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_SCOPE_RE = re.compile(r"smp/[\w\-]+/[\w\-]+")
+# XLA's own rematerialization pass clones an instruction under its name
+# plus ``.remat`` / ``.remat2`` (/ ``.remat.3`` for the tuple elements).
+_XLA_REMAT_RE = re.compile(r"\.remat\d*(?:\.\d+)?$")
+_BWD_TICKS = ("smp/pipeline/tick_bwd", "smp/pipeline/cooldown_weight")
+_BWD_SCOPES = _BWD_TICKS + ("smp/step/accumulate",)
+_FWD_SCOPES = ("smp/pipeline/embed", "smp/pipeline/tick_fwd",
+               "smp/pipeline/head", "smp/step/cast_params")
+# The ``-start`` a ``-done`` waits for (operand types hold no ``%``).
+_DONE_OPERAND_RE = re.compile(r"-done\([^%]*%([\w.\-]+)")
+
+
+def phase_of(op_name, instr_name=""):
+    """Which part of the training step an instruction belongs to, from the
+    markers JAX and this library write into its ``op_name`` (first match
+    wins; of a ``;``-joined op_name the first part decides):
+
+    - ``optimizer``: under the ``smp/optimizer/update`` scope;
+    - ``recompute``: ``rematted_computation`` (``jax.checkpoint``), an
+      instruction XLA's rematerialization cloned (``.remat`` in its
+      name), or the forward half (``jvp(`` without ``transpose(``) inside
+      a pipeline backward tick, which runs the stage's forward again;
+    - ``backward``: ``transpose(``, a ``smp/pipeline/tick_bwd*`` /
+      ``cooldown_weight`` scope, or ``smp/step/accumulate`` (microbatch
+      gradients added into the accumulator);
+    - ``forward``: ``jvp(``, ``smp/pipeline/{embed,tick_fwd,head}``, or
+      ``smp/step/cast_params`` (the half-precision copy the forward reads);
+    - ``other``: none of these (no metadata, schedule glue, RNG).
+    """
+    first = op_name.split(";", 1)[0]
+    if "smp/optimizer/update" in first:
+        return "optimizer"
+    transposed = "transpose(" in first
+    in_bwd_tick = any(s in first for s in _BWD_TICKS)
+    if ("rematted_computation" in first
+            or _XLA_REMAT_RE.search(instr_name)
+            or (in_bwd_tick and "jvp(" in first and not transposed)):
+        return "recompute"
+    if transposed or any(s in first for s in _BWD_SCOPES):
+        return "backward"
+    if "jvp(" in first or any(s in first for s in _FWD_SCOPES):
+        return "forward"
+    return "other"
+
+
+def scope_of(op_name):
+    """The ``smp/<subsystem>/<name>`` segment nearest the leaf, or None."""
+    found = _SCOPE_RE.findall(op_name.split(";", 1)[0])
+    return found[-1] if found else None
+
+
+def _collective_axis(op, line, mesh, maps):
+    use_global = "use_global_device_ids=true" in line
+    if op == "collective-permute":
+        return _attribute_pairs(_parse_pairs(line), maps, use_global)
+    groups = _parse_replica_groups(line)
+    if groups is None:
+        return "unattributed"
+    if groups == "all":
+        return "world"
+    return _attribute_groups(groups, mesh, maps, use_global)
+
+
+def op_records(hlo_text, mesh=None):
+    """``{instruction name: record}`` over every instruction of the HLO
+    text, in text order, keyed as a device trace prints the name (no
+    ``%``). A record holds ``phase`` (``phase_of``) and ``scope``
+    (``scope_of``); a collective's also ``op``, ``axis`` (the mesh-axis
+    label of its ``replica_groups`` / ``source_target_pairs``) and
+    ``bytes`` (per-device result payload). The ``-done`` half of an async
+    pair takes its axis from its ``-start`` and is marked ``done`` (the
+    census counts the pair once, a trace times both halves).
+
+    An instruction the compiler left without ``op_name`` takes that of
+    the computation it calls (a fusion: its root's, else the first one
+    inside), so a fusion is never anonymous where its body is not; and
+    one whose ``op_name`` holds no marker takes the phase of its first
+    operand that has one. Both hold for an executable that a compile
+    cache filled by an older build hands back, whose metadata is that
+    build's and lacks any scope added since."""
+    records = {}
+    maps = _mesh_coord_maps(mesh)
+    comp = None
+    comp_op_name = {}    # computation -> its root's op_name, else the first
+    for lineno, line in enumerate(hlo_text.splitlines()):
+        header = _COMP_HEADER_RE.match(line)
+        if header is not None:
+            comp = header.group(1)
+            continue
+        named = _INSTR_NAME_RE.match(line)
+        coll = _COLL_RE.search(line)
+        if named is None and coll is None:
+            continue
+        name = named.group(1) if named else f"#{lineno}"
+        if name in records:      # hand-written text; XLA's names are unique
+            name = f"{name}#{lineno}"
+        found = _OP_NAME_RE.search(line)
+        op_name = found.group(1) if found else ""
+        if op_name:
+            if "ROOT " in line[:line.find("=")]:
+                comp_op_name[comp] = op_name
+            else:
+                comp_op_name.setdefault(comp, op_name)
+        else:
+            called = _CALLS_RE.search(line)
+            if called is not None:
+                op_name = comp_op_name.get(called.group(1), "")
+        rec = {"phase": phase_of(op_name, name), "scope": scope_of(op_name)}
+        if rec["phase"] == "other" and named is not None:
+            # No marker of its own (a compiler-made copy, the add that
+            # accumulates gradients): it works on what its first marked
+            # operand produced. Operands come first in the text, so this
+            # follows a chain (copy of get-tuple-element of a while).
+            for ref in _REF_RE.findall(line[named.end():]):
+                phase = records.get(ref, rec)["phase"]
+                if phase != "other":
+                    rec["phase"] = phase
+                    break
+        if coll is not None:
+            op = coll.group("op")
+            if coll.group("suffix") == "-done":
+                start = _DONE_OPERAND_RE.search(line)
+                start = records.get(start.group(1), {}) if start else {}
+                rec.update(op=op, bytes=0, done=True,
+                           axis=start.get("axis", "unattributed"))
+            else:
+                rec.update(op=op, bytes=_shape_bytes(coll.group("shape")),
+                           axis=_collective_axis(op, line, mesh, maps))
+        records[name] = rec
+    return records
+
+
+def census_of(records):
+    """The collective census as a sum over ``op_records``."""
+    census = {}
+    for rec in records.values():
+        if "op" not in rec or rec.get("done"):
+            continue
+        ent = census.setdefault(
+            rec["op"], {"count": 0, "bytes": 0, "axes": {}})
+        ent["count"] += 1
+        ent["bytes"] += rec["bytes"]
+        ax = ent["axes"].setdefault(rec["axis"], {"count": 0, "bytes": 0})
+        ax["count"] += 1
+        ax["bytes"] += rec["bytes"]
+    return census
+
+
 def collective_census(hlo_text, mesh=None):
     """``{op: {"count", "bytes", "axes": {label: {"count", "bytes"}}}}``
     over every collective instruction in the HLO text. ``bytes`` is the
     per-device result payload (summed over tuple elements)."""
-    census = {}
-    maps = _mesh_coord_maps(mesh)
-    for line in hlo_text.splitlines():
-        m = _COLL_RE.search(line)
-        if m is None or m.group("suffix") == "-done":
-            continue
-        op = m.group("op")
-        nbytes = _shape_bytes(m.group("shape"))
-        use_global = "use_global_device_ids=true" in line
-        if op == "collective-permute":
-            pairs = _parse_pairs(line)
-            axis = _attribute_pairs(pairs, maps, use_global)
-        else:
-            groups = _parse_replica_groups(line)
-            if groups is None:
-                axis = "unattributed"
-            elif groups == "all":
-                axis = "world"
-            else:
-                axis = _attribute_groups(groups, mesh, maps, use_global)
-        ent = census.setdefault(op, {"count": 0, "bytes": 0, "axes": {}})
-        ent["count"] += 1
-        ent["bytes"] += nbytes
-        ax = ent["axes"].setdefault(axis, {"count": 0, "bytes": 0})
-        ax["count"] += 1
-        ax["bytes"] += nbytes
-    return census
+    return census_of(op_records(hlo_text, mesh))
 
 
 def remat_census(hlo_text):
@@ -1181,7 +1312,7 @@ class ProgramAudit:
 
     def __init__(self, name, key, census, remat, memory, findings,
                  flops, bytes_accessed, hlo_sha256, config, zero=None,
-                 recompute=None, tp_overlap=None, quant=None):
+                 recompute=None, tp_overlap=None, quant=None, op_index=None):
         self.name = name
         self.key = key
         self.census = census
@@ -1196,6 +1327,9 @@ class ProgramAudit:
         self.recompute = recompute
         self.tp_overlap = tp_overlap
         self.quant = quant
+        # ``op_records`` of the program: held in memory for whoever joins
+        # a device trace to it (``op_index``), never persisted or hashed.
+        self.op_index = op_index or {}
         self.fingerprint = self._fingerprint()
         self.fingerprint_hash = fingerprint_hash(self.fingerprint)
 
@@ -1328,7 +1462,8 @@ def audit_compiled(name, compiled, key=None, params=None,
     except Exception:
         pass
     text = compiled.as_text()
-    census = collective_census(text, mesh=mesh)
+    index = op_records(text, mesh=mesh)
+    census = census_of(index)
     remat = remat_census(text)
     memory = memory_breakdown(compiled)
     zero = None
@@ -1394,7 +1529,7 @@ def audit_compiled(name, compiled, key=None, params=None,
     audit = ProgramAudit(
         name, key, census, remat, memory, findings, flops, bytes_accessed,
         hlo_sha, _config_snapshot(cfg), zero=zero, recompute=recompute,
-        tp_overlap=tp_overlap, quant=quant,
+        tp_overlap=tp_overlap, quant=quant, op_index=index,
     )
     if publish:
         # Unpublished audits stay out of the registry too: a verification
@@ -1471,7 +1606,17 @@ def republish(audit, seconds=0.0):
 
 
 #: Latest audit per program name (``step``, ``step_pipeline_1f1b``, ...).
+#: Process-global: it outlives ``smp.shutdown()``.
 audits = {}
+
+
+def op_index(name):
+    """The op index (``op_records``) of the latest audit of program
+    ``name``: ``{instruction name: {"phase", "scope"[, "op", "axis",
+    "bytes"]}}``, the join key to a device trace's op names. ``{}`` when
+    that program was never audited (``SMP_HLO_AUDIT=off``)."""
+    audit = audits.get(name)
+    return audit.op_index if audit is not None else {}
 
 
 def of_step_function(step_fn):

@@ -12,18 +12,40 @@ ad-hoc probes. Four cooperating pieces:
 1. **Named regions** — one vocabulary for every profiling surface.
    ``region(name)`` brackets a host-side phase with
    ``jax.profiler.TraceAnnotation`` (so the region shows up, by the same
-   name, in an XLA profiler trace) AND a ``state.timeline`` span (so
-   ``scripts/trace_fuse.py`` can align it cross-rank and report per-phase
-   skew). ``named_region(name)`` is the in-graph twin: a
-   ``jax.named_scope`` whose name lands in the compiled HLO's op
-   metadata, tagging pipeline warmup/steady/cooldown phases, per-tick
-   sub-steps — with the pass coordinate under split-backward schedules:
-   ``smp/pipeline/tick_fwd``, ``tick_bwd`` (fused executors) vs
-   ``tick_bwd_input`` / ``tick_bwd_weight`` (zero-bubble), plus the
-   ZB-only ``cooldown_weight`` drain segment — and the optimizer update
-   inside the device timeline. Wired through the step engine
-   (trace/compile/dispatch/fetch), all pipeline executors, host
-   collectives, and ``optimizer.step``.
+   name, in an XLA profiler trace, on the device trace's clock) AND a
+   ``state.timeline`` span (so ``scripts/trace_fuse.py`` can align it
+   cross-rank and report per-phase skew), and observes its duration in
+   the one histogram family ``smp_host_phase_seconds{phase=<name>}``.
+   Regions nest on a thread-local stack: each writes the step it belongs
+   to and its parent into the annotation (``step=``, ``parent=``) and
+   the timeline event. The regions in use:
+
+   - ``step`` round ``StepFunction.__call__``, and its children, which
+     together cover it: ``step/prepare`` (model extraction, bucketing,
+     microbatch stacking, init/discover, begin-edge hooks),
+     ``step/lookup`` (cache key and get; on a miss ``step/trace`` inside
+     it), ``step/place`` (input placement up to the executable call),
+     ``step/dispatch`` (the executable call alone; on a first call
+     ``step/lower`` and ``step/compile`` with ``step/exec_cache_load`` /
+     ``_store`` before it), ``step/install`` (from its return: update,
+     finite flag, health word), ``step/bookkeeping`` (telemetry, flight
+     recorder, goodput, memory telemetry, chaos / preemption /
+     supervisor edges); ``step/fetch`` only where the timeline blocks;
+   - ``optimizer/step``; ``collective/broadcast``, ``allgather``,
+     ``barrier/<group>`` (host collectives); ``serve/compile_<kind>``,
+     ``serve/decode_step``, ``serve/prefill_chunk``.
+
+   ``named_region(name)`` is the in-graph twin: a ``jax.named_scope``
+   whose name lands in the compiled HLO's op metadata, tagging pipeline
+   warmup/steady/cooldown phases, per-tick sub-steps — with the pass
+   coordinate under split-backward schedules: ``smp/pipeline/tick_fwd``,
+   ``tick_bwd`` (fused executors) vs ``tick_bwd_input`` /
+   ``tick_bwd_weight`` (zero-bubble), plus the ZB-only
+   ``cooldown_weight`` drain segment — the optimizer update
+   (``smp/optimizer/update``), gradient accumulation
+   (``smp/step/accumulate``) and the half-precision parameter cast
+   (``smp/step/cast_params``) inside the device timeline.
+   ``hlo_audit.op_index`` reads them back per instruction.
 
 2. **On-demand capture** — ``SMP_PROFILE=steps=N:M`` brackets
    ``jax.profiler.start_trace``/``stop_trace`` around exactly steps
@@ -43,9 +65,9 @@ ad-hoc probes. Four cooperating pieces:
    compute-vs-comm-vs-bubble decomposition of the step time (bubble from
    the pipeline occupancy gauges). Published as ``smp_mfu`` /
    ``smp_roofline_*`` gauges and rendered by the "performance" section of
-   ``scripts/telemetry_report.py``. The step engine calls
-   ``record_step_roofline`` on every dispatch, so a run on known hardware
-   carries its MFU in every telemetry dump with no extra configuration.
+   ``scripts/telemetry_report.py``. The caller brings the step time,
+   measured to the end of the device's work: the step engine blocks no
+   step to take one.
 
 4. **Breakdown API** — ``StepBreakdown`` collects named component
    timings and emits them in the same one-JSON-object-per-line schema
@@ -70,7 +92,10 @@ import time
 import jax
 
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
-from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+from smdistributed_modelparallel_tpu.utils.telemetry import (
+    HOST_PHASE_BUCKETS,
+    telemetry,
+)
 
 logger = get_logger()
 
@@ -96,24 +121,50 @@ def _timeline():
     return state.timeline
 
 
+_open = threading.local()    # .stack: this thread's open regions, outermost first
+
+
+def _open_regions():
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
 class _Region:
     """One named host-side profiler region (see ``region``)."""
 
-    __slots__ = ("name", "track", "_ta", "_tl", "_begin_us")
+    __slots__ = ("name", "track", "step", "parent", "_ta", "_tl",
+                 "_begin_us", "_t0")
 
-    def __init__(self, name, track):
+    def __init__(self, name, track, step=None):
         self.name = name
         self.track = track
+        self.step = step
+        self.parent = None
         self._ta = None
         self._tl = None
         self._begin_us = 0.0
+        self._t0 = 0.0
 
     def __enter__(self):
+        stack = _open_regions()
+        if stack:
+            self.parent = stack[-1].name
+            if self.step is None:
+                self.step = stack[-1].step
+        stack.append(self)
+        stats = {}
+        if self.step is not None:
+            stats["step"] = self.step
+        if self.parent is not None:
+            stats["parent"] = self.parent
         # TraceAnnotation is a TraceMe under the hood: near-free when no
         # profiler session is active, and a named host event when one is —
-        # exactly the "same region names in the XLA trace" contract.
+        # exactly the "same region names in the XLA trace" contract. The
+        # step and the enclosing region ride along as the event's stats.
         try:
-            self._ta = jax.profiler.TraceAnnotation(self.name)
+            self._ta = jax.profiler.TraceAnnotation(self.name, **stats)
             self._ta.__enter__()
         except Exception:  # pragma: no cover - profiler backend quirks
             self._ta = None
@@ -121,30 +172,46 @@ class _Region:
         if tl is not None and tl.enabled:
             self._tl = tl
             self._begin_us = tl._now_us()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
         if self._tl is not None:
             self._tl.record_event(
                 self.name, self._begin_us, self._tl._now_us(),
-                track=self.track,
+                track=self.track, parent=self.parent,
             )
         if self._ta is not None:
             self._ta.__exit__(*exc)
+        stack = _open_regions()
+        if self in stack:            # and whatever was left open inside it
+            del stack[stack.index(self):]
+        telemetry.histogram(
+            "smp_host_phase_seconds",
+            "host wall time of each profiling.region() by phase name "
+            "(the step engine's phases cover StepFunction.__call__)",
+            buckets=HOST_PHASE_BUCKETS,
+        ).labels(phase=self.name[len(REGION_PREFIX):]).observe(seconds)
         return False
 
 
-def region(name, track="phase"):
+def region(name, track="phase", step=None):
     """Context manager: one named host-side profiler region.
 
     Emits the region under ``smp_phase/<name>`` to BOTH observability
     surfaces at once: a ``jax.profiler.TraceAnnotation`` (visible in an
     XLA profiler capture) and a ``state.timeline`` span on the ``phase``
     track (visible in the fused Perfetto view; ``trace_fuse.py`` computes
-    per-phase cross-rank skew from these). No-op-cheap when neither a
-    profiler session nor the timeline is active.
+    per-phase cross-rank skew from these), and observes its duration in
+    the ``smp_host_phase_seconds{phase=<name>}`` histogram. Regions nest:
+    each knows the region open round it on its thread (``parent``) and
+    the training step it belongs to (``step``: given, else its
+    parent's), and writes both into the annotation's stats and the
+    timeline event. Microseconds when neither a profiler session nor the
+    timeline is active.
     """
-    return _Region(REGION_PREFIX + name, track)
+    return _Region(REGION_PREFIX + name, track, step)
 
 
 def named_region(name):
@@ -657,48 +724,6 @@ def _publish(r):
             "smp_roofline_compute_bound",
             "1 when arithmetic intensity sits above the ridge point",
         ).labels(**lab).set(1.0 if r.bound == "compute" else 0.0)
-
-
-ROOFLINE_SAMPLE_EVERY = 16
-
-
-def should_sample_step(step):
-    """Steps where the engine blocks on the step's outputs to measure an
-    EXACT wall time for the roofline gauges (step 1, then every 16th).
-
-    Without a block, async dispatch returns long before the device
-    finishes; dividing program FLOPs by that lower-bound time would
-    publish an upper-bound — i.e. wrong, possibly >1.0 — MFU. Sampling
-    keeps the gauges honest at ~zero throughput cost (one drained
-    dispatch queue per 16 steps)."""
-    return step % ROOFLINE_SAMPLE_EVERY == 1
-
-
-def record_step_roofline(runner, step_time_s):
-    """Per-step hook from the step engine: publish ``smp_mfu`` and the
-    decomposition for this runner's program, costing a few float ops.
-
-    The runner's compiled cost analysis is read once and cached on the
-    runner; attribution is skipped entirely (cached as unavailable) when
-    the executable or its cost analysis is missing. The engine only calls
-    this with EXACT step times — the timeline-blocked path, or a sampled
-    ``should_sample_step`` block — never the async-dispatch lower bound.
-    """
-    if runner is None or not step_time_s:
-        return None
-    cached = getattr(runner, "_roofline_cost", None)
-    if cached is None:
-        compiled = runner.holder.get("compiled") if hasattr(runner, "holder") else None
-        cost = cost_of(compiled) if compiled is not None else (None, None)
-        cached = cost if cost[0] is not None else False
-        runner._roofline_cost = cached
-    if cached is False:
-        return None
-    flops, nbytes = cached
-    return roofline(
-        getattr(runner, "step_name", "step"),
-        step_time_s=step_time_s, flops=flops, bytes_accessed=nbytes,
-    )
 
 
 # ----------------------------------------------------------------------

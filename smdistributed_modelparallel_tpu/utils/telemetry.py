@@ -34,6 +34,7 @@ an accelerator backend (stdlib + the package logger/exceptions only).
 """
 
 import atexit
+import bisect
 import copy
 import json
 import os
@@ -78,6 +79,9 @@ def _geometric_buckets(lo, hi, growth):
 # ITL, prefill, decode step) and training step times all live in this
 # range; the relative quantile error is bounded by the growth factor.
 LATENCY_BUCKETS = _geometric_buckets(5e-4, 240.0, 1.3)
+# The step engine's host phases take tens of microseconds each (measured
+# on the chip, PERF.md): the same growth from 5 us, ~70 buckets.
+HOST_PHASE_BUCKETS = _geometric_buckets(5e-6, 240.0, 1.3)
 
 #: Serving latency distributions the engine feeds (the ``kind`` label of
 #: ``smp_serve_latency_seconds`` and the stem of the per-kind gauges).
@@ -278,10 +282,8 @@ class _Child:
         if self._kind != "histogram":
             raise ValueError("observe() is histogram-only")
         v = float(value)
+        i = bisect.bisect_left(self._buckets, v)   # first bound >= v
         with self._lock:
-            i = 0
-            while i < len(self._buckets) and v > self._buckets[i]:
-                i += 1
             self._counts[i] += 1
             self._sum += v
             self._count += 1
@@ -364,6 +366,9 @@ class TelemetryRegistry:
         self._phase_ts = time.time()
         self._phase_history = []
         self._created = time.time()
+        # ``report()`` as it stood at the last ``reset()`` that dropped
+        # anything, or None.
+        self.closed_report = None
         # Set by backend/core.py at smp.init (asking jax at dump time could
         # itself initialize — or hang on — a wedged backend at exit).
         self.process_index = None
@@ -420,8 +425,28 @@ class TelemetryRegistry:
 
     # -- export ---------------------------------------------------------
 
+    def _derive_step_time_quantiles(self):
+        """``smp_step_time_quantile_seconds{stat=p50|p90|p99}`` from the
+        step-time histogram, computed when a report is taken."""
+        with self._lock:
+            fam = self._families.get("smp_step_time_seconds")
+        if fam is None:
+            return
+        snap = fam._snapshot()["series"]
+        snap = [s for s in snap if not s["labels"] and s["count"]]
+        if not snap:
+            return
+        g = self.gauge(
+            "smp_step_time_quantile_seconds",
+            "step wall-time percentiles from the streaming histogram",
+        )
+        for stat, q in (("p50", 0.50), ("p90", 0.90), ("p99", 0.99)):
+            g.labels(stat=stat).set(quantile_from_counts(
+                snap[0]["buckets"], snap[0]["counts"], q))
+
     def report(self):
         """Plain-dict snapshot of every metric plus phase metadata."""
+        self._derive_step_time_quantiles()
         with self._lock:
             families = dict(self._families)
             meta = {
@@ -464,7 +489,11 @@ class TelemetryRegistry:
         return _atomic_json_dump(self.report(), path, "telemetry dump")
 
     def reset(self):
-        """Testing hook: drop every metric and the phase history."""
+        """Drop every metric and the phase history (``smp.shutdown()``,
+        and tests). What is dropped stays readable in-process as
+        ``closed_report``: the final numbers of the session just ended."""
+        if self._families:
+            self.closed_report = self.report()
         with self._lock:
             self._families.clear()
             self._phase = "startup"
@@ -1134,25 +1163,16 @@ def serve_latency_summary(kind, qs=(0.5, 0.9, 0.99)):
 
 def record_step_time(seconds):
     """One training-step wall-time sample into the log-bucketed step-time
-    histogram ``smp_step_time_seconds`` plus p50/p90/p99 gauges — the
-    training-path counterpart of the serving latency distributions (a
-    p99 step blowup is invisible in the dispatch-seconds mean)."""
-    v = float(seconds)
-    child = telemetry.histogram(
+    histogram ``smp_step_time_seconds`` — the training-path counterpart
+    of the serving latency distributions (a p99 step blowup is invisible
+    in a mean). Its p50/p90/p99 gauges
+    (``smp_step_time_quantile_seconds``) are derived where a report is
+    taken (``TelemetryRegistry.report``), not on every step."""
+    telemetry.histogram(
         "smp_step_time_seconds",
         "per-step dispatch wall-time distribution (log-bucketed)",
         buckets=LATENCY_BUCKETS,
-    ).labels()
-    child.observe(v)
-    snap = child._snapshot()
-    g = telemetry.gauge(
-        "smp_step_time_quantile_seconds",
-        "step wall-time percentiles from the streaming histogram",
-    )
-    for stat, q in (("p50", 0.50), ("p90", 0.90), ("p99", 0.99)):
-        est = quantile_from_counts(snap["buckets"], snap["counts"], q)
-        if est is not None:
-            g.labels(stat=stat).set(est)
+    ).observe(float(seconds))
 
 
 def record_serve_trace(event, rid, trace=None, slot=-1, pos=-1, detail=""):

@@ -99,7 +99,10 @@ class Timeline:
         """Barrier-exit alignment instant (see module docstring)."""
         self.record_instant(f"smp_sync/{name}/{group}/{seq}", track="sync")
 
-    def record_event(self, name, begin_us, end_us, microbatch=None, track="pipeline"):
+    def record_event(self, name, begin_us, end_us, microbatch=None,
+                     track="pipeline", parent=None):
+        """``parent``: the enclosing profiler region's name (the native
+        recorder's records have no field for it and drop it)."""
         if not self.enabled:
             return
         if self._native is not None:
@@ -108,6 +111,8 @@ class Timeline:
         args = {"step": self._step}
         if microbatch is not None:
             args["microbatch"] = microbatch
+        if parent is not None:
+            args["parent"] = parent
         with self._lock:
             self._events.append(
                 {"name": name, "ph": "X", "ts": begin_us, "dur": end_us - begin_us,

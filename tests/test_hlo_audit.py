@@ -313,6 +313,317 @@ class TestAuditOff:
 
 
 # ----------------------------------------------------------------------
+# Op index: a record per instruction, the census as a sum over them
+# ----------------------------------------------------------------------
+
+_J = "jit(full_impl)/"
+# sha256 of tests/goldens/hlo_fingerprints.json as PR 23's tree has it.
+_GOLDENS_SHA256 = (
+    "30662943993551d27a437d9b95327456f1f483258ba2223ca7883d89ed1a44af")
+# op_name (as jax 0.9.0 and this library write them on the two benchmark
+# cells' compiled steps), instruction name -> phase, nearest smp scope.
+_PHASE_CASES = [
+    (_J + "smp/optimizer/update/mul", "fusion.1", "optimizer",
+     "smp/optimizer/update"),
+    # First match wins: whatever else an op under the update's scope says.
+    (_J + "smp/optimizer/update/transpose(jvp())/add", "add.2", "optimizer",
+     "smp/optimizer/update"),
+    (_J + "transpose(jvp(jvp()))/checkpoint/rematted_computation/tanh",
+     "tanh.3", "recompute", None),
+    (_J + "while/body/closed_call/transpose(jvp(TransformerLM))/"
+     "layers/block/proj/dot_general", "fusion.415.remat2", "recompute", None),
+    (_J + "while/body/jvp(TransformerLM)/layers/block/fc/dot_general",
+     "gte.remat.13", "recompute", None),
+    (_J + "smp/pipeline/steady/while/body/closed_call/smp/pipeline/tick_bwd"
+     "/vmap(jvp())/while/body/DistributedTransformerLayer/output/dot_general",
+     "fusion.711", "recompute", "smp/pipeline/tick_bwd"),
+    (_J + "smp/pipeline/steady/while/body/closed_call/smp/pipeline/tick_bwd"
+     "/vmap(transpose(jvp()))/attention/shard_map/psum", "all-reduce.96",
+     "backward", "smp/pipeline/tick_bwd"),
+    (_J + "smp/pipeline/cooldown/smp/pipeline/tick_bwd_weight/dot_general",
+     "fusion.5", "backward", "smp/pipeline/tick_bwd_weight"),
+    (_J + "smp/pipeline/cooldown_weight/while/body/add", "add.6",
+     "backward", "smp/pipeline/cooldown_weight"),
+    (_J + "while/body/closed_call/transpose(jvp(TransformerLM))/"
+     "layers/block/attn/qkv/dot_general", "multiply_reduce_fusion.14",
+     "backward", None),
+    (_J + "while/body/closed_call/smp/step/accumulate/add",
+     "select_add_fusion.46", "backward", "smp/step/accumulate"),
+    (_J + "while/body/closed_call/jvp(TransformerLM)/layers/block/fc/"
+     "dot_general", "fusion.7", "forward", None),
+    (_J + "smp/pipeline/steady/while/body/closed_call/smp/pipeline/tick_fwd"
+     "/vmap()/while/body/DistributedTransformerLayer/output/dot_general",
+     "all-reduce.93", "forward", "smp/pipeline/tick_fwd"),
+    (_J + "smp/pipeline/embed/jit(_take)/gather", "fusion.8", "forward",
+     "smp/pipeline/embed"),
+    (_J + "smp/pipeline/steady/smp/pipeline/head/jvp()/reduce_sum",
+     "reduce.9", "forward", "smp/pipeline/head"),
+    (_J + "smp/step/cast_params/convert_element_type",
+     "convert_element_type.127", "forward", "smp/step/cast_params"),
+    (_J + "jit(_threefry_split)/threefry2x32", "fusion.10", "other", None),
+    (_J + "smp/pipeline/steady/while/body/dynamic_slice", "fusion.11",
+     "other", "smp/pipeline/steady"),
+    ("", "copy.601", "other", None),
+]
+
+
+def _instr(name, op_name, rhs="f32[8]{0} fusion(%p), kind=kLoop"):
+    meta = f', metadata={{op_name="{op_name}" stack_frame_id=3}}' \
+        if op_name else ""
+    return f"  %{name} = {rhs}{meta}\n"
+
+
+class TestOpIndex:
+    @pytest.mark.parametrize(
+        "op_name,instr,phase,scope", _PHASE_CASES,
+        ids=[f"{c[2]}:{c[1]}" for c in _PHASE_CASES])
+    def test_phase_and_scope_from_the_markers(self, op_name, instr, phase,
+                                              scope):
+        assert hlo_audit.phase_of(op_name, instr) == phase
+        (rec,) = hlo_audit.op_records(_instr(instr, op_name)).values()
+        assert rec == {"phase": phase, "scope": scope}
+        assert phase in hlo_audit.PHASES
+
+    def test_of_a_joined_op_name_the_first_part_decides(self):
+        fwd = _J + "jvp(Net)/dot_general"
+        bwd = _J + "transpose(jvp(Net))/dot_general"
+        upd = _J + "smp/optimizer/update/sub"
+        assert hlo_audit.phase_of(f"{fwd};{bwd};{upd}") == "forward"
+        assert hlo_audit.phase_of(f"{upd};{fwd}") == "optimizer"
+        assert hlo_audit.scope_of(f"{fwd};{upd}") is None
+        assert hlo_audit.scope_of(f"{upd};{fwd}") == "smp/optimizer/update"
+        index = hlo_audit.op_records(_instr("fusion.3", f"{bwd};{fwd}"))
+        assert index == {"fusion.3": {"phase": "backward", "scope": None}}
+
+    def test_keys_are_the_names_a_trace_prints(self):
+        text = (
+            "HloModule jit_step, is_scheduled=true\n\n"
+            "%fused_computation.1 (p: f32[8]) -> f32[8] {\n"
+            "  %p = f32[8]{0} parameter(0)\n"
+            + _instr("neg.1", _J + "jvp(Net)/neg", "f32[8]{0} negate(%p)")
+            .replace("  %neg", "  ROOT %neg") +
+            "}\n\n"
+            "ENTRY %main.9 (a: f32[8]) -> f32[8] {\n"
+            "  %a = f32[8]{0} parameter(0)\n"
+            "  ROOT %fusion.3 = f32[8]{0} fusion(%a), kind=kLoop, "
+            "calls=%fused_computation.1\n"
+            "}\n"
+        )
+        index = hlo_audit.op_records(text)
+        assert list(index) == ["p", "neg.1", "a", "fusion.3"]
+        # A fusion the compiler left anonymous takes its body's op_name.
+        assert index["fusion.3"]["phase"] == "forward"
+        assert index["a"] == {"phase": "other", "scope": None}
+
+    def test_anonymous_fusion_takes_its_roots_op_name_else_the_first(self):
+        bwd = _J + "transpose(jvp(Net))/mul"
+        text = (
+            "%fused_computation.2 (p: f32[8]) -> f32[8] {\n"
+            + _instr("mul.1", _J + "smp/optimizer/update/mul",
+                     "f32[8]{0} multiply(%p, %p)")
+            + _instr("mul.2", bwd, "f32[8]{0} multiply(%mul.1, %p)")
+            .replace("  %mul.2", "  ROOT %mul.2") +
+            "}\n"
+            "%fused_computation.3 (p: f32[8]) -> f32[8] {\n"
+            + _instr("mul.3", _J + "smp/optimizer/update/mul",
+                     "f32[8]{0} multiply(%p, %p)")
+            + "  ROOT %b = f32[8]{0} bitcast(%mul.3)\n"
+            "}\n"
+            "  %fusion.4 = f32[8]{0} fusion(%a), kind=kLoop, "
+            "calls=%fused_computation.2\n"
+            "  %fusion.5 = f32[8]{0} fusion(%a), kind=kLoop, "
+            "calls=%fused_computation.3\n"
+        )
+        index = hlo_audit.op_records(text)
+        assert index["fusion.4"]["phase"] == "backward"
+        assert index["fusion.5"] == {
+            "phase": "optimizer", "scope": "smp/optimizer/update"}
+
+    def test_an_unmarked_op_takes_the_phase_of_its_first_marked_operand(
+            self):
+        """The gradient accumulation and the compiler's copies carry no
+        marker (and none of the scopes a later build adds, where a compile
+        cache hands back an older build's executable)."""
+        bwd = _J + "while/body/closed_call/transpose(jvp(Net))/while"
+        text = (
+            _instr("while.269", bwd, "bf16[8]{0} get-tuple-element(%while.2)"
+                   ", index=13")
+            + "  %gte.5 = f32[8]{0} get-tuple-element(%arg_tuple.0), index=1\n"
+            + _instr("select_add_fusion.46",
+                     _J + "while/body/closed_call/add",
+                     "f32[8]{0} fusion(%gte.5, %while.269, %compare.7), "
+                     "kind=kLoop")
+            + "  %gte.6 = f32[8]{0} get-tuple-element(%select_add_fusion.46)"
+            ", index=0\n"
+            "  %copy.601 = f32[8]{0} copy(%gte.6)\n"
+            + _instr("convert.1", _J + "convert_element_type",
+                     "bf16[8]{0} convert(%params.1)")
+        )
+        index = hlo_audit.op_records(text)
+        assert index["gte.5"]["phase"] == "other"
+        assert index["select_add_fusion.46"]["phase"] == "backward"
+        assert index["copy.601"]["phase"] == "backward"      # a chain
+        assert index["convert.1"]["phase"] == "other"
+        assert {r["scope"] for r in index.values()} == {None}
+
+    @pytest.mark.parametrize("line,record", [
+        ("%ar = f32[16,16]{1,0} all-reduce(f32[16,16]{1,0} %x), "
+         "channel_id=1, replica_groups={{0,2},{1,3}}, "
+         "use_global_device_ids=true, to_apply=%sum",
+         {"op": "all-reduce", "axis": "pp", "bytes": 1024}),
+        ("%ag = f32[4,4]{1,0} all-gather(f32[2,4]{1,0} %x), channel_id=2, "
+         "replica_groups=[2,2]<=[4], dimensions={0}, "
+         "use_global_device_ids=true",
+         {"op": "all-gather", "axis": "dp", "bytes": 64}),
+        ("%ar2 = bf16[8]{0} all-reduce(bf16[8]{0} %x), channel_id=1, "
+         "replica_groups=[2,2]<=[2,2]T(1,0), use_global_device_ids=true, "
+         "to_apply=%sum",
+         {"op": "all-reduce", "axis": "pp", "bytes": 16}),
+        ("%cp = f32[4,8]{1,0} collective-permute(f32[4,8]{1,0} %x), "
+         "channel_id=3, source_target_pairs={{0,1},{2,3},{1,0},{3,2}}",
+         {"op": "collective-permute", "axis": "dp", "bytes": 128}),
+    ], ids=["literal", "iota", "iota-transposed", "permute"])
+    def test_each_collective_carries_its_axis(self, line, record):
+        (rec,) = hlo_audit.op_records(line + "\n", mesh=_mesh22()).values()
+        assert rec == {"phase": "other", "scope": None, **record}
+
+    _PROGRAM = (
+        "  %ars = f32[8]{0} all-reduce-start(f32[8]{0} %x), "
+        "replica_groups={{0,2},{1,3}}, use_global_device_ids=true, "
+        "to_apply=%sum, metadata={op_name=\"" + _J
+        + "transpose(jvp(Net))/psum\"}\n"
+        "  %ard = f32[8]{0} all-reduce-done(f32[8]{0} %ars)\n"
+        "  %ar.2 = (f32[4]{0}, f32[4]{0}) all-reduce(%a, %b), "
+        "replica_groups={{0,1},{2,3}}, use_global_device_ids=true, "
+        "to_apply=%sum\n"
+        "  %ag = f32[4,4]{1,0} all-gather(f32[2,4]{1,0} %x), "
+        "replica_groups=[2,2]<=[4], dimensions={0}, "
+        "use_global_device_ids=true\n"
+        "  %cps = (f32[4]{0}, f32[4]{0}) collective-permute-start(%y), "
+        "source_target_pairs={{0,2},{1,3}}\n"
+        "  %cpd = f32[4]{0} collective-permute-done(%cps)\n"
+        "  %f = f32[4]{0} fusion(%cpd), kind=kLoop\n"
+    )
+
+    def test_done_half_takes_axis_and_phase_from_its_start(self):
+        index = hlo_audit.op_records(self._PROGRAM, mesh=_mesh22())
+        assert index["ard"] == {
+            "phase": "backward", "scope": None, "op": "all-reduce",
+            "axis": "pp", "bytes": 0, "done": True}
+        assert index["cpd"]["axis"] == index["cps"]["axis"] == "pp"
+        assert index["ar.2"]["axis"] == "dp"
+
+    def test_census_is_the_sum_over_the_index(self):
+        index = hlo_audit.op_records(self._PROGRAM, mesh=_mesh22())
+        census = hlo_audit.collective_census(self._PROGRAM, mesh=_mesh22())
+        assert census == hlo_audit.census_of(index)
+        assert census == {
+            "all-reduce": {"count": 2, "bytes": 64, "axes": {
+                "pp": {"count": 1, "bytes": 32},
+                "dp": {"count": 1, "bytes": 32}}},
+            "all-gather": {"count": 1, "bytes": 64, "axes": {
+                "dp": {"count": 1, "bytes": 64}}},
+            "collective-permute": {"count": 1, "bytes": 32, "axes": {
+                "pp": {"count": 1, "bytes": 32}}},
+        }
+        for op, ent in census.items():
+            mine = [r for r in index.values()
+                    if r.get("op") == op and not r.get("done")]
+            assert ent["count"] == len(mine)
+            assert ent["bytes"] == sum(r["bytes"] for r in mine)
+
+    def test_compiled_step_has_every_phase(self):
+        """``value_and_grad`` of checkpointed layers plus an update under
+        the optimizer's scope, as the installed JAX names them."""
+        def layer(x, w):
+            return jnp.tanh(x @ w)
+
+        def loss(params, x):
+            for w in params:
+                x = jax.checkpoint(layer)(x, w)
+            return jnp.sum(x * x)
+
+        def step(params, x):
+            value, grads = jax.value_and_grad(loss)(params, x)
+            with jax.named_scope("smp/optimizer/update"):
+                params = [w - 0.1 * g for w, g in zip(params, grads)]
+            return value, params
+
+        params = [jnp.ones((16, 16))] * 3
+        compiled = jax.jit(step).lower(params, jnp.ones((4, 16))).compile()
+        index = hlo_audit.op_records(compiled.as_text())
+        phases = {rec["phase"] for rec in index.values()}
+        assert phases == set(hlo_audit.PHASES)
+        scopes = {rec["scope"] for rec in index.values()}
+        assert scopes == {None, "smp/optimizer/update"}
+        assert hlo_audit.census_of(index) == {}
+
+    def test_index_is_held_but_never_persisted_or_hashed(self):
+        text_fp = {}
+        for with_index in (False, True):
+            audit = hlo_audit.ProgramAudit(
+                "step", "k", {}, {"fraction": 0.0}, {}, [], 1.0, 2.0, "sha",
+                {"pp": 1},
+                op_index={"fusion.1": {"phase": "forward", "scope": None}}
+                if with_index else None,
+            )
+            text_fp[with_index] = json.dumps(audit.as_dict(), sort_keys=True)
+            assert "op_index" not in audit.as_dict()
+            assert "op_index" not in audit.fingerprint
+        assert text_fp[False] == text_fp[True]
+        assert audit.op_index == {
+            "fusion.1": {"phase": "forward", "scope": None}}
+
+    def test_committed_goldens_are_as_committed(self):
+        """The goldens hash nothing the index adds: the file's bytes are
+        the ones PR 23's tree committed."""
+        import hashlib
+
+        path = os.path.join(_REPO, "tests", "goldens",
+                            "hlo_fingerprints.json")
+        with open(path, "rb") as f:
+            assert hashlib.sha256(f.read()).hexdigest() == _GOLDENS_SHA256
+
+    def test_off_builds_no_index(self, monkeypatch):
+        monkeypatch.setenv("SMP_HLO_AUDIT", "off")
+        monkeypatch.setattr(hlo_audit, "audits", {})
+        assert hlo_audit.maybe_audit(
+            "step", _UntouchableExecutable()) is None
+        assert hlo_audit.op_index("step") == {}
+
+    def test_accessor_returns_the_latest_audit_of_a_compiled_step(self):
+        """A real ``@smp.step`` compile: the index of program ``step``
+        names the optimizer update, the accumulation and both passes."""
+        smp.reset()
+        smp.init({"microbatches": 2, "bf16": True})
+        model = smp.DistributedModel(TransformerLM(
+            vocab_size=32, max_len=12, d_model=16, n_layers=2, n_heads=2))
+        optimizer = smp.DistributedOptimizer(optax.sgd(0.1), model)
+
+        @smp.step
+        def step_fn(model, batch):
+            logits = model(batch)
+            loss = jnp.mean(softmax_xent(logits[:, :-1], batch[:, 1:]))
+            model.backward(loss)
+            return loss
+
+        step_fn(model, jax.random.randint(jax.random.key(0), (4, 12), 0, 32))
+        optimizer.step()
+        index = hlo_audit.op_index("step")
+        assert index is hlo_audit.audits["step"].op_index and index
+        assert index is hlo_audit.of_step_function(step_fn).op_index
+        by_scope = {}
+        for rec in index.values():
+            by_scope.setdefault(rec["scope"], set()).add(rec["phase"])
+        assert by_scope["smp/optimizer/update"] == {"optimizer"}
+        assert by_scope["smp/step/accumulate"] == {"backward"}
+        assert by_scope["smp/step/cast_params"] == {"forward"}
+        assert {"forward", "backward"} <= by_scope[None]
+        assert hlo_audit.census_of(index) == \
+            hlo_audit.audits["step"].census
+
+
+# ----------------------------------------------------------------------
 # End-to-end: real pipeline compiles
 # ----------------------------------------------------------------------
 

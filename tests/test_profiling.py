@@ -112,6 +112,98 @@ class TestRegions:
             state.timeline = old
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps what each
+    region would have written into the profiler's trace."""
+
+    seen = []
+
+    def __init__(self, name, **stats):
+        type(self).seen.append((name, stats))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _phase_series():
+    series = telemetry.report()["metrics"].get(
+        "smp_host_phase_seconds", {}).get("series", [])
+    return {s["labels"]["phase"]: s for s in series}
+
+
+class TestRegionNesting:
+    def test_a_region_knows_its_parent_and_its_step(self, monkeypatch):
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotations)
+        _Annotations.seen = []
+        with profiling.region("unit/outer", step=7) as outer:
+            with profiling.region("unit/inner") as inner:
+                with profiling.region("unit/leaf", step=9) as leaf:
+                    pass
+            with profiling.region("unit/second") as second:
+                pass
+        with profiling.region("unit/alone") as alone:
+            pass
+        assert (outer.parent, outer.step) == (None, 7)
+        assert (inner.parent, inner.step) == ("smp_phase/unit/outer", 7)
+        assert (leaf.parent, leaf.step) == ("smp_phase/unit/inner", 9)
+        assert (second.parent, second.step) == ("smp_phase/unit/outer", 7)
+        assert (alone.parent, alone.step) == (None, None)
+        assert _Annotations.seen == [
+            ("smp_phase/unit/outer", {"step": 7}),
+            ("smp_phase/unit/inner",
+             {"step": 7, "parent": "smp_phase/unit/outer"}),
+            ("smp_phase/unit/leaf",
+             {"step": 9, "parent": "smp_phase/unit/inner"}),
+            ("smp_phase/unit/second",
+             {"step": 7, "parent": "smp_phase/unit/outer"}),
+            ("smp_phase/unit/alone", {}),
+        ]
+
+    def test_an_exception_leaves_no_region_open(self):
+        with pytest.raises(ValueError):
+            with profiling.region("unit/raises", step=1):
+                with profiling.region("unit/raises_inner"):
+                    raise ValueError("boom")
+        with profiling.region("unit/after") as after:
+            pass
+        assert (after.parent, after.step) == (None, None)
+
+    def test_every_region_observes_the_one_histogram_family(self):
+        from smdistributed_modelparallel_tpu.utils.telemetry import (
+            HOST_PHASE_BUCKETS,
+        )
+
+        before = _phase_series().get("unit/timed", {"count": 0, "sum": 0.0})
+        for _ in range(3):
+            with profiling.region("unit/timed"):
+                time.sleep(0.002)
+        series = _phase_series()["unit/timed"]
+        assert series["count"] - before["count"] == 3
+        assert series["sum"] - before["sum"] >= 0.006
+        assert tuple(series["buckets"]) == HOST_PHASE_BUCKETS
+        assert HOST_PHASE_BUCKETS[0] == 5e-6      # phases take microseconds
+
+    def test_timeline_event_carries_step_and_parent(self, tmp_path):
+        tl = Timeline(path=str(tmp_path / "tl.json"))
+        tl._native = None          # the Python recorder keeps event args
+        old = state.timeline
+        state.timeline = tl
+        try:
+            tl.start_step(4)
+            with profiling.region("unit/outer", step=4):
+                with profiling.region("unit/inner"):
+                    pass
+        finally:
+            state.timeline = old
+        events = {e["name"]: e for e in tl._events if e.get("ph") == "X"}
+        assert events["smp_phase/unit/inner"]["args"] == {
+            "step": 4, "parent": "smp_phase/unit/outer"}
+        assert events["smp_phase/unit/outer"]["args"] == {"step": 4}
+
+
 # ----------------------------------------------------------------------
 # On-demand capture
 # ----------------------------------------------------------------------
@@ -302,6 +394,13 @@ class TestEndToEnd:
         for _ in range(4):
             train(model, x, y)
             opt.step()
+        # The step engine publishes no roofline of its own (it would have
+        # to block a step to time it): whoever measured a step time joins
+        # it with the compiled step's cost.
+        profiling.roofline(
+            "step", step_time_s=0.02,
+            compiled=train._last_runner.holder["compiled"],
+        )
 
         # Capture bracketed exactly steps 1..2, into the per-rank dir.
         assert profiling.capture.last_window == (1, 2)

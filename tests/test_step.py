@@ -493,3 +493,146 @@ def test_no_warning_for_eval_steps_between_updates():
     finally:
         get_logger().removeHandler(handler)
     assert not any("NOT learning" in m for m in records), records
+
+
+# ----------------------------------------------------------------------
+# The step engine's host spans cover the whole call
+# ----------------------------------------------------------------------
+
+STEP_PHASES = ("step/prepare", "step/lookup", "step/place", "step/dispatch",
+               "step/install", "step/bookkeeping")
+
+
+def _host_phases():
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    series = telemetry.report()["metrics"].get(
+        "smp_host_phase_seconds", {}).get("series", [])
+    return {s["labels"]["phase"]: (s["count"], s["sum"]) for s in series}
+
+
+def _spanned_steps(steps, monkeypatch=None, fused=False):
+    """``steps`` train steps of a tiny MLP; returns the growth of every
+    host phase's (count, seconds) over them and the annotations seen."""
+    seen = []
+    if monkeypatch is not None:
+        class Annotation:
+            def __init__(self, name, **stats):
+                seen.append((name, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    cfg = {"microbatches": 2}
+    if fused:
+        cfg.update(fused_optimizer_step=True, fused_step_donation=True)
+    smp.init(cfg)
+    x, y = make_data(jax.random.key(0))
+    model = smp.DistributedModel(MLP())
+    optimizer = smp.DistributedOptimizer(optax.sgd(0.1), model)
+
+    @smp.step
+    def train_step(model, xb, yb):
+        loss = jnp.mean(softmax_xent(model(xb), yb))
+        model.backward(loss)
+        return loss
+
+    before = _host_phases()
+    first_step = smp.state.step_count
+    for _ in range(steps):
+        train_step(model, x, y)
+        optimizer.step()
+    after = _host_phases()
+    grown = {
+        phase: (count - before.get(phase, (0, 0.0))[0],
+                seconds - before.get(phase, (0, 0.0))[1])
+        for phase, (count, seconds) in after.items()
+    }
+    return grown, seen, first_step
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
+def test_after_two_steps_every_phase_has_count_two(fused):
+    grown, _, _ = _spanned_steps(2, fused=fused)
+    for phase in ("step", "optimizer/step") + STEP_PHASES:
+        assert grown[phase][0] == 2, phase
+    # First-call phases ran once: trace inside lookup, lower and compile
+    # before the first dispatch.
+    for phase in ("step/trace", "step/lower", "step/compile"):
+        assert grown[phase][0] == 1, phase
+    assert grown.get("step/fetch", (0, 0.0))[0] == 0
+    # One step-time histogram a step, not two.
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    metrics = telemetry.report()["metrics"]
+    assert "smp_step_time_seconds" in metrics
+    assert "smp_step_dispatch_seconds" not in metrics
+
+
+def test_children_cover_the_parent_and_sum_to_within_it():
+    grown, _, _ = _spanned_steps(2)
+    parent = grown["step"][1]
+    children = sum(grown[p][1] for p in STEP_PHASES
+                   + ("step/lower", "step/compile"))
+    assert children <= parent
+    # What no child covers (set_mesh round the executable, the context
+    # managers themselves) is a sliver of a call that compiles.
+    assert parent - children < 0.05 * parent
+
+
+def test_annotations_carry_parent_and_step(monkeypatch):
+    _, seen, first = _spanned_steps(2, monkeypatch)
+    parents = [(n, s) for n, s in seen if n == "smp_phase/step"]
+    assert parents == [("smp_phase/step", {"step": first}),
+                       ("smp_phase/step", {"step": first + 1})]
+    for phase in STEP_PHASES:
+        mine = [s for n, s in seen if n == "smp_phase/" + phase]
+        assert mine == [{"step": first, "parent": "smp_phase/step"},
+                        {"step": first + 1, "parent": "smp_phase/step"}]
+    trace = [s for n, s in seen if n == "smp_phase/step/trace"]
+    assert trace == [{"step": first, "parent": "smp_phase/step/lookup"}]
+    # optimizer.step() runs after the call returns: a root of its own,
+    # tied to the step by the shared number.
+    opt = [s for n, s in seen if n == "smp_phase/optimizer/step"]
+    assert opt == [{"step": first}, {"step": first + 1}]
+
+
+def test_no_step_blocks_on_its_outputs(monkeypatch):
+    """The engine used to block every 16th step to time a roofline; it
+    blocks none now (17 steps cover the old sampling period)."""
+    from smdistributed_modelparallel_tpu.utils import profiling
+
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: blocked.append(1) or real(x))
+    grown, _, _ = _spanned_steps(17)
+    assert grown["step"][0] == 17
+    assert blocked == []
+    assert grown.get("step/fetch", (0, 0.0))[0] == 0
+    for gone in ("should_sample_step", "record_step_roofline",
+                 "ROOFLINE_SAMPLE_EVERY"):
+        assert not hasattr(profiling, gone)
+
+
+def test_step_time_quantiles_are_derived_when_a_report_is_taken():
+    from smdistributed_modelparallel_tpu.utils.telemetry import (
+        TelemetryRegistry, LATENCY_BUCKETS,
+    )
+
+    reg = TelemetryRegistry()
+    hist = reg.histogram("smp_step_time_seconds", buckets=LATENCY_BUCKETS)
+    assert "smp_step_time_quantile_seconds" not in reg.report()["metrics"]
+    for v in (0.1, 0.1, 0.1, 2.0):
+        hist.observe(v)
+    assert "smp_step_time_quantile_seconds" not in reg._families
+    series = reg.report()["metrics"]["smp_step_time_quantile_seconds"][
+        "series"]
+    got = {s["labels"]["stat"]: s["value"] for s in series}
+    assert set(got) == {"p50", "p90", "p99"}
+    assert 0.05 < got["p50"] < 0.15 and 1.5 < got["p99"] < 2.5
