@@ -9,6 +9,12 @@ statically in Python and baked into ONE ``lax.scan`` over ticks:
 
 - each tick has a forward sub-step and a backward sub-step; per stage the
   static schedule says which microbatch (if any) to process in each;
+- a sub-step that no stage uses is not executed: the backward sub-step
+  of the leading ticks with no backward anywhere and the forward sub-step
+  of the trailing ticks with no forward anywhere (the bounds are
+  ``interleaved_phase_bounds``) sit behind a ``lax.cond`` on the tick
+  index. On the chip a masked sub-step costs what a busy one does
+  (PERF.md, PR 25);
 - stage inputs are stashed into a ring buffer of ``active_microbatches + 1``
   slots; backward re-runs the stage forward from the stash under ``jax.vjp``
   (activation recomputation, Megatron-style 1F1B-with-remat) — peak live
@@ -31,8 +37,9 @@ losses); the step engine (``step.py``) divides out the loss scale exactly as
 in the fill-drain path so the two schedules are numerically interchangeable.
 
 Three executors share this module: the plain v=1 path (``pipeline_1f1b``
-below, byte-stable by contract), the interleaved virtual-stage
-generalization (``_pipeline_1f1b_virtual``: (chunk, microbatch) units over
+below; setting its knobs to their defaults compiles the program that
+leaving them unset does), the interleaved virtual-stage generalization
+(``_pipeline_1f1b_virtual``: (chunk, microbatch) units over
 ``pp*v`` chunks), and the zero-bubble ZB-H1 executor
 (``_pipeline_zero_bubble``: (chunk, microbatch, pass) units — backward
 split into an input-grad pass and a deferred weight-grad pass that fills
@@ -114,10 +121,13 @@ def schedule_occupancy(fwd, bwd, fwd_ticks=None, bwd_ticks=None, wgt=None,
     measured occupancy. Under virtual pipeline stages the entries are
     (chunk, microbatch) units, so busy counts CHUNK sub-steps (busy ==
     2*S*V*M) and stays comparable across ``virtual_pipeline_degree``
-    values; ``fwd_ticks``/``bwd_ticks`` then restrict the denominator to
-    the ticks whose sub-step actually executes (the virtual executor's
+    values; ``fwd_ticks``/``bwd_ticks`` restrict the denominator to the
+    ticks whose sub-step actually executes (the virtual executor's
     warmup ticks are forward-only and its cooldown ticks backward-only —
-    idle sub-steps that are never compiled are not bubble).
+    idle sub-steps that are never compiled are not bubble). The plain v=1
+    executor keeps one loop and skips the same sub-steps at run time, so
+    it passes the same bounds; the defaults give the accounting of
+    rigidly paired ticks.
 
     Zero-bubble schedules split the backward into input-grad (B) and
     weight-grad (W) passes: ``bwd`` then carries the B pass, ``wgt`` the
@@ -231,10 +241,12 @@ def build_interleaved_1f1b_schedule(num_stages, num_microbatches, window,
 
 
 def interleaved_phase_bounds(fwd_mb, bwd_mb):
-    """(t_bwd_start, t_fwd_end) of an interleaved schedule.
+    """(t_bwd_start, t_fwd_end) of a ``pipeline: "interleaved"`` (1F1B)
+    schedule, plain or virtual: any ``[n_ticks, S]`` pair of arrays.
 
     Ticks ``[0, t_bwd_start)`` have no backward work anywhere (warmup:
-    the executor compiles them as forward-only sub-steps) and ticks
+    the virtual executor compiles them as forward-only sub-steps, the
+    plain one skips their backward sub-step) and ticks
     ``[t_fwd_end, n_ticks)`` no forward work (cooldown: backward-only).
     This phase split is what realizes the interleaved bubble win: the
     rigidly paired tick (one fwd + one bwd sub-step) would idle a full
@@ -395,6 +407,24 @@ def _zb_segment_region(do_fwd, do_bwd, do_wgt):
     return "smp/pipeline/cooldown_weight" if do_wgt else "smp/pipeline/idle"
 
 
+def _run_in_span(in_span, spans_all_ticks, substep, ops):
+    """``substep(ops)`` on the ticks of its span; ``ops`` as they are on
+    the others, where no stage has such a slot and the sub-step would
+    compute a result whose every write is masked.
+
+    ``spans_all_ticks`` is static: a span that covers the whole loop
+    emits no conditional. ``in_span`` derives from the tick index, the
+    same on every device, so the collectives inside a branch stay
+    matched. One loop body with conditionals, not one loop per phase: a
+    phase of its own compiles a second copy of the sub-step, and a second
+    backward sub-step holds a second stack of per-layer residuals, which
+    the compiler paid for with rematerialization (PERF.md, PR 25).
+    """
+    if spans_all_ticks:
+        return substep(ops)
+    return jax.lax.cond(in_span, substep, lambda unchanged: unchanged, ops)
+
+
 def _tree_zeros(avals_or_tree, like=None):
     src = avals_or_tree if like is None else like
     return jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), src)
@@ -409,8 +439,7 @@ def _inexact_leaves(tree):
 
 # ---- shared ring/scatter primitives of the chunk-generalized executors
 # (_pipeline_1f1b_virtual and _pipeline_zero_bubble; the plain v=1
-# executor keeps its own 2-level ring helpers so its traced program —
-# byte-identity contract — is built from untouched code). All are pure
+# executor keeps its own 2-level ring helpers). All are pure
 # in their arguments: ring geometry ([S, V, R, ...]) rides in the
 # buffers themselves.
 
@@ -772,13 +801,13 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
         rmode = "full"
     if virtual > 1 or rmode in ("stash_all", "auto"):
         # Interleaved virtual stages take the generalized executor; the
-        # default path below stays byte-for-byte the v=1 program. The
-        # stash modes also route v=1 through it (the plan needs the
-        # chunked ring layout), leaving the plain executor untouched —
-        # including when an auto plan later degrades every chunk: the
-        # run then stays on the chunk-generalized executor at v=1
-        # (numerically identical, chunk-ring program) rather than
-        # re-entering this dispatch.
+        # default path below is the plain v=1 program, whether its knobs
+        # are unset or spelled out at their defaults. The stash modes
+        # also route v=1 through the generalized one (the plan needs the
+        # chunked ring layout), including when an auto plan later
+        # degrades every chunk: the run then stays on the
+        # chunk-generalized executor at v=1 (numerically identical,
+        # chunk-ring program) rather than re-entering this dispatch.
         return _pipeline_1f1b_virtual(
             model, params, stacked_inputs, rng, mb_loss_fn, loss_seed_scale,
             virtual, rmode=rmode,
@@ -804,7 +833,10 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
         record_pipeline_occupancy,
     )
 
-    busy, total = schedule_occupancy(fwd_np, bwd_np)
+    t_b0, t_fe = interleaved_phase_bounds(fwd_np, bwd_np)
+    busy, total = schedule_occupancy(
+        fwd_np, bwd_np, fwd_ticks=t_fe, bwd_ticks=n_ticks - t_b0
+    )
     record_pipeline_occupancy("1f1b", S, M, busy_slots=busy, total_slots=total)
     # Busy schedule slots (with microbatch ids) into the flight recorder,
     # once per trace — see pipeline.py for why.
@@ -1057,177 +1089,194 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
     hc = health.active()
 
     def tick(carry, t):
-        if hc is not None:
-            (inbuf, stash, cotbuf, outbuf, dlay, drep, dembed, dsides,
-             losses, outs, (hbad, habs, hmb)) = carry
-        else:
-            (inbuf, stash, cotbuf, outbuf, dlay, drep, dembed, dsides,
-             losses, outs) = carry
+        """One schedule tick: a forward and a backward sub-step. Each is
+        skipped (``_run_in_span``) on the ticks where the schedule has no
+        such slot on ANY stage; there its every write would be masked, so
+        the results are those of always running both."""
+        (inbuf, stash, cotbuf, outbuf, dlay, drep, dembed, dsides,
+         losses, outs) = carry[:10]
+        hstats = carry[10] if hc is not None else None
 
         # ---------------- forward sub-step ----------------
-        fm = fwd_sched[t]                       # [S]; -1 idle
-        f_active = fm >= 0
-        fmc = jnp.maximum(fm, 0)
-        f_slots = fmc % W1
-        # Stage 0 reads from the embedded queue; others from inbuf.
-        from_q = gather_mb(hidden_q, fmc[0])
-        buf_in = get_ring(inbuf, f_slots)
-        x_in = jax.tree_util.tree_map(
-            lambda q, b: b.at[0].set(q), from_q, buf_in
-        )
-        f_sides = gather_sides_rows(fmc)
-        with named_region("smp/pipeline/tick_fwd"):
-            outs_f, _aux_f = jax.vmap(
-                stage_fwd,
-                in_axes=(0, 0, 0, 0 if sides is not None else None, 0, 0, 0),
-            )(staged_params, staged_xs, x_in, f_sides, stage_ids, fmc,
-              active_rows)
-        # Stash the consumed inputs for backward recompute.
-        stash = set_ring(stash, f_slots, x_in, f_active)
-        if hc is not None:
-            brow, arow = health.stage_row_stats(outs_f, S)
-            brow = jnp.where(f_active, brow, 0.0)
-            arow = jnp.where(f_active, arow, 0.0)
-            hmb = jnp.where(
-                (hmb < 0) & (brow > 0), fmc.astype(jnp.float32), hmb
+        def fwd_substep(ops):
+            inbuf, stash, outbuf, hstats = ops
+            fm = fwd_sched[t]                       # [S]; -1 idle
+            f_active = fm >= 0
+            fmc = jnp.maximum(fm, 0)
+            f_slots = fmc % W1
+            # Stage 0 reads from the embedded queue; others from inbuf.
+            from_q = gather_mb(hidden_q, fmc[0])
+            buf_in = get_ring(inbuf, f_slots)
+            x_in = jax.tree_util.tree_map(
+                lambda q, b: b.at[0].set(q), from_q, buf_in
             )
-            hbad = hbad + brow
-            habs = jnp.maximum(habs, arow)
-        # Ship outputs forward one stage (collective-permute on pp): the
-        # value produced by stage s lands in inbuf[s+1] at slot m % W1.
-        shifted_vals = jax.tree_util.tree_map(
-            lambda o: jnp.roll(o, 1, axis=0), outs_f
+            f_sides = gather_sides_rows(fmc)
+            with named_region("smp/pipeline/tick_fwd"):
+                outs_f, _aux_f = jax.vmap(
+                    stage_fwd,
+                    in_axes=(0, 0, 0, 0 if sides is not None else None, 0, 0, 0),
+                )(staged_params, staged_xs, x_in, f_sides, stage_ids, fmc,
+                  active_rows)
+            # Stash the consumed inputs for backward recompute.
+            stash = set_ring(stash, f_slots, x_in, f_active)
+            if hc is not None:
+                hbad, habs, hmb = hstats
+                brow, arow = health.stage_row_stats(outs_f, S)
+                brow = jnp.where(f_active, brow, 0.0)
+                arow = jnp.where(f_active, arow, 0.0)
+                hmb = jnp.where(
+                    (hmb < 0) & (brow > 0), fmc.astype(jnp.float32), hmb
+                )
+                hstats = (hbad + brow, jnp.maximum(habs, arow), hmb)
+            # Ship outputs forward one stage (collective-permute on pp): the
+            # value produced by stage s lands in inbuf[s+1] at slot m % W1.
+            shifted_vals = jax.tree_util.tree_map(
+                lambda o: jnp.roll(o, 1, axis=0), outs_f
+            )
+            shifted_slots = jnp.roll(f_slots, 1)
+            shifted_active = jnp.roll(f_active, 1).at[0].set(False)
+            inbuf = set_ring(inbuf, shifted_slots, shifted_vals, shifted_active)
+            # The last stage's output feeds the head/loss at its backward tick.
+            last_row_active = f_active & (stage_ids == S - 1)
+            outbuf = set_ring(outbuf, f_slots, outs_f, last_row_active)
+            return inbuf, stash, outbuf, hstats
+
+        inbuf, stash, outbuf, hstats = _run_in_span(
+            t < t_fe, t_fe == n_ticks, fwd_substep,
+            (inbuf, stash, outbuf, hstats),
         )
-        shifted_slots = jnp.roll(f_slots, 1)
-        shifted_active = jnp.roll(f_active, 1).at[0].set(False)
-        inbuf = set_ring(inbuf, shifted_slots, shifted_vals, shifted_active)
-        # The last stage's output feeds the head/loss at its backward tick.
-        last_row_active = f_active & (stage_ids == S - 1)
-        outbuf = set_ring(outbuf, f_slots, outs_f, last_row_active)
 
         # ---------------- backward sub-step ----------------
-        bm = bwd_sched[t]
-        b_active = bm >= 0
-        bmc = jnp.maximum(bm, 0)
-        b_slots = bmc % W1
+        def bwd_substep(ops):
+            cotbuf, dlay, drep, dembed, dsides, losses, outs = ops
+            bm = bwd_sched[t]
+            b_active = bm >= 0
+            bmc = jnp.maximum(bm, 0)
+            b_slots = bmc % W1
 
-        # Head + user loss VJP on the last stage's STASHED output: yields
-        # the replicated/head param grads and the stage-output cotangent.
-        # The stage forward itself is NOT in this VJP — the uniform vmapped
-        # stage backward below recomputes it once, same as every stage.
-        m_last = bmc[S - 1]
-        key_last = jax.lax.dynamic_index_in_dim(mb_keys, m_last, 0, keepdims=False)
-        out_last = jax.tree_util.tree_map(
-            lambda ob: jax.lax.dynamic_index_in_dim(
-                ob[S - 1], b_slots[S - 1], 0, keepdims=False
-            ),
-            outbuf,
-        )
-
-        def head_loss(p_rest, out):
-            final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
-            loss, user_out = mb_loss_fn(final, m_last, key_last)
-            # Head-resident MoE aux joins the differentiated loss with the
-            # same weight as the layer-stack aux (parity with pp=1).
-            loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
-                loss.dtype
+            # Head + user loss VJP on the last stage's STASHED output: yields
+            # the replicated/head param grads and the stage-output cotangent.
+            # The stage forward itself is NOT in this VJP — the uniform vmapped
+            # stage backward below recomputes it once, same as every stage.
+            m_last = bmc[S - 1]
+            key_last = jax.lax.dynamic_index_in_dim(mb_keys, m_last, 0, keepdims=False)
+            out_last = jax.tree_util.tree_map(
+                lambda ob: jax.lax.dynamic_index_in_dim(
+                    ob[S - 1], b_slots[S - 1], 0, keepdims=False
+                ),
+                outbuf,
             )
-            return loss, user_out
 
-        with named_region("smp/pipeline/head"):
-            loss_m, head_vjp, user_out = jax.vjp(
-                head_loss, params_rest, out_last, has_aux=True
-            )
-            seed = jnp.asarray(loss_seed_scale, jnp.float32) * jnp.where(
-                b_active[S - 1], 1.0, 0.0
-            )
-            d_rep, d_out_last = head_vjp(seed.astype(loss_m.dtype))
-
-        # All stages: plain stage VJP; cotangents come from cotbuf except
-        # the last stage's, which is the head/loss cotangent just computed.
-        cot_in = get_ring(cotbuf, b_slots)
-        cot_in = jax.tree_util.tree_map(
-            lambda c, d: c.at[S - 1].set(d.astype(c.dtype)), cot_in, d_out_last
-        )
-        b_sides = gather_sides_rows(bmc)
-        stash_in = get_ring(stash, b_slots)
-
-        def stage_bwd(lp, lxs, x, side, cot, s_idx, m_idx, act_row):
-            def f(lp_, x_, side_):
-                return stage_fwd(lp_, lxs, x_, side_, s_idx, m_idx, act_row)
-
-            _, vjp = jax.vjp(f, lp, x, side)
-            # Seed both outputs: the downstream cotangent for the hidden
-            # carry, and the MoE aux-loss seed (same mean-loss scaling as
-            # the task loss; idle-stage contributions are masked when
-            # accumulated below).
-            return vjp((cot, aux_seed))
-
-        with named_region("smp/pipeline/tick_bwd"):
-            d_lp_rows, d_x_rows, d_side_rows = jax.vmap(
-                stage_bwd,
-                in_axes=(0, 0, 0, 0 if sides is not None else None,
-                         0, 0, 0, 0),
-            )(staged_params, staged_xs, stash_in,
-              b_sides, cot_in, stage_ids, bmc, active_rows)
-
-        # Accumulate layer grads (mask idle rows).
-        mask_b = b_active
-
-        def acc_rows(acc, rows):
-            def add(a, r):
-                m = mask_b.reshape((S,) + (1,) * (r.ndim - 1))
-                return a + jnp.where(m, r.astype(a.dtype), 0)
-
-            return jax.tree_util.tree_map(add, acc, rows)
-
-        dlay = acc_rows(dlay, d_lp_rows)
-
-        # Replicated/head grads: only when the last stage was active.
-        drep = jax.tree_util.tree_map(
-            lambda a, g: a + jnp.where(b_active[S - 1], g.astype(a.dtype), 0),
-            drep, d_rep,
-        )
-
-        # Route input cotangents: stage s's d_input goes to stage s-1's
-        # output cotangent (cotbuf[s-1]); stage 0's goes to the embedding.
-        shifted_cots = jax.tree_util.tree_map(
-            lambda o: jnp.roll(o, -1, axis=0), d_x_rows
-        )
-        cot_slots = jnp.roll(b_slots, -1)
-        cot_active = jnp.roll(b_active, -1).at[S - 1].set(False)
-        cotbuf = set_ring(cotbuf, cot_slots, shifted_cots, cot_active)
-        dembed = scatter_add_mb(
-            dembed, bmc[0],
-            jax.tree_util.tree_map(lambda r: r[0], d_x_rows),
-            b_active[0],
-        )
-
-        # Side cotangents: every active stage contributes to its microbatch.
-        if sides is not None and dsides is not None:
-            def one_stage_side_add(ds, s):
-                row_leaves, _, _ = _inexact_leaves(
-                    jax.tree_util.tree_map(lambda r: r[s], d_side_rows)
+            def head_loss(p_rest, out):
+                final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
+                loss, user_out = mb_loss_fn(final, m_last, key_last)
+                # Head-resident MoE aux joins the differentiated loss with the
+                # same weight as the layer-stack aux (parity with pp=1).
+                loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
+                    loss.dtype
                 )
-                vals = [row_leaves[i] for i in side_idx]
-                return [
-                    _scatter_add_leaf(d, bmc[s], v, b_active[s])
-                    for d, v in zip(ds, vals)
-                ]
+                return loss, user_out
 
-            for s in range(S):
-                dsides = one_stage_side_add(dsides, s)
+            with named_region("smp/pipeline/head"):
+                loss_m, head_vjp, user_out = jax.vjp(
+                    head_loss, params_rest, out_last, has_aux=True
+                )
+                seed = jnp.asarray(loss_seed_scale, jnp.float32) * jnp.where(
+                    b_active[S - 1], 1.0, 0.0
+                )
+                d_rep, d_out_last = head_vjp(seed.astype(loss_m.dtype))
 
-        # Loss / user outputs at the last stage's backward tick.
-        losses = losses.at[m_last].set(
-            jnp.where(b_active[S - 1], loss_m.astype(jnp.float32), losses[m_last])
+            # All stages: plain stage VJP; cotangents come from cotbuf except
+            # the last stage's, which is the head/loss cotangent just computed.
+            cot_in = get_ring(cotbuf, b_slots)
+            cot_in = jax.tree_util.tree_map(
+                lambda c, d: c.at[S - 1].set(d.astype(c.dtype)), cot_in, d_out_last
+            )
+            b_sides = gather_sides_rows(bmc)
+            stash_in = get_ring(stash, b_slots)
+
+            def stage_bwd(lp, lxs, x, side, cot, s_idx, m_idx, act_row):
+                def f(lp_, x_, side_):
+                    return stage_fwd(lp_, lxs, x_, side_, s_idx, m_idx, act_row)
+
+                _, vjp = jax.vjp(f, lp, x, side)
+                # Seed both outputs: the downstream cotangent for the hidden
+                # carry, and the MoE aux-loss seed (same mean-loss scaling as
+                # the task loss; idle-stage contributions are masked when
+                # accumulated below).
+                return vjp((cot, aux_seed))
+
+            with named_region("smp/pipeline/tick_bwd"):
+                d_lp_rows, d_x_rows, d_side_rows = jax.vmap(
+                    stage_bwd,
+                    in_axes=(0, 0, 0, 0 if sides is not None else None,
+                             0, 0, 0, 0),
+                )(staged_params, staged_xs, stash_in,
+                  b_sides, cot_in, stage_ids, bmc, active_rows)
+
+            # Accumulate layer grads (mask idle rows).
+            mask_b = b_active
+
+            def acc_rows(acc, rows):
+                def add(a, r):
+                    m = mask_b.reshape((S,) + (1,) * (r.ndim - 1))
+                    return a + jnp.where(m, r.astype(a.dtype), 0)
+
+                return jax.tree_util.tree_map(add, acc, rows)
+
+            dlay = acc_rows(dlay, d_lp_rows)
+
+            # Replicated/head grads: only when the last stage was active.
+            drep = jax.tree_util.tree_map(
+                lambda a, g: a + jnp.where(b_active[S - 1], g.astype(a.dtype), 0),
+                drep, d_rep,
+            )
+
+            # Route input cotangents: stage s's d_input goes to stage s-1's
+            # output cotangent (cotbuf[s-1]); stage 0's goes to the embedding.
+            shifted_cots = jax.tree_util.tree_map(
+                lambda o: jnp.roll(o, -1, axis=0), d_x_rows
+            )
+            cot_slots = jnp.roll(b_slots, -1)
+            cot_active = jnp.roll(b_active, -1).at[S - 1].set(False)
+            cotbuf = set_ring(cotbuf, cot_slots, shifted_cots, cot_active)
+            dembed = scatter_add_mb(
+                dembed, bmc[0],
+                jax.tree_util.tree_map(lambda r: r[0], d_x_rows),
+                b_active[0],
+            )
+
+            # Side cotangents: every active stage contributes to its microbatch.
+            if sides is not None and dsides is not None:
+                def one_stage_side_add(ds, s):
+                    row_leaves, _, _ = _inexact_leaves(
+                        jax.tree_util.tree_map(lambda r: r[s], d_side_rows)
+                    )
+                    vals = [row_leaves[i] for i in side_idx]
+                    return [
+                        _scatter_add_leaf(d, bmc[s], v, b_active[s])
+                        for d, v in zip(ds, vals)
+                    ]
+
+                for s in range(S):
+                    dsides = one_stage_side_add(dsides, s)
+
+            # Loss / user outputs at the last stage's backward tick.
+            losses = losses.at[m_last].set(
+                jnp.where(b_active[S - 1], loss_m.astype(jnp.float32), losses[m_last])
+            )
+            outs = scatter_set_mb(outs, m_last, user_out, b_active[S - 1])
+            return cotbuf, dlay, drep, dembed, dsides, losses, outs
+
+        cotbuf, dlay, drep, dembed, dsides, losses, outs = _run_in_span(
+            t >= t_b0, t_b0 == 0, bwd_substep,
+            (cotbuf, dlay, drep, dembed, dsides, losses, outs),
         )
-        outs = scatter_set_mb(outs, m_last, user_out, b_active[S - 1])
 
         new_carry = (inbuf, stash, cotbuf, outbuf, dlay, drep, dembed,
                      dsides, losses, outs)
         if hc is not None:
-            new_carry = new_carry + ((hbad, habs, hmb),)
+            new_carry = new_carry + (hstats,)
         return new_carry, None
 
     def _scatter_add_leaf(buf, m, val, active):

@@ -317,9 +317,10 @@ class TestAuditOff:
 # ----------------------------------------------------------------------
 
 _J = "jit(full_impl)/"
-# sha256 of tests/goldens/hlo_fingerprints.json as PR 23's tree has it.
+# sha256 of tests/goldens/hlo_fingerprints.json as PR 25's tree has it
+# (PR 23's, with `1f1b_pp2_mb4` regenerated for the conditional sub-steps).
 _GOLDENS_SHA256 = (
-    "30662943993551d27a437d9b95327456f1f483258ba2223ca7883d89ed1a44af")
+    "1141a9a2924aaa36b0217d1df780c29acd86d3e20ca39c53ceaa6f107f339090")
 # op_name (as jax 0.9.0 and this library write them on the two benchmark
 # cells' compiled steps), instruction name -> phase, nearest smp scope.
 _PHASE_CASES = [
@@ -576,7 +577,7 @@ class TestOpIndex:
 
     def test_committed_goldens_are_as_committed(self):
         """The goldens hash nothing the index adds: the file's bytes are
-        the ones PR 23's tree committed."""
+        the ones the last regeneration committed."""
         import hashlib
 
         path = os.path.join(_REPO, "tests", "goldens",

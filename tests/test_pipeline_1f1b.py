@@ -10,6 +10,7 @@ peak-memory advantage (compiled-HLO temp buffer sizes), and window
 sensitivity.
 """
 
+import functools
 import re
 
 import numpy as np
@@ -146,12 +147,20 @@ class TestInterleavedSchedule:
             assert 1 - busy / total == pytest.approx(want)
 
     def test_occupancy_default_args_match_v1_executor(self):
-        """schedule_occupancy without tick bounds keeps the v=1 executor's
-        accounting (paired ticks: total = 2*T*S)."""
+        """What the v=1 executor records: the denominator holds only the
+        sub-steps it runs (no backward on warmup ticks, no forward on
+        cooldown ticks), not the paired 2*T*S that schedule_occupancy's
+        defaults give."""
         fwd, bwd = build_1f1b_schedule(2, 4, 3)
-        busy, total = schedule_occupancy(fwd, bwd)
-        assert total == 2 * fwd.shape[0] * 2
-        assert busy == 2 * 2 * 4
+        n_ticks = fwd.shape[0]
+        busy, paired = schedule_occupancy(fwd, bwd)
+        assert (busy, paired) == (2 * 2 * 4, 2 * n_ticks * 2)
+        t_b0, t_fe = interleaved_phase_bounds(fwd, bwd)
+        assert (t_b0, t_fe, n_ticks) == (1, 5, 6)
+        busy, total = schedule_occupancy(
+            fwd, bwd, fwd_ticks=t_fe, bwd_ticks=n_ticks - t_b0
+        )
+        assert (busy, total) == (16, 2 * 5 * 2)     # bubble 1/5, was 1/3
 
     def test_phase_bounds_split_warmup_and_cooldown(self):
         fk, fm, bk, bm = build_interleaved_1f1b_schedule(2, 8, 4, 2)
@@ -191,6 +200,188 @@ def _train(cfg, steps=2, n_layers=4, batch=8, step_fn=None):
         optimizer.step()
     report = state.last_compile_report
     return losses, grads, report
+
+
+def _run_step(cfg, M):
+    """One step of a (loss, logits) train step on 2*M seeded rows: the
+    per-microbatch losses and outputs, the gradients, the step function."""
+    smp.reset()
+    smp.init(dict(cfg, microbatches=M))
+    module = TransformerLM(
+        vocab_size=32, max_len=12, d_model=16, n_layers=4, n_heads=2,
+    )
+    model = smp.DistributedModel(module)
+    ids = jax.random.randint(jax.random.key(0), (2 * M, 12), 0, 32)
+
+    @smp.step
+    def train_step(model, batch):
+        logits = model(batch)
+        loss = jnp.mean(softmax_xent(logits[:, :-1], batch[:, 1:]))
+        model.backward(loss)
+        return loss, logits
+
+    loss, logits = train_step(model, ids).stack()
+    return (np.asarray(loss), np.asarray(logits),
+            jax.device_get(model.grads), train_step)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_run(S, M):
+    """The fill-drain executor at pp=S (S > 1) or the unsplit model
+    (S == 1), once per (S, M) of the parity matrix below."""
+    cfg = {"_device_count_override": S}
+    if S > 1:
+        cfg.update(pipeline_parallel_degree=S, pipeline="simple", ddp=True)
+    return _run_step(cfg, M)[:3]
+
+
+def _plain_1f1b_cfg(S, W, M):
+    """The plain executor at window W (the config refuses a window above
+    the microbatch count; the executor clamps its own default the same)."""
+    return {"pipeline_parallel_degree": S, "active_microbatches": min(W, M),
+            "ddp": True, "_device_count_override": S}
+
+
+def _op_names(hlo_text, scope):
+    """The op_name of every instruction of the compiled text that sits
+    under ``scope`` (the op index keeps the nearest scope, not the path)."""
+    return [m.group(1) for m in hlo_audit._OP_NAME_RE.finditer(hlo_text)
+            if scope in m.group(1).split(";", 1)[0]]
+
+
+class TestPlainSkipsEmptySubSteps:
+    """The plain (v=1) executor runs only the sub-steps its baked schedule
+    uses: no backward sub-step on the leading ticks that have no backward
+    on any stage, no forward sub-step on the trailing ticks that have no
+    forward."""
+
+    @pytest.mark.parametrize("S,M,W", [
+        (2, 8, 3), (2, 4, 3), (4, 8, 5), (2, 8, 2), (3, 7, 4), (2, 2, 3),
+    ])
+    def test_parity_and_recorded_bubble(self, S, M, W):
+        losses, outs, grads, _ = _run_step(_plain_1f1b_cfg(S, W, M), M)
+        measured, _, _ = _bubble_gauges()
+        fwd, bwd = build_1f1b_schedule(S, M, min(W, M))
+        n_ticks = fwd.shape[0]
+        t_b0, t_fe = interleaved_phase_bounds(fwd, bwd)
+        busy = 2 * S * M
+        assert measured == pytest.approx(
+            1 - busy / (S * (t_fe + n_ticks - t_b0))
+        )
+        assert measured < 1 - busy / (2 * S * n_ticks)      # the paired tick's
+        for ref_S in (S, 1):
+            ref_losses, ref_outs, ref_grads = _reference_run(ref_S, M)
+            np.testing.assert_allclose(
+                losses, ref_losses, rtol=1e-4, atol=1e-5)
+            np.testing.assert_allclose(outs, ref_outs, rtol=1e-4, atol=1e-5)
+            jax.tree_util.tree_map(
+                lambda a, b: np.testing.assert_allclose(
+                    a, b, rtol=1e-3, atol=1e-5
+                ),
+                grads, ref_grads,
+            )
+
+    def test_sub_steps_sit_behind_the_tick_conditionals(self, monkeypatch):
+        """Read off the compiled pp=2, mb=4 step: every instruction of a
+        sub-step (``tick_fwd``; ``head``, ``tick_bwd``) is inside a
+        ``lax.cond`` branch of the one tick loop, and the op index still
+        attributes it to its sub-step. With bounds that leave no tick
+        without a forward or a backward (t_b0 == 0, t_fe == n_ticks) no
+        conditional is emitted, and that program, the one this executor
+        used to compile, gives the same numbers bit for bit: the sub-steps
+        skipped only ever wrote masked zeros."""
+        from smdistributed_modelparallel_tpu.parallel import pipeline_1f1b
+
+        subs = ("smp/pipeline/tick_fwd", "smp/pipeline/head",
+                "smp/pipeline/tick_bwd")
+        skipping = _run_step(_plain_1f1b_cfg(2, 3, 4), 4)
+        text = _compiled_step_hlo(skipping[3])
+        for sub in subs:
+            names = _op_names(text, sub)
+            assert names
+            assert all("cond/branch_1_fun/" in n[:n.index(sub)]
+                       for n in names), sub
+        assert not _op_names(text, "smp/pipeline/warmup")
+        assert not _op_names(text, "smp/pipeline/cooldown")
+        index = _audit_of(skipping[3]).op_index
+        assert set(subs) <= {r["scope"] for r in index.values()}
+
+        monkeypatch.setattr(
+            pipeline_1f1b, "interleaved_phase_bounds",
+            lambda fwd, bwd: (0, int(fwd.shape[0])),
+        )
+        paired = _run_step(_plain_1f1b_cfg(2, 3, 4), 4)
+        measured, _, _ = _bubble_gauges()
+        assert measured == pytest.approx(1 - 16 / 24)
+        text = _compiled_step_hlo(paired[3])
+        for sub in subs:
+            names = _op_names(text, sub)
+            assert names and not any(
+                "cond/" in n[:n.index(sub)] for n in names), sub
+        np.testing.assert_array_equal(skipping[0], paired[0])
+        np.testing.assert_array_equal(skipping[1], paired[1])
+        jax.tree_util.tree_map(
+            np.testing.assert_array_equal, skipping[2], paired[2]
+        )
+
+    def test_health_rows_name_stage_and_microbatch(self, monkeypatch):
+        """The sentinel's rows ride in the tick carry and are written by
+        the forward sub-step only: a token whose embedding is NaN and that
+        only microbatch 2 holds is put down to microbatch 2 on both
+        stages; a NaN in layer 2 (stage 1) to stage 1 alone."""
+        from smdistributed_modelparallel_tpu.utils import health
+
+        monkeypatch.setenv("SMP_HEALTH_CHECK", "cheap")
+        smp.reset()
+        smp.init(dict(_plain_1f1b_cfg(2, 3, 4), microbatches=4))
+        module = TransformerLM(
+            vocab_size=32, max_len=12, d_model=16, n_layers=4, n_heads=2,
+        )
+        model = smp.DistributedModel(module)
+        ids = jax.random.randint(jax.random.key(0), (8, 12), 0, 31)
+        ids = ids.at[4:6, 3].set(31)        # rows 4-5 are microbatch 2
+
+        @smp.step
+        def train_step(model, batch):
+            logits = model(batch)
+            loss = jnp.mean(softmax_xent(logits[:, :-1], batch[:, 1:]))
+            model.backward(loss)
+            return loss
+
+        def stage_rows():
+            train_step(model, ids)
+            health.monitor.flush()
+            tags = health.monitor.last_check["tags"]
+            return [tags[f"pp/1f1b/stage{s}"] for s in range(2)]
+
+        rows = stage_rows()
+        assert [r["bad"] for r in rows] == [0, 0]
+        assert all(r["absmax"] > 0 for r in rows)
+        clean = jax.device_get(model.params)
+
+        def poisoned(path, index):
+            params = jax.tree_util.tree_map(jnp.asarray, clean)
+            node = params
+            for key in path[:-1]:
+                node = node[key]
+            node[path[-1]] = node[path[-1]].at[index].set(jnp.nan)
+            return params
+
+        embed_path = next(
+            tuple(k.key for k in path)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(clean)
+            if leaf.shape == (32, 16)
+        )
+        model.params = poisoned(embed_path, 31)
+        rows = stage_rows()
+        assert [r["microbatch"] for r in rows] == [2, 2]
+        assert all(r["bad"] > 0 for r in rows)
+
+        model.params = poisoned(
+            ("layers", "block", "attn", "qkv", "kernel"), 2)
+        rows = stage_rows()
+        assert rows[0]["bad"] == 0 and rows[0]["microbatch"] == -1
+        assert rows[1]["bad"] > 0 and rows[1]["microbatch"] == 0
 
 
 class TestInterleavedParity:
@@ -349,7 +540,13 @@ class TestVirtualStages:
 
 
 def _strip_hlo(text):
-    return re.sub(r"metadata=\{[^}]*\}", "", text)
+    """The program without its source positions: the per-instruction
+    ``metadata={...}`` and the module header's FileNames ... StackFrames
+    tables (two call sites of one step differ there and nowhere else)."""
+    text = re.sub(r"metadata=\{[^}]*\}", "", text)
+    return re.sub(
+        r"(?ms)^FileNames\n.*?^StackFrames\n(?:\d+ \{[^\n]*\}\n)*", "", text
+    )
 
 
 def _mk_step():
