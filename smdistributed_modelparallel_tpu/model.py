@@ -127,7 +127,10 @@ class DistributedModel:
         # Run with intermediates mutable so MoE router load-balancing losses
         # (sown under "moe_aux_loss", nn/moe.py) reach the step engine; they
         # are folded into the differentiated loss in _end_step_trace.
-        from smdistributed_modelparallel_tpu.nn.moe import collect_moe_aux
+        from smdistributed_modelparallel_tpu.nn.moe import (
+            collect_moe_aux,
+            collect_moe_stats,
+        )
 
         out, mut = self.module.apply(
             variables, *args, rngs=rngs, mutable=["intermediates"], **kwargs
@@ -137,6 +140,7 @@ class DistributedModel:
             if aux is not None:
                 prev = getattr(self._tls, "aux_loss", None)
                 self._tls.aux_loss = aux if prev is None else prev + aux
+            self._tls.moe_stats = collect_moe_stats(mut.get("intermediates"))
         self._output_aval = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), out
         )
@@ -146,6 +150,15 @@ class DistributedModel:
             (args, kwargs),
         )
         return out
+
+    def moe_stats(self):
+        """Inside an @smp.step function, after the model call: the
+        dropless expert layers' counters for this microbatch, ``{layer
+        path: int32 [..., held + 1]}`` (held experts' loads, then the
+        dropped count; ``{}`` for a model without such layers). Return it
+        from the step function beside the loss, and hand the step's output
+        to ``smp.nn.record_moe_stats`` outside any timed path."""
+        return dict(getattr(self._tls, "moe_stats", None) or {})
 
     def backward(self, loss):
         """Record the scalar to differentiate for this microbatch.
@@ -173,6 +186,7 @@ class DistributedModel:
         self._tls.call_mode = None
         self._tls.captured_calls = []
         self._tls.aux_loss = None
+        self._tls.moe_stats = None
 
     def _begin_capture(self, out_aval):
         """Intercept the model call: record inputs, return zeros(out_aval)."""

@@ -37,6 +37,8 @@ from smdistributed_modelparallel_tpu.nn.transformer import (
     DistributedTransformerOutputLayer,
 )
 from smdistributed_modelparallel_tpu.nn.moe import (
+    DistributedDroplessMoE,
     DistributedMoE,
     moe_aux_losses,
+    record_moe_stats,
 )

@@ -1,10 +1,10 @@
-"""Mixture-of-Experts with expert parallelism over the ``ep`` mesh axis.
+"""Mixture-of-Experts layers: a one-hot capacity design over the ``ep`` mesh
+axis, and a dropless sort-and-group design for a chip's share of the experts.
 
 NEW CAPABILITY relative to the reference: SURVEY §2.6 records MoE/EP as
-absent from ``smdistributed.modelparallel`` v1.12.1. The TPU build carries
-an ``ep`` mesh axis from the start (``backend/topology.py:33``), and this
-module puts it to work with the GShard/Switch dense-dispatch formulation —
-the design that maps best onto XLA:
+absent from ``smdistributed.modelparallel`` v1.12.1.
+
+``DistributedMoE`` (GShard/Switch dense dispatch):
 
 - routing, position-in-expert bookkeeping, and capacity dropping are pure
   einsum/cumsum math on one-hot tensors (no scatters, no dynamic shapes —
@@ -14,19 +14,48 @@ the design that maps best onto XLA:
 - the token->expert shuffle is not hand-written: tokens are batch-sharded
   over the data axes (which include ``ep``) while expert tensors are
   ep-sharded, so GSPMD lowers the dispatch/combine einsums to the
-  all-to-all exchanges over ICI.
+  all-to-all exchanges over ICI;
+- its ``[N, E, C]`` masks grow with tokens x experts x capacity (8,192
+  tokens, 256 experts, capacity 400: 8.4e8 elements each) and a token over
+  an expert's capacity is dropped.
 
-The router's load-balancing auxiliary loss (Switch-style
+``DistributedDroplessMoE`` (many small experts, a share of them here):
+
+- the layer is told which experts it holds (``held = (first, count)`` of
+  the published ``num_experts``; all of them by default). The router keeps
+  its ``num_experts`` outputs and its ``top_k``; the layer computes what
+  its own experts add for the tokens routed to them, plus the shared
+  expert. What absent experts would add is left out: this is the part of
+  the result one expert-parallel rank computes, and on one chip it runs
+  without the exchange that would gather the other ranks' parts;
+- assignments are sorted by expert (one ``argsort``), and the held
+  experts' rows go through a grouped matrix product
+  (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
+  grouped-matmul kernel) in chunks of ``ROWS_PER_CHUNK`` sorted rows. The
+  loop runs over the chunks that hold rows, so the work follows the rows
+  that landed here and no assignment is ever dropped: the row buffer's
+  bound is the worst case, every token on every held expert;
+- gated experts without biases (``act(x W_gate) * (x W_up)) W_down``),
+  top-k weights renormalised over all ``top_k`` (held or not) and scaled
+  by ``routed_scaling``;
+- per layer it sows ``moe_stats`` (held experts' loads, dropped count)
+  into ``intermediates``; ``DistributedModel.moe_stats()`` hands them to
+  the step function, which returns them beside the loss, and
+  ``record_moe_stats`` reads them back into ``smp_moe_*`` gauges.
+
+The one-hot router's load-balancing auxiliary loss (Switch-style
 ``E * sum(fraction_routed * mean_gate)``) is sown into the
 ``intermediates`` collection under ``moe_aux_loss``; callers training with
 it add ``module.apply(..., mutable=["intermediates"])`` output, or read it
 through ``smp.nn.moe_aux_losses(...)``.
 """
 
+import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import flax.linen as nn
 
 from smdistributed_modelparallel_tpu.backend.topology import EP_AXIS, TP_AXIS
@@ -211,3 +240,294 @@ def moe_aux_losses(intermediates):
     ``moe_aux_loss_weight`` config key)."""
     total = collect_moe_aux(intermediates)
     return 0.0 if total is None else total
+
+
+# ----------------------------------------------------------------------
+# Dropless: sort by expert, grouped matrix product over the experts held
+# ----------------------------------------------------------------------
+
+
+# Sorted rows a grouped product takes at a time: large enough to fill the
+# MXU over a handful of experts, small enough that the loop's work follows
+# the rows that landed here. Not tuned per model; tests shrink it.
+ROWS_PER_CHUNK = 1024
+
+
+def _expert_ffn(rows, w_gate_up, w_down, group_sizes, weights, activation):
+    """``weights * E_e(rows)`` for sorted ``rows`` [R, D] whose first
+    ``sum(group_sizes)`` rows belong, group by group, to the held experts;
+    rows past them give zeros. ``w_gate_up`` [n, D, 2F], ``w_down``
+    [n, F, D]. A grouped product leaves the rows past its groups as the
+    memory was (on the chip: anything), so they are masked going in, in
+    the middle and coming out: nothing of them reaches a result or, through
+    the transposes, a gradient."""
+    valid = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+    rows = jnp.where(valid, rows, 0)
+    h = jax.lax.ragged_dot(rows, w_gate_up, group_sizes)
+    gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
+    h = (_activation(activation)(gate) * up).astype(rows.dtype)
+    y = jax.lax.ragged_dot(h, w_down, group_sizes)
+    return jnp.where(valid, y, 0).astype(jnp.float32) * jnp.where(
+        valid[:, 0], weights, 0.0)[:, None]
+
+
+def _chunk(c, tokens, weights, offsets, rows):
+    """Chunk ``c`` of the sorted assignments: its tokens and combine
+    weights [rows], and how many of its rows each held expert owns."""
+    start = c * rows
+    group_sizes = (jnp.clip(offsets[1:] - start, 0, rows)
+                   - jnp.clip(offsets[:-1] - start, 0, rows))
+    return (jax.lax.dynamic_slice(tokens, (start,), (rows,)),
+            jax.lax.dynamic_slice(weights, (start,), (rows,)),
+            group_sizes)
+
+
+def _used_chunks(offsets, rows):
+    return (offsets[-1] + rows - 1) // rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def held_experts_output(x, w_gate_up, w_down, weights, tokens, offsets,
+                        activation, rows):
+    """What the held experts add to each token: [N, D] float32.
+
+    ``tokens`` / ``weights`` [A]: the token and combine weight of each
+    assignment, sorted by held expert (``offsets`` [n + 1] are the
+    experts' row ranges; assignments to experts elsewhere come last and
+    are never read). Runs ``ceil(offsets[-1] / rows)`` chunks of ``rows``
+    assignments: gather the chunk's token rows, the grouped gated FFN,
+    scatter-add into the output. The loop's trip count is data, so the
+    backward pass is written out (``_held_bwd``) and runs the same chunks
+    again."""
+    out, _ = _held_fwd(x, w_gate_up, w_down, weights, tokens, offsets,
+                       activation, rows)
+    return out
+
+
+def _held_fwd(x, w_gate_up, w_down, weights, tokens, offsets, activation,
+              rows):
+    def body(c, out):
+        with jax.named_scope("smp/moe/dispatch"):
+            t, w, sizes = _chunk(c, tokens, weights, offsets, rows)
+            picked = x[t]
+        with jax.named_scope("smp/moe/experts"):
+            y = _expert_ffn(picked, w_gate_up, w_down, sizes, w, activation)
+        with jax.named_scope("smp/moe/combine"):
+            return out.at[t].add(y)
+
+    out = jax.lax.fori_loop(
+        0, _used_chunks(offsets, rows), body,
+        jnp.zeros(x.shape, jnp.float32))
+    return out, (x, w_gate_up, w_down, weights, tokens, offsets)
+
+
+def _held_bwd(activation, rows, res, g):
+    x, w_gate_up, w_down, weights, tokens, offsets = res
+
+    def body(c, carry):
+        dx, dgu, dd, dw = carry
+        with jax.named_scope("smp/moe/dispatch"):
+            t, w, sizes = _chunk(c, tokens, weights, offsets, rows)
+            picked, g_picked = x[t], g[t]
+        with jax.named_scope("smp/moe/experts"):
+            _, vjp = jax.vjp(
+                lambda r, a, b, w: _expert_ffn(r, a, b, sizes, w, activation),
+                picked, w_gate_up, w_down, w)
+            dr, da, db, dwc = vjp(g_picked)
+            dgu, dd = dgu + da.astype(jnp.float32), dd + db.astype(jnp.float32)
+        with jax.named_scope("smp/moe/combine"):
+            return (dx.at[t].add(dr.astype(jnp.float32)), dgu, dd,
+                    jax.lax.dynamic_update_slice(dw, dwc, (c * rows,)))
+
+    dx, dgu, dd, dw = jax.lax.fori_loop(
+        0, _used_chunks(offsets, rows), body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(w_gate_up.shape, jnp.float32),
+         jnp.zeros(w_down.shape, jnp.float32),
+         jnp.zeros(weights.shape, jnp.float32)))
+    no_grad = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
+    return (dx.astype(x.dtype), dgu.astype(w_gate_up.dtype),
+            dd.astype(w_down.dtype), dw, no_grad(tokens), no_grad(offsets))
+
+
+held_experts_output.defvjp(_held_fwd, _held_bwd)
+
+
+def _row_buffer(tokens, top_k, count, rows):
+    """Rows of the sorted-assignment buffer: the worst case, every token on
+    every held expert it could reach, rounded up to whole chunks."""
+    return -(-tokens * min(top_k, count) // rows) * rows
+
+
+def route_to_held(top_idx, top_weight, first, count, rows):
+    """Sort the [N, K] assignments by held expert.
+
+    Returns ``tokens`` and ``weights`` [``_row_buffer``], ``offsets``
+    [count + 1], and the counters: ``loads`` [count] and ``dropped``
+    (assignments to a held expert that the buffer has no row for: 0 under
+    ``_row_buffer``'s bound, and what a smaller buffer would lose)."""
+    N, K = top_idx.shape
+    here = (top_idx >= first) & (top_idx < first + count)
+    # ``count`` marks an expert held elsewhere: it sorts after every held one.
+    expert = jnp.where(here, top_idx - first, count).reshape(-1)
+    order = jnp.argsort(expert, stable=True)
+    loads = jnp.sum(
+        expert[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(loads)])
+    buffer = _row_buffer(N, K, count, rows)
+    order = jnp.pad(order, (0, max(0, buffer - N * K)))[:buffer]
+    landed = jnp.arange(buffer) < offsets[-1]
+    tokens = jnp.where(landed, order // K, 0).astype(jnp.int32)
+    weights = jnp.where(landed, top_weight.reshape(-1)[order], 0.0)
+    dropped = offsets[-1] - jnp.minimum(offsets[-1], buffer)
+    return tokens, weights, offsets, loads, dropped
+
+
+class DistributedDroplessMoE(nn.Module):
+    """Expert MLP block that drops no token: top-k routing over
+    ``num_experts``, a grouped matrix product over the experts ``held``
+    here, a shared expert, renormalised and scaled top-k weights. See the
+    module docstring."""
+
+    hidden_size: int
+    intermediate_size: int            # an expert's width
+    num_experts: int                  # the router's width (published count)
+    top_k: int = 2
+    held: Optional[tuple] = None      # (first, count); None: all of them
+    shared_intermediate_size: int = 0
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    activation: str = "silu"
+    initializer_range: float = 0.02
+    dtype: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, hidden):
+        from smdistributed_modelparallel_tpu.backend.state import state
+        from smdistributed_modelparallel_tpu.nn.transformer import (
+            DistributedTransformerOutputLayer,
+        )
+
+        D, F, E, K = (self.hidden_size, self.intermediate_size,
+                      self.num_experts, self.top_k)
+        first, count = self.held if self.held is not None else (0, E)
+        if not (1 <= K <= E and 0 <= first and first + count <= E
+                and count >= 1):
+            raise SMPValidationError(
+                f"dropless moe: top_k {K} of num_experts {E}, held "
+                f"({first}, {count}) must lie inside them."
+            )
+        if state.initialized and state.mesh.shape.get(EP_AXIS, 1) > 1:
+            raise SMPValidationError(
+                "DistributedDroplessMoE computes one expert-parallel rank's "
+                "share; the exchange between ranks (expert_parallel_degree "
+                "> 1) is not written yet. Use DistributedMoE over ep."
+            )
+        dtype = self.dtype or hidden.dtype
+        init = _init(self.initializer_range)
+        B, T = hidden.shape[0], hidden.shape[1]
+        x = hidden.reshape(B * T, D)
+
+        with jax.named_scope("smp/moe/route"):
+            router_kernel = self.param(
+                "router/kernel", init, (D, E), jnp.float32)
+            probs = jax.nn.softmax(jnp.dot(
+                x.astype(jnp.float32), router_kernel,
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+            top_weight, top_idx = jax.lax.top_k(probs, K)
+            if self.norm_topk:
+                top_weight = top_weight / jnp.sum(
+                    top_weight, axis=-1, keepdims=True)
+            top_weight = top_weight * self.routed_scaling
+        with jax.named_scope("smp/moe/dispatch"):
+            tokens, weights, offsets, loads, dropped = route_to_held(
+                top_idx, top_weight, first, count, ROWS_PER_CHUNK)
+        self.sow("intermediates", "moe_stats",
+                 jnp.concatenate([loads, dropped[None]]))
+
+        gate_up = self.param(
+            "experts/gate_up/kernel", init, (count, D, 2, F), dtype)
+        down = self.param("experts/down/kernel", init, (count, F, D), dtype)
+        # Its own scopes inside: gather, grouped FFN, scatter-add.
+        out = held_experts_output(
+            x, gate_up.astype(x.dtype).reshape(count, D, 2 * F),
+            down.astype(x.dtype), weights, tokens, offsets,
+            self.activation, ROWS_PER_CHUNK).reshape(B, T, D)
+        if self.shared_intermediate_size:
+            with jax.named_scope("smp/moe/shared"):
+                shared = DistributedTransformerOutputLayer(
+                    hidden_size=D,
+                    intermediate_size=self.shared_intermediate_size,
+                    hidden_dropout_prob=0.0, activation=self.activation,
+                    initializer_range=self.initializer_range,
+                    use_mlp_bias=False, gated_mlp=True, dtype=self.dtype,
+                    name="shared",
+                )(hidden)
+        with jax.named_scope("smp/moe/combine"):
+            if self.shared_intermediate_size:
+                out = out + shared.astype(jnp.float32)
+            out = out.astype(hidden.dtype)
+        memory_opt = _cfg("optimize", "speed") == "memory"
+        return shard_activation(out, *_hidden_spec(memory_opt))
+
+
+def collect_moe_stats(intermediates):
+    """``{layer path: stats}`` of every sown ``moe_stats`` in an
+    intermediates tree: int32 [..., count + 1] (a leading axis for each
+    scan the layer sits in), the held experts' loads and then the dropped
+    count. ``{}`` when nothing was sown."""
+    found = {}
+    if not intermediates:
+        return found
+    for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
+        keys = [getattr(k, "key", None) for k in path]
+        if "moe_stats" in keys:
+            found["/".join(k for k in keys[:keys.index("moe_stats")]
+                           if isinstance(k, str))] = leaf
+    return found
+
+
+def record_moe_stats(stats):
+    """Read a step's ``moe_stats`` back (a host transfer: call it outside
+    a timed path) into ``smp_moe_local_assignments`` (the assignments that
+    landed on held experts, summed over layers and microbatches),
+    ``smp_moe_dropped_assignments`` and, for each expert layer,
+    ``smp_moe_expert_load_max_over_mean{layer}``. ``stats``: what the step
+    function returned from ``model.moe_stats()`` (arrays or the
+    ``StepOutput`` holding them). Returns ``{"local", "dropped",
+    "max_over_mean": {layer: value}}``."""
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    if hasattr(stats, "stack"):
+        stats = stats.stack()
+    local, dropped, ratios = 0, 0, {}
+    for path, leaf in sorted(stats.items()):
+        leaf = np.asarray(leaf)
+        count = leaf.shape[-1] - 1
+        # [microbatches, (scans ...,) layers of this run, count + 1]
+        per_layer = leaf.reshape(leaf.shape[0], -1, count + 1).sum(axis=0)
+        for i, row in enumerate(per_layer):
+            loads = row[:count].astype(np.float64)
+            local += int(loads.sum())
+            dropped += int(row[count])
+            ratios[f"{path}#{i}"] = (
+                float(loads.max() / loads.mean()) if loads.sum() else 0.0)
+    telemetry.gauge(
+        "smp_moe_local_assignments",
+        "token-expert assignments of the last recorded step that landed on "
+        "experts held here (all expert layers, all microbatches)",
+    ).set(local)
+    telemetry.gauge(
+        "smp_moe_dropped_assignments",
+        "assignments to a held expert the last recorded step did not "
+        "compute (the dropless layer's bound makes this 0)",
+    ).set(dropped)
+    ratio_gauge = telemetry.gauge(
+        "smp_moe_expert_load_max_over_mean",
+        "largest held expert's load over the mean load, per expert layer, "
+        "last recorded step",
+    )
+    for layer, value in ratios.items():
+        ratio_gauge.labels(layer=layer).set(value)
+    return {"local": local, "dropped": dropped, "max_over_mean": ratios}

@@ -29,6 +29,7 @@ query-key-layer-scaling and GPT-Neo-style alternating local/global
 attention.
 """
 
+import contextlib
 from typing import Any, Optional
 
 import numpy as np
@@ -93,6 +94,11 @@ def _seq_parallel(memory_opt):
     return memory_opt or _ring_active()
 
 
+def _named_scope(name):
+    """``jax.named_scope(name)``, or nothing for ``None``."""
+    return contextlib.nullcontext() if name is None else jax.named_scope(name)
+
+
 def _init(range_, use_normal=True):
     return nn.initializers.normal(stddev=range_)
 
@@ -117,8 +123,41 @@ def _fp8_mm(x, w, site, **kw):
     return quant.fp8_matmul(x, w, site, **kw)
 
 
-def apply_rotary(q, k, rotary_dim, base=10000.0, neox_style=False, offset=0):
+def yarn_inv_freq(rotary_dim, base, factor, original_max_position,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN inverse frequencies [rotary_dim // 2] (float64 numpy, static).
+
+    Dimension i of the rotary half has the plain frequency
+    ``base ** (-2i / rotary_dim)`` (extrapolation) or that over ``factor``
+    (interpolation), blended by a linear ramp between the dimensions that
+    make ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position`` positions (floor / ceil, clamped to the
+    dimensions there are): ramp 0 keeps the plain frequency, ramp 1 takes
+    the interpolated one."""
+    half = rotary_dim // 2
+    pos_freqs = base ** (np.arange(half, dtype=np.float64) / half)
+
+    def correction_dim(rotations):
+        return (rotary_dim * np.log(
+            original_max_position / (rotations * 2 * np.pi)
+        )) / (2 * np.log(base))
+
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+
+
+def apply_rotary(q, k, rotary_dim, base=10000.0, neox_style=False, offset=0,
+                 yarn=None):
     """Rotary position embedding on the first ``rotary_dim`` channels.
+
+    ``yarn``: ``(factor, original_max_position, beta_fast, beta_slow,
+    attention_factor)`` switches the frequencies to ``yarn_inv_freq`` and
+    multiplies cos and sin by ``attention_factor``.
 
     Parity: reference ``torch/nn/transformer.py:114-183`` — interleaved
     (GPT-J) vs half-split (``gpt_neox_type_rotary``) variants.
@@ -132,12 +171,17 @@ def apply_rotary(q, k, rotary_dim, base=10000.0, neox_style=False, offset=0):
         d = rotary_dim
         x_rot, x_pass = x[..., :d], x[..., d:]
         half = d // 2
-        freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+        if yarn is None:
+            freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+        else:
+            freqs = jnp.asarray(yarn_inv_freq(d, base, *yarn[:4]), jnp.float32)
         off = jnp.asarray(offset, jnp.float32)
         t = off[..., None] + jnp.arange(T, dtype=jnp.float32)  # [T] or [B,T]
         angles = t[..., None] * freqs                 # [.., T, half]
         cos = jnp.cos(angles)[..., None, :]
         sin = jnp.sin(angles)[..., None, :]
+        if yarn is not None:
+            cos, sin = cos * yarn[4], sin * yarn[4]
         if cos.ndim == 3:                             # scalar offset
             cos = cos[None]
             sin = sin[None]
@@ -187,7 +231,17 @@ class DistributedAttentionLayer(nn.Module):
     rotary_dim: Optional[int] = None
     rotary_emb_base: Optional[float] = None
     gpt_neox_type_rotary: bool = False
+    # (factor, original_max_position, beta_fast, beta_slow,
+    # attention_factor): YaRN frequencies for this layer's rotary.
+    rotary_yarn: Optional[tuple] = None
     window_size: Optional[int] = None
+    # Grouped KV heads: K and V have this many heads (it divides
+    # num_attention_heads); query head h reads KV head h // group. None:
+    # as many as query heads, in one fused [D, 3, H, hd] kernel.
+    num_key_value_heads: Optional[int] = None
+    # Per-head output gate: head h's attention output is multiplied by
+    # sigmoid(x W_g)[h] before the output projection.
+    head_gate: bool = False
     # KV-cache decoding for smp.generate (nn/utils.DecodeKVCache); only
     # self-attention caches (cross-attention K/V are recomputed from the
     # encoder states passed each step).
@@ -287,6 +341,40 @@ class DistributedAttentionLayer(nn.Module):
                 kv = self.variable("cache", "cross_kv", cross_kv).value
             else:
                 kv = cross_kv()
+            k, v = kv[:, 0], kv[:, 1]
+        elif self.num_key_value_heads not in (None, H):
+            Hkv = self.num_key_value_heads
+            if H % Hkv:
+                raise SMPValidationError(
+                    f"num_key_value_heads ({Hkv}) must divide "
+                    f"num_attention_heads ({H})."
+                )
+            q_kernel = self.param(
+                "query/kernel", partitioned(init, (None, TP_AXIS, None)),
+                (D, H, hd), dtype,
+            )
+            kv_kernel = self.param(
+                "key_value/kernel",
+                partitioned(init, (None, None, TP_AXIS, None)),
+                (D, 2, Hkv, hd), dtype,
+            )
+            q = jnp.einsum("btd,dhk->bthk", hidden, q_kernel.astype(hidden.dtype))
+            kv = jnp.einsum(
+                "btd,dchk->bcthk", hidden, kv_kernel.astype(hidden.dtype)
+            )
+            if self.use_qkv_bias:
+                q_bias = self.param(
+                    "query/bias",
+                    partitioned(nn.initializers.zeros, (TP_AXIS, None)),
+                    (H, hd), dtype,
+                )
+                kv_bias = self.param(
+                    "key_value/bias",
+                    partitioned(nn.initializers.zeros, (None, TP_AXIS, None)),
+                    (2, Hkv, hd), dtype,
+                )
+                q = q + q_bias.astype(q.dtype)
+                kv = kv + kv_bias[:, None].astype(kv.dtype)
             k, v = kv[:, 0], kv[:, 1]
         else:
             qkv_kernel = self.param(
@@ -399,7 +487,7 @@ class DistributedAttentionLayer(nn.Module):
                     "decode."
                 )
             cache = DecodeKVCache(
-                self, (B, self.decode_cache_len, H, hd), k.dtype
+                self, (B, self.decode_cache_len, k.shape[2], hd), k.dtype
             )
 
             # Left-padded prompts: each row's absolute positions shift
@@ -417,6 +505,8 @@ class DistributedAttentionLayer(nn.Module):
                 base=self.rotary_emb_base or 10000.0,
                 neox_style=self.gpt_neox_type_rotary,
                 offset=pos_offset,
+                **({} if self.rotary_yarn is None
+                   else {"yarn": tuple(self.rotary_yarn)}),
             )
 
         if cache is not None:
@@ -490,6 +580,16 @@ class DistributedAttentionLayer(nn.Module):
             dropout_rng=dropout_rng,
             use_pallas=_cfg("use_pallas_kernels", True),
         )
+
+        if self.head_gate:
+            gate_kernel = self.param(
+                "gate/kernel", partitioned(init, (None, TP_AXIS)), (D, H),
+                dtype,
+            )
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "btd,dh->bth", hidden, gate_kernel.astype(hidden.dtype)
+            ).astype(jnp.float32))
+            ctx = (ctx * gate[..., None]).astype(ctx.dtype)
 
         proj_kernel = self.param(
             "dense/kernel",
@@ -726,6 +826,23 @@ class DistributedTransformerLayer(nn.Module):
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # Set per layer kind by a stack with a ``layer_pattern`` (see
+    # DistributedTransformer): attention shape of this kind of layer ...
+    rotary_yarn: Optional[tuple] = None
+    num_key_value_heads: Optional[int] = None
+    head_gate: bool = False
+    # ... its expert layer: dropless (nn/moe.DistributedDroplessMoE) with
+    # the ``(first, count)`` range of the ``num_experts`` it holds, a shared
+    # expert's width, renormalised top-k weights and their scale ...
+    moe_dropless: bool = False
+    moe_held: Optional[tuple] = None
+    moe_shared_intermediate_size: int = 0
+    moe_norm_topk: bool = True
+    moe_routed_scaling: float = 1.0
+    # ... and the kind's name, which a patterned stack always sets: the
+    # layer's ops then trace under ``smp/layer/<kind>`` and its attention
+    # under ``smp/attn/window`` or ``smp/attn/full``.
+    kind: Optional[str] = None
     decode: bool = False
     decode_cache_len: Optional[int] = None
     deterministic: Optional[bool] = None
@@ -733,6 +850,11 @@ class DistributedTransformerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, cross_states=None, attention_mask=None, xs=None):
+        with _named_scope(self.kind and f"smp/layer/{self.kind}"):
+            return self._block(hidden, cross_states, attention_mask, xs)
+
+    @nn.nowrap
+    def _block(self, hidden, cross_states, attention_mask, xs):
         # attention_mask may be a (self_mask, cross_mask) pair: the stack's
         # carry protocol has one mask slot, and T5-style models need both a
         # per-head relative-position bias on self-attention and an encoder
@@ -763,14 +885,44 @@ class DistributedTransformerLayer(nn.Module):
             rotary_dim=self.rotary_dim,
             rotary_emb_base=self.rotary_emb_base,
             gpt_neox_type_rotary=self.gpt_neox_type_rotary,
+            rotary_yarn=self.rotary_yarn,
             window_size=self.window_size,
+            num_key_value_heads=self.num_key_value_heads,
+            head_gate=self.head_gate,
             decode=self.decode,
             decode_cache_len=self.decode_cache_len,
             deterministic=self.deterministic,
             dtype=self.dtype,
             name="attention",
         )
-        if self.num_experts > 0:
+        attention = attn
+
+        def attn(*args, **kwargs):
+            with _named_scope(self.kind and (
+                    "smp/attn/window" if self.window_size
+                    else "smp/attn/full")):
+                return attention(*args, **kwargs)
+
+        if self.num_experts > 0 and self.moe_dropless:
+            from smdistributed_modelparallel_tpu.nn.moe import (
+                DistributedDroplessMoE,
+            )
+
+            mlp = DistributedDroplessMoE(
+                hidden_size=self.hidden_size,
+                intermediate_size=self.intermediate_size,
+                num_experts=self.num_experts,
+                top_k=self.moe_top_k,
+                held=self.moe_held,
+                shared_intermediate_size=self.moe_shared_intermediate_size,
+                norm_topk=self.moe_norm_topk,
+                routed_scaling=self.moe_routed_scaling,
+                activation=self.activation,
+                initializer_range=self.initializer_range,
+                dtype=self.dtype,
+                name="output",
+            )
+        elif self.num_experts > 0:
             from smdistributed_modelparallel_tpu.nn.moe import DistributedMoE
 
             mlp = DistributedMoE(
@@ -895,8 +1047,100 @@ class _LayerScanBody(nn.Module):
         return (out, cross_states, attention_mask), ys
 
 
+def pattern_segments(pattern):
+    """A per-layer pattern of kind names as ``[(repeats, [(kind, count),
+    ...]), ...]``: runs of one kind, and a sequence of runs that repeats
+    folded into one segment (leading layers, whole periods, a tail).
+    ``("lead", "w", "w", "w", "f") * ...`` -> lead once, then (3 w, 1 f)
+    as often as it repeats, then what is left."""
+    runs = []
+    for kind in pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    runs = [tuple(r) for r in runs]
+    segments, at = [], 0
+    while at < len(runs):
+        best = (1, 1)                                  # (repeats, period)
+        for period in range(1, (len(runs) - at) // 2 + 1):
+            unit, repeats = runs[at:at + period], 1
+            while runs[at + repeats * period:
+                       at + (repeats + 1) * period] == unit:
+                repeats += 1
+            if repeats > 1 and repeats * period > best[0] * best[1]:
+                best = (repeats, period)
+        repeats, period = best
+        segments.append((repeats, runs[at:at + period]))
+        at += repeats * period
+    return segments
+
+
+def pattern_layer_paths(pattern):
+    """Where each layer of a patterned stack keeps its parameters:
+    ``[(path under the stack, index into the leading scan axes)]`` in
+    layer order. A run outside a period is ``seq_layers_<n>_<kind>/layer``
+    indexed ``(j,)``; inside one, ``seq_layers_<n>_period/<i>_<kind>/layer``
+    indexed ``(repeat, j)``."""
+    where, at = [None] * len(pattern), 0
+    for n, (repeats, runs) in enumerate(pattern_segments(pattern)):
+        period, start = sum(count for _, count in runs), 0
+        for i, (kind, count) in enumerate(runs):
+            for r in range(repeats):
+                for j in range(count):
+                    where[at + r * period + start + j] = (
+                        (f"seq_layers_{n}_{kind}/layer", (j,))
+                        if repeats == 1 and len(runs) == 1 else
+                        (f"seq_layers_{n}_period/{i}_{kind}/layer", (r, j)))
+            start += count
+        at += repeats * period
+    return where
+
+
+class _PeriodScanBody(nn.Module):
+    """One period of a patterned stack: a scan over each of its runs."""
+
+    runs: tuple               # ((kind, count, layer kwargs), ...)
+    body: Any
+
+    @nn.compact
+    def __call__(self, carry, xs):
+        for i, (kind, count, kwargs) in enumerate(self.runs):
+            carry, _ = _scan_layers(self.body, count)(
+                kwargs, name=f"{i}_{kind}"
+            )(carry, {"layer_idx": xs["layer_idx"][i]})
+        return carry, None
+
+
+def _scan_layers(body, length):
+    return nn.scan(
+        body,
+        # intermediates: per-layer sown values (MoE aux losses) stack
+        # on the layer axis when applied with mutable=["intermediates"];
+        # cache: per-layer decode KV caches (smp.generate).
+        variable_axes={"params": 0, "intermediates": 0, "cache": 0},
+        split_rngs={"params": True, "dropout": True},
+        length=length,
+        in_axes=(0,),
+        # The scan (layer) axis carries no TP name; its 'pp' sharding is
+        # applied by the pipeline's spec provider at partition time.
+        metadata_params={nn.meta.PARTITION_NAME: None},
+    )
+
+
 class DistributedTransformer(nn.Module):
     """The scanned transformer stack.
+
+    ``layer_pattern`` (a kind name for each layer) with ``layer_kinds``
+    ({kind: overrides of the layer's fields}) builds a stack of layers
+    that differ in shape: head counts, window, rotary, dense or expert
+    MLP. The pattern is static: consecutive layers of one kind are one
+    scan with their parameters stacked (``seq_layers_<segment>_<kind>``),
+    and a sequence of runs that repeats is scanned over its repeats with
+    the runs' scans inside (``..._period/<run>_<kind>``). Each layer's
+    window is a Python constant, so the static-window kernel path holds.
+    Without a pattern every layer is alike and the stack is one scan
+    (``seq_layers``), as before.
 
     Parity: reference ``DistributedTransformer`` (``torch/nn/transformer.py:
     551-687``) — ``seq_layers`` of DistributedTransformerLayer. Accepts the
@@ -937,6 +1181,8 @@ class DistributedTransformer(nn.Module):
     use_mlp_bias: bool = True
     gated_mlp: bool = False
     attention_layers_type: Optional[tuple] = None
+    layer_pattern: Optional[tuple] = None
+    layer_kinds: Optional[Any] = None
     activation_checkpointing: bool = False
     num_experts: int = 0
     moe_top_k: int = 2
@@ -945,6 +1191,12 @@ class DistributedTransformer(nn.Module):
     decode_cache_len: Optional[int] = None
     deterministic: Optional[bool] = None
     dtype: Optional[Any] = None
+
+    @nn.nowrap
+    def _kind_kwargs(self, kind):
+        """The layer kwargs of one kind of a patterned stack."""
+        return dict(self._layer_kwargs(), **dict(self.layer_kinds[kind]),
+                    kind=kind)
 
     @nn.nowrap
     def _layer_kwargs(self):
@@ -1016,22 +1268,57 @@ class DistributedTransformer(nn.Module):
             # container (torch/module_manager.py:969-1010) -> per-layer
             # remat, optionally offloading the boundary activation.
             body = nn.remat(body, policy=remat_policy())
-        ScanLayers = nn.scan(
-            body,
-            # intermediates: per-layer sown values (MoE aux losses) stack
-            # on the layer axis when applied with mutable=["intermediates"];
-            # cache: per-layer decode KV caches (smp.generate).
-            variable_axes={"params": 0, "intermediates": 0, "cache": 0},
-            split_rngs={"params": True, "dropout": True},
-            length=self.num_layers,
-            in_axes=(0,),
-            # The scan (layer) axis carries no TP name; its 'pp' sharding is
-            # applied by the pipeline's spec provider at partition time.
-            metadata_params={nn.meta.PARTITION_NAME: None},
-        )
+        if self.layer_pattern is not None:
+            self.segments = self._pattern_segments(body)
+            return
+        ScanLayers = _scan_layers(body, self.num_layers)
         self.seq_layers = ScanLayers(self._layer_kwargs(), name="seq_layers")
 
+    @nn.nowrap
+    def _pattern_segments(self, body):
+        """``[(module, xs)]`` of a patterned stack, in layer order."""
+        pattern = tuple(self.layer_pattern)
+        if len(pattern) != self.num_layers:
+            raise SMPValidationError(
+                "layer_pattern must have num_layers entries."
+            )
+        if self.attention_layers_type is not None or _fp8_active():
+            raise SMPValidationError(
+                "layer_pattern gives each layer a static window and shape; "
+                "it takes neither attention_layers_type nor fp8 matmuls."
+            )
+        built, at = [], 0
+        for n, (repeats, runs) in enumerate(pattern_segments(pattern)):
+            period = sum(count for _, count in runs)
+            # layer_idx [repeats, count] of each run: this segment's slice
+            # of 0 .. num_layers - 1.
+            idx, start = [], at
+            for _, count in runs:
+                idx.append(jnp.asarray(
+                    start + np.arange(count)[None, :]
+                    + period * np.arange(repeats)[:, None], jnp.int32))
+                start += count
+            at += repeats * period
+            if repeats == 1 and len(runs) == 1:
+                kind, count = runs[0]
+                module = _scan_layers(body, count)(
+                    self._kind_kwargs(kind), name=f"seq_layers_{n}_{kind}")
+                xs = {"layer_idx": idx[0][0]}
+            else:
+                module = _scan_layers(_PeriodScanBody, repeats)(
+                    tuple((kind, count, self._kind_kwargs(kind))
+                          for kind, count in runs),
+                    body, name=f"seq_layers_{n}_period")
+                xs = {"layer_idx": tuple(idx)}
+            built.append((module, xs))
+        return built
+
     def __call__(self, hidden, cross_states=None, attention_mask=None):
+        if self.layer_pattern is not None:
+            carry = (hidden, cross_states, attention_mask)
+            for module, xs in self.segments:
+                carry, _ = module(carry, xs)
+            return carry[0]
         (out, _, _), ys = self.seq_layers(
             (hidden, cross_states, attention_mask), self.layer_xs()
         )
@@ -1055,6 +1342,8 @@ class DistributedTransformer(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
+        if self.layer_pattern is not None:
+            return None     # two kinds of layer in a stage: not yet
         return PipelineSpec(
             layer_path="seq_layers/layer",
             num_layers=self.num_layers,
@@ -1115,6 +1404,13 @@ class DistributedTransformerLMHead(nn.Module):
     parallel_attn_output: bool = False
     use_lm_head_bias: bool = False
     attention_layers_type: Optional[tuple] = None
+    # A stack of layers that differ in shape: see DistributedTransformer.
+    layer_pattern: Optional[tuple] = None
+    layer_kinds: Optional[Any] = None
+    # "layer" or "rms" (RMSNorm, no bias), for every norm of the model.
+    layernorm_type: str = "layer"
+    use_mlp_bias: bool = True
+    gated_mlp: bool = False
     use_qkv_bias: bool = True
     use_attn_dense_bias: bool = True
     window_size: Optional[int] = None
@@ -1170,8 +1466,12 @@ class DistributedTransformerLMHead(nn.Module):
             **self._transformer_kwargs(), name="transformer"
         )
         if self.final_layernorm or self.pre_layernorm:
+            rms = (
+                {"rms": True, "use_bias": False}
+                if self.layernorm_type == "rms" else {}
+            )
             self.ln_f = DistributedLayerNorm(
-                epsilon=self.layernorm_epsilon, name="ln_f"
+                epsilon=self.layernorm_epsilon, name="ln_f", **rms
             )
         if self.add_lm_head and not self.tie_input_output_embedding:
             self.lm_head = nn.Dense(
@@ -1218,7 +1518,12 @@ class DistributedTransformerLMHead(nn.Module):
             window_size=self.window_size,
             parallel_attn_output=self.parallel_attn_output,
             causal_mask_size=self.causal_mask_size,
+            layernorm_type=self.layernorm_type,
+            use_mlp_bias=self.use_mlp_bias,
+            gated_mlp=self.gated_mlp,
             attention_layers_type=self.attention_layers_type,
+            layer_pattern=self.layer_pattern,
+            layer_kinds=self.layer_kinds,
             activation_checkpointing=self.activation_checkpointing,
             num_experts=self.num_experts,
             moe_top_k=self.moe_top_k,
@@ -1326,6 +1631,8 @@ class DistributedTransformerLMHead(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
+        if self.layer_pattern is not None:
+            return None     # two kinds of layer in a stage: not yet
         return PipelineSpec(
             layer_path="transformer/seq_layers/layer",
             num_layers=self.num_layers,
@@ -1336,6 +1643,8 @@ class DistributedTransformerLMHead(nn.Module):
                     if k not in (
                         "num_layers",
                         "attention_layers_type",
+                        "layer_pattern",
+                        "layer_kinds",
                         "activation_checkpointing",
                     )
                 }

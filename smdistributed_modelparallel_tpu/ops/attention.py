@@ -76,7 +76,10 @@ def attention_core(
     dropout_rng=None,
     use_pallas: bool = True,
 ):
-    """Multi-head attention over [B, T, H, hd] q and [B, S, H, hd] k/v.
+    """Multi-head attention over [B, T, H, hd] q and [B, S, H_kv, hd] k/v.
+    H_kv may divide H (grouped KV heads: query head h reads KV head
+    h // (H / H_kv)); the flash kernels share the K/V blocks, the jnp path
+    repeats them.
 
     Args:
       causal/window: static masking (window = local attention band).
@@ -120,6 +123,7 @@ def attention_core(
         and local_select is None
         and window is None
         and q.shape[1] == k.shape[1]
+        and q.shape[2] == k.shape[2]
         and q.shape[1] % cp_size() == 0
         # The in-region flash kernels share _pallas_ok's mixed-dtype
         # restriction (MXU dots run on the operand dtype).
@@ -162,6 +166,9 @@ def attention_core(
         )
 
     T, S = q.shape[1], k.shape[1]
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     compute_dtype = jnp.float32 if attention_in_fp32 else q.dtype
     # Pre-scale q so the half-precision score matmul cannot overflow
     # (reference applies the norm factor inside the baddbmm alpha).
@@ -240,7 +247,9 @@ def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
     n_data = int(np.prod([mesh.shape[a] for a in data])) if data else 1
     b_axes = data if data and B % n_data == 0 else None
     tp = mesh.shape[TP_AXIS]
-    h_axis = TP_AXIS if tp > 1 and H % tp == 0 else None
+    h_axis = (
+        TP_AXIS if tp > 1 and H % tp == 0 and k.shape[2] % tp == 0 else None
+    )
     qkv_spec = P(b_axes, None, h_axis, None)
 
     operands, specs = [q, k, v], [qkv_spec] * 3

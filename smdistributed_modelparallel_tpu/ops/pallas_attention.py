@@ -29,6 +29,13 @@ logsumexp and the precomputed ``delta = rowsum(dO * O)``, the standard
 flash-attention backward decomposition.
 
 Layout: inputs [B, T, H, hd]; kernels run on [B*H, T, hd].
+
+Grouped KV heads: k and v may hold fewer heads than q ([B, S, H_kv, hd],
+H a multiple of H_kv); query head h reads KV head h // (H / H_kv). The
+forward and dq kernels reach the shared K/V block through their index map
+(no repeated copy in HBM); the dkv kernel writes each query head's
+contribution in fp32 and the group is summed outside it. With H_kv == H
+every index map and shape is the multi-head one.
 """
 
 import functools
@@ -483,9 +490,24 @@ def _clamp_block(block, dim):
     return min(block, ((dim + 127) // 128) * 128)
 
 
+def _kv_index(group):
+    """Index maps of a K/V block: the whole [s_pad] rows for the forward
+    and dq kernels, one kv block for the dkv kernel. Program ``b`` runs
+    query head ``b % H`` of batch row ``b // H``; with H = H_kv * group its
+    KV head sits at ``b // group`` of the [B*H_kv] leading axis."""
+    if group == 1:
+        return (lambda b, i: (b, 0, 0)), (lambda b, j: (b, j, 0))
+    return (lambda b, i: (b // group, 0, 0)), (lambda b, j: (b // group, j, 0))
+
+
 def _prep(q, k, v, block_q, block_k):
     B, T, H, hd = q.shape
     S = k.shape[1]
+    if H % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"flash attention: {H} query heads against {k.shape[2]} key and "
+            f"{v.shape[2]} value heads (H must be a multiple of H_kv)."
+        )
 
     def to_bht(x):
         return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], x.shape[1], hd)
@@ -501,6 +523,10 @@ def _prep(q, k, v, block_q, block_k):
         kt = jnp.pad(kt, pad)
         vt = jnp.pad(vt, pad)
     return qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad)
+
+
+def _group_of(q, k):
+    return q.shape[2] // k.shape[2]
 
 
 def _common_inputs(kpad_bias, seed, s_pad, B, H, interpret, head0=None):
@@ -566,6 +592,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         id_in, id_specs = _ids_extra(q_ids, kv_ids, t_pad, s_pad)
         extra, extra_specs = extra + id_in, extra_specs + id_specs
     grid = (B * H, t_pad // block_q)
+    kv_whole, _ = _kv_index(_group_of(q, k))
     kern = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
         q_len=T, kv_len=S, causal=causal, window=window,
@@ -580,8 +607,8 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_pad, hd_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_pad, hd_pad), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
+            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
             *extra_specs,
         ],
         out_specs=[
@@ -639,14 +666,19 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
     )
     res_spec_q = pl.BlockSpec((1, t_pad, hd_pad), lambda b, i: (b, 0, 0))
     row_spec = pl.BlockSpec((1, 1, t_pad), lambda b, i: (b, 0, 0))
+    group = _group_of(q, k)
+    kv_whole, kv_block = _kv_index(group)
+    # A query head's dk/dv is a partial sum when its KV head is shared:
+    # fp32 out of the kernel, summed over the group below.
+    partial_kv = group > 1
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(B * H, t_pad // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s_pad, hd_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s_pad, hd_pad), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
+            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
             pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
@@ -665,8 +697,8 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         grid=(B * H, s_pad // block_k),
         in_specs=[
             res_spec_q,
-            pl.BlockSpec((1, block_k, hd_pad), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, hd_pad), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, hd_pad), kv_block),
+            pl.BlockSpec((1, block_k, hd_pad), kv_block),
             res_spec_q,
             row_spec,
             row_spec,
@@ -680,10 +712,12 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
             # ids mode: fp32 per-step gradients for the ring's rotating
             # accumulators (see fwd out_shape note).
             jax.ShapeDtypeStruct(
-                (B * H, s_pad, hd_pad), jnp.float32 if has_ids else k.dtype
+                (B * H, s_pad, hd_pad),
+                jnp.float32 if has_ids or partial_kv else k.dtype,
             ),
             jax.ShapeDtypeStruct(
-                (B * H, s_pad, hd_pad), jnp.float32 if has_ids else v.dtype
+                (B * H, s_pad, hd_pad),
+                jnp.float32 if has_ids or partial_kv else v.dtype,
             ),
         ],
         name="smp_flash_bwd_dkv",
@@ -693,7 +727,14 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
     def from_bht(x, L):
         return x[:, :L, :hd].reshape(B, H, L, hd).transpose(0, 2, 1, 3)
 
-    return from_bht(dq, T), from_bht(dk, S), from_bht(dv, S)
+    def kv_from_bht(x, like):
+        if not partial_kv:
+            return from_bht(x, S)
+        x = x[:, :S, :hd].reshape(B, H // group, group, S, hd).sum(axis=2)
+        return x.astype(jnp.float32 if has_ids else like.dtype).transpose(
+            0, 2, 1, 3)
+
+    return from_bht(dq, T), kv_from_bht(dk, k), kv_from_bht(dv, v)
 
 
 # ----------------------------------------------------------------------
@@ -707,7 +748,8 @@ def flash_attention(q, k, v, kpad_bias=None, seed=None, head0=None,
                     scale=None, causal=True, window=None, dropout_rate=0.0,
                     block_q=None, block_k=None, interpret=False,
                     head_total=None, counter_len=None):
-    """Flash attention over [B, T, H, hd] q and [B, S, H, hd] k/v.
+    """Flash attention over [B, T, H, hd] q and [B, S, H_kv, hd] k/v
+    (H a multiple of H_kv; query head h reads KV head h // (H / H_kv)).
 
     ``kpad_bias``: additive float [B, S] bias (0 keep / -1e30 drop for
     boolean masks). ``seed``: int32 scalar array enabling dropout at

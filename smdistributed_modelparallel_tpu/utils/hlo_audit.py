@@ -340,9 +340,15 @@ def phase_of(op_name, instr_name=""):
     return "other"
 
 
+def scopes_of(op_name):
+    """Every ``smp/<subsystem>/<name>`` segment of an ``op_name``, outermost
+    first (a layer's scope, then its attention's or its expert layer's)."""
+    return tuple(_SCOPE_RE.findall(op_name.split(";", 1)[0]))
+
+
 def scope_of(op_name):
     """The ``smp/<subsystem>/<name>`` segment nearest the leaf, or None."""
-    found = _SCOPE_RE.findall(op_name.split(";", 1)[0])
+    found = scopes_of(op_name)
     return found[-1] if found else None
 
 
@@ -362,7 +368,8 @@ def op_records(hlo_text, mesh=None):
     """``{instruction name: record}`` over every instruction of the HLO
     text, in text order, keyed as a device trace prints the name (no
     ``%``). A record holds ``phase`` (``phase_of``) and ``scope``
-    (``scope_of``); a collective's also ``op``, ``axis`` (the mesh-axis
+    (``scope_of``), and where scopes are nested ``scopes`` (``scopes_of``:
+    every scope round the instruction); a collective's also ``op``, ``axis`` (the mesh-axis
     label of its ``replica_groups`` / ``source_target_pairs``) and
     ``bytes`` (per-device result payload). The ``-done`` half of an async
     pair takes its axis from its ``-start`` and is marked ``done`` (the
@@ -402,7 +409,11 @@ def op_records(hlo_text, mesh=None):
             called = _CALLS_RE.search(line)
             if called is not None:
                 op_name = comp_op_name.get(called.group(1), "")
-        rec = {"phase": phase_of(op_name, name), "scope": scope_of(op_name)}
+        scopes = scopes_of(op_name)
+        rec = {"phase": phase_of(op_name, name),
+               "scope": scopes[-1] if scopes else None}
+        if len(scopes) > 1:
+            rec["scopes"] = scopes
         if rec["phase"] == "other" and named is not None:
             # No marker of its own (a compiler-made copy, the add that
             # accumulates gradients): it works on what its first marked

@@ -382,7 +382,11 @@ class TestOpIndex:
                                               scope):
         assert hlo_audit.phase_of(op_name, instr) == phase
         (rec,) = hlo_audit.op_records(_instr(instr, op_name)).values()
+        # Nested scopes are all listed too, outermost first (PR 27).
+        nested = rec.pop("scopes", None)
         assert rec == {"phase": phase, "scope": scope}
+        assert nested is None or (len(nested) > 1 and nested[-1] == scope
+                                  and nested == hlo_audit.scopes_of(op_name))
         assert phase in hlo_audit.PHASES
 
     def test_of_a_joined_op_name_the_first_part_decides(self):
