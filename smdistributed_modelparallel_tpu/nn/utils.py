@@ -136,7 +136,7 @@ def tp_ring_active():
 
 
 @functools.lru_cache(maxsize=64)
-def _fused_bias_gelu_region(mesh, ndim, interpret):
+def _fused_bias_gelu_region(mesh, ndim, interpret, manual):
     from smdistributed_modelparallel_tpu.ops.pallas_gelu import bias_gelu
     from smdistributed_modelparallel_tpu.parallel.sharding import (
         single_axis_spec,
@@ -147,7 +147,7 @@ def _fused_bias_gelu_region(mesh, ndim, interpret):
     return jax.jit(jax.shard_map(
         lambda h, b: bias_gelu(h, b, interpret),
         mesh=mesh, in_specs=(h_spec, b_spec), out_specs=h_spec,
-        axis_names={TP_AXIS}, check_vma=False,
+        axis_names=manual, check_vma=False,
     ))
 
 
@@ -175,13 +175,17 @@ def fused_bias_gelu(h, b):
 
         record_quant_dispatch("gelu_in", "fp8")
         h = quant.fake_quant(h, "gelu_in.x")
+    from smdistributed_modelparallel_tpu.parallel.sharding import manual_axes
+
     interpret = jax.default_backend() != "tpu"
     mesh = _mesh()
     tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
     if tp <= 1 or h.shape[-1] % tp != 0:
         return bias_gelu(h, b, interpret)
     h = shard_activation(h, *([None] * (h.ndim - 1) + [TP_AXIS]))
-    return _fused_bias_gelu_region(mesh, h.ndim, interpret)(h, b)
+    return _fused_bias_gelu_region(
+        mesh, h.ndim, interpret, manual_axes(TP_AXIS)
+    )(h, b)
 
 
 def dense_init(scale=None, stddev=0.02):

@@ -223,6 +223,15 @@ def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
     data axes and heads over tp, each only when it divides — a dim that
     does not divide stays whole and is computed replicated. Sequence and
     head_dim stay whole. On one device this is the bare call.
+
+    The specs name no axis for pipeline stages, and must not: inside a
+    pipeline stage the call is batched by the executors' ``stage_vmap``
+    (``parallel/pipeline.py``), whose ``spmd_axis_name`` is pp, and
+    ``shard_map``'s batching rule puts that name on the new leading stage
+    dim of every spec. Each pp rank then runs the kernels on its own
+    stage's rows. Under a vmap that names nothing the stage dim enters
+    whole: q, k, v and dO all-gathered over pp, both stages' rows computed
+    on every rank, a psum over pp in the backward (PR 28).
     """
     from smdistributed_modelparallel_tpu.backend.state import state
     from smdistributed_modelparallel_tpu.ops.pallas_attention import (

@@ -83,6 +83,7 @@ from smdistributed_modelparallel_tpu.backend.topology import TP_AXIS
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 
 from smdistributed_modelparallel_tpu.parallel.sharding import (
+    manual_axes,
     single_axis_spec,
 )
 
@@ -232,7 +233,7 @@ def _maybe_fp8_operands(x, w, site):
 
 @functools.lru_cache(maxsize=64)
 def _build_ag(mesh, tp, x_ndim, w_ndim, w_tp_dim, has_bias, use_pallas,
-              interpret, axis_name=TP_AXIS):
+              interpret, manual, axis_name=TP_AXIS):
     """custom_vjp ``allgather_seq(x) @ w`` with the gather decomposed
     into a tp-step ring. x: [*lead, S, D] sequence-sharded over tp;
     w: [D, *out] with tp on ``w_tp_dim``; bias (optional): w.shape[1:]
@@ -331,12 +332,12 @@ def _build_ag(mesh, tp, x_ndim, w_ndim, w_tp_dim, has_bias, use_pallas,
         (lambda x, w, b: fwd_body(x, w, b)) if has_bias
         else (lambda x, w: fwd_body(x, w, None)),
         mesh=mesh, in_specs=fwd_specs, out_specs=out_spec,
-        axis_names={axis_name}, check_vma=False,
+        axis_names=manual, check_vma=False,
     )
     bwd_out = (x_spec, w_spec) + ((b_spec,) if has_bias else ())
     bwd_fn = jax.shard_map(
         bwd_body, mesh=mesh, in_specs=(x_spec, w_spec, out_spec),
-        out_specs=bwd_out, axis_names={axis_name}, check_vma=False,
+        out_specs=bwd_out, axis_names=manual, check_vma=False,
     )
 
     if has_bias:
@@ -390,7 +391,8 @@ def ring_ag_matmul(x, w, bias=None, *, w_tp_dim=1, fused=False):
     )
     interpret = jax.default_backend() != "tpu"
     fn = _build_ag(mesh, tp, x.ndim, w.ndim, w_tp_dim,
-                   bias is not None, bool(fused), interpret)
+                   bias is not None, bool(fused), interpret,
+                   manual_axes(TP_AXIS))
     return fn(x, w, bias) if bias is not None else fn(x, w)
 
 
@@ -401,7 +403,7 @@ def ring_ag_matmul(x, w, bias=None, *, w_tp_dim=1, fused=False):
 
 @functools.lru_cache(maxsize=64)
 def _build_rs(mesh, tp, x_ndim, n_contract, x_tp_dim, w_ndim,
-              interpret, axis_name=TP_AXIS):
+              interpret, manual, axis_name=TP_AXIS):
     """custom_vjp ``reduce_scatter_seq(x @ w)`` with the reduction
     decomposed into a tp-step accumulator ring. x: [*lead, S, *contract]
     with tp on ``x_tp_dim`` (a contract dim); w: [*contract, *out] with
@@ -482,11 +484,11 @@ def _build_rs(mesh, tp, x_ndim, n_contract, x_tp_dim, w_ndim,
 
     fwd_fn = jax.shard_map(
         fwd_body, mesh=mesh, in_specs=(x_spec, w_spec),
-        out_specs=out_spec, axis_names={axis_name}, check_vma=False,
+        out_specs=out_spec, axis_names=manual, check_vma=False,
     )
     bwd_fn = jax.shard_map(
         bwd_body, mesh=mesh, in_specs=(x_spec, w_spec, out_spec),
-        out_specs=(x_spec, w_spec), axis_names={axis_name},
+        out_specs=(x_spec, w_spec), axis_names=manual,
         check_vma=False,
     )
 
@@ -533,5 +535,5 @@ def ring_rs_matmul(x, w, *, n_contract=1, x_tp_dim=None):
     )
     interpret = jax.default_backend() != "tpu"
     fn = _build_rs(mesh, tp, x.ndim, n_contract, x_tp_dim, w.ndim,
-                   interpret)
+                   interpret, manual_axes(TP_AXIS))
     return fn(x, w)

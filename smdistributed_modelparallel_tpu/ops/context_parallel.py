@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from smdistributed_modelparallel_tpu.backend.state import state
 from smdistributed_modelparallel_tpu.backend.topology import CP_AXIS
 from smdistributed_modelparallel_tpu.ops.pallas_attention import _dropout_keep
+from smdistributed_modelparallel_tpu.parallel.sharding import manual_axes
 from smdistributed_modelparallel_tpu.utils.exceptions import SMPValidationError
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 
@@ -812,7 +813,7 @@ def cp_attention(q, k, v, *, scale, causal, impl=None, kpad=None,
         call_args.append(jnp.asarray(seed, jnp.int32))
     jitted = _build_cp_call(
         body_fn, tuple(sorted(body_kw.items())), mesh, spec,
-        kpad is not None, seed is not None,
+        kpad is not None, seed is not None, manual_axes(CP_AXIS),
     )
     out = jitted(*call_args)
     if pad_rows:
@@ -821,7 +822,8 @@ def cp_attention(q, k, v, *, scale, causal, impl=None, kpad=None,
 
 
 @functools.lru_cache(maxsize=64)
-def _build_cp_call(body_fn, body_kw_items, mesh, spec, has_kp, has_seed):
+def _build_cp_call(body_fn, body_kw_items, mesh, spec, has_kp, has_seed,
+                   manual):
     """Cached jit-of-shard_map builder with optional operands (kpad/seed
     dropped from the arg list when absent; the body receives None).
 
@@ -849,7 +851,7 @@ def _build_cp_call(body_fn, body_kw_items, mesh, spec, has_kp, has_seed):
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=spec,
-        axis_names={CP_AXIS},
+        axis_names=manual,
         check_vma=False,
     )
     # Partial-manual shard_map must be staged under a jit trace (eager

@@ -657,7 +657,7 @@ def pipeline_forward(model, params, stacked_inputs, rngs_key, mb_kwargs=None):
         lambda x: jnp.zeros((S,) + x.shape, x.dtype), carry_shape
     )
 
-    vmapped_stages = jax.vmap(stage_body, in_axes=(0, 0, 0, 0, 0))
+    vmapped_stages = stage_vmap(stage_body, S)
     stage_keys = jax.random.split(rngs_key, S)
     stage_ids = jnp.arange(S)
 
@@ -881,6 +881,66 @@ def staged_layer_views(spec, layer_params, num_stages):
             lambda x: jnp.asarray(x)[gidx], spec.layer_xs
         )
     return staged_params, staged_xs, jnp.asarray(active)
+
+
+def _pp_size():
+    mesh = state.mesh
+    return dict(mesh.shape).get(PP_AXIS, 1) if mesh is not None else 1
+
+
+def pin_stage_axis(tree, num_stages):
+    """Pin ONLY the leading stage axis of every stage-parallel value
+    (leading dim ``num_stages``) to the pp mesh axis and leave the rest
+    unconstrained, so batch/tp shardings still propagate. The chunked
+    gather ([L] -> [S, V, maxp]) breaks the sharding propagation that gives
+    the plain v=1 executor its stage placement for free (a reshape keeps
+    dim 0 on pp; a gather's output is unconstrained, and GSPMD then happily
+    replicates the whole tick loop).
+
+    UNCONSTRAINED (not None) on the non-stage dims is load-bearing for
+    pp x zero3 composition: None would force the staged views replicated,
+    upfront-gathering every rdp-sharded parameter before the tick loop.
+    UNCONSTRAINED lets propagation keep the rdp dims sharded, so the
+    all-gather lands INSIDE the loop at each stage's point of use
+    (per-stage gather scoping — asserted by the zero3 composition gate's
+    loop_gather_ops census)."""
+    if _pp_size() <= 1:
+        return tree
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def pin(x):
+        if getattr(x, "ndim", 0) < 1 or x.shape[0] != num_stages:
+            return x
+        rest = [P.UNCONSTRAINED] * (x.ndim - 1)
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(state.mesh, P(PP_AXIS, *rest))
+        )
+
+    return jax.tree_util.tree_map(pin, tree)
+
+
+def stage_vmap(fn, num_stages, in_axes=0):
+    """``jax.vmap`` of model code over the leading stage axis that also
+    NAMES the axis: ``spmd_axis_name=pp`` when the mesh has pp > 1 and the
+    ``num_stages`` rows divide over it (S = pp, or pp * v contiguous chunks
+    in the fill-drain executor), plain ``vmap`` otherwise.
+
+    GSPMD partitions plain ops over the pp-sharded stage dim by propagation,
+    but what a stage holds that is NOT left to propagation only learns of
+    the stage dim through this name: a ``shard_map`` region (the flash
+    kernel's, the cp and collective-matmul rings) gives the batched dim the
+    vmap's ``spmd_axis_name`` in its specs, or nothing — and with nothing
+    every pp rank gathers and computes all stages' rows; a
+    ``with_sharding_constraint`` likewise pins the stage dim to pp instead
+    of leaving it open. Every stage-mapped call of model code in the
+    executors goes through here; the ring helpers map the same axis over
+    pure indexing and stay plain. No region inside a stage may itself name
+    pp (``shard_map`` refuses a spec that repeats the vmap's axis)."""
+    pp = _pp_size()
+    named = pp > 1 and num_stages % pp == 0
+    return jax.vmap(
+        fn, in_axes=in_axes, spmd_axis_name=PP_AXIS if named else None
+    )
 
 
 def _get_subtree(params, path):

@@ -46,12 +46,18 @@ split into an input-grad pass and a deferred weight-grad pass that fills
 the cooldown bubble; selected by ``pipeline: "zero_bubble"``).
 """
 
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from smdistributed_modelparallel_tpu.backend.state import state
+from smdistributed_modelparallel_tpu.parallel.pipeline import (
+    pin_stage_axis as _pin_stage_axis,
+    stage_vmap,
+)
 from smdistributed_modelparallel_tpu.utils.exceptions import PartitionError
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 from smdistributed_modelparallel_tpu.utils.profiling import named_region
@@ -714,14 +720,14 @@ def _probe_stash_avals(S, staged_params, staged_xs, active_rows, carry_aval,
         )
 
     def probe(ch_params, ch_xs, x, side, c_ids, mrow, act):
-        _out, _aux, res = jax.vmap(
-            capture_fwd,
+        _out, _aux, res = stage_vmap(
+            capture_fwd, S,
             in_axes=(0, 0, 0, 0 if sides is not None else None, 0, 0, 0),
         )(ch_params, ch_xs, x, side, c_ids, mrow, act)
         if bwd_from_res is None:
             return res
         cot = jax.tree_util.tree_map(lambda a: jnp.zeros_like(a), x)
-        _d_x, _side_acc, cot_stack = jax.vmap(bwd_from_res)(res, cot)
+        _d_x, _side_acc, cot_stack = stage_vmap(bwd_from_res, S)(res, cot)
         return res, cot_stack
 
     return jax.eval_shape(
@@ -1112,8 +1118,8 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             )
             f_sides = gather_sides_rows(fmc)
             with named_region("smp/pipeline/tick_fwd"):
-                outs_f, _aux_f = jax.vmap(
-                    stage_fwd,
+                outs_f, _aux_f = stage_vmap(
+                    stage_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None, 0, 0, 0),
                 )(staged_params, staged_xs, x_in, f_sides, stage_ids, fmc,
                   active_rows)
@@ -1207,8 +1213,8 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
                 return vjp((cot, aux_seed))
 
             with named_region("smp/pipeline/tick_bwd"):
-                d_lp_rows, d_x_rows, d_side_rows = jax.vmap(
-                    stage_bwd,
+                d_lp_rows, d_x_rows, d_side_rows = stage_vmap(
+                    stage_bwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(staged_params, staged_xs, stash_in,
@@ -1492,40 +1498,9 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
         spec, layer_params, S, V
     )
 
-    # The chunked gather ([L] -> [S, V, maxp]) breaks the sharding
-    # propagation that gives the v=1 executor its stage placement for free
-    # (a reshape keeps dim 0 on pp; a gather's output is unconstrained, and
-    # GSPMD then happily replicates the whole tick loop). Pin ONLY the
-    # leading stage axis of every stage-parallel value to the pp mesh axis
-    # and leave the rest unconstrained so batch/tp shardings still
-    # propagate.
-    from jax.sharding import NamedSharding, PartitionSpec as _P
-
-    from smdistributed_modelparallel_tpu.backend.topology import PP_AXIS
-
-    mesh = state.mesh
-    _pp_size = dict(mesh.shape).get(PP_AXIS, 1) if mesh is not None else 1
-
-    def pin_stage_axis(tree):
-        if mesh is None or _pp_size <= 1:
-            return tree
-
-        # UNCONSTRAINED (not None) on the non-stage dims is load-bearing
-        # for pp x zero3 composition: None would force the staged views
-        # replicated, upfront-gathering every rdp-sharded parameter
-        # before the tick loop. UNCONSTRAINED lets propagation keep the
-        # rdp dims sharded, so the all-gather lands INSIDE the loop at
-        # each stage's point of use (per-stage gather scoping — asserted
-        # by the zero3 composition gate's loop_gather_ops census).
-        def pin(x):
-            if getattr(x, "ndim", 0) < 1 or x.shape[0] != S:
-                return x
-            rest = [_P.UNCONSTRAINED] * (x.ndim - 1)
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(PP_AXIS, *rest))
-            )
-
-        return jax.tree_util.tree_map(pin, tree)
+    # Stage-axis sharding pins (the chunked gather breaks GSPMD's
+    # propagation; pin ONLY dim 0: ``pipeline.pin_stage_axis``).
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
     staged_params = pin_stage_axis(staged_params)
     staged_xs = pin_stage_axis(staged_xs)
@@ -1844,8 +1819,8 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                     # Same forward compute; the per-layer vjp capture
                     # additionally emits the residual leaves the backward
                     # sub-step will consume instead of re-running this.
-                    outs_f, _aux_f, res_f = jax.vmap(
-                        capture_fwd,
+                    outs_f, _aux_f, res_f = stage_vmap(
+                        capture_fwd, S,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0),
                     )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -1854,8 +1829,8 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                         f_active & stash_of_arr[fkc],
                     )
                 else:
-                    outs_f, _aux_f = jax.vmap(
-                        chunk_fwd,
+                    outs_f, _aux_f = stage_vmap(
+                        chunk_fwd, S,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0),
                     )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -1961,8 +1936,8 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                     # Backward from the residuals the forward sub-step
                     # stashed: no forward re-run for stashed chunks.
                     res_b = get_ring(fres, res_col_arr[bkc], bmc % Rfb)
-                    d_lp_res, d_x_res, side_res = jax.vmap(
-                        bwd_full_from_res
+                    d_lp_res, d_x_res, side_res = stage_vmap(
+                        bwd_full_from_res, S
                     )(res_b, cot_in)
                     if all_rstash:
                         d_lp_rows, d_x_rows = d_lp_res, d_x_res
@@ -1970,8 +1945,8 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                     else:
                         # Budget-degraded chunks keep the recompute path;
                         # a static per-chunk mask selects.
-                        d_lp_rec, d_x_rec, d_side_rec = jax.vmap(
-                            chunk_bwd,
+                        d_lp_rec, d_x_rec, d_side_rec = stage_vmap(
+                            chunk_bwd, S,
                             in_axes=(0, 0, 0,
                                      0 if sides is not None else None,
                                      0, 0, 0, 0),
@@ -1998,8 +1973,8 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                                 for a, i in zip(side_res, side_idx)
                             ]
                 else:
-                    d_lp_rows, d_x_rows, d_side_rows = jax.vmap(
-                        chunk_bwd,
+                    d_lp_rows, d_x_rows, d_side_rows = stage_vmap(
+                        chunk_bwd, S,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0, 0),
                     )(ch_params_b, ch_xs_b, stash_in,
@@ -2303,35 +2278,9 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
         spec, layer_params, S, V
     )
 
-    # Stage-axis sharding pins: same rationale as the virtual executor
-    # (the chunked gather breaks GSPMD's propagation; pin ONLY dim 0).
-    from jax.sharding import NamedSharding, PartitionSpec as _P
-
-    from smdistributed_modelparallel_tpu.backend.topology import PP_AXIS
-
-    mesh = state.mesh
-    _pp_size = dict(mesh.shape).get(PP_AXIS, 1) if mesh is not None else 1
-
-    def pin_stage_axis(tree):
-        if mesh is None or _pp_size <= 1:
-            return tree
-
-        # UNCONSTRAINED (not None) on the non-stage dims is load-bearing
-        # for pp x zero3 composition: None would force the staged views
-        # replicated, upfront-gathering every rdp-sharded parameter
-        # before the tick loop. UNCONSTRAINED lets propagation keep the
-        # rdp dims sharded, so the all-gather lands INSIDE the loop at
-        # each stage's point of use (per-stage gather scoping — asserted
-        # by the zero3 composition gate's loop_gather_ops census).
-        def pin(x):
-            if getattr(x, "ndim", 0) < 1 or x.shape[0] != S:
-                return x
-            rest = [_P.UNCONSTRAINED] * (x.ndim - 1)
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(PP_AXIS, *rest))
-            )
-
-        return jax.tree_util.tree_map(pin, tree)
+    # Stage-axis sharding pins (the chunked gather breaks GSPMD's
+    # propagation; pin ONLY dim 0: ``pipeline.pin_stage_axis``).
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
     staged_params = pin_stage_axis(staged_params)
     staged_xs = pin_stage_axis(staged_xs)
@@ -2588,8 +2537,8 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             f_sides = gather_sides_rows(fmc)
             c_ids = fkc * S + stage_ids
             with named_region("smp/pipeline/tick_fwd"):
-                outs_f, _aux_f = jax.vmap(
-                    chunk_fwd,
+                outs_f, _aux_f = stage_vmap(
+                    chunk_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -2695,8 +2644,8 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
                 return vjp((cot, aux_seed))
 
             with named_region("smp/pipeline/tick_bwd_input"):
-                d_x_rows, d_side_rows = jax.vmap(
-                    chunk_bwd_input,
+                d_x_rows, d_side_rows = stage_vmap(
+                    chunk_bwd_input, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(ch_params_b, ch_xs_b, stash_in,
@@ -2780,8 +2729,8 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
                 return d_lp
 
             with named_region("smp/pipeline/tick_bwd_weight"):
-                d_lp_rows = jax.vmap(
-                    chunk_bwd_weight,
+                d_lp_rows = stage_vmap(
+                    chunk_bwd_weight, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(ch_params_w, ch_xs_w, stash_w,
@@ -3048,26 +2997,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         spec, layer_params, S, V
     )
 
-    from jax.sharding import NamedSharding, PartitionSpec as _P
-
-    from smdistributed_modelparallel_tpu.backend.topology import PP_AXIS
-
-    mesh = state.mesh
-    _pp_size = dict(mesh.shape).get(PP_AXIS, 1) if mesh is not None else 1
-
-    def pin_stage_axis(tree):
-        if mesh is None or _pp_size <= 1:
-            return tree
-
-        def pin(x):
-            if getattr(x, "ndim", 0) < 1 or x.shape[0] != S:
-                return x
-            rest = [_P.UNCONSTRAINED] * (x.ndim - 1)
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(PP_AXIS, *rest))
-            )
-
-        return jax.tree_util.tree_map(pin, tree)
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
     staged_params = pin_stage_axis(staged_params)
     staged_xs = pin_stage_axis(staged_xs)
@@ -3338,8 +3268,8 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         c_ids = fkc * S + stage_ids
         with named_region("smp/pipeline/tick_fwd"):
             if capture_at_f:
-                outs_f, _aux_f, res_f = jax.vmap(
-                    capture_fwd,
+                outs_f, _aux_f, res_f = stage_vmap(
+                    capture_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -3348,8 +3278,8 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
                     f_active & stash_of_arr[fkc],
                 )
             else:
-                outs_f, _aux_f = jax.vmap(
-                    chunk_fwd,
+                outs_f, _aux_f = stage_vmap(
+                    chunk_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -3472,13 +3402,13 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
                 assert all_stash
                 res_b = _chunk_ring_get(wres, b_cols, bmc % Rres)
             else:
-                _out_b, _aux_b, res_b = jax.vmap(
-                    capture_fwd,
+                _out_b, _aux_b, res_b = stage_vmap(
+                    capture_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params_b, ch_xs_b, stash_in, b_sides, c_ids_b, bmc,
                   ch_act_b)
-            d_x_rows, d_side_rows, cot_stack = jax.vmap(bwd_from_res)(
+            d_x_rows, d_side_rows, cot_stack = stage_vmap(bwd_from_res, S)(
                 res_b, cot_in
             )
         d_x_rows = pin_stage_axis(d_x_rows)
@@ -3552,7 +3482,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         with named_region("smp/pipeline/tick_bwd_weight"):
             res_w = _chunk_ring_get(wres, w_cols, wmc % Rres)
             cot_w = _chunk_ring_get(wcot, w_cols, wmc % Rcot)
-            d_lp_rows = jax.vmap(wgt_from_res)(res_w, cot_w)
+            d_lp_rows = stage_vmap(wgt_from_res, S)(res_w, cot_w)
             if not all_stash:
                 # Degraded chunks keep the recompute path: vjp w.r.t. the
                 # chunk params re-running the forward from the input
@@ -3575,8 +3505,8 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
                     (d_lp,) = vjp((cot, aux_seed))
                     return d_lp
 
-                d_lp_rec = jax.vmap(
-                    chunk_bwd_weight,
+                d_lp_rec = stage_vmap(
+                    chunk_bwd_weight, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(ch_params_w, ch_xs_w, stash_w, w_sides, cotc_w,

@@ -65,6 +65,25 @@ def single_axis_spec(ndim, dim, axis):
     return P(*(axis if d == dim else None for d in range(ndim)))
 
 
+def manual_axes(*axes):
+    """The ``axis_names`` of a partial-manual ``shard_map`` region over
+    ``axes`` traced here: those, plus the mesh axes that enclosing ``vmap``s
+    gave as ``spmd_axis_name`` (the pipeline executors' ``stage_vmap``
+    names pp on the stage axis). ``shard_map``'s batching rule puts the
+    vmap's name on the batched dim of the region's specs and traces the
+    body at the per-shard size whether or not the region is manual over
+    that axis; where it is not, the program fails verification ("operand
+    shape ... and region operand shape must match"). So under such a vmap
+    the region is manual over its axis as well, and each rank of it runs
+    its own rows. JAX's axis environment is where the name is observable
+    whatever traces (``scan``, ``checkpoint``, ``vjp``) lie between the
+    vmap and the region; ``shard_map`` reads it there for the same
+    purpose."""
+    from jax._src import core
+
+    return frozenset(axes) | frozenset(core.get_axis_env().spmd_axis_names)
+
+
 def strip_axis(spec, axis):
     """PartitionSpec with every occurrence of one mesh axis removed —
     the "gathered over that axis" layout of a sharded value. Shared by

@@ -20,15 +20,14 @@ import jax.numpy as jnp
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A sharding on one described v5e chip. While the module runs the
-    compile cache is off (a described-chip entry cannot be read back and the
-    next compile would warn) and the matmul precision is JAX's default, as on
-    the chip: ``conftest.py`` pins "highest" for the CPU parity tests, under
-    which Mosaic refuses the bf16 dots or asks for more VMEM."""
+def topo():
+    """The described v5e 2x2. While the module runs the compile cache is
+    off (a described-chip entry cannot be read back and the next compile
+    would warn) and the matmul precision is JAX's default, as on the chip:
+    ``conftest.py`` pins "highest" for the CPU parity tests, under which
+    Mosaic refuses the bf16 dots or asks for more VMEM."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -42,10 +41,18 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     jax.config.update("jax_default_matmul_precision", None)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was)
     jax.config.update("jax_default_matmul_precision", precision_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -81,6 +88,51 @@ def test_flash_attention_forward_backward(one_chip, shape):
     assert text.count("tpu_custom_call") >= 3
     for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
         assert name in text
+
+
+def test_flash_attention_under_the_stage_vmap_on_a_mesh(topo, monkeypatch):
+    """Pythia-1.4B's attention (16 heads of 128, T 2048, a microbatch of 1)
+    as the pipeline executors run it at pp 2 x tp 2: ``stage_vmap`` over
+    two stages, the kernels in ``_flash_on_mesh``'s manual region. The
+    vmap names pp, so each chip's three Mosaic calls take its own stage's 8
+    heads, ``[8, 2048, 128]``, and nothing crosses pp; unnamed, they took
+    ``[2, 8, 2048, 128]`` behind three all-gathers and an all-reduce over
+    pp (PR 28). Interpret mode has accepted what this compiler refused
+    (PR 21), hence here as well as on the CPU mesh."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.backend.state import state
+    from smdistributed_modelparallel_tpu.ops import attention
+    from smdistributed_modelparallel_tpu.parallel.pipeline import stage_vmap
+    from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+    monkeypatch.delenv("SMP_DISABLE_PALLAS_ATTN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    smp.init({"pipeline_parallel_degree": 2, "tensor_parallel_degree": 2,
+              "ddp": True, "microbatches": 2}, devices=topo.devices[:4])
+
+    def loss(q, k, v):
+        return _sum32(attention.attention_core(q, k, v, causal=True))
+
+    staged = NamedSharding(state.mesh, P("pp", None, None, "tp", None))
+    text = _compile(
+        stage_vmap(jax.grad(loss, argnums=(0, 1, 2)), 2), staged,
+        *[(2, 1, 2048, 16, 128)] * 3,
+    )
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert any(name in line for line in calls)
+    blocks = {dims for line in calls
+              for dims in re.findall(r"bf16\[([\d,]+)\]", line)}
+    assert blocks == {"8,2048,128"}
+    census = hlo_audit.collective_census(text, state.mesh)
+    assert not [(op, axis) for op, entry in census.items()
+                for axis in entry["axes"] if "pp" in axis]
 
 
 @pytest.mark.parametrize(

@@ -469,6 +469,45 @@ class TestComposition:
         assert 0 in health.monitor.checked_steps
 
 
+class TestStageAxis:
+    def test_ring_regions_take_the_stage_vmaps_axis(self):
+        """The pipeline executors map a tick's stages with ``stage_vmap``,
+        which names pp. ``shard_map`` then puts pp on the batched dim of a
+        region's specs and traces its body one stage at a time, whether or
+        not the region is manual over pp: the tp rings, manual over tp
+        alone, failed verification there. ``manual_axes`` adds the vmap's
+        axis, so each pp rank runs its own stage's ring. Fast-tier twin of
+        ``TestComposition.test_pp2_composition_parity``: both rings, values
+        and gradients against the plain products."""
+        from smdistributed_modelparallel_tpu.parallel.pipeline import (
+            stage_vmap,
+        )
+
+        smp.shutdown()
+        smp.init(dict(TP2, pipeline_parallel_degree=2, tp_overlap="ring"))
+        ks = jax.random.split(jax.random.key(0), 3)
+        x = jax.random.normal(ks[0], (2, 2, 8, 16))     # [stage, B, S, D]
+        w_col = jax.random.normal(ks[1], (2, 16, 12))
+        w_row = jax.random.normal(ks[2], (2, 12, 16))
+
+        def ring(x, w_col, w_row):
+            h = collective_matmul.ring_ag_matmul(x, w_col)
+            return jnp.sum(jnp.sin(collective_matmul.ring_rs_matmul(h, w_row)))
+
+        def plain(x, w_col, w_row):
+            return jnp.sum(jnp.sin((x @ w_col) @ w_row))
+
+        def run(fn):
+            return jax.jit(stage_vmap(
+                jax.value_and_grad(fn, argnums=(0, 1, 2)), 2
+            ))(x, w_col, w_row)
+
+        (got, got_g), (want, want_g) = run(ring), run(plain)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
 # ----------------------------------------------------------------------
 # GSPMD resharding census pin (satellite): back-to-back tp layers
 # ----------------------------------------------------------------------
