@@ -36,17 +36,35 @@ The executor returns (mean_loss-scaled grads, stacked user outputs, stacked
 losses); the step engine (``step.py``) divides out the loss scale exactly as
 in the fill-drain path so the two schedules are numerically interchangeable.
 
-Three executors share this module: the plain v=1 path (``pipeline_1f1b``
-below; setting its knobs to their defaults compiles the program that
-leaving them unset does), the interleaved virtual-stage generalization
-(``_pipeline_1f1b_virtual``: (chunk, microbatch) units over
-``pp*v`` chunks), and the zero-bubble ZB-H1 executor
-(``_pipeline_zero_bubble``: (chunk, microbatch, pass) units — backward
-split into an input-grad pass and a deferred weight-grad pass that fills
-the cooldown bubble; selected by ``pipeline: "zero_bubble"``).
+Four executors share this module and one scaffold. ``pipeline_1f1b``
+only dispatches: it reads the schedule, the virtual degree and the
+recompute mode off the config, runs the set-up once (``_setup_run``: the
+staged layer views, the embedded microbatch queue, the layer / head
+applications, the backward seeds; one frozen ``_Run`` record) and hands
+the record to
+
+- ``_pipeline_1f1b_plain``: v=1, one loop with conditional sub-steps
+  (setting the knobs to their defaults compiles the program that leaving
+  them unset does);
+- ``_pipeline_1f1b_virtual``: (chunk, microbatch) units over ``pp*v``
+  chunks, one loop per phase;
+- ``_pipeline_zero_bubble``: ZB-H1, (chunk, microbatch, pass) units — the
+  backward split into an input-grad pass and a deferred weight-grad pass
+  that fills the cooldown bubble (``pipeline: "zero_bubble"``);
+- ``_pipeline_zero_bubble_stash``: the same schedule with the W pass fed
+  from stashed vjp residuals (``recompute`` other than ``full``).
+
+Each executor owns its schedule tables, its ring buffers, its tick body
+and its loop(s), and ends in ``_finish_run`` (embedding backward, layer
+gradients back to ``[L, ...]``, the gradient tree in the parameters'
+dtypes). The ``smp/pipeline/*`` scope names round the sub-steps and the
+loops are read by ``utils/hlo_audit.op_index`` and the benchmark's phase
+shares: they are an interface, not decoration.
 """
 
+import dataclasses
 import functools
+from typing import Any, Callable, Optional
 
 import numpy as np
 
@@ -54,13 +72,38 @@ import jax
 import jax.numpy as jnp
 
 from smdistributed_modelparallel_tpu.backend.state import state
-from smdistributed_modelparallel_tpu.parallel.pipeline import (
-    pin_stage_axis as _pin_stage_axis,
-    stage_vmap,
+from smdistributed_modelparallel_tpu.nn.auto_distribute import unwrap_hooks
+from smdistributed_modelparallel_tpu.nn.utils import half_cast
+from smdistributed_modelparallel_tpu.parallel import remat_plan
+from smdistributed_modelparallel_tpu.parallel.memory import (
+    recompute_ring_plan,
+    remat_policy,
+    zero_bubble_ring_plan,
 )
+from smdistributed_modelparallel_tpu.parallel.pipeline import (
+    _get_subtree,
+    _mk_rngs,
+    _scan_map,
+    apply_collecting_aux,
+    chunk_layout,
+    make_layer_apply,
+    pin_stage_axis as _pin_stage_axis,
+    stage_layout,
+    stage_vmap,
+    staged_chunk_views,
+    staged_layer_views,
+)
+from smdistributed_modelparallel_tpu.utils import health
 from smdistributed_modelparallel_tpu.utils.exceptions import PartitionError
+from smdistributed_modelparallel_tpu.utils.flight_recorder import (
+    flight_recorder,
+)
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
 from smdistributed_modelparallel_tpu.utils.profiling import named_region
+from smdistributed_modelparallel_tpu.utils.telemetry import (
+    record_pipeline_occupancy,
+    telemetry,
+)
 
 logger = get_logger()
 
@@ -431,11 +474,6 @@ def _run_in_span(in_span, spans_all_ticks, substep, ops):
     return jax.lax.cond(in_span, substep, lambda unchanged: unchanged, ops)
 
 
-def _tree_zeros(avals_or_tree, like=None):
-    src = avals_or_tree if like is None else like
-    return jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), src)
-
-
 def _inexact_leaves(tree):
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     idx = [i for i, l in enumerate(leaves)
@@ -443,11 +481,38 @@ def _inexact_leaves(tree):
     return leaves, treedef, idx
 
 
-# ---- shared ring/scatter primitives of the chunk-generalized executors
-# (_pipeline_1f1b_virtual and _pipeline_zero_bubble; the plain v=1
-# executor keeps its own 2-level ring helpers). All are pure
-# in their arguments: ring geometry ([S, V, R, ...]) rides in the
-# buffers themselves.
+# ---- ring/scatter primitives. All are pure in their arguments: ring
+# geometry rides in the buffers themselves. ``_stage_ring_*`` index
+# [S, R, ...] rings (the plain executor's, and every executor's last-stage
+# output ring); ``_chunk_ring_*`` the chunk-generalized [S, V, R, ...]
+# ones; the ``_chunk_scatter_*`` write one microbatch row of an [M, ...]
+# collection buffer.
+
+
+def _stage_ring_set(buf, row_slots, row_vals, row_active):
+    """buf[s, row_slots[s]] = row_vals[s] where row_active[s]."""
+
+    def upd(b, v):
+        def one(bs, slot, vs, act):
+            new = jax.lax.dynamic_update_index_in_dim(
+                bs, vs.astype(bs.dtype), slot, 0
+            )
+            return jnp.where(act, new, bs)
+
+        return jax.vmap(one)(b, row_slots, v, row_active)
+
+    return jax.tree_util.tree_map(upd, buf, row_vals)
+
+
+def _stage_ring_get(buf, row_slots):
+    return jax.tree_util.tree_map(
+        lambda b: jax.vmap(
+            lambda bs, slot: jax.lax.dynamic_index_in_dim(
+                bs, slot, 0, keepdims=False
+            )
+        )(b, row_slots),
+        buf,
+    )
 
 
 def _chunk_ring_set(buf, row_chunks, row_slots, row_vals, row_active):
@@ -475,19 +540,6 @@ def _chunk_ring_get(buf, row_chunks, row_slots):
     return jax.tree_util.tree_map(
         lambda b: jax.vmap(one)(b, row_chunks, row_slots), buf
     )
-
-
-def _chunk_outbuf_set(buf, row_slots, row_vals, row_active):
-    def upd(b, v):
-        def one(bs, slot, vs, act):
-            new = jax.lax.dynamic_update_index_in_dim(
-                bs, vs.astype(bs.dtype), slot, 0
-            )
-            return jnp.where(act, new, bs)
-
-        return jax.vmap(one)(b, row_slots, v, row_active)
-
-    return jax.tree_util.tree_map(upd, buf, row_vals)
 
 
 def _chunk_scatter_add_mb(buf, m, val, active):
@@ -540,8 +592,101 @@ def _chunk_acc_rows(acc, rows, krow, act):
     return jax.tree_util.tree_map(upd, acc, rows)
 
 
-def _make_residual_split(apply_one_layer, cast_half, rng, maxp, aux_seed,
-                         has_sides, side_leaf_avals=None):
+def _select_chunk(tree, krow):
+    """Per-stage view of one chunk: [S, V, ...] -> [S, ...] at krow[s]."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.vmap(
+            lambda av, k: jax.lax.dynamic_index_in_dim(av, k, 0, keepdims=False)
+        )(a, krow),
+        tree,
+    )
+
+
+def _gather_mb(tree, m):
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
+        tree,
+    )
+
+
+def _gather_sides_rows(sides, ms):
+    """Per-stage side tuples for a [S] vector of microbatch indices."""
+    if sides is None:
+        return None
+    return tuple(
+        jax.tree_util.tree_map(
+            lambda a: jax.vmap(
+                lambda i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+            )(ms),
+            s,
+        )
+        for s in sides
+    )
+
+
+def _zeros_chunk_ring(run, n):
+    """[S, V, n, ...] of one microbatch's hidden carry, zeroed."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros((run.S, run.V, n) + a.shape, a.dtype),
+        run.carry_aval,
+    )
+
+
+def _zeros_stage_ring(run, n):
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros((run.S, n) + a.shape, a.dtype), run.carry_aval
+    )
+
+
+def _zeros_stage_rows(run):
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros((run.S,) + a.shape, a.dtype), run.carry_aval
+    )
+
+
+def _zeros_health_grids(S, V):
+    """(bad count, max |x|, first bad microbatch) per (stage, chunk)."""
+    return (
+        jnp.zeros((S, V), jnp.float32), jnp.zeros((S, V), jnp.float32),
+        jnp.full((S, V), -1.0, jnp.float32),
+    )
+
+
+def _rows_to_layers(idx_np, active_np, num_layers):
+    """``to_layers`` for grads accumulated in a padded layout: the
+    [S, maxp, ...] / [S, V, maxp, ...] rows scatter-add back to [L, ...]
+    through the layout's index grid (``stage_layout`` / ``chunk_layout``),
+    padded slots masked."""
+    flat_idx = jnp.asarray(idx_np.reshape(-1))
+    flat_mask = active_np.reshape(-1)
+    lead = idx_np.ndim
+
+    def to_layers(g):
+        gf = g.reshape((idx_np.size,) + g.shape[lead:])
+        gf = gf * flat_mask.reshape((-1,) + (1,) * (gf.ndim - 1))
+        zeros = jnp.zeros((num_layers,) + g.shape[lead:], g.dtype)
+        return zeros.at[flat_idx].add(gf)
+
+    return to_layers
+
+
+def _chunk_slots(S, passes):
+    """Busy slots of a chunked schedule for the flight recorder, in tick
+    order. ``passes``: ``(direction, chunk table, microbatch table[, pass
+    tag])`` each. Slot events carry the GLOBAL chunk (boundary) index
+    k*S + s: stage says where the work ran, chunk identifies the layers —
+    the same coordinates the fill-drain executor records for chunked
+    specs."""
+    n_ticks = passes[0][2].shape[0]
+    return (
+        (t, s, d, int(m_arr[t, s]), int(k_arr[t, s]) * S + s, *tag)
+        for t in range(n_ticks) for s in range(S)
+        for d, k_arr, m_arr, *tag in passes
+        if m_arr[t, s] >= 0
+    )
+
+
+def _make_residual_split(run):
     """Per-layer vjp split of one chunk application, for the recompute
     planner's stash modes (``parallel/remat_plan.py``).
 
@@ -571,6 +716,12 @@ def _make_residual_split(apply_one_layer, cast_half, rng, maxp, aux_seed,
     embedded backward is jaxpr-closed and trace-independent, so leaves
     written by one compiled segment reconstruct in another.
     """
+    apply_one_layer, cast_half = run.apply_one_layer, run.cast_half
+    rng, maxp, aux_seed = run.rng, run.maxp, run.aux_seed
+    has_sides = run.sides is not None
+    side_leaf_avals = (
+        [run.side_leaves[i] for i in run.side_idx] if has_sides else []
+    )
     captured = {}
 
     def capture_fwd(chunk_lp, chunk_lxs, x, side, c_idx, m_idx, act_row):
@@ -689,14 +840,14 @@ def _stash_slot_bytes(avals):
     ))
 
 
-def _probe_stash_avals(S, staged_params, staged_xs, active_rows, carry_aval,
-                       sides, capture_fwd, bwd_from_res=None):
+def _probe_stash_avals(run, capture_fwd, bwd_from_res=None):
     """Abstract-trace one vmapped chunk-row capture to learn the stash
     leaf shapes (and capture the per-layer vjp treedef as a side effect
     — this must run before any ``bwd_*_from_res`` trace). Returns the
     residual avals, or ``(res_avals, cot_avals)`` when ``bwd_from_res``
     is given (the zero-bubble executor also stashes the per-layer
     output cotangents)."""
+    S, sides = run.S, run.sides
 
     def row_aval(tree):
         return jax.tree_util.tree_map(
@@ -732,11 +883,11 @@ def _probe_stash_avals(S, staged_params, staged_xs, active_rows, carry_aval,
 
     return jax.eval_shape(
         probe,
-        row_aval(staged_params), row_aval(staged_xs),
-        stage_rows_aval(carry_aval), side_row_aval,
+        row_aval(run.staged_params), row_aval(run.staged_xs),
+        stage_rows_aval(run.carry_aval), side_row_aval,
         jax.ShapeDtypeStruct((S,), jnp.int32),
         jax.ShapeDtypeStruct((S,), jnp.int32),
-        row_aval(active_rows),
+        row_aval(run.active_rows),
     )
 
 
@@ -753,126 +904,96 @@ def _stash_chunk_maps(plan, V):
     return (jnp.asarray(stash_of_np), jnp.asarray(res_col_np), Vs, Vs == V)
 
 
-def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
-                  loss_seed_scale):
-    """Run the full 1F1B forward+backward for all microbatches.
+# ---- the scaffold every executor stands on: set-up before its tick
+# loop(s), finish after ------------------------------------------------
 
-    Args:
-      model: DistributedModel with ``_pipeline_spec`` installed.
-      params: master parameter tree (layer subtree leaves lead with [L]).
-      stacked_inputs: pytree with leading [num_microbatches] — captured
-        inputs of the user's single ``model(...)`` call.
-      rng: PRNG key (dropout etc.; folded per stage/microbatch so backward
-        recompute reproduces the forward exactly).
-      mb_loss_fn(out, mb_index, key) -> (loss, user_out): the user step
-        function re-run with the model call forced to ``out``.
-      loss_seed_scale: scalar multiplied into the backward seed (the step
-        engine passes loss_scale / num_microbatches so grads come out as
-        d(mean(losses) * loss_scale)).
 
-    Returns: (grads_tree, stacked_losses [M], stacked_user_outs [M, ...]).
-    """
+@dataclasses.dataclass(frozen=True, eq=False)
+class _Run:
+    """What one traced pipeline step's executor reads, built once by
+    ``_setup_run`` and dropped with the trace. Arrays are tracers of the
+    step's trace; the staged views are in the layout the dispatch asked
+    for ([S, maxp, ...] plain, [S, V, maxp, ...] chunked)."""
+
+    model: Any
+    module: Any                  # model.module, hooks unwrapped
+    spec: Any                    # model._pipeline_spec
+    cfg: Any
+    S: int                       # pipeline stages
+    M: int                       # microbatches
+    L: int                       # layers in the pipelined stack
+    W: int                       # in-flight window (active_microbatches)
+    V: int                       # virtual chunks per stage
+    params: Any
+    stacked_inputs: Any
+    rng: Any
+    mb_loss_fn: Callable
+    loss_seed_scale: Any
+    params_rest: Any             # params with the layer subtree emptied
+    with_layers: Callable        # params_rest-shaped tree -> full tree
+    staged_params: Any
+    staged_xs: Any
+    active_rows: Any
+    idx_np: np.ndarray           # layer index of every staged slot
+    active_np: np.ndarray        # which staged slots hold a layer
+    maxp: int                    # layer slots per stage / chunk
+    mb_keys: Any                 # [M] PRNG keys
+    hidden_q: Any                # [M, ...] embedded microbatches
+    sides: Optional[tuple]       # tuple-carry side values, [M, ...] each
+    carry_aval: Any              # one microbatch's hidden carry
+    apply_one_layer: Callable
+    cast_half: Callable
+    chunk_fwd: Callable          # one stage's / chunk's layer slots
+    head_apply_aux: Callable
+    loss_out_aval: Any           # avals of mb_loss_fn's (loss, user_out)
+    stage_ids: Any
+    aux_w: float                 # moe_aux_loss_weight
+    aux_seed: Any                # backward seed of a stage's MoE aux loss
+    side_leaves: Optional[list]
+    side_idx: Optional[list]     # the inexact leaves among side_leaves
+    hc: Any                      # health collector, or None
+
+
+def _stage_views(spec, layer_params, S, V):
+    """The plain layout: one run of layers per stage, [S, maxp, ...]
+    (``V`` is 1 and has no axis)."""
+    return (*staged_layer_views(spec, layer_params, S),
+            *stage_layout(spec, S))
+
+
+def _chunk_views(spec, layer_params, S, V):
+    """The chunked layout: chunk c on stage c % S, [S, V, maxp, ...]."""
+    staged_params, staged_xs, active_rows = staged_chunk_views(
+        spec, layer_params, S, V
+    )
+    # Stage-axis sharding pins (the chunked gather breaks GSPMD's
+    # propagation; pin ONLY dim 0: ``pipeline.pin_stage_axis``).
+    return (_pin_stage_axis(staged_params, S), _pin_stage_axis(staged_xs, S),
+            active_rows, *chunk_layout(spec, S, V))
+
+
+def _setup_run(model, params, stacked_inputs, rng, mb_loss_fn,
+               loss_seed_scale, virtual, staged_views):
+    """Everything an executor needs before its first tick, once per traced
+    step: the staged layer views, the embedded microbatch queue (the
+    ``smp/pipeline/embed`` scan), the per-layer / per-chunk / head
+    applications, the abstract loss and output shapes and the backward
+    seeds. The first six arguments are ``pipeline_1f1b``'s; the layout
+    comes in as ``staged_views`` (``_stage_views``, or ``_chunk_views``
+    at ``virtual`` chunks a stage)."""
     spec = model._pipeline_spec
     cfg = state.cfg
-    virtual = int(getattr(cfg, "virtual_pipeline_degree", 1) or 1)
-    from smdistributed_modelparallel_tpu.parallel import remat_plan
-
-    rmode = remat_plan.resolve(cfg)
-    if getattr(cfg, "pipeline", "interleaved") == "zero_bubble":
-        # ZB-H1: backward split into input-grad/weight-grad passes; the
-        # executor is chunk-generalized for any v >= 1. A non-default
-        # recompute plan routes to the stash executor (which itself
-        # falls back here when the plan degrades every chunk).
-        if rmode != "full":
-            return _pipeline_zero_bubble_stash(
-                model, params, stacked_inputs, rng, mb_loss_fn,
-                loss_seed_scale, virtual, rmode,
-            )
-        return _pipeline_zero_bubble(
-            model, params, stacked_inputs, rng, mb_loss_fn, loss_seed_scale,
-            virtual,
-        )
-    if rmode == "stash_weight":
-        # No deferred weight-grad pass to stash for on the fused
-        # schedules: the SCHEDULE-level stash is inert here (the knob
-        # still maps onto the jax.checkpoint policy in
-        # memory.remat_policy for models that rematerialize, and the
-        # fingerprint config snapshot keeps recording the knob).
-        logger.warning(
-            "recompute: 'stash_weight' targets the zero_bubble schedule's "
-            "W pass; pipeline: %r has none — no schedule-level stash "
-            "(use 'stash_all' to remove this schedule's B recompute).",
-            getattr(cfg, "pipeline", "interleaved"),
-        )
-        rmode = "full"
-    if virtual > 1 or rmode in ("stash_all", "auto"):
-        # Interleaved virtual stages take the generalized executor; the
-        # default path below is the plain v=1 program, whether its knobs
-        # are unset or spelled out at their defaults. The stash modes
-        # also route v=1 through the generalized one (the plan needs the
-        # chunked ring layout), including when an auto plan later
-        # degrades every chunk: the run then stays on the
-        # chunk-generalized executor at v=1 (numerically identical,
-        # chunk-ring program) rather than re-entering this dispatch.
-        return _pipeline_1f1b_virtual(
-            model, params, stacked_inputs, rng, mb_loss_fn, loss_seed_scale,
-            virtual, rmode=rmode,
-        )
     S = cfg.pipeline_parallel_degree
     M = cfg.microbatches
-    L = spec.num_layers
-    W = min(cfg.active_microbatches or (S + 1), M)
-    W1 = W + 1
-    from smdistributed_modelparallel_tpu.nn.auto_distribute import unwrap_hooks
-
     module = unwrap_hooks(model.module)
-    layer_module = spec.layer_module
     half = cfg.half_dtype
 
-    fwd_np, bwd_np = build_1f1b_schedule(S, M, W)
-    n_ticks = fwd_np.shape[0]
-    from smdistributed_modelparallel_tpu.utils import health
-    from smdistributed_modelparallel_tpu.utils.flight_recorder import (
-        flight_recorder,
-    )
-    from smdistributed_modelparallel_tpu.utils.telemetry import (
-        record_pipeline_occupancy,
-    )
-
-    t_b0, t_fe = interleaved_phase_bounds(fwd_np, bwd_np)
-    busy, total = schedule_occupancy(
-        fwd_np, bwd_np, fwd_ticks=t_fe, bwd_ticks=n_ticks - t_b0
-    )
-    record_pipeline_occupancy("1f1b", S, M, busy_slots=busy, total_slots=total)
-    # Busy schedule slots (with microbatch ids) into the flight recorder,
-    # once per trace — see pipeline.py for why.
-    flight_recorder.record_schedule(
-        "1f1b",
-        ((t, s, d, int(sched[t, s]))
-         for t in range(n_ticks) for s in range(S)
-         for d, sched in (("fwd", fwd_np), ("bwd", bwd_np))
-         if sched[t, s] >= 0),
-    )
-    fwd_sched = jnp.asarray(fwd_np)
-    bwd_sched = jnp.asarray(bwd_np)
-
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        _get_subtree,
-        _mk_rngs,
-        _scan_map,
-        stage_layout,
-        staged_layer_views,
-    )
-
     def cast_half(tree):
-        from smdistributed_modelparallel_tpu.nn.utils import half_cast
-
         return half_cast(tree, half)
 
     layer_params = _get_subtree(params, spec.layer_path)
-    staged_params, staged_xs, active_rows = staged_layer_views(
-        spec, layer_params, S
-    )
+    (staged_params, staged_xs, active_rows, idx_np, active_np,
+     maxp) = staged_views(spec, layer_params, S, virtual)
     # The head/loss and embed VJPs differentiate only the NON-layer subtree
     # (head, tied/replicated, embedding params): layer gradients come from
     # the per-stage VJPs, so carrying full-tree zero cotangents through the
@@ -885,7 +1006,6 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
 
     def with_layers(p_rest):
         return _set_subtree(p_rest, spec.layer_path, layer_params)
-    idx_np, active_np, maxp = stage_layout(spec, S)
 
     mb_keys = jax.random.split(rng, M)
 
@@ -915,29 +1035,25 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), hidden_q
     )
 
-    # ---- per-stage forward (pure in stage params and carry) ----------
-
-    from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        apply_collecting_aux,
-        make_layer_apply,
-    )
+    # ---- per-stage / per-chunk forward (pure in its params and carry) -
 
     apply_one_layer = make_layer_apply(
-        model, spec, layer_module, side_in_carry=False
+        model, spec, spec.layer_module, side_in_carry=False
     )
 
     if spec.carry_remat:
         apply_one_layer = jax.checkpoint(apply_one_layer, policy=remat_policy())
 
-    def stage_fwd(stage_lp, stage_lxs, x, side, s_idx, m_idx, act_row):
-        """Apply this stage's layer slots; keys derived from (stage, mb) so
-        the backward recompute reproduces dropout exactly. Padded slots pass
-        the carry through unchanged. Returns (carry, summed MoE aux loss of
-        the active slots) — the aux output is what lets the backward VJP
-        seed router load-balancing gradients (see stage_bwd)."""
-        base = jax.random.fold_in(jax.random.fold_in(rng, s_idx), m_idx)
-        stage_lp = cast_half(stage_lp)
+    def chunk_fwd(chunk_lp, chunk_lxs, x, side, c_idx, m_idx, act_row):
+        """Apply one chunk's layer slots (at v=1: one stage's, the global
+        chunk id IS the stage id); keys derived from (global chunk, mb) so
+        every backward recompute reproduces the forward, dropout included,
+        exactly. Padded slots pass the carry through unchanged. Returns
+        (carry, summed MoE aux loss of the active slots) — the aux output
+        is what lets the backward VJPs seed router load-balancing
+        gradients."""
+        base = jax.random.fold_in(jax.random.fold_in(rng, c_idx), m_idx)
+        chunk_lp = cast_half(chunk_lp)
 
         def body(c, xs):
             lp, lxs, i, act = xs
@@ -950,30 +1066,10 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             return out_c, jnp.where(act, aux, 0.0)
 
         idx = jnp.arange(maxp)
-        out, auxs = jax.lax.scan(body, x, (stage_lp, stage_lxs, idx, act_row))
+        out, auxs = jax.lax.scan(body, x, (chunk_lp, chunk_lxs, idx, act_row))
         return out, jnp.sum(auxs)
 
-    def gather_mb(tree, m):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
-            tree,
-        )
-
-    def gather_sides_rows(ms):
-        """Per-stage side tuples for a [S] vector of microbatch indices."""
-        if sides is None:
-            return None
-        return tuple(
-            jax.tree_util.tree_map(
-                lambda a: jax.vmap(
-                    lambda i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                )(ms),
-                s,
-            )
-            for s in sides
-        )
-
-    # ---- head + user loss (last stage only) --------------------------
+    # ---- head + user loss (last stage, last chunk only) ---------------
 
     def head_apply_aux(p, carry, key):
         if spec.head_method is None:
@@ -983,62 +1079,12 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             rngs=_mk_rngs(model, key, "head"), method=spec.head_method,
         )
 
-    def head_apply(p, carry, key):
-        return head_apply_aux(p, carry, key)[0]
-
     # Abstract shapes of (loss, user_out) for the collection buffers.
     loss_out_aval = jax.eval_shape(
-        lambda c: mb_loss_fn(head_apply(params, c, mb_keys[0]), 0, mb_keys[0]),
+        lambda c: mb_loss_fn(
+            head_apply_aux(params, c, mb_keys[0])[0], 0, mb_keys[0]
+        ),
         jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), carry_aval),
-    )
-
-    # ---- buffers ------------------------------------------------------
-
-    def zeros_ring(n):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S, n) + a.shape, a.dtype), carry_aval
-        )
-
-    # Intermediate cotangent buffers (dembed/dsides) stay fp32; parameter
-    # gradient accumulators follow the same policy as the fill-drain path
-    # (step.py::_acc_dtype — fp32 under _fp32_grad_accumulation, else the
-    # parameter's own dtype, which for master weights is fp32 anyway).
-    grad_dtype = jnp.float32
-
-    def _acc_dtype(dtype):
-        if jnp.issubdtype(dtype, jnp.floating) and cfg._fp32_grad_accumulation:
-            return jnp.float32
-        return dtype
-
-    def param_grad_zeros(tree):
-        return jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, _acc_dtype(p.dtype)), tree
-        )
-
-    inbuf0 = zeros_ring(W1)      # inbuf[s, m % W1] = input for stage s's fwd of m
-    stash0 = zeros_ring(W1)      # stash[s, m % W1] = input consumed by fwd of m
-    cotbuf0 = zeros_ring(W1)     # cotbuf[s, m % W1] = cotangent for stage s's output of m
-    outbuf0 = zeros_ring(W1)     # outbuf[S-1, m % W1] = last stage's fwd output of m
-    #                              (only row S-1 is ever written; keeping the
-    #                              [S] axis keeps the buffer pp-sharded like
-    #                              its siblings instead of replicated)
-    dlay0 = param_grad_zeros(staged_params)
-    drep0 = param_grad_zeros(params_rest)     # head/tied/replicated contributions
-    dembed0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, grad_dtype), carry_aval
-    )
-    side_leaves = side_treedef = side_idx = None
-    dsides0 = None
-    if sides is not None:
-        side_leaves, side_treedef, side_idx = _inexact_leaves(
-            tuple(jax.tree_util.tree_map(lambda a: a[0], s) for s in sides)
-        )
-        dsides0 = [
-            jnp.zeros((M,) + side_leaves[i].shape, grad_dtype) for i in side_idx
-        ]
-    losses0 = jnp.zeros((M,), jnp.float32)
-    outs0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, a.dtype), loss_out_aval[1]
     )
 
     stage_ids = jnp.arange(S)
@@ -1051,48 +1097,300 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
         * jnp.asarray(loss_seed_scale, jnp.float32)
     )
 
-    def set_ring(buf, row_slots, row_vals, row_active):
-        """buf[s, row_slots[s]] = row_vals[s] where row_active[s]."""
-
-        def upd(b, v):
-            def one(bs, slot, vs, act):
-                new = jax.lax.dynamic_update_index_in_dim(bs, vs.astype(bs.dtype), slot, 0)
-                return jnp.where(act, new, bs)
-
-            return jax.vmap(one)(b, row_slots, v, row_active)
-
-        return jax.tree_util.tree_map(upd, buf, row_vals)
-
-    def get_ring(buf, row_slots):
-        return jax.tree_util.tree_map(
-            lambda b: jax.vmap(
-                lambda bs, slot: jax.lax.dynamic_index_in_dim(bs, slot, 0, keepdims=False)
-            )(b, row_slots),
-            buf,
+    side_leaves = side_idx = None
+    if sides is not None:
+        side_leaves, _, side_idx = _inexact_leaves(
+            tuple(jax.tree_util.tree_map(lambda a: a[0], s) for s in sides)
         )
 
-    def scatter_add_mb(buf, m, val, active):
-        """buf[m] += val if active (single microbatch row)."""
+    return _Run(
+        model=model, module=module, spec=spec, cfg=cfg, S=S, M=M,
+        L=spec.num_layers, W=min(cfg.active_microbatches or (S + 1), M),
+        V=virtual, params=params, stacked_inputs=stacked_inputs, rng=rng,
+        mb_loss_fn=mb_loss_fn, loss_seed_scale=loss_seed_scale,
+        params_rest=params_rest, with_layers=with_layers,
+        staged_params=staged_params, staged_xs=staged_xs,
+        active_rows=active_rows, idx_np=idx_np, active_np=active_np,
+        maxp=maxp, mb_keys=mb_keys, hidden_q=hidden_q, sides=sides,
+        carry_aval=carry_aval, apply_one_layer=apply_one_layer,
+        cast_half=cast_half, chunk_fwd=chunk_fwd,
+        head_apply_aux=head_apply_aux, loss_out_aval=loss_out_aval,
+        stage_ids=stage_ids, aux_w=aux_w, aux_seed=aux_seed,
+        side_leaves=side_leaves, side_idx=side_idx,
+        # Health sentinel (utils/health.py): per-stage boundary-activation
+        # stats accumulate in the tick carry; the tick loops run in the
+        # step trace itself, so the totals feed the collector directly
+        # after them.
+        hc=health.active(),
+    )
 
-        def upd(b, v):
-            cur = jax.lax.dynamic_index_in_dim(b, m, 0, keepdims=False)
-            new = cur + jnp.where(active, v.astype(b.dtype), jnp.zeros_like(cur))
-            return jax.lax.dynamic_update_index_in_dim(b, new, m, 0)
 
-        return jax.tree_util.tree_map(upd, buf, val)
+def _param_grad_zeros(tree):
+    """Zero parameter-gradient accumulators under the fill-drain path's
+    policy (step.py::_acc_dtype — fp32 under _fp32_grad_accumulation, else
+    the parameter's own dtype, which for master weights is fp32 anyway)."""
+    fp32 = state.cfg._fp32_grad_accumulation
 
-    def scatter_set_mb(buf, m, val, active):
-        def upd(b, v):
-            cur = jax.lax.dynamic_index_in_dim(b, m, 0, keepdims=False)
-            new = jnp.where(active, v.astype(b.dtype), cur)
-            return jax.lax.dynamic_update_index_in_dim(b, new, m, 0)
+    def acc_dtype(dtype):
+        if jnp.issubdtype(dtype, jnp.floating) and fp32:
+            return jnp.float32
+        return dtype
 
-        return jax.tree_util.tree_map(upd, buf, val)
+    return jax.tree_util.tree_map(
+        lambda p: jnp.zeros(p.shape, acc_dtype(p.dtype)), tree
+    )
 
-    # Health sentinel (utils/health.py): per-stage boundary-activation
-    # stats accumulate in the tick carry; this scan runs in the step
-    # trace itself, so the totals feed the collector directly after it.
-    hc = health.active()
+
+def _zero_accumulators(run):
+    """``(dlay, drep, dembed, dsides, losses, outs)`` at zero: what every
+    tick loop carries beside its rings and hands to ``_finish_run``. The
+    intermediate cotangent buffers (dembed/dsides) stay fp32."""
+    M = run.M
+    dlay0 = _param_grad_zeros(run.staged_params)
+    drep0 = _param_grad_zeros(run.params_rest)   # head/tied/replicated
+    dembed0 = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((M,) + a.shape, jnp.float32), run.carry_aval
+    )
+    dsides0 = None
+    if run.sides is not None:
+        dsides0 = [
+            jnp.zeros((M,) + run.side_leaves[i].shape, jnp.float32)
+            for i in run.side_idx
+        ]
+    losses0 = jnp.zeros((M,), jnp.float32)
+    outs0 = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((M,) + a.shape, a.dtype), run.loss_out_aval[1]
+    )
+    return dlay0, drep0, dembed0, dsides0, losses0, outs0
+
+
+def _head_loss(run, m_last, key_last, p_rest, out):
+    """Head + user loss of microbatch ``m_last`` on the last chunk's
+    stashed output: what a backward tick differentiates in (p_rest, out)."""
+    final, h_aux = run.head_apply_aux(run.with_layers(p_rest), out, key_last)
+    loss, user_out = run.mb_loss_fn(final, m_last, key_last)
+    # Head-resident MoE aux joins the differentiated loss with the same
+    # weight as the layer-stack aux (parity with pp=1).
+    loss = loss + jnp.asarray(run.aux_w, loss.dtype) * h_aux.astype(loss.dtype)
+    return loss, user_out
+
+
+def _run_head(run, m_last, key_last, out_last):
+    """The head + loss VJP as the chunked executors run it, a ``lax.cond``
+    branch: (loss, replicated/head param grads, the last chunk's output
+    cotangent, user_out)."""
+    loss_m, head_vjp, user_out = jax.vjp(
+        functools.partial(_head_loss, run, m_last, key_last),
+        run.params_rest, out_last, has_aux=True,
+    )
+    seed = jnp.asarray(run.loss_seed_scale, loss_m.dtype)
+    d_rep, d_out_last = head_vjp(seed)
+    return loss_m.astype(jnp.float32), d_rep, d_out_last, user_out
+
+
+def _chunk_bwd(run, lp, lxs, x, side, cot, c_idx, m_idx, act_row):
+    """Monolithic backward of one chunk (at v=1: one stage): re-run its
+    forward from the stashed input under ``jax.vjp``. Both outputs are
+    seeded: the downstream cotangent for the hidden carry, and the MoE
+    aux-loss seed (same mean-loss scaling as the task loss; idle rows'
+    contributions are masked when accumulated)."""
+
+    def f(lp_, x_, side_):
+        return run.chunk_fwd(lp_, lxs, x_, side_, c_idx, m_idx, act_row)
+
+    _, vjp = jax.vjp(f, lp, x, side)
+    return vjp((cot, run.aux_seed))
+
+
+def _chunk_bwd_weight(run, lp, lxs, x, side, cot, c_idx, m_idx, act_row):
+    """Weight-grad pass by recompute: VJP w.r.t. the chunk params only,
+    re-running the forward from the stashed input and the retained
+    chunk-output cotangent."""
+
+    def f(lp_):
+        return run.chunk_fwd(lp_, lxs, x, side, c_idx, m_idx, act_row)
+
+    _, vjp = jax.vjp(f, lp)
+    (d_lp,) = vjp((cot, run.aux_seed))
+    return d_lp
+
+
+def _add_side_cotangents(dsides, d_side_rows, side_idx, mrow, act):
+    """Side cotangents: every active stage's row adds to the [M, ...] slot
+    of the microbatch it ran (``mrow[s]``)."""
+    for s in range(act.shape[0]):
+        row_leaves, _, _ = _inexact_leaves(
+            jax.tree_util.tree_map(lambda r: r[s], d_side_rows)
+        )
+        dsides = [
+            _chunk_scatter_add_leaf(d, mrow[s], row_leaves[i], act[s])
+            for d, i in zip(dsides, side_idx)
+        ]
+    return dsides
+
+
+def _finish_run(run, to_layers, dlay, drep, dembed, dsides, losses, outs):
+    """After the last tick: the embedding backward from the collected
+    stage-0 input cotangents, the stage-accumulated layer gradients back
+    to [L, ...] (``to_layers``: the executor's, per leaf), and the full
+    gradient tree in the parameters' dtypes. Returns what
+    ``pipeline_1f1b`` does."""
+    model, module, spec = run.model, run.module, run.spec
+
+    def embed_bwd(acc, xs):
+        mb_input, key, dcarry, dside_row = xs
+
+        def embed_inexact(p_rest):
+            args, kwargs = mb_input
+            out, aux = apply_collecting_aux(
+                module, {"params": run.cast_half(run.with_layers(p_rest))},
+                *args, rngs=_mk_rngs(model, key, "embed"),
+                method=spec.embed_method, **kwargs,
+            )
+            leaves, _, idx = _inexact_leaves(out)
+            # The embed's own MoE aux (0.0 for dense embeds) rides along as
+            # a final output so its balancing gradient is seeded below.
+            return [leaves[i] for i in idx] + [aux]
+
+        out_aval = jax.eval_shape(embed_inexact, run.params_rest)
+        # Cotangent list: hidden cotangent (+ side cotangents for tuples),
+        # then the aux seed.
+        if run.sides is not None:
+            cots = list(jax.tree_util.tree_leaves(dcarry)) + list(dside_row)
+        else:
+            cots = jax.tree_util.tree_leaves(dcarry)
+        cots = cots + [run.aux_seed]
+        cots = [c.astype(a.dtype) for c, a in zip(cots, out_aval)]
+        _, vjp = jax.vjp(embed_inexact, run.params_rest)
+        (dp,) = vjp(cots)
+        acc = jax.tree_util.tree_map(
+            lambda a, g: a + g.astype(a.dtype), acc, dp
+        )
+        return acc, None
+
+    demb_params = None
+    if spec.embed_method is not None:
+        dside_stack = tuple(dsides) if dsides is not None else ()
+        demb_params, _ = jax.lax.scan(
+            embed_bwd, _param_grad_zeros(run.params_rest),
+            (run.stacked_inputs, run.mb_keys, dembed, dside_stack),
+        )
+    layer_grads = jax.tree_util.tree_map(to_layers, dlay)
+    if demb_params is not None:
+        # Embedding contributions (a rest-tree like drep; the layer
+        # subtree never appears in either).
+        drep = jax.tree_util.tree_map(
+            lambda a, b: a + b.astype(a.dtype), drep, demb_params
+        )
+    # Install the stage-accumulated layer grads into the rest-tree: the
+    # result has the full parameter structure.
+    grads = _set_subtree(drep, spec.layer_path, layer_grads)
+    grads = jax.tree_util.tree_map(
+        lambda g, p: g.astype(jnp.result_type(p)), grads, run.params
+    )
+    return grads, losses, outs
+
+
+def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
+                  loss_seed_scale):
+    """Run the full 1F1B forward+backward for all microbatches.
+
+    Args:
+      model: DistributedModel with ``_pipeline_spec`` installed.
+      params: master parameter tree (layer subtree leaves lead with [L]).
+      stacked_inputs: pytree with leading [num_microbatches] — captured
+        inputs of the user's single ``model(...)`` call.
+      rng: PRNG key (dropout etc.; folded per stage/microbatch so backward
+        recompute reproduces the forward exactly).
+      mb_loss_fn(out, mb_index, key) -> (loss, user_out): the user step
+        function re-run with the model call forced to ``out``.
+      loss_seed_scale: scalar multiplied into the backward seed (the step
+        engine passes loss_scale / num_microbatches so grads come out as
+        d(mean(losses) * loss_scale)).
+
+    Returns: (grads_tree, stacked_losses [M], stacked_user_outs [M, ...]).
+    """
+    cfg = state.cfg
+    schedule = getattr(cfg, "pipeline", "interleaved")
+    zero_bubble = schedule == "zero_bubble"
+    virtual = int(getattr(cfg, "virtual_pipeline_degree", 1) or 1)
+    rmode = remat_plan.resolve(cfg)
+    if rmode == "stash_weight" and not zero_bubble:
+        # No deferred weight-grad pass to stash for on the fused
+        # schedules: the SCHEDULE-level stash is inert here (the knob
+        # still maps onto the jax.checkpoint policy in
+        # memory.remat_policy for models that rematerialize, and the
+        # fingerprint config snapshot keeps recording the knob).
+        logger.warning(
+            "recompute: 'stash_weight' targets the zero_bubble schedule's "
+            "W pass; pipeline: %r has none — no schedule-level stash "
+            "(use 'stash_all' to remove this schedule's B recompute).",
+            schedule,
+        )
+        rmode = "full"
+    # Only the default program (1F1B, v=1, recompute as the model has it)
+    # is the plain executor's, whether its knobs are unset or spelled out
+    # at their defaults. The stash modes route v=1 through the chunked
+    # executors too: their plans need the chunked ring layout.
+    chunked = zero_bubble or virtual > 1 or rmode != "full"
+    run = _setup_run(
+        model, params, stacked_inputs, rng, mb_loss_fn, loss_seed_scale,
+        virtual, _chunk_views if chunked else _stage_views,
+    )
+    if not chunked:
+        return _pipeline_1f1b_plain(run)
+    if not zero_bubble:
+        # An auto plan that degrades every chunk stays on this executor
+        # (numerically identical, chunk-ring program).
+        return _pipeline_1f1b_virtual(run, rmode)
+    if rmode != "full":
+        result = _pipeline_zero_bubble_stash(run, rmode)
+        if result is not None:
+            return result
+        # The plan degraded every chunk (auto under a tight budget): the
+        # untouched recompute executor IS the plan.
+    return _pipeline_zero_bubble(run)
+
+
+def _pipeline_1f1b_plain(run):
+    """The plain v=1 executor, the module docstring's program: one run of
+    layers per stage, [S, W+1] rings, and ONE tick loop whose forward and
+    backward sub-steps sit behind conditionals on the tick index
+    (``_run_in_span``)."""
+    S, M, sides, hc = run.S, run.M, run.sides, run.hc
+    W1 = run.W + 1
+
+    fwd_np, bwd_np = build_1f1b_schedule(S, M, run.W)
+    n_ticks = fwd_np.shape[0]
+    t_b0, t_fe = interleaved_phase_bounds(fwd_np, bwd_np)
+    busy, total = schedule_occupancy(
+        fwd_np, bwd_np, fwd_ticks=t_fe, bwd_ticks=n_ticks - t_b0
+    )
+    record_pipeline_occupancy("1f1b", S, M, busy_slots=busy, total_slots=total)
+    # Busy schedule slots (with microbatch ids) into the flight recorder,
+    # once per trace — see pipeline.py for why.
+    flight_recorder.record_schedule(
+        "1f1b",
+        ((t, s, d, int(sched[t, s]))
+         for t in range(n_ticks) for s in range(S)
+         for d, sched in (("fwd", fwd_np), ("bwd", bwd_np))
+         if sched[t, s] >= 0),
+    )
+    fwd_sched = jnp.asarray(fwd_np)
+    bwd_sched = jnp.asarray(bwd_np)
+
+    # ---- buffers ------------------------------------------------------
+
+    # All [S, W1, ...], slot m % W1: inbuf the input for stage s's fwd of
+    # m; stash the input that fwd consumed; cotbuf the cotangent for stage
+    # s's output of m; outbuf the last stage's fwd output of m (only row
+    # S-1 is ever written; keeping the [S] axis keeps the buffer pp-sharded
+    # like its siblings instead of replicated).
+    inbuf0 = _zeros_stage_ring(run, W1)
+    stash0 = _zeros_stage_ring(run, W1)
+    cotbuf0 = _zeros_stage_ring(run, W1)
+    outbuf0 = _zeros_stage_ring(run, W1)
+    dlay0, drep0, dembed0, dsides0, losses0, outs0 = _zero_accumulators(run)
 
     def tick(carry, t):
         """One schedule tick: a forward and a backward sub-step. Each is
@@ -1111,20 +1409,20 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             fmc = jnp.maximum(fm, 0)
             f_slots = fmc % W1
             # Stage 0 reads from the embedded queue; others from inbuf.
-            from_q = gather_mb(hidden_q, fmc[0])
-            buf_in = get_ring(inbuf, f_slots)
+            from_q = _gather_mb(run.hidden_q, fmc[0])
+            buf_in = _stage_ring_get(inbuf, f_slots)
             x_in = jax.tree_util.tree_map(
                 lambda q, b: b.at[0].set(q), from_q, buf_in
             )
-            f_sides = gather_sides_rows(fmc)
+            f_sides = _gather_sides_rows(sides, fmc)
             with named_region("smp/pipeline/tick_fwd"):
                 outs_f, _aux_f = stage_vmap(
-                    stage_fwd, S,
+                    run.chunk_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None, 0, 0, 0),
-                )(staged_params, staged_xs, x_in, f_sides, stage_ids, fmc,
-                  active_rows)
+                )(run.staged_params, run.staged_xs, x_in, f_sides,
+                  run.stage_ids, fmc, run.active_rows)
             # Stash the consumed inputs for backward recompute.
-            stash = set_ring(stash, f_slots, x_in, f_active)
+            stash = _stage_ring_set(stash, f_slots, x_in, f_active)
             if hc is not None:
                 hbad, habs, hmb = hstats
                 brow, arow = health.stage_row_stats(outs_f, S)
@@ -1141,10 +1439,12 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             )
             shifted_slots = jnp.roll(f_slots, 1)
             shifted_active = jnp.roll(f_active, 1).at[0].set(False)
-            inbuf = set_ring(inbuf, shifted_slots, shifted_vals, shifted_active)
+            inbuf = _stage_ring_set(
+                inbuf, shifted_slots, shifted_vals, shifted_active
+            )
             # The last stage's output feeds the head/loss at its backward tick.
-            last_row_active = f_active & (stage_ids == S - 1)
-            outbuf = set_ring(outbuf, f_slots, outs_f, last_row_active)
+            last_row_active = f_active & (run.stage_ids == S - 1)
+            outbuf = _stage_ring_set(outbuf, f_slots, outs_f, last_row_active)
             return inbuf, stash, outbuf, hstats
 
         inbuf, stash, outbuf, hstats = _run_in_span(
@@ -1165,7 +1465,9 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             # The stage forward itself is NOT in this VJP — the uniform vmapped
             # stage backward below recomputes it once, same as every stage.
             m_last = bmc[S - 1]
-            key_last = jax.lax.dynamic_index_in_dim(mb_keys, m_last, 0, keepdims=False)
+            key_last = jax.lax.dynamic_index_in_dim(
+                run.mb_keys, m_last, 0, keepdims=False
+            )
             out_last = jax.tree_util.tree_map(
                 lambda ob: jax.lax.dynamic_index_in_dim(
                     ob[S - 1], b_slots[S - 1], 0, keepdims=False
@@ -1173,52 +1475,33 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
                 outbuf,
             )
 
-            def head_loss(p_rest, out):
-                final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
-                loss, user_out = mb_loss_fn(final, m_last, key_last)
-                # Head-resident MoE aux joins the differentiated loss with the
-                # same weight as the layer-stack aux (parity with pp=1).
-                loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
-                    loss.dtype
-                )
-                return loss, user_out
+            head_loss = functools.partial(_head_loss, run, m_last, key_last)
 
             with named_region("smp/pipeline/head"):
                 loss_m, head_vjp, user_out = jax.vjp(
-                    head_loss, params_rest, out_last, has_aux=True
+                    head_loss, run.params_rest, out_last, has_aux=True
                 )
-                seed = jnp.asarray(loss_seed_scale, jnp.float32) * jnp.where(
+                seed = jnp.asarray(run.loss_seed_scale, jnp.float32) * jnp.where(
                     b_active[S - 1], 1.0, 0.0
                 )
                 d_rep, d_out_last = head_vjp(seed.astype(loss_m.dtype))
 
             # All stages: plain stage VJP; cotangents come from cotbuf except
             # the last stage's, which is the head/loss cotangent just computed.
-            cot_in = get_ring(cotbuf, b_slots)
+            cot_in = _stage_ring_get(cotbuf, b_slots)
             cot_in = jax.tree_util.tree_map(
                 lambda c, d: c.at[S - 1].set(d.astype(c.dtype)), cot_in, d_out_last
             )
-            b_sides = gather_sides_rows(bmc)
-            stash_in = get_ring(stash, b_slots)
-
-            def stage_bwd(lp, lxs, x, side, cot, s_idx, m_idx, act_row):
-                def f(lp_, x_, side_):
-                    return stage_fwd(lp_, lxs, x_, side_, s_idx, m_idx, act_row)
-
-                _, vjp = jax.vjp(f, lp, x, side)
-                # Seed both outputs: the downstream cotangent for the hidden
-                # carry, and the MoE aux-loss seed (same mean-loss scaling as
-                # the task loss; idle-stage contributions are masked when
-                # accumulated below).
-                return vjp((cot, aux_seed))
+            b_sides = _gather_sides_rows(sides, bmc)
+            stash_in = _stage_ring_get(stash, b_slots)
 
             with named_region("smp/pipeline/tick_bwd"):
                 d_lp_rows, d_x_rows, d_side_rows = stage_vmap(
-                    stage_bwd, S,
+                    functools.partial(_chunk_bwd, run), S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
-                )(staged_params, staged_xs, stash_in,
-                  b_sides, cot_in, stage_ids, bmc, active_rows)
+                )(run.staged_params, run.staged_xs, stash_in,
+                  b_sides, cot_in, run.stage_ids, bmc, run.active_rows)
 
             # Accumulate layer grads (mask idle rows).
             mask_b = b_active
@@ -1245,8 +1528,8 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
             )
             cot_slots = jnp.roll(b_slots, -1)
             cot_active = jnp.roll(b_active, -1).at[S - 1].set(False)
-            cotbuf = set_ring(cotbuf, cot_slots, shifted_cots, cot_active)
-            dembed = scatter_add_mb(
+            cotbuf = _stage_ring_set(cotbuf, cot_slots, shifted_cots, cot_active)
+            dembed = _chunk_scatter_add_mb(
                 dembed, bmc[0],
                 jax.tree_util.tree_map(lambda r: r[0], d_x_rows),
                 b_active[0],
@@ -1254,24 +1537,15 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
 
             # Side cotangents: every active stage contributes to its microbatch.
             if sides is not None and dsides is not None:
-                def one_stage_side_add(ds, s):
-                    row_leaves, _, _ = _inexact_leaves(
-                        jax.tree_util.tree_map(lambda r: r[s], d_side_rows)
-                    )
-                    vals = [row_leaves[i] for i in side_idx]
-                    return [
-                        _scatter_add_leaf(d, bmc[s], v, b_active[s])
-                        for d, v in zip(ds, vals)
-                    ]
-
-                for s in range(S):
-                    dsides = one_stage_side_add(dsides, s)
+                dsides = _add_side_cotangents(
+                    dsides, d_side_rows, run.side_idx, bmc, b_active
+                )
 
             # Loss / user outputs at the last stage's backward tick.
             losses = losses.at[m_last].set(
                 jnp.where(b_active[S - 1], loss_m.astype(jnp.float32), losses[m_last])
             )
-            outs = scatter_set_mb(outs, m_last, user_out, b_active[S - 1])
+            outs = _chunk_scatter_set_mb(outs, m_last, user_out, b_active[S - 1])
             return cotbuf, dlay, drep, dembed, dsides, losses, outs
 
         cotbuf, dlay, drep, dembed, dsides, losses, outs = _run_in_span(
@@ -1284,11 +1558,6 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
         if hc is not None:
             new_carry = new_carry + (hstats,)
         return new_carry, None
-
-    def _scatter_add_leaf(buf, m, val, active):
-        cur = jax.lax.dynamic_index_in_dim(buf, m, 0, keepdims=False)
-        new = cur + jnp.where(active, val.astype(buf.dtype), jnp.zeros_like(cur))
-        return jax.lax.dynamic_update_index_in_dim(buf, new, m, 0)
 
     carry0 = (inbuf0, stash0, cotbuf0, outbuf0, dlay0, drep0, dembed0,
               dsides0, losses0, outs0)
@@ -1306,102 +1575,36 @@ def pipeline_1f1b(model, params, stacked_inputs, rng, mb_loss_fn,
     else:
         (_, _, _, _, dlay, drep, dembed, dsides, losses, outs) = carry_end
 
-    # ---- embedding backward ------------------------------------------
-
-    def embed_bwd(acc, xs):
-        mb_input, key, dcarry, dside_row = xs
-
-        def embed_inexact(p_rest):
-            args, kwargs = mb_input
-            out, aux = apply_collecting_aux(
-                module, {"params": cast_half(with_layers(p_rest))}, *args,
-                rngs=_mk_rngs(model, key, "embed"),
-                method=spec.embed_method, **kwargs,
-            )
-            leaves, _, idx = _inexact_leaves(out)
-            # The embed's own MoE aux (0.0 for dense embeds) rides along as
-            # a final output so its balancing gradient is seeded below.
-            return [leaves[i] for i in idx] + [aux]
-
-        out_aval = jax.eval_shape(embed_inexact, params_rest)
-        # Cotangent list: hidden cotangent (+ side cotangents for tuples),
-        # then the aux seed.
-        if sides is not None:
-            cots = list(jax.tree_util.tree_leaves(dcarry)) + list(dside_row)
-        else:
-            cots = jax.tree_util.tree_leaves(dcarry)
-        cots = cots + [aux_seed]
-        cots = [c.astype(a.dtype) for c, a in zip(cots, out_aval)]
-        _, vjp = jax.vjp(embed_inexact, params_rest)
-        (dp,) = vjp(cots)
-        acc = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(a.dtype), acc, dp
-        )
-        return acc, None
-
-    if spec.embed_method is not None:
-        demb_params0 = param_grad_zeros(params_rest)
-        dside_stack = tuple(dsides) if dsides is not None else ()
-        demb_params, _ = jax.lax.scan(
-            embed_bwd, demb_params0,
-            (stacked_inputs, mb_keys, dembed, dside_stack),
-        )
-    else:
-        demb_params = None
-
-    # ---- assemble the full gradient tree -----------------------------
-
     # [S, maxp, ...] accumulated stage grads -> [L, ...] (scatter-add for
     # padded/uneven layouts; a pure reshape when the layout is dense).
-    if active_np.all() and L == S * maxp:
-        layer_grads = jax.tree_util.tree_map(
-            lambda g: g.reshape((L,) + g.shape[2:]), dlay
-        )
-    else:
-        flat_idx = jnp.asarray(idx_np.reshape(-1))
-        flat_mask = active_np.reshape(-1)
-
+    if run.active_np.all() and run.L == S * run.maxp:
         def to_layers(g):
-            gf = g.reshape((S * maxp,) + g.shape[2:])
-            gf = gf * flat_mask.reshape((-1,) + (1,) * (gf.ndim - 1))
-            return jnp.zeros((L,) + g.shape[2:], g.dtype).at[flat_idx].add(gf)
-
-        layer_grads = jax.tree_util.tree_map(to_layers, dlay)
-    if demb_params is not None:
-        # Embedding contributions (a rest-tree like drep; the layer
-        # subtree never appears in either).
-        drep = jax.tree_util.tree_map(
-            lambda a, b: a + b.astype(a.dtype), drep, demb_params
-        )
-    # Install the stage-accumulated layer grads into the rest-tree: the
-    # result has the full parameter structure.
-    grads = _set_subtree(drep, spec.layer_path, layer_grads)
-    grads = jax.tree_util.tree_map(
-        lambda g, p: g.astype(jnp.result_type(p)), grads, params
+            return g.reshape((run.L,) + g.shape[2:])
+    else:
+        to_layers = _rows_to_layers(run.idx_np, run.active_np, run.L)
+    return _finish_run(
+        run, to_layers, dlay, drep, dembed, dsides, losses, outs
     )
-    return grads, losses, outs
 
 
-def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
-                           loss_seed_scale, virtual, rmode="full"):
-    """1F1B with ``virtual`` interleaved model chunks per pipeline stage.
+def _pipeline_1f1b_virtual(run, rmode):
+    """1F1B with ``run.V`` interleaved model chunks per pipeline stage.
 
-    ``rmode`` ("full" default) is the recompute-planner knob: under
-    ``stash_all``/``auto`` the forward sub-step captures per-layer vjp
-    residuals into a stash ring (``memory.recompute_ring_plan``'s
-    ``f_to_b`` lifetime) and the backward sub-step consumes them instead
-    of re-running the chunk forward under ``jax.vjp`` — the 1F1B
-    B-recompute disappears where the plan stashes. At the default every
-    code path below is untouched (the plan machinery never runs).
+    ``rmode`` is the recompute-planner knob: under ``stash_all``/``auto``
+    the forward sub-step captures per-layer vjp residuals into a stash
+    ring (``memory.recompute_ring_plan``'s ``f_to_b`` lifetime) and the
+    backward sub-step consumes them instead of re-running the chunk
+    forward under ``jax.vjp`` — the 1F1B B-recompute disappears where the
+    plan stashes. At ``"full"`` the plan machinery never runs.
 
     Same numerical contract as the v=1 executor (grads/losses/outputs
     interchangeable with the fill-drain path), different schedule shape:
 
-    - the partitioner cut the model into ``C = S*virtual`` chunks; global
+    - the partitioner cut the model into ``C = S*V`` chunks; global
       chunk ``c`` lives on stage ``c % S`` (``parallel/pipeline.py::
       chunk_layout``), so every chunk boundary crossing is a +1 rotation
       on the pp axis — ``jnp.roll`` -> one collective-permute, exactly as
-      at v=1, just ``virtual`` times as often per microbatch;
+      at v=1, just ``V`` times as often per microbatch;
     - ring buffers are keyed by (local chunk, microbatch): shape
       ``[S, V, W+1, ...]``;
     - stage transfers are DOUBLE-BUFFERED: tick t's fwd outputs / bwd
@@ -1415,37 +1618,18 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
     - the tick loop is split into three scans — forward-only warmup
       ticks, paired steady-state ticks, backward-only cooldown ticks
       (``interleaved_phase_bounds``). This is what makes the bubble
-      shrink with ``virtual``: a rigidly paired tick would idle one full
+      shrink with ``V``: a rigidly paired tick would idle one full
       sub-step per warmup/cooldown tick and the sub-slot bubble would
       stay at its v=1 value no matter how many chunks exist.
     """
-    spec = model._pipeline_spec
-    cfg = state.cfg
-    S = cfg.pipeline_parallel_degree
-    M = cfg.microbatches
-    L = spec.num_layers
-    V = virtual
-    W = min(cfg.active_microbatches or (S + 1), M)
-    W1 = W + 1
-    from smdistributed_modelparallel_tpu.nn.auto_distribute import unwrap_hooks
+    S, M, V, sides, hc = run.S, run.M, run.V, run.sides, run.hc
+    W1 = run.W + 1
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
-    module = unwrap_hooks(model.module)
-    layer_module = spec.layer_module
-    half = cfg.half_dtype
-
-    fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np = build_interleaved_1f1b_schedule(
-        S, M, W, V
-    )
+    tables = build_interleaved_1f1b_schedule(S, M, run.W, V)
+    fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np = tables
     n_ticks = fwd_m_np.shape[0]
     t_b0, t_fe = interleaved_phase_bounds(fwd_m_np, bwd_m_np)
-    from smdistributed_modelparallel_tpu.utils import health
-    from smdistributed_modelparallel_tpu.utils.flight_recorder import (
-        flight_recorder,
-    )
-    from smdistributed_modelparallel_tpu.utils.telemetry import (
-        record_pipeline_occupancy,
-    )
-
     busy, total = schedule_occupancy(
         fwd_m_np, bwd_m_np, fwd_ticks=t_fe, bwd_ticks=n_ticks - t_b0
     )
@@ -1455,8 +1639,6 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
     # Phase tick counts next to the occupancy gauges: the roofline
     # bubble attribution (utils/profiling.py) and the trace_fuse phase
     # view both read the warmup/steady/cooldown split from here.
-    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
-
     _phase_gauge = telemetry.gauge(
         "smp_pipeline_phase_ticks",
         "ticks per interleaved schedule phase (warmup/steady/cooldown)",
@@ -1464,230 +1646,24 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
     _phase_gauge.labels(phase="warmup").set(t_b0)
     _phase_gauge.labels(phase="steady").set(t_fe - t_b0)
     _phase_gauge.labels(phase="cooldown").set(n_ticks - t_fe)
-    # Slot events carry the GLOBAL chunk (boundary) index k*S + s: stage
-    # says where the work ran, chunk identifies the layers — the same
-    # coordinates the fill-drain executor records for chunked specs.
     flight_recorder.record_schedule(
         "1f1b",
-        ((t, s, d, int(m_arr[t, s]), int(k_arr[t, s]) * S + s)
-         for t in range(n_ticks) for s in range(S)
-         for d, k_arr, m_arr in (("fwd", fwd_k_np, fwd_m_np),
-                                 ("bwd", bwd_k_np, bwd_m_np))
-         if m_arr[t, s] >= 0),
+        _chunk_slots(S, (("fwd", fwd_k_np, fwd_m_np),
+                         ("bwd", bwd_k_np, bwd_m_np))),
     )
-    fwd_k_sched = jnp.asarray(fwd_k_np)
-    fwd_m_sched = jnp.asarray(fwd_m_np)
-    bwd_k_sched = jnp.asarray(bwd_k_np)
-    bwd_m_sched = jnp.asarray(bwd_m_np)
-
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        _get_subtree,
-        _mk_rngs,
-        _scan_map,
-        chunk_layout,
-        staged_chunk_views,
-    )
-
-    def cast_half(tree):
-        from smdistributed_modelparallel_tpu.nn.utils import half_cast
-
-        return half_cast(tree, half)
-
-    layer_params = _get_subtree(params, spec.layer_path)
-    staged_params, staged_xs, active_rows = staged_chunk_views(
-        spec, layer_params, S, V
-    )
-
-    # Stage-axis sharding pins (the chunked gather breaks GSPMD's
-    # propagation; pin ONLY dim 0: ``pipeline.pin_stage_axis``).
-    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
-
-    staged_params = pin_stage_axis(staged_params)
-    staged_xs = pin_stage_axis(staged_xs)
-    params_rest = _set_subtree(params, spec.layer_path, {})
-
-    def with_layers(p_rest):
-        return _set_subtree(p_rest, spec.layer_path, layer_params)
-
-    idx_np, active_np, maxp = chunk_layout(spec, S, V)
-
-    mb_keys = jax.random.split(rng, M)
-
-    # ---- embed all microbatches (the input queue) --------------------
-
-    def embed_mb(mb_input, key):
-        args, kwargs = mb_input
-        if spec.embed_method is None:
-            return args[0]
-        return module.apply(
-            {"params": cast_half(params)}, *args,
-            rngs=_mk_rngs(model, key, "embed"),
-            method=spec.embed_method, **kwargs,
-        )
-
-    with named_region("smp/pipeline/embed"):
-        embedded = _scan_map(embed_mb, stacked_inputs, mb_keys)
-
-    if spec.carry_is_tuple:
-        hidden_q = embedded[0]
-        sides = embedded[1:]
-    else:
-        hidden_q = embedded
-        sides = None
-
-    carry_aval = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), hidden_q
-    )
-
-    # ---- per-chunk forward (pure in chunk params and carry) ----------
-
-    from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        apply_collecting_aux,
-        make_layer_apply,
-    )
-
-    apply_one_layer = make_layer_apply(
-        model, spec, layer_module, side_in_carry=False
-    )
-
-    if spec.carry_remat:
-        apply_one_layer = jax.checkpoint(apply_one_layer, policy=remat_policy())
-
-    def chunk_fwd(chunk_lp, chunk_lxs, x, side, c_idx, m_idx, act_row):
-        """Apply one chunk's layer slots; keys derived from (global chunk,
-        mb) — at V=1 the global chunk id IS the stage id, so the key
-        schedule is the v=1 executor's. Returns (carry, summed MoE aux)."""
-        base = jax.random.fold_in(jax.random.fold_in(rng, c_idx), m_idx)
-        chunk_lp = cast_half(chunk_lp)
-
-        def body(c, xs):
-            lp, lxs, i, act = xs
-            new_c, aux = apply_one_layer(
-                lp, c, lxs, jax.random.fold_in(base, i), side
-            )
-            out_c = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(act, n, o), new_c, c
-            )
-            return out_c, jnp.where(act, aux, 0.0)
-
-        idx = jnp.arange(maxp)
-        out, auxs = jax.lax.scan(body, x, (chunk_lp, chunk_lxs, idx, act_row))
-        return out, jnp.sum(auxs)
-
-    def gather_mb(tree, m):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
-            tree,
-        )
-
-    def gather_sides_rows(ms):
-        if sides is None:
-            return None
-        return tuple(
-            jax.tree_util.tree_map(
-                lambda a: jax.vmap(
-                    lambda i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                )(ms),
-                s,
-            )
-            for s in sides
-        )
-
-    def select_chunk(tree, krow):
-        """Per-stage view of one chunk: [S, V, ...] -> [S, ...] at krow[s]."""
-        return jax.tree_util.tree_map(
-            lambda a: jax.vmap(
-                lambda av, k: jax.lax.dynamic_index_in_dim(av, k, 0, keepdims=False)
-            )(a, krow),
-            tree,
-        )
-
-    # ---- head + user loss (last stage, last chunk only) ---------------
-
-    def head_apply_aux(p, carry, key):
-        if spec.head_method is None:
-            return carry, jnp.zeros((), jnp.float32)
-        return apply_collecting_aux(
-            module, {"params": cast_half(p)}, carry,
-            rngs=_mk_rngs(model, key, "head"), method=spec.head_method,
-        )
-
-    def head_apply(p, carry, key):
-        return head_apply_aux(p, carry, key)[0]
-
-    loss_out_aval = jax.eval_shape(
-        lambda c: mb_loss_fn(head_apply(params, c, mb_keys[0]), 0, mb_keys[0]),
-        jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), carry_aval),
+    fwd_k_sched, fwd_m_sched, bwd_k_sched, bwd_m_sched = (
+        jnp.asarray(a) for a in tables
     )
 
     # ---- buffers ------------------------------------------------------
 
-    def zeros_chunk_ring(n):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S, V, n) + a.shape, a.dtype), carry_aval
-        )
-
-    def zeros_stage_rows():
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S,) + a.shape, a.dtype), carry_aval
-        )
-
-    grad_dtype = jnp.float32
-
-    def _acc_dtype(dtype):
-        if jnp.issubdtype(dtype, jnp.floating) and cfg._fp32_grad_accumulation:
-            return jnp.float32
-        return dtype
-
-    def param_grad_zeros(tree):
-        return jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, _acc_dtype(p.dtype)), tree
-        )
-
-    inbuf0 = zeros_chunk_ring(W1)    # inbuf[s, k, m % W1]: fwd input of (k, m)
-    stash0 = zeros_chunk_ring(W1)    # consumed fwd inputs (bwd recompute)
-    cotbuf0 = zeros_chunk_ring(W1)   # output cotangent of (k, m)
-    outbuf0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((S, W1) + a.shape, a.dtype), carry_aval
-    )                                # last chunk's fwd output (row S-1 only)
-    xfer_f0 = zeros_stage_rows()     # tick t's raw fwd outputs, rolled at t+1
-    xfer_b0 = zeros_stage_rows()     # tick t's raw input cotangents, ditto
-    dlay0 = param_grad_zeros(staged_params)
-    drep0 = param_grad_zeros(params_rest)
-    dembed0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, grad_dtype), carry_aval
-    )
-    side_leaves = side_treedef = side_idx = None
-    dsides0 = None
-    if sides is not None:
-        side_leaves, side_treedef, side_idx = _inexact_leaves(
-            tuple(jax.tree_util.tree_map(lambda a: a[0], s) for s in sides)
-        )
-        dsides0 = [
-            jnp.zeros((M,) + side_leaves[i].shape, grad_dtype) for i in side_idx
-        ]
-    losses0 = jnp.zeros((M,), jnp.float32)
-    outs0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, a.dtype), loss_out_aval[1]
-    )
-
-    stage_ids = jnp.arange(S)
-    aux_w = float(getattr(cfg, "moe_aux_loss_weight", 1.0))
-    aux_seed = (
-        jnp.asarray(aux_w, jnp.float32)
-        * jnp.asarray(loss_seed_scale, jnp.float32)
-    )
-
-    # Ring/scatter primitives shared with the zero-bubble executor
-    # (module level — see _chunk_ring_set and friends above).
-    set_ring = _chunk_ring_set
-    get_ring = _chunk_ring_get
-    set_outbuf = _chunk_outbuf_set
-    scatter_add_mb = _chunk_scatter_add_mb
-    scatter_set_mb = _chunk_scatter_set_mb
-    _scatter_add_leaf = _chunk_scatter_add_leaf
-    scatter_chunk_stat = _chunk_scatter_stat
+    inbuf0 = _zeros_chunk_ring(run, W1)    # inbuf[s, k, m % W1]: fwd input of (k, m)
+    stash0 = _zeros_chunk_ring(run, W1)    # consumed fwd inputs (bwd recompute)
+    cotbuf0 = _zeros_chunk_ring(run, W1)   # output cotangent of (k, m)
+    outbuf0 = _zeros_stage_ring(run, W1)   # last chunk's fwd output (row S-1 only)
+    xfer_f0 = _zeros_stage_rows(run)       # tick t's raw fwd outputs, rolled at t+1
+    xfer_b0 = _zeros_stage_rows(run)       # tick t's raw input cotangents, ditto
+    dlay0, drep0, dembed0, dsides0, losses0, outs0 = _zero_accumulators(run)
 
     # ---- recompute planner (stash_all / auto): capture residuals at F,
     # consume at B — everything below is inert at rmode == "full".
@@ -1695,32 +1671,15 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
     all_rstash = True
     fres0 = None
     if rmode != "full":
-        from smdistributed_modelparallel_tpu.parallel import remat_plan
-        from smdistributed_modelparallel_tpu.parallel.memory import (
-            recompute_ring_plan,
-        )
-
-        stash_rings = recompute_ring_plan(
-            fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np,
-            num_stages=S, virtual=V,
-        )
-        side_leaf_avals = (
-            [side_leaves[i] for i in side_idx] if sides is not None else []
-        )
+        stash_rings = recompute_ring_plan(*tables, num_stages=S, virtual=V)
         (capture_fwd, _bwd_in, bwd_full_from_res, _wgt,
-         _captured) = _make_residual_split(
-            apply_one_layer, cast_half, rng, maxp, aux_seed,
-            sides is not None, side_leaf_avals=side_leaf_avals,
-        )
-        res_avals = _probe_stash_avals(
-            S, staged_params, staged_xs, active_rows, carry_aval, sides,
-            capture_fwd,
-        )
+         _captured) = _make_residual_split(run)
+        res_avals = _probe_stash_avals(run, capture_fwd)
         rplan = remat_plan.plan_pipeline(
             "1f1b", rmode, S, V,
             res_ring_slots=stash_rings["f_to_b"], cot_ring_slots=0,
             res_slot_bytes=_stash_slot_bytes(res_avals),
-            cot_slot_bytes=0, cfg=cfg,
+            cot_slot_bytes=0, cfg=run.cfg,
         )
         if rplan.effective != "full":
             rstash = True
@@ -1732,8 +1691,6 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                 lambda a: jnp.zeros((S, Vs_r, Rfb) + a.shape[1:], a.dtype),
                 res_avals,
             )
-
-    hc = health.active()
 
     def tick_impl(carry, t, do_fwd, do_bwd):
         """One schedule tick. ``do_fwd``/``do_bwd`` are STATIC phase flags:
@@ -1768,12 +1725,12 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
             pk = fwd_k_sched[prev]
             pm = fwd_m_sched[prev]
             p_act = (pm >= 0) & was_prev
-            dst_k = jnp.roll(pk, 1) + (stage_ids == 0)
+            dst_k = jnp.roll(pk, 1) + (run.stage_ids == 0)
             dst_m = jnp.roll(jnp.maximum(pm, 0), 1)
             # The last chunk's output (dst_k == V) is the head input, kept
             # in outbuf at its producing tick, not routed forward.
             dst_act = jnp.roll(p_act, 1) & (dst_k < V)
-            inbuf = set_ring(
+            inbuf = _chunk_ring_set(
                 inbuf, jnp.clip(dst_k, 0, V - 1), dst_m % W1,
                 jax.tree_util.tree_map(lambda o: jnp.roll(o, 1, axis=0), xfer_f),
                 dst_act,
@@ -1782,12 +1739,12 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
             pbk = bwd_k_sched[prev]
             pbm = bwd_m_sched[prev]
             pb_act = (pbm >= 0) & was_prev
-            dst_bk = jnp.roll(pbk, -1) - (stage_ids == S - 1)
+            dst_bk = jnp.roll(pbk, -1) - (run.stage_ids == S - 1)
             dst_bm = jnp.roll(jnp.maximum(pbm, 0), -1)
             # Global chunk 0's input cotangent (dst_bk == -1) went to the
             # embedding accumulator at its producing tick.
             dst_b_act = jnp.roll(pb_act, -1) & (dst_bk >= 0)
-            cotbuf = set_ring(
+            cotbuf = _chunk_ring_set(
                 cotbuf, jnp.clip(dst_bk, 0, V - 1), dst_bm % W1,
                 jax.tree_util.tree_map(lambda o: jnp.roll(o, -1, axis=0), xfer_b),
                 dst_b_act,
@@ -1801,19 +1758,19 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
             fkc = jnp.clip(fk, 0, V - 1)
             fmc = jnp.maximum(fm, 0)
             f_slots = fmc % W1
-            ch_params = select_chunk(staged_params, fkc)
-            ch_xs = select_chunk(staged_xs, fkc)
-            ch_act = select_chunk(active_rows, fkc)
+            ch_params = _select_chunk(run.staged_params, fkc)
+            ch_xs = _select_chunk(run.staged_xs, fkc)
+            ch_act = _select_chunk(run.active_rows, fkc)
             # Stage 0 chunk 0 reads the embedded queue; everything else
             # reads its ring slot.
-            from_q = gather_mb(hidden_q, fmc[0])
-            buf_in = get_ring(inbuf, fkc, f_slots)
+            from_q = _gather_mb(run.hidden_q, fmc[0])
+            buf_in = _chunk_ring_get(inbuf, fkc, f_slots)
             x_in = jax.tree_util.tree_map(
                 lambda q, b: b.at[0].set(jnp.where(fkc[0] == 0, q, b[0])),
                 from_q, buf_in,
             )
-            f_sides = gather_sides_rows(fmc)
-            c_ids = fkc * S + stage_ids
+            f_sides = _gather_sides_rows(sides, fmc)
+            c_ids = fkc * S + run.stage_ids
             with named_region("smp/pipeline/tick_fwd"):
                 if rstash:
                     # Same forward compute; the per-layer vjp capture
@@ -1824,35 +1781,35 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0),
                     )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
-                    fres = set_ring(
+                    fres = _chunk_ring_set(
                         fres, res_col_arr[fkc], fmc % Rfb, res_f,
                         f_active & stash_of_arr[fkc],
                     )
                 else:
                     outs_f, _aux_f = stage_vmap(
-                        chunk_fwd, S,
+                        run.chunk_fwd, S,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0),
                     )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
             outs_f = pin_stage_axis(outs_f)
-            stash = set_ring(stash, fkc, f_slots, x_in, f_active)
+            stash = _chunk_ring_set(stash, fkc, f_slots, x_in, f_active)
             if hc is not None:
                 brow, arow = health.stage_row_stats(outs_f, S)
                 brow = jnp.where(f_active, brow, 0.0)
                 arow = jnp.where(f_active, arow, 0.0)
-                hmb = scatter_chunk_stat(
+                hmb = _chunk_scatter_stat(
                     hmb, fkc, fmc.astype(jnp.float32),
                     f_active & (brow > 0),
                     lambda cur, mb: jnp.where(cur < 0, mb, cur),
                 )
-                hbad = scatter_chunk_stat(
+                hbad = _chunk_scatter_stat(
                     hbad, fkc, brow, f_active, lambda cur, v: cur + v
                 )
-                habs = scatter_chunk_stat(
+                habs = _chunk_scatter_stat(
                     habs, fkc, arow, f_active, jnp.maximum
                 )
-            last_row_active = f_active & (stage_ids == S - 1) & (fkc == V - 1)
-            outbuf = set_outbuf(outbuf, f_slots, outs_f, last_row_active)
+            last_row_active = f_active & (run.stage_ids == S - 1) & (fkc == V - 1)
+            outbuf = _stage_ring_set(outbuf, f_slots, outs_f, last_row_active)
             xfer_f = outs_f
 
         # ---------------- backward sub-step ----------------
@@ -1869,7 +1826,7 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
             is_lastk = b_active[S - 1] & (bkc[S - 1] == V - 1)
             m_last = bmc[S - 1]
             key_last = jax.lax.dynamic_index_in_dim(
-                mb_keys, m_last, 0, keepdims=False
+                run.mb_keys, m_last, 0, keepdims=False
             )
             out_last = jax.tree_util.tree_map(
                 lambda ob: jax.lax.dynamic_index_in_dim(
@@ -1878,21 +1835,9 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                 outbuf,
             )
 
-            def head_loss(p_rest, out):
-                final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
-                loss, user_out = mb_loss_fn(final, m_last, key_last)
-                loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
-                    loss.dtype
-                )
-                return loss, user_out
-
-            def run_head():
-                loss_m, head_vjp, user_out = jax.vjp(
-                    head_loss, params_rest, out_last, has_aux=True
-                )
-                seed = jnp.asarray(loss_seed_scale, loss_m.dtype)
-                d_rep, d_out_last = head_vjp(seed)
-                return loss_m.astype(jnp.float32), d_rep, d_out_last, user_out
+            run_head = functools.partial(
+                _run_head, run, m_last, key_last, out_last
+            )
 
             # Only 1/V of the backward ticks carry the last chunk, but the
             # head+loss VJP is replicated (not stage-parallel) work: run it
@@ -1909,33 +1854,26 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                     ),
                 )
 
-            cot_in = get_ring(cotbuf, bkc, b_slots)
+            cot_in = _chunk_ring_get(cotbuf, bkc, b_slots)
             cot_in = jax.tree_util.tree_map(
                 lambda c, d: c.at[S - 1].set(
                     jnp.where(is_lastk, d.astype(c.dtype), c[S - 1])
                 ),
                 cot_in, d_out_last,
             )
-            b_sides = gather_sides_rows(bmc)
-            stash_in = get_ring(stash, bkc, b_slots)
-            ch_params_b = select_chunk(staged_params, bkc)
-            ch_xs_b = select_chunk(staged_xs, bkc)
-            ch_act_b = select_chunk(active_rows, bkc)
-            c_ids_b = bkc * S + stage_ids
-
-            def chunk_bwd(lp, lxs, x, side, cot, c_idx, m_idx, act_row):
-                def f(lp_, x_, side_):
-                    return chunk_fwd(lp_, lxs, x_, side_, c_idx, m_idx, act_row)
-
-                _, vjp = jax.vjp(f, lp, x, side)
-                return vjp((cot, aux_seed))
+            b_sides = _gather_sides_rows(sides, bmc)
+            stash_in = _chunk_ring_get(stash, bkc, b_slots)
+            ch_params_b = _select_chunk(run.staged_params, bkc)
+            ch_xs_b = _select_chunk(run.staged_xs, bkc)
+            ch_act_b = _select_chunk(run.active_rows, bkc)
+            c_ids_b = bkc * S + run.stage_ids
 
             d_side_leaf_rows = None
             with named_region("smp/pipeline/tick_bwd"):
                 if rstash:
                     # Backward from the residuals the forward sub-step
                     # stashed: no forward re-run for stashed chunks.
-                    res_b = get_ring(fres, res_col_arr[bkc], bmc % Rfb)
+                    res_b = _chunk_ring_get(fres, res_col_arr[bkc], bmc % Rfb)
                     d_lp_res, d_x_res, side_res = stage_vmap(
                         bwd_full_from_res, S
                     )(res_b, cot_in)
@@ -1946,7 +1884,7 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                         # Budget-degraded chunks keep the recompute path;
                         # a static per-chunk mask selects.
                         d_lp_rec, d_x_rec, d_side_rec = stage_vmap(
-                            chunk_bwd, S,
+                            functools.partial(_chunk_bwd, run), S,
                             in_axes=(0, 0, 0,
                                      0 if sides is not None else None,
                                      0, 0, 0, 0),
@@ -1970,11 +1908,11 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                             rec_all, _, _ = _inexact_leaves(d_side_rec)
                             d_side_leaf_rows = [
                                 sel(a, rec_all[i])
-                                for a, i in zip(side_res, side_idx)
+                                for a, i in zip(side_res, run.side_idx)
                             ]
                 else:
                     d_lp_rows, d_x_rows, d_side_rows = stage_vmap(
-                        chunk_bwd, S,
+                        functools.partial(_chunk_bwd, run), S,
                         in_axes=(0, 0, 0, 0 if sides is not None else None,
                                  0, 0, 0, 0),
                     )(ch_params_b, ch_xs_b, stash_in,
@@ -1990,7 +1928,7 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                 drep, d_rep,
             )
 
-            dembed = scatter_add_mb(
+            dembed = _chunk_scatter_add_mb(
                 dembed, bmc[0],
                 jax.tree_util.tree_map(lambda r: r[0], d_x_rows),
                 b_active[0] & (bkc[0] == 0),
@@ -2000,29 +1938,18 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
                 if d_side_leaf_rows is not None:
                     for s in range(S):
                         dsides = [
-                            _scatter_add_leaf(d, bmc[s], leaf[s], b_active[s])
+                            _chunk_scatter_add_leaf(d, bmc[s], leaf[s], b_active[s])
                             for d, leaf in zip(dsides, d_side_leaf_rows)
                         ]
                 else:
-                    def one_stage_side_add(ds, s):
-                        row_leaves, _, _ = _inexact_leaves(
-                            jax.tree_util.tree_map(
-                                lambda r: r[s], d_side_rows
-                            )
-                        )
-                        vals = [row_leaves[i] for i in side_idx]
-                        return [
-                            _scatter_add_leaf(d, bmc[s], v, b_active[s])
-                            for d, v in zip(ds, vals)
-                        ]
-
-                    for s in range(S):
-                        dsides = one_stage_side_add(dsides, s)
+                    dsides = _add_side_cotangents(
+                        dsides, d_side_rows, run.side_idx, bmc, b_active
+                    )
 
             losses = losses.at[m_last].set(
                 jnp.where(is_lastk, loss_m.astype(jnp.float32), losses[m_last])
             )
-            outs = scatter_set_mb(outs, m_last, user_out, is_lastk)
+            outs = _chunk_scatter_set_mb(outs, m_last, user_out, is_lastk)
             xfer_b = d_x_rows
 
         new_carry = (inbuf, stash, cotbuf, outbuf, xfer_f, xfer_b, dlay,
@@ -2040,10 +1967,7 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
         pin_stage_axis(dlay0), drep0, dembed0, dsides0, losses0, outs0,
     )
     if hc is not None:
-        carry0 = carry0 + ((
-            jnp.zeros((S, V), jnp.float32), jnp.zeros((S, V), jnp.float32),
-            jnp.full((S, V), -1.0, jnp.float32),
-        ),)
+        carry0 = carry0 + (_zeros_health_grids(S, V),)
     if rstash:
         carry0 = carry0 + (pin_stage_axis(fres0),)
 
@@ -2077,72 +2001,63 @@ def _pipeline_1f1b_virtual(model, params, stacked_inputs, rng, mb_loss_fn,
         (_, _, _, _, _, _, dlay, drep, dembed, dsides, losses,
          outs) = carry_end
 
-    # ---- embedding backward ------------------------------------------
-
-    def embed_bwd(acc, xs):
-        mb_input, key, dcarry, dside_row = xs
-
-        def embed_inexact(p_rest):
-            args, kwargs = mb_input
-            out, aux = apply_collecting_aux(
-                module, {"params": cast_half(with_layers(p_rest))}, *args,
-                rngs=_mk_rngs(model, key, "embed"),
-                method=spec.embed_method, **kwargs,
-            )
-            leaves, _, idx = _inexact_leaves(out)
-            return [leaves[i] for i in idx] + [aux]
-
-        out_aval = jax.eval_shape(embed_inexact, params_rest)
-        if sides is not None:
-            cots = list(jax.tree_util.tree_leaves(dcarry)) + list(dside_row)
-        else:
-            cots = jax.tree_util.tree_leaves(dcarry)
-        cots = cots + [aux_seed]
-        cots = [c.astype(a.dtype) for c, a in zip(cots, out_aval)]
-        _, vjp = jax.vjp(embed_inexact, params_rest)
-        (dp,) = vjp(cots)
-        acc = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(a.dtype), acc, dp
-        )
-        return acc, None
-
-    if spec.embed_method is not None:
-        demb_params0 = param_grad_zeros(params_rest)
-        dside_stack = tuple(dsides) if dsides is not None else ()
-        demb_params, _ = jax.lax.scan(
-            embed_bwd, demb_params0,
-            (stacked_inputs, mb_keys, dembed, dside_stack),
-        )
-    else:
-        demb_params = None
-
-    # ---- assemble the full gradient tree -----------------------------
-
-    # [S, V, maxp, ...] accumulated chunk grads -> [L, ...]. The chunked
-    # placement interleaves the layer axis across stages, so this is
-    # always a scatter-add (the v=1 dense-reshape shortcut cannot apply).
-    flat_idx = jnp.asarray(idx_np.reshape(-1))
-    flat_mask = active_np.reshape(-1)
-
-    def to_layers(g):
-        gf = g.reshape((S * V * maxp,) + g.shape[3:])
-        gf = gf * flat_mask.reshape((-1,) + (1,) * (gf.ndim - 1))
-        return jnp.zeros((L,) + g.shape[3:], g.dtype).at[flat_idx].add(gf)
-
-    layer_grads = jax.tree_util.tree_map(to_layers, dlay)
-    if demb_params is not None:
-        drep = jax.tree_util.tree_map(
-            lambda a, b: a + b.astype(a.dtype), drep, demb_params
-        )
-    grads = _set_subtree(drep, spec.layer_path, layer_grads)
-    grads = jax.tree_util.tree_map(
-        lambda g, p: g.astype(jnp.result_type(p)), grads, params
+    # The chunked placement interleaves the layer axis across stages, so
+    # [S, V, maxp, ...] -> [L, ...] is always a scatter-add (the v=1
+    # dense-reshape shortcut cannot apply).
+    return _finish_run(
+        run, _rows_to_layers(run.idx_np, run.active_np, run.L),
+        dlay, drep, dembed, dsides, losses, outs,
     )
-    return grads, losses, outs
 
 
-def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
-                          loss_seed_scale, virtual):
+def _record_zero_bubble_schedule(run, tables, ring_plan, pass_ticks):
+    """Gauges and flight-recorder slots of a zero-bubble build.
+    ``pass_ticks``: executed ticks per pass, the occupancy's denominator."""
+    S, M, V = run.S, run.M, run.V
+    fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np, wgt_m_np = tables
+    busy, total = schedule_occupancy(
+        fwd_m_np, bwd_m_np, fwd_ticks=pass_ticks["fwd"],
+        bwd_ticks=pass_ticks["bwd_input"], wgt=wgt_m_np,
+        wgt_ticks=pass_ticks["bwd_weight"],
+    )
+    record_pipeline_occupancy(
+        "zb", S, M, busy_slots=busy, total_slots=total, virtual=V,
+        passes=3, pass_ticks=pass_ticks,
+    )
+    # W-queue accounting next to the occupancy gauges: ring slots actually
+    # allocated per (stage, chunk) and the peak number of deferred
+    # weight-grad units — the memory side of the bubble trade.
+    telemetry.gauge(
+        "smp_pipeline_ring_slots",
+        "per-(stage, chunk) ring-buffer slots of the pipeline executor",
+    ).labels(schedule="zb").set(ring_plan["ring_slots"])
+    telemetry.gauge(
+        "smp_pipeline_wqueue_peak",
+        "peak deferred weight-grad units per (stage, chunk) [zero-bubble]",
+    ).labels(schedule="zb").set(ring_plan["w_queue_peak"])
+    flight_recorder.record_schedule(
+        "zb",
+        _chunk_slots(S, (("fwd", fwd_k_np, fwd_m_np, "F"),
+                         ("bwd_input", bwd_k_np, bwd_m_np, "B"),
+                         ("bwd_weight", wgt_k_np, wgt_m_np, "W"))),
+    )
+
+
+def _add_zero_bubble_stage_stats(run, hstats):
+    """The zero-bubble executors' sentinel rows into the collector. Grid
+    position (s, k) holds GLOBAL chunk k*S + s; tags carry the pass
+    coordinate so a tripped sentinel attributes to the exact (chunk,
+    pass) — forward activations vs input cotangents."""
+    S, V = run.S, run.V
+    ((hbad, habs, hmb), (hbad_b, habs_b, hmb_b)) = hstats
+    chunk_ids = np.arange(V)[None, :] * S + np.arange(S)[:, None]
+    run.hc.add_stage_stats("zb", hbad, habs, hmb, chunk_ids=chunk_ids,
+                           pass_name="fwd")
+    run.hc.add_stage_stats("zb", hbad_b, habs_b, hmb_b, chunk_ids=chunk_ids,
+                           pass_name="bwd_input")
+
+
+def _pipeline_zero_bubble(run):
     """ZB-H1 executor: backward split into B (input-grad) and W
     (weight-grad) passes over (chunk, microbatch, pass) schedule units.
 
@@ -2179,294 +2094,41 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
     transfer registers carry over from the virtual executor unchanged
     (W produces no transfers: weight grads stay stage-local).
     """
-    spec = model._pipeline_spec
-    cfg = state.cfg
-    S = cfg.pipeline_parallel_degree
-    M = cfg.microbatches
-    L = spec.num_layers
-    V = virtual
-    W = min(cfg.active_microbatches or (S + 1), M)
-    from smdistributed_modelparallel_tpu.nn.auto_distribute import unwrap_hooks
+    S, M, V, sides, hc = run.S, run.M, run.V, run.sides, run.hc
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
-    module = unwrap_hooks(model.module)
-    layer_module = spec.layer_module
-    half = cfg.half_dtype
-
-    (fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np,
-     wgt_m_np) = build_zero_bubble_schedule(S, M, W, V)
+    tables = build_zero_bubble_schedule(S, M, run.W, V)
+    _, fwd_m_np, _, bwd_m_np, _, wgt_m_np = tables
     n_ticks = fwd_m_np.shape[0]
     f_span, b_span, w_span = zero_bubble_phase_bounds(
         fwd_m_np, bwd_m_np, wgt_m_np
     )
     segments = _zb_segments(f_span, b_span, w_span, n_ticks)
-
-    from smdistributed_modelparallel_tpu.parallel.memory import (
-        zero_bubble_ring_plan,
-    )
-
     plan = zero_bubble_ring_plan(
-        fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np, wgt_m_np,
-        num_stages=S, virtual=V, window=W,
+        *tables, num_stages=S, virtual=V, window=run.W
     )
     R1 = plan["ring_slots"]
-
-    from smdistributed_modelparallel_tpu.utils import health
-    from smdistributed_modelparallel_tpu.utils.flight_recorder import (
-        flight_recorder,
-    )
-    from smdistributed_modelparallel_tpu.utils.telemetry import (
-        record_pipeline_occupancy,
-        telemetry,
-    )
-
-    f_len = f_span[1] - f_span[0]
-    b_len = b_span[1] - b_span[0]
-    w_len = w_span[1] - w_span[0]
-    busy, total = schedule_occupancy(
-        fwd_m_np, bwd_m_np, fwd_ticks=f_len, bwd_ticks=b_len,
-        wgt=wgt_m_np, wgt_ticks=w_len,
-    )
-    record_pipeline_occupancy(
-        "zb", S, M, busy_slots=busy, total_slots=total, virtual=V,
-        passes=3,
-        pass_ticks={"fwd": f_len, "bwd_input": b_len, "bwd_weight": w_len},
-    )
-    # W-queue accounting next to the occupancy gauges: ring slots actually
-    # allocated per (stage, chunk) and the peak number of deferred
-    # weight-grad units — the memory side of the bubble trade.
-    _ring_gauge = telemetry.gauge(
-        "smp_pipeline_ring_slots",
-        "per-(stage, chunk) ring-buffer slots of the pipeline executor",
-    )
-    _ring_gauge.labels(schedule="zb").set(R1)
-    telemetry.gauge(
-        "smp_pipeline_wqueue_peak",
-        "peak deferred weight-grad units per (stage, chunk) [zero-bubble]",
-    ).labels(schedule="zb").set(plan["w_queue_peak"])
-    flight_recorder.record_schedule(
-        "zb",
-        ((t, s, d, int(m_arr[t, s]), int(k_arr[t, s]) * S + s, p)
-         for t in range(n_ticks) for s in range(S)
-         for d, p, k_arr, m_arr in (
-             ("fwd", "F", fwd_k_np, fwd_m_np),
-             ("bwd_input", "B", bwd_k_np, bwd_m_np),
-             ("bwd_weight", "W", wgt_k_np, wgt_m_np))
-         if m_arr[t, s] >= 0),
-    )
-    fwd_k_sched = jnp.asarray(fwd_k_np)
-    fwd_m_sched = jnp.asarray(fwd_m_np)
-    bwd_k_sched = jnp.asarray(bwd_k_np)
-    bwd_m_sched = jnp.asarray(bwd_m_np)
-    wgt_k_sched = jnp.asarray(wgt_k_np)
-    wgt_m_sched = jnp.asarray(wgt_m_np)
-
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        _get_subtree,
-        _mk_rngs,
-        _scan_map,
-        chunk_layout,
-        staged_chunk_views,
-    )
-
-    def cast_half(tree):
-        from smdistributed_modelparallel_tpu.nn.utils import half_cast
-
-        return half_cast(tree, half)
-
-    layer_params = _get_subtree(params, spec.layer_path)
-    staged_params, staged_xs, active_rows = staged_chunk_views(
-        spec, layer_params, S, V
-    )
-
-    # Stage-axis sharding pins (the chunked gather breaks GSPMD's
-    # propagation; pin ONLY dim 0: ``pipeline.pin_stage_axis``).
-    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
-
-    staged_params = pin_stage_axis(staged_params)
-    staged_xs = pin_stage_axis(staged_xs)
-    params_rest = _set_subtree(params, spec.layer_path, {})
-
-    def with_layers(p_rest):
-        return _set_subtree(p_rest, spec.layer_path, layer_params)
-
-    idx_np, active_np, maxp = chunk_layout(spec, S, V)
-
-    mb_keys = jax.random.split(rng, M)
-
-    # ---- embed all microbatches (the input queue) --------------------
-
-    def embed_mb(mb_input, key):
-        args, kwargs = mb_input
-        if spec.embed_method is None:
-            return args[0]
-        return module.apply(
-            {"params": cast_half(params)}, *args,
-            rngs=_mk_rngs(model, key, "embed"),
-            method=spec.embed_method, **kwargs,
-        )
-
-    with named_region("smp/pipeline/embed"):
-        embedded = _scan_map(embed_mb, stacked_inputs, mb_keys)
-
-    if spec.carry_is_tuple:
-        hidden_q = embedded[0]
-        sides = embedded[1:]
-    else:
-        hidden_q = embedded
-        sides = None
-
-    carry_aval = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), hidden_q
-    )
-
-    # ---- per-chunk forward (pure in chunk params and carry) ----------
-
-    from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        apply_collecting_aux,
-        make_layer_apply,
-    )
-
-    apply_one_layer = make_layer_apply(
-        model, spec, layer_module, side_in_carry=False
-    )
-
-    if spec.carry_remat:
-        apply_one_layer = jax.checkpoint(apply_one_layer, policy=remat_policy())
-
-    def chunk_fwd(chunk_lp, chunk_lxs, x, side, c_idx, m_idx, act_row):
-        """Apply one chunk's layer slots; keys derived from (global chunk,
-        mb), so the B and W recomputes reproduce the forward (dropout
-        included) exactly. Returns (carry, summed MoE aux)."""
-        base = jax.random.fold_in(jax.random.fold_in(rng, c_idx), m_idx)
-        chunk_lp = cast_half(chunk_lp)
-
-        def body(c, xs):
-            lp, lxs, i, act = xs
-            new_c, aux = apply_one_layer(
-                lp, c, lxs, jax.random.fold_in(base, i), side
-            )
-            out_c = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(act, n, o), new_c, c
-            )
-            return out_c, jnp.where(act, aux, 0.0)
-
-        idx = jnp.arange(maxp)
-        out, auxs = jax.lax.scan(body, x, (chunk_lp, chunk_lxs, idx, act_row))
-        return out, jnp.sum(auxs)
-
-    def gather_mb(tree, m):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
-            tree,
-        )
-
-    def gather_sides_rows(ms):
-        if sides is None:
-            return None
-        return tuple(
-            jax.tree_util.tree_map(
-                lambda a: jax.vmap(
-                    lambda i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                )(ms),
-                s,
-            )
-            for s in sides
-        )
-
-    def select_chunk(tree, krow):
-        """Per-stage view of one chunk: [S, V, ...] -> [S, ...] at krow[s]."""
-        return jax.tree_util.tree_map(
-            lambda a: jax.vmap(
-                lambda av, k: jax.lax.dynamic_index_in_dim(av, k, 0, keepdims=False)
-            )(a, krow),
-            tree,
-        )
-
-    # ---- head + user loss (last stage, last chunk only) ---------------
-
-    def head_apply_aux(p, carry, key):
-        if spec.head_method is None:
-            return carry, jnp.zeros((), jnp.float32)
-        return apply_collecting_aux(
-            module, {"params": cast_half(p)}, carry,
-            rngs=_mk_rngs(model, key, "head"), method=spec.head_method,
-        )
-
-    def head_apply(p, carry, key):
-        return head_apply_aux(p, carry, key)[0]
-
-    loss_out_aval = jax.eval_shape(
-        lambda c: mb_loss_fn(head_apply(params, c, mb_keys[0]), 0, mb_keys[0]),
-        jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), carry_aval),
-    )
+    _record_zero_bubble_schedule(run, tables, plan, {
+        "fwd": f_span[1] - f_span[0], "bwd_input": b_span[1] - b_span[0],
+        "bwd_weight": w_span[1] - w_span[0],
+    })
+    (fwd_k_sched, fwd_m_sched, bwd_k_sched, bwd_m_sched, wgt_k_sched,
+     wgt_m_sched) = (jnp.asarray(a) for a in tables)
 
     # ---- buffers ------------------------------------------------------
-
-    def zeros_chunk_ring(n):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S, V, n) + a.shape, a.dtype), carry_aval
-        )
-
-    def zeros_stage_rows():
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S,) + a.shape, a.dtype), carry_aval
-        )
-
-    grad_dtype = jnp.float32
-
-    def _acc_dtype(dtype):
-        if jnp.issubdtype(dtype, jnp.floating) and cfg._fp32_grad_accumulation:
-            return jnp.float32
-        return dtype
-
-    def param_grad_zeros(tree):
-        return jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, _acc_dtype(p.dtype)), tree
-        )
 
     # Ring slot count R1 comes from the memory plan: stash and cotangent
     # entries live until the W pass (not just B), so the alive depth can
     # exceed the 1F1B executors' window+1 — but never does at the default
     # window (the deferral hides inside the slack the in-flight cap
     # already paid for).
-    inbuf0 = zeros_chunk_ring(R1)    # inbuf[s, k, m % R1]: fwd input of (k, m)
-    stash0 = zeros_chunk_ring(R1)    # consumed fwd inputs (B AND W recompute)
-    cotbuf0 = zeros_chunk_ring(R1)   # output cotangent of (k, m); W re-reads
-    outbuf0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((S, R1) + a.shape, a.dtype), carry_aval
-    )                                # last chunk's fwd output (row S-1 only)
-    xfer_f0 = zeros_stage_rows()     # tick t's raw fwd outputs, rolled at t+1
-    xfer_b0 = zeros_stage_rows()     # tick t's raw input cotangents, ditto
-    dlay0 = param_grad_zeros(staged_params)
-    drep0 = param_grad_zeros(params_rest)
-    dembed0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, grad_dtype), carry_aval
-    )
-    side_leaves = side_treedef = side_idx = None
-    dsides0 = None
-    if sides is not None:
-        side_leaves, side_treedef, side_idx = _inexact_leaves(
-            tuple(jax.tree_util.tree_map(lambda a: a[0], s) for s in sides)
-        )
-        dsides0 = [
-            jnp.zeros((M,) + side_leaves[i].shape, grad_dtype) for i in side_idx
-        ]
-    losses0 = jnp.zeros((M,), jnp.float32)
-    outs0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, a.dtype), loss_out_aval[1]
-    )
-
-    stage_ids = jnp.arange(S)
-    aux_w = float(getattr(cfg, "moe_aux_loss_weight", 1.0))
-    aux_seed = (
-        jnp.asarray(aux_w, jnp.float32)
-        * jnp.asarray(loss_seed_scale, jnp.float32)
-    )
-
-    # Ring/scatter primitives are the module-level _chunk_* helpers,
-    # shared with the virtual executor.
-    hc = health.active()
+    inbuf0 = _zeros_chunk_ring(run, R1)    # inbuf[s, k, m % R1]: fwd input of (k, m)
+    stash0 = _zeros_chunk_ring(run, R1)    # consumed fwd inputs (B AND W recompute)
+    cotbuf0 = _zeros_chunk_ring(run, R1)   # output cotangent of (k, m); W re-reads
+    outbuf0 = _zeros_stage_ring(run, R1)   # last chunk's fwd output (row S-1 only)
+    xfer_f0 = _zeros_stage_rows(run)       # tick t's raw fwd outputs, rolled at t+1
+    xfer_b0 = _zeros_stage_rows(run)       # tick t's raw input cotangents, ditto
+    dlay0, drep0, dembed0, dsides0, losses0, outs0 = _zero_accumulators(run)
 
     def tick_impl(carry, t, do_fwd, do_bwd, do_wgt):
         """One schedule tick. The pass flags are STATIC per segment:
@@ -2496,7 +2158,7 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             pk = fwd_k_sched[prev]
             pm = fwd_m_sched[prev]
             p_act = (pm >= 0) & was_prev
-            dst_k = jnp.roll(pk, 1) + (stage_ids == 0)
+            dst_k = jnp.roll(pk, 1) + (run.stage_ids == 0)
             dst_m = jnp.roll(jnp.maximum(pm, 0), 1)
             dst_act = jnp.roll(p_act, 1) & (dst_k < V)
             inbuf = _chunk_ring_set(
@@ -2508,7 +2170,7 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             pbk = bwd_k_sched[prev]
             pbm = bwd_m_sched[prev]
             pb_act = (pbm >= 0) & was_prev
-            dst_bk = jnp.roll(pbk, -1) - (stage_ids == S - 1)
+            dst_bk = jnp.roll(pbk, -1) - (run.stage_ids == S - 1)
             dst_bm = jnp.roll(jnp.maximum(pbm, 0), -1)
             dst_b_act = jnp.roll(pb_act, -1) & (dst_bk >= 0)
             cotbuf = _chunk_ring_set(
@@ -2525,20 +2187,20 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             fkc = jnp.clip(fk, 0, V - 1)
             fmc = jnp.maximum(fm, 0)
             f_slots = fmc % R1
-            ch_params = select_chunk(staged_params, fkc)
-            ch_xs = select_chunk(staged_xs, fkc)
-            ch_act = select_chunk(active_rows, fkc)
-            from_q = gather_mb(hidden_q, fmc[0])
+            ch_params = _select_chunk(run.staged_params, fkc)
+            ch_xs = _select_chunk(run.staged_xs, fkc)
+            ch_act = _select_chunk(run.active_rows, fkc)
+            from_q = _gather_mb(run.hidden_q, fmc[0])
             buf_in = _chunk_ring_get(inbuf, fkc, f_slots)
             x_in = jax.tree_util.tree_map(
                 lambda q, b: b.at[0].set(jnp.where(fkc[0] == 0, q, b[0])),
                 from_q, buf_in,
             )
-            f_sides = gather_sides_rows(fmc)
-            c_ids = fkc * S + stage_ids
+            f_sides = _gather_sides_rows(sides, fmc)
+            c_ids = fkc * S + run.stage_ids
             with named_region("smp/pipeline/tick_fwd"):
                 outs_f, _aux_f = stage_vmap(
-                    chunk_fwd, S,
+                    run.chunk_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -2559,8 +2221,8 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
                 habs = _chunk_scatter_stat(
                     habs, fkc, arow, f_active, jnp.maximum
                 )
-            last_row_active = f_active & (stage_ids == S - 1) & (fkc == V - 1)
-            outbuf = _chunk_outbuf_set(outbuf, f_slots, outs_f, last_row_active)
+            last_row_active = f_active & (run.stage_ids == S - 1) & (fkc == V - 1)
+            outbuf = _stage_ring_set(outbuf, f_slots, outs_f, last_row_active)
             xfer_f = outs_f
 
         # ---------------- backward-input sub-step ----------------
@@ -2575,7 +2237,7 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             is_lastk = b_active[S - 1] & (bkc[S - 1] == V - 1)
             m_last = bmc[S - 1]
             key_last = jax.lax.dynamic_index_in_dim(
-                mb_keys, m_last, 0, keepdims=False
+                run.mb_keys, m_last, 0, keepdims=False
             )
             out_last = jax.tree_util.tree_map(
                 lambda ob: jax.lax.dynamic_index_in_dim(
@@ -2584,21 +2246,9 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
                 outbuf,
             )
 
-            def head_loss(p_rest, out):
-                final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
-                loss, user_out = mb_loss_fn(final, m_last, key_last)
-                loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
-                    loss.dtype
-                )
-                return loss, user_out
-
-            def run_head():
-                loss_m, head_vjp, user_out = jax.vjp(
-                    head_loss, params_rest, out_last, has_aux=True
-                )
-                seed = jnp.asarray(loss_seed_scale, loss_m.dtype)
-                d_rep, d_out_last = head_vjp(seed)
-                return loss_m.astype(jnp.float32), d_rep, d_out_last, user_out
+            run_head = functools.partial(
+                _run_head, run, m_last, key_last, out_last
+            )
 
             head_aval = jax.eval_shape(run_head)
             with named_region("smp/pipeline/head"):
@@ -2624,24 +2274,24 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             # entries are untouched.
             cotbuf = _chunk_ring_set(
                 cotbuf, bkc, b_slots, cot_in,
-                b_active & (stage_ids == S - 1) & (bkc == V - 1),
+                b_active & (run.stage_ids == S - 1) & (bkc == V - 1),
             )
-            b_sides = gather_sides_rows(bmc)
+            b_sides = _gather_sides_rows(sides, bmc)
             stash_in = _chunk_ring_get(stash, bkc, b_slots)
-            ch_params_b = select_chunk(staged_params, bkc)
-            ch_xs_b = select_chunk(staged_xs, bkc)
-            ch_act_b = select_chunk(active_rows, bkc)
-            c_ids_b = bkc * S + stage_ids
+            ch_params_b = _select_chunk(run.staged_params, bkc)
+            ch_xs_b = _select_chunk(run.staged_xs, bkc)
+            ch_act_b = _select_chunk(run.active_rows, bkc)
+            c_ids_b = bkc * S + run.stage_ids
 
             def chunk_bwd_input(lp, lxs, x, side, cot, c_idx, m_idx, act_row):
                 """Input-grad pass: VJP w.r.t. (input, sides) only — the
                 weight cotangent is never formed here."""
 
                 def f(x_, side_):
-                    return chunk_fwd(lp, lxs, x_, side_, c_idx, m_idx, act_row)
+                    return run.chunk_fwd(lp, lxs, x_, side_, c_idx, m_idx, act_row)
 
                 _, vjp = jax.vjp(f, x, side)
-                return vjp((cot, aux_seed))
+                return vjp((cot, run.aux_seed))
 
             with named_region("smp/pipeline/tick_bwd_input"):
                 d_x_rows, d_side_rows = stage_vmap(
@@ -2680,18 +2330,9 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             )
 
             if sides is not None and dsides is not None:
-                def one_stage_side_add(ds, s):
-                    row_leaves, _, _ = _inexact_leaves(
-                        jax.tree_util.tree_map(lambda r: r[s], d_side_rows)
-                    )
-                    vals = [row_leaves[i] for i in side_idx]
-                    return [
-                        _chunk_scatter_add_leaf(d, bmc[s], v, b_active[s])
-                        for d, v in zip(ds, vals)
-                    ]
-
-                for s in range(S):
-                    dsides = one_stage_side_add(dsides, s)
+                dsides = _add_side_cotangents(
+                    dsides, d_side_rows, run.side_idx, bmc, b_active
+                )
 
             losses = losses.at[m_last].set(
                 jnp.where(is_lastk, loss_m.astype(jnp.float32), losses[m_last])
@@ -2708,29 +2349,17 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
             wmc = jnp.maximum(wm, 0)
             w_slots = wmc % R1
 
-            w_sides = gather_sides_rows(wmc)
+            w_sides = _gather_sides_rows(sides, wmc)
             stash_w = _chunk_ring_get(stash, wkc, w_slots)
             cot_w = _chunk_ring_get(cotbuf, wkc, w_slots)
-            ch_params_w = select_chunk(staged_params, wkc)
-            ch_xs_w = select_chunk(staged_xs, wkc)
-            ch_act_w = select_chunk(active_rows, wkc)
-            c_ids_w = wkc * S + stage_ids
-
-            def chunk_bwd_weight(lp, lxs, x, side, cot, c_idx, m_idx,
-                                 act_row):
-                """Weight-grad pass: VJP w.r.t. the chunk params only,
-                re-reading the stashed input and retained cotangent."""
-
-                def f(lp_):
-                    return chunk_fwd(lp_, lxs, x, side, c_idx, m_idx, act_row)
-
-                _, vjp = jax.vjp(f, lp)
-                (d_lp,) = vjp((cot, aux_seed))
-                return d_lp
+            ch_params_w = _select_chunk(run.staged_params, wkc)
+            ch_xs_w = _select_chunk(run.staged_xs, wkc)
+            ch_act_w = _select_chunk(run.active_rows, wkc)
+            c_ids_w = wkc * S + run.stage_ids
 
             with named_region("smp/pipeline/tick_bwd_weight"):
                 d_lp_rows = stage_vmap(
-                    chunk_bwd_weight, S,
+                    functools.partial(_chunk_bwd_weight, run), S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(ch_params_w, ch_xs_w, stash_w,
@@ -2753,14 +2382,9 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
         pin_stage_axis(dlay0), drep0, dembed0, dsides0, losses0, outs0,
     )
     if hc is not None:
-
-        def hgrids():
-            return (
-                jnp.zeros((S, V), jnp.float32), jnp.zeros((S, V), jnp.float32),
-                jnp.full((S, V), -1.0, jnp.float32),
-            )
-
-        carry0 = carry0 + ((hgrids(), hgrids()),)
+        carry0 = carry0 + (
+            (_zeros_health_grids(S, V), _zeros_health_grids(S, V)),
+        )
 
     carry_end = carry0
     for a, b, (do_f, do_b, do_w) in segments:
@@ -2774,84 +2398,23 @@ def _pipeline_zero_bubble(model, params, stacked_inputs, rng, mb_loss_fn,
     if hc is not None:
         (_, _, _, _, _, _, dlay, drep, dembed, dsides, losses, outs,
          hstats) = carry_end
-        ((hbad, habs, hmb), (hbad_b, habs_b, hmb_b)) = hstats
-        # Grid position (s, k) holds GLOBAL chunk k*S + s; tags carry the
-        # pass coordinate so a tripped sentinel attributes to the exact
-        # (chunk, pass) — forward activations vs input cotangents.
-        chunk_ids = np.arange(V)[None, :] * S + np.arange(S)[:, None]
-        hc.add_stage_stats("zb", hbad, habs, hmb, chunk_ids=chunk_ids,
-                           pass_name="fwd")
-        hc.add_stage_stats("zb", hbad_b, habs_b, hmb_b, chunk_ids=chunk_ids,
-                           pass_name="bwd_input")
+        _add_zero_bubble_stage_stats(run, hstats)
     else:
         (_, _, _, _, _, _, dlay, drep, dembed, dsides, losses,
          outs) = carry_end
 
-    # ---- embedding backward ------------------------------------------
-
-    def embed_bwd(acc, xs):
-        mb_input, key, dcarry, dside_row = xs
-
-        def embed_inexact(p_rest):
-            args, kwargs = mb_input
-            out, aux = apply_collecting_aux(
-                module, {"params": cast_half(with_layers(p_rest))}, *args,
-                rngs=_mk_rngs(model, key, "embed"),
-                method=spec.embed_method, **kwargs,
-            )
-            leaves, _, idx = _inexact_leaves(out)
-            return [leaves[i] for i in idx] + [aux]
-
-        out_aval = jax.eval_shape(embed_inexact, params_rest)
-        if sides is not None:
-            cots = list(jax.tree_util.tree_leaves(dcarry)) + list(dside_row)
-        else:
-            cots = jax.tree_util.tree_leaves(dcarry)
-        cots = cots + [aux_seed]
-        cots = [c.astype(a.dtype) for c, a in zip(cots, out_aval)]
-        _, vjp = jax.vjp(embed_inexact, params_rest)
-        (dp,) = vjp(cots)
-        acc = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(a.dtype), acc, dp
-        )
-        return acc, None
-
-    if spec.embed_method is not None:
-        demb_params0 = param_grad_zeros(params_rest)
-        dside_stack = tuple(dsides) if dsides is not None else ()
-        demb_params, _ = jax.lax.scan(
-            embed_bwd, demb_params0,
-            (stacked_inputs, mb_keys, dembed, dside_stack),
-        )
-    else:
-        demb_params = None
-
-    # ---- assemble the full gradient tree -----------------------------
-
-    flat_idx = jnp.asarray(idx_np.reshape(-1))
-    flat_mask = active_np.reshape(-1)
-
-    def to_layers(g):
-        gf = g.reshape((S * V * maxp,) + g.shape[3:])
-        gf = gf * flat_mask.reshape((-1,) + (1,) * (gf.ndim - 1))
-        return jnp.zeros((L,) + g.shape[3:], g.dtype).at[flat_idx].add(gf)
-
-    layer_grads = jax.tree_util.tree_map(to_layers, dlay)
-    if demb_params is not None:
-        drep = jax.tree_util.tree_map(
-            lambda a, b: a + b.astype(a.dtype), drep, demb_params
-        )
-    grads = _set_subtree(drep, spec.layer_path, layer_grads)
-    grads = jax.tree_util.tree_map(
-        lambda g, p: g.astype(jnp.result_type(p)), grads, params
+    return _finish_run(
+        run, _rows_to_layers(run.idx_np, run.active_np, run.L),
+        dlay, drep, dembed, dsides, losses, outs,
     )
-    return grads, losses, outs
 
 
-def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
-                                mb_loss_fn, loss_seed_scale, virtual, rmode):
+def _pipeline_zero_bubble_stash(run, rmode):
     """ZB-H1 executor under a non-default recompute plan
-    (``recompute: stash_weight | stash_all | auto``).
+    (``recompute: stash_weight | stash_all | auto``). Returns ``None``,
+    before anything is recorded or emitted, when the plan degrades every
+    chunk: the dispatch then runs ``_pipeline_zero_bubble`` on the same
+    ``run``.
 
     Same numerical contract and schedule as ``_pipeline_zero_bubble``;
     two structural differences, both existing only on this knob-gated
@@ -2879,47 +2442,42 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
       compiles every pass into each of its segments, which is most of
       what the structural remat census counts against the ZB schedule.
     """
-    spec = model._pipeline_spec
-    cfg = state.cfg
-    S = cfg.pipeline_parallel_degree
-    M = cfg.microbatches
-    L = spec.num_layers
-    V = virtual
-    W = min(cfg.active_microbatches or (S + 1), M)
-    from smdistributed_modelparallel_tpu.nn.auto_distribute import unwrap_hooks
+    S, M, V, sides, hc = run.S, run.M, run.V, run.sides, run.hc
+    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
 
-    module = unwrap_hooks(model.module)
-    layer_module = spec.layer_module
-    half = cfg.half_dtype
-
-    (fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np,
-     wgt_m_np) = build_zero_bubble_schedule(S, M, W, V)
+    tables = build_zero_bubble_schedule(S, M, run.W, V)
+    fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, _, wgt_m_np = tables
     n_ticks = fwd_m_np.shape[0]
-
-    from smdistributed_modelparallel_tpu.parallel.memory import (
-        recompute_ring_plan,
-        zero_bubble_ring_plan,
-    )
-    from smdistributed_modelparallel_tpu.parallel import remat_plan
-
     plan_rings = zero_bubble_ring_plan(
-        fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np, wgt_m_np,
-        num_stages=S, virtual=V, window=W,
+        *tables, num_stages=S, virtual=V, window=run.W
     )
     R1 = plan_rings["ring_slots"]
-    stash_rings = recompute_ring_plan(
-        fwd_k_np, fwd_m_np, bwd_k_np, bwd_m_np, wgt_k_np, wgt_m_np,
-        num_stages=S, virtual=V,
-    )
+    stash_rings = recompute_ring_plan(*tables, num_stages=S, virtual=V)
 
-    from smdistributed_modelparallel_tpu.utils import health
-    from smdistributed_modelparallel_tpu.utils.flight_recorder import (
-        flight_recorder,
+    # ---- residual split and the plan ----------------------------------
+
+    capture_fwd, bwd_from_res, _bwd_full, wgt_from_res, _captured = (
+        _make_residual_split(run)
     )
-    from smdistributed_modelparallel_tpu.utils.telemetry import (
-        record_pipeline_occupancy,
-        telemetry,
+    # Probe the residual/cotangent stash shapes (and capture the vjp
+    # treedef) with an abstract trace of one B-style capture row sweep.
+    res_avals, cot_avals = _probe_stash_avals(
+        run, capture_fwd, bwd_from_res=bwd_from_res
     )
+    plan = remat_plan.plan_pipeline(
+        "zb", rmode, S, V,
+        res_ring_slots=(stash_rings["f_to_w"] if rmode == "stash_all"
+                        else stash_rings["b_to_w"]),
+        cot_ring_slots=stash_rings["b_to_w"],
+        res_slot_bytes=_stash_slot_bytes(res_avals),
+        cot_slot_bytes=_stash_slot_bytes(cot_avals), cfg=run.cfg,
+    )
+    if plan.effective == "full":
+        return None
+    capture_at_f = plan.effective == "stash_all"
+    stash_of_arr, res_col_arr, Vs, all_stash = _stash_chunk_maps(plan, V)
+    Rres = plan.res_ring_slots
+    Rcot = plan.cot_ring_slots
 
     # Static per-tick activity: which sub-steps this tick executes. A
     # sub-step also runs (masked) on a tick whose PREVIOUS tick produced
@@ -2939,220 +2497,17 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
     b_run[1:] |= b_xfer[:-1]
     w_run = w_any
 
-    busy, total = schedule_occupancy(
-        fwd_m_np, bwd_m_np, fwd_ticks=int(f_run.sum()),
-        bwd_ticks=int(b_run.sum()), wgt=wgt_m_np,
-        wgt_ticks=int(w_run.sum()),
-    )
-    record_pipeline_occupancy(
-        "zb", S, M, busy_slots=busy, total_slots=total, virtual=V,
-        passes=3,
-        pass_ticks={"fwd": int(f_run.sum()), "bwd_input": int(b_run.sum()),
-                    "bwd_weight": int(w_run.sum())},
-    )
-    _ring_gauge = telemetry.gauge(
-        "smp_pipeline_ring_slots",
-        "per-(stage, chunk) ring-buffer slots of the pipeline executor",
-    )
-    _ring_gauge.labels(schedule="zb").set(R1)
-    telemetry.gauge(
-        "smp_pipeline_wqueue_peak",
-        "peak deferred weight-grad units per (stage, chunk) [zero-bubble]",
-    ).labels(schedule="zb").set(plan_rings["w_queue_peak"])
-    flight_recorder.record_schedule(
-        "zb",
-        ((t, s, d, int(m_arr[t, s]), int(k_arr[t, s]) * S + s, p)
-         for t in range(n_ticks) for s in range(S)
-         for d, p, k_arr, m_arr in (
-             ("fwd", "F", fwd_k_np, fwd_m_np),
-             ("bwd_input", "B", bwd_k_np, bwd_m_np),
-             ("bwd_weight", "W", wgt_k_np, wgt_m_np))
-         if m_arr[t, s] >= 0),
-    )
-    fwd_k_sched = jnp.asarray(fwd_k_np)
-    fwd_m_sched = jnp.asarray(fwd_m_np)
-    bwd_k_sched = jnp.asarray(bwd_k_np)
-    bwd_m_sched = jnp.asarray(bwd_m_np)
-    wgt_k_sched = jnp.asarray(wgt_k_np)
-    wgt_m_sched = jnp.asarray(wgt_m_np)
+    _record_zero_bubble_schedule(run, tables, plan_rings, {
+        "fwd": int(f_run.sum()), "bwd_input": int(b_run.sum()),
+        "bwd_weight": int(w_run.sum()),
+    })
+    (fwd_k_sched, fwd_m_sched, bwd_k_sched, bwd_m_sched, wgt_k_sched,
+     wgt_m_sched) = (jnp.asarray(a) for a in tables)
     f_run_sched = jnp.asarray(f_run)
     b_run_sched = jnp.asarray(b_run)
     w_run_sched = jnp.asarray(w_run)
 
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        _get_subtree,
-        _mk_rngs,
-        _scan_map,
-        chunk_layout,
-        staged_chunk_views,
-    )
-
-    def cast_half(tree):
-        from smdistributed_modelparallel_tpu.nn.utils import half_cast
-
-        return half_cast(tree, half)
-
-    layer_params = _get_subtree(params, spec.layer_path)
-    staged_params, staged_xs, active_rows = staged_chunk_views(
-        spec, layer_params, S, V
-    )
-
-    pin_stage_axis = functools.partial(_pin_stage_axis, num_stages=S)
-
-    staged_params = pin_stage_axis(staged_params)
-    staged_xs = pin_stage_axis(staged_xs)
-    params_rest = _set_subtree(params, spec.layer_path, {})
-
-    def with_layers(p_rest):
-        return _set_subtree(p_rest, spec.layer_path, layer_params)
-
-    idx_np, active_np, maxp = chunk_layout(spec, S, V)
-
-    mb_keys = jax.random.split(rng, M)
-
-    # ---- embed all microbatches (the input queue) --------------------
-
-    def embed_mb(mb_input, key):
-        args, kwargs = mb_input
-        if spec.embed_method is None:
-            return args[0]
-        return module.apply(
-            {"params": cast_half(params)}, *args,
-            rngs=_mk_rngs(model, key, "embed"),
-            method=spec.embed_method, **kwargs,
-        )
-
-    with named_region("smp/pipeline/embed"):
-        embedded = _scan_map(embed_mb, stacked_inputs, mb_keys)
-
-    if spec.carry_is_tuple:
-        hidden_q = embedded[0]
-        sides = embedded[1:]
-    else:
-        hidden_q = embedded
-        sides = None
-
-    carry_aval = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), hidden_q
-    )
-
-    # ---- per-chunk forward + residual split --------------------------
-
-    from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
-    from smdistributed_modelparallel_tpu.parallel.pipeline import (
-        apply_collecting_aux,
-        make_layer_apply,
-    )
-
-    apply_one_layer = make_layer_apply(
-        model, spec, layer_module, side_in_carry=False
-    )
-
-    if spec.carry_remat:
-        apply_one_layer = jax.checkpoint(apply_one_layer, policy=remat_policy())
-
-    def chunk_fwd(chunk_lp, chunk_lxs, x, side, c_idx, m_idx, act_row):
-        base = jax.random.fold_in(jax.random.fold_in(rng, c_idx), m_idx)
-        chunk_lp = cast_half(chunk_lp)
-
-        def body(c, xs):
-            lp, lxs, i, act = xs
-            new_c, aux = apply_one_layer(
-                lp, c, lxs, jax.random.fold_in(base, i), side
-            )
-            out_c = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(act, n, o), new_c, c
-            )
-            return out_c, jnp.where(act, aux, 0.0)
-
-        idx = jnp.arange(maxp)
-        out, auxs = jax.lax.scan(body, x, (chunk_lp, chunk_lxs, idx, act_row))
-        return out, jnp.sum(auxs)
-
-    stage_ids = jnp.arange(S)
-    aux_w = float(getattr(cfg, "moe_aux_loss_weight", 1.0))
-    aux_seed = (
-        jnp.asarray(aux_w, jnp.float32)
-        * jnp.asarray(loss_seed_scale, jnp.float32)
-    )
-
-    side_leaves = side_treedef = side_idx = None
-    if sides is not None:
-        side_leaves, side_treedef, side_idx = _inexact_leaves(
-            tuple(jax.tree_util.tree_map(lambda a: a[0], s) for s in sides)
-        )
-    side_leaf_avals = (
-        [side_leaves[i] for i in side_idx] if sides is not None else []
-    )
-
-    capture_fwd, bwd_from_res, _bwd_full, wgt_from_res, _captured = (
-        _make_residual_split(
-            apply_one_layer, cast_half, rng, maxp, aux_seed,
-            sides is not None, side_leaf_avals=side_leaf_avals,
-        )
-    )
-
-    # Probe the residual/cotangent stash shapes (and capture the vjp
-    # treedef) with an abstract trace of one B-style capture row sweep.
-    res_avals, cot_avals = _probe_stash_avals(
-        S, staged_params, staged_xs, active_rows, carry_aval, sides,
-        capture_fwd, bwd_from_res=bwd_from_res,
-    )
-    _slot_bytes = _stash_slot_bytes
-
-    capture_at_f_target = rmode == "stash_all"
-    res_ring_slots = (
-        stash_rings["f_to_w"] if capture_at_f_target
-        else stash_rings["b_to_w"]
-    )
-    cot_ring_slots = stash_rings["b_to_w"]
-    plan = remat_plan.plan_pipeline(
-        "zb", rmode, S, V,
-        res_ring_slots=res_ring_slots, cot_ring_slots=cot_ring_slots,
-        res_slot_bytes=_slot_bytes(res_avals),
-        cot_slot_bytes=_slot_bytes(cot_avals), cfg=cfg,
-    )
-    if plan.effective == "full":
-        # Every chunk degraded (auto under a tight budget): the untouched
-        # recompute executor IS the plan.
-        return _pipeline_zero_bubble(
-            model, params, stacked_inputs, rng, mb_loss_fn, loss_seed_scale,
-            virtual,
-        )
-    capture_at_f = plan.effective == "stash_all"
-    stash_of_arr, res_col_arr, Vs, all_stash = _stash_chunk_maps(plan, V)
-    Rres = plan.res_ring_slots
-    Rcot = plan.cot_ring_slots
-
-    # ---- head + user loss (last stage, last chunk only) ---------------
-
-    def head_apply_aux(p, carry, key):
-        if spec.head_method is None:
-            return carry, jnp.zeros((), jnp.float32)
-        return apply_collecting_aux(
-            module, {"params": cast_half(p)}, carry,
-            rngs=_mk_rngs(model, key, "head"), method=spec.head_method,
-        )
-
-    def head_apply(p, carry, key):
-        return head_apply_aux(p, carry, key)[0]
-
-    loss_out_aval = jax.eval_shape(
-        lambda c: mb_loss_fn(head_apply(params, c, mb_keys[0]), 0, mb_keys[0]),
-        jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), carry_aval),
-    )
-
     # ---- buffers ------------------------------------------------------
-
-    def zeros_chunk_ring(n):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S, V, n) + a.shape, a.dtype), carry_aval
-        )
-
-    def zeros_stage_rows():
-        return jax.tree_util.tree_map(
-            lambda a: jnp.zeros((S,) + a.shape, a.dtype), carry_aval
-        )
 
     def zeros_stash_ring(avals, n):
         # [S, Vs, n, ...]: stage axis leads (pp-sharded like its
@@ -3162,71 +2517,15 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
             lambda a: jnp.zeros((S, Vs, n) + a.shape[1:], a.dtype), avals
         )
 
-    grad_dtype = jnp.float32
-
-    def _acc_dtype(dtype):
-        if jnp.issubdtype(dtype, jnp.floating) and cfg._fp32_grad_accumulation:
-            return jnp.float32
-        return dtype
-
-    def param_grad_zeros(tree):
-        return jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, _acc_dtype(p.dtype)), tree
-        )
-
-    inbuf0 = zeros_chunk_ring(R1)
-    stash0 = zeros_chunk_ring(R1)
-    cotbuf0 = zeros_chunk_ring(R1)
-    outbuf0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((S, R1) + a.shape, a.dtype), carry_aval
-    )
-    xfer_f0 = zeros_stage_rows()
-    xfer_b0 = zeros_stage_rows()
+    inbuf0 = _zeros_chunk_ring(run, R1)
+    stash0 = _zeros_chunk_ring(run, R1)
+    cotbuf0 = _zeros_chunk_ring(run, R1)
+    outbuf0 = _zeros_stage_ring(run, R1)
+    xfer_f0 = _zeros_stage_rows(run)
+    xfer_b0 = _zeros_stage_rows(run)
     wres0 = zeros_stash_ring(res_avals, Rres)
     wcot0 = zeros_stash_ring(cot_avals, Rcot)
-    dlay0 = param_grad_zeros(staged_params)
-    drep0 = param_grad_zeros(params_rest)
-    dembed0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, grad_dtype), carry_aval
-    )
-    dsides0 = None
-    if sides is not None:
-        dsides0 = [
-            jnp.zeros((M,) + side_leaves[i].shape, grad_dtype) for i in side_idx
-        ]
-    losses0 = jnp.zeros((M,), jnp.float32)
-    outs0 = jax.tree_util.tree_map(
-        lambda a: jnp.zeros((M,) + a.shape, a.dtype), loss_out_aval[1]
-    )
-
-    hc = health.active()
-
-    def gather_mb(tree, m):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
-            tree,
-        )
-
-    def gather_sides_rows(ms):
-        if sides is None:
-            return None
-        return tuple(
-            jax.tree_util.tree_map(
-                lambda a: jax.vmap(
-                    lambda i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                )(ms),
-                s,
-            )
-            for s in sides
-        )
-
-    def select_chunk(tree, krow):
-        return jax.tree_util.tree_map(
-            lambda a: jax.vmap(
-                lambda av, k: jax.lax.dynamic_index_in_dim(av, k, 0, keepdims=False)
-            )(a, krow),
-            tree,
-        )
+    dlay0, drep0, dembed0, dsides0, losses0, outs0 = _zero_accumulators(run)
 
     # ---- sub-steps (each a lax.cond branch over the whole carry) ------
 
@@ -3240,7 +2539,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         pk = fwd_k_sched[prev]
         pm = fwd_m_sched[prev]
         p_act = (pm >= 0) & was_prev
-        dst_k = jnp.roll(pk, 1) + (stage_ids == 0)
+        dst_k = jnp.roll(pk, 1) + (run.stage_ids == 0)
         dst_m = jnp.roll(jnp.maximum(pm, 0), 1)
         dst_act = jnp.roll(p_act, 1) & (dst_k < V)
         inbuf = _chunk_ring_set(
@@ -3255,17 +2554,17 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         fkc = jnp.clip(fk, 0, V - 1)
         fmc = jnp.maximum(fm, 0)
         f_slots = fmc % R1
-        ch_params = select_chunk(staged_params, fkc)
-        ch_xs = select_chunk(staged_xs, fkc)
-        ch_act = select_chunk(active_rows, fkc)
-        from_q = gather_mb(hidden_q, fmc[0])
+        ch_params = _select_chunk(run.staged_params, fkc)
+        ch_xs = _select_chunk(run.staged_xs, fkc)
+        ch_act = _select_chunk(run.active_rows, fkc)
+        from_q = _gather_mb(run.hidden_q, fmc[0])
         buf_in = _chunk_ring_get(inbuf, fkc, f_slots)
         x_in = jax.tree_util.tree_map(
             lambda q, b: b.at[0].set(jnp.where(fkc[0] == 0, q, b[0])),
             from_q, buf_in,
         )
-        f_sides = gather_sides_rows(fmc)
-        c_ids = fkc * S + stage_ids
+        f_sides = _gather_sides_rows(sides, fmc)
+        c_ids = fkc * S + run.stage_ids
         with named_region("smp/pipeline/tick_fwd"):
             if capture_at_f:
                 outs_f, _aux_f, res_f = stage_vmap(
@@ -3279,7 +2578,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
                 )
             else:
                 outs_f, _aux_f = stage_vmap(
-                    chunk_fwd, S,
+                    run.chunk_fwd, S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0),
                 )(ch_params, ch_xs, x_in, f_sides, c_ids, fmc, ch_act)
@@ -3300,8 +2599,8 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
             habs = _chunk_scatter_stat(
                 habs, fkc, arow, f_active, jnp.maximum
             )
-        last_row_active = f_active & (stage_ids == S - 1) & (fkc == V - 1)
-        outbuf = _chunk_outbuf_set(outbuf, f_slots, outs_f, last_row_active)
+        last_row_active = f_active & (run.stage_ids == S - 1) & (fkc == V - 1)
+        outbuf = _stage_ring_set(outbuf, f_slots, outs_f, last_row_active)
         xfer_f = outs_f
         return (inbuf, stash, cotbuf, outbuf, xfer_f, xfer_b, wres, wcot,
                 dlay, drep, dembed, dsides, losses, outs,
@@ -3317,7 +2616,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         pbk = bwd_k_sched[prev]
         pbm = bwd_m_sched[prev]
         pb_act = (pbm >= 0) & was_prev
-        dst_bk = jnp.roll(pbk, -1) - (stage_ids == S - 1)
+        dst_bk = jnp.roll(pbk, -1) - (run.stage_ids == S - 1)
         dst_bm = jnp.roll(jnp.maximum(pbm, 0), -1)
         dst_b_act = jnp.roll(pb_act, -1) & (dst_bk >= 0)
         cotbuf = _chunk_ring_set(
@@ -3336,7 +2635,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         is_lastk = b_active[S - 1] & (bkc[S - 1] == V - 1)
         m_last = bmc[S - 1]
         key_last = jax.lax.dynamic_index_in_dim(
-            mb_keys, m_last, 0, keepdims=False
+            run.mb_keys, m_last, 0, keepdims=False
         )
         out_last = jax.tree_util.tree_map(
             lambda ob: jax.lax.dynamic_index_in_dim(
@@ -3345,21 +2644,9 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
             outbuf,
         )
 
-        def head_loss(p_rest, out):
-            final, h_aux = head_apply_aux(with_layers(p_rest), out, key_last)
-            loss, user_out = mb_loss_fn(final, m_last, key_last)
-            loss = loss + jnp.asarray(aux_w, loss.dtype) * h_aux.astype(
-                loss.dtype
-            )
-            return loss, user_out
-
-        def run_head():
-            loss_m, head_vjp, user_out = jax.vjp(
-                head_loss, params_rest, out_last, has_aux=True
-            )
-            seed = jnp.asarray(loss_seed_scale, loss_m.dtype)
-            d_rep, d_out_last = head_vjp(seed)
-            return loss_m.astype(jnp.float32), d_rep, d_out_last, user_out
+        run_head = functools.partial(
+            _run_head, run, m_last, key_last, out_last
+        )
 
         head_aval = jax.eval_shape(run_head)
         with named_region("smp/pipeline/head"):
@@ -3382,14 +2669,14 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         # degraded last chunk (mixed auto plans); harmless otherwise.
         cotbuf = _chunk_ring_set(
             cotbuf, bkc, b_slots, cot_in,
-            b_active & (stage_ids == S - 1) & (bkc == V - 1),
+            b_active & (run.stage_ids == S - 1) & (bkc == V - 1),
         )
-        b_sides = gather_sides_rows(bmc)
+        b_sides = _gather_sides_rows(sides, bmc)
         stash_in = _chunk_ring_get(stash, bkc, b_slots)
-        ch_params_b = select_chunk(staged_params, bkc)
-        ch_xs_b = select_chunk(staged_xs, bkc)
-        ch_act_b = select_chunk(active_rows, bkc)
-        c_ids_b = bkc * S + stage_ids
+        ch_params_b = _select_chunk(run.staged_params, bkc)
+        ch_xs_b = _select_chunk(run.staged_xs, bkc)
+        ch_act_b = _select_chunk(run.active_rows, bkc)
+        c_ids_b = bkc * S + run.stage_ids
         b_cols = res_col_arr[bkc]
         b_stash_act = b_active & stash_of_arr[bkc]
 
@@ -3450,7 +2737,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
 
         if sides is not None and dsides is not None:
             # d_side_rows: per-stage accumulated inexact side-cotangent
-            # leaves (already filtered to side_idx order).
+            # leaves (already filtered to run.side_idx order).
             for s in range(S):
                 dsides = [
                     _chunk_scatter_add_leaf(d, bmc[s], leaf[s], b_active[s])
@@ -3477,7 +2764,7 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         wmc = jnp.maximum(wm, 0)
         w_cols = res_col_arr[wkc]
         w_stash = stash_of_arr[wkc]
-        ch_act_w = select_chunk(active_rows, wkc)
+        ch_act_w = _select_chunk(run.active_rows, wkc)
 
         with named_region("smp/pipeline/tick_bwd_weight"):
             res_w = _chunk_ring_get(wres, w_cols, wmc % Rres)
@@ -3488,25 +2775,15 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
                 # chunk params re-running the forward from the input
                 # stash and the retained chunk-output cotangent.
                 w_slots = wmc % R1
-                w_sides = gather_sides_rows(wmc)
+                w_sides = _gather_sides_rows(sides, wmc)
                 stash_w = _chunk_ring_get(stash, wkc, w_slots)
                 cotc_w = _chunk_ring_get(cotbuf, wkc, w_slots)
-                ch_params_w = select_chunk(staged_params, wkc)
-                ch_xs_w = select_chunk(staged_xs, wkc)
-                c_ids_w = wkc * S + stage_ids
-
-                def chunk_bwd_weight(lp, lxs, x, side, cot, c_idx, m_idx,
-                                     act_row):
-                    def g(lp_):
-                        return chunk_fwd(lp_, lxs, x, side, c_idx, m_idx,
-                                         act_row)
-
-                    _, vjp = jax.vjp(g, lp)
-                    (d_lp,) = vjp((cot, aux_seed))
-                    return d_lp
+                ch_params_w = _select_chunk(run.staged_params, wkc)
+                ch_xs_w = _select_chunk(run.staged_xs, wkc)
+                c_ids_w = wkc * S + run.stage_ids
 
                 d_lp_rec = stage_vmap(
-                    chunk_bwd_weight, S,
+                    functools.partial(_chunk_bwd_weight, run), S,
                     in_axes=(0, 0, 0, 0 if sides is not None else None,
                              0, 0, 0, 0),
                 )(ch_params_w, ch_xs_w, stash_w, w_sides, cotc_w,
@@ -3534,91 +2811,25 @@ def _pipeline_zero_bubble_stash(model, params, stacked_inputs, rng,
         )
         return carry, None
 
-    def hgrids():
-        return (
-            jnp.zeros((S, V), jnp.float32), jnp.zeros((S, V), jnp.float32),
-            jnp.full((S, V), -1.0, jnp.float32),
-        )
-
     carry0 = (
         pin_stage_axis(inbuf0), pin_stage_axis(stash0),
         pin_stage_axis(cotbuf0), pin_stage_axis(outbuf0),
         pin_stage_axis(xfer_f0), pin_stage_axis(xfer_b0),
         pin_stage_axis(wres0), pin_stage_axis(wcot0),
         pin_stage_axis(dlay0), drep0, dembed0, dsides0, losses0, outs0,
-        (hgrids(), hgrids()),
+        (_zeros_health_grids(S, V), _zeros_health_grids(S, V)),
     )
     with named_region("smp/pipeline/steady"):
         carry_end, _ = jax.lax.scan(tick, carry0, jnp.arange(n_ticks))
     (_, _, _, _, _, _, _, _, dlay, drep, dembed, dsides, losses, outs,
      hstats) = carry_end
     if hc is not None:
-        ((hbad, habs, hmb), (hbad_b, habs_b, hmb_b)) = hstats
-        chunk_ids = np.arange(V)[None, :] * S + np.arange(S)[:, None]
-        hc.add_stage_stats("zb", hbad, habs, hmb, chunk_ids=chunk_ids,
-                           pass_name="fwd")
-        hc.add_stage_stats("zb", hbad_b, habs_b, hmb_b, chunk_ids=chunk_ids,
-                           pass_name="bwd_input")
+        _add_zero_bubble_stage_stats(run, hstats)
 
-    # ---- embedding backward ------------------------------------------
-
-    def embed_bwd(acc, xs):
-        mb_input, key, dcarry, dside_row = xs
-
-        def embed_inexact(p_rest):
-            args, kwargs = mb_input
-            out, aux = apply_collecting_aux(
-                module, {"params": cast_half(with_layers(p_rest))}, *args,
-                rngs=_mk_rngs(model, key, "embed"),
-                method=spec.embed_method, **kwargs,
-            )
-            leaves, _, idx = _inexact_leaves(out)
-            return [leaves[i] for i in idx] + [aux]
-
-        out_aval = jax.eval_shape(embed_inexact, params_rest)
-        if sides is not None:
-            cots = list(jax.tree_util.tree_leaves(dcarry)) + list(dside_row)
-        else:
-            cots = jax.tree_util.tree_leaves(dcarry)
-        cots = cots + [aux_seed]
-        cots = [c.astype(a.dtype) for c, a in zip(cots, out_aval)]
-        _, vjp = jax.vjp(embed_inexact, params_rest)
-        (dp,) = vjp(cots)
-        acc = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(a.dtype), acc, dp
-        )
-        return acc, None
-
-    if spec.embed_method is not None:
-        demb_params0 = param_grad_zeros(params_rest)
-        dside_stack = tuple(dsides) if dsides is not None else ()
-        demb_params, _ = jax.lax.scan(
-            embed_bwd, demb_params0,
-            (stacked_inputs, mb_keys, dembed, dside_stack),
-        )
-    else:
-        demb_params = None
-
-    # ---- assemble the full gradient tree -----------------------------
-
-    flat_idx = jnp.asarray(idx_np.reshape(-1))
-    flat_mask = active_np.reshape(-1)
-
-    def to_layers(g):
-        gf = g.reshape((S * V * maxp,) + g.shape[3:])
-        gf = gf * flat_mask.reshape((-1,) + (1,) * (gf.ndim - 1))
-        return jnp.zeros((L,) + g.shape[3:], g.dtype).at[flat_idx].add(gf)
-
-    layer_grads = jax.tree_util.tree_map(to_layers, dlay)
-    if demb_params is not None:
-        drep = jax.tree_util.tree_map(
-            lambda a, b: a + b.astype(a.dtype), drep, demb_params
-        )
-    grads = _set_subtree(drep, spec.layer_path, layer_grads)
-    grads = jax.tree_util.tree_map(
-        lambda g, p: g.astype(jnp.result_type(p)), grads, params
+    return _finish_run(
+        run, _rows_to_layers(run.idx_np, run.active_np, run.L),
+        dlay, drep, dembed, dsides, losses, outs,
     )
-    return grads, losses, outs
 
 
 def _set_subtree(tree, path, sub):

@@ -28,7 +28,7 @@ import optax
 
 import smdistributed_modelparallel_tpu as smp
 from smdistributed_modelparallel_tpu.models.transformer_lm import TransformerLM
-from smdistributed_modelparallel_tpu.parallel import remat_plan
+from smdistributed_modelparallel_tpu.parallel import pipeline_1f1b, remat_plan
 from smdistributed_modelparallel_tpu.parallel.memory import (
     recompute_ring_plan,
 )
@@ -510,11 +510,19 @@ class TestStashParity:
 
 
 class TestAutoDegradation:
-    def test_auto_zero_budget_routes_to_full_executor(self):
+    def test_auto_zero_budget_routes_to_full_executor(self, monkeypatch):
         """auto with no headroom degrades every chunk and the build
         falls back to the untouched recompute executor — parity holds
-        and the plan says so."""
+        and the plan says so. The fall-back is the dispatch's, on the
+        set-up the stash executor planned from: the microbatch queue is
+        embedded once for that build."""
         base, base_grads, _ = _train({"microbatches": 4})
+        regions = []
+        real_region = pipeline_1f1b.named_region
+        monkeypatch.setattr(
+            pipeline_1f1b, "named_region",
+            lambda name: regions.append(name) or real_region(name),
+        )
         ab, ab_grads, _ = _train({
             "pipeline_parallel_degree": 2, "microbatches": 4, "ddp": True,
             "pipeline": "zero_bubble", "recompute": "auto",
@@ -524,6 +532,7 @@ class TestAutoDegradation:
         plan = remat_plan.plans["zb"]
         assert plan.effective == "full"
         assert plan.degraded_chunks and not plan.stash_chunks
+        assert regions.count("smp/pipeline/embed") == 1
 
     def test_auto_mixed_plan_dual_path_parity(self, monkeypatch):
         """A budget that fits exactly ONE of two chunks: the executor
