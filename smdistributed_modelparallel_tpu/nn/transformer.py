@@ -42,12 +42,14 @@ from smdistributed_modelparallel_tpu.backend.state import state
 from smdistributed_modelparallel_tpu.backend.topology import (
     CP_AXIS,
     EP_AXIS,
+    PP_AXIS,
     RDP_AXIS,
     TP_AXIS,
 )
 from smdistributed_modelparallel_tpu.nn.embedding import DistributedEmbedding
 from smdistributed_modelparallel_tpu.nn.layer_norm import DistributedLayerNorm
 from smdistributed_modelparallel_tpu.nn.utils import (
+    axis_partitioned,
     partitioned,
     resolve_deterministic,
     shard_activation,
@@ -1353,6 +1355,25 @@ class DistributedTransformer(nn.Module):
         )
 
 
+def _lm_head_vocab_split(vocab_size):
+    """(mesh axes, shards) of the untied LM head's vocabulary dim, read
+    from the mesh and ``vocab_size``: over tp and pp where the vocabulary
+    divides by both, else over tp, else whole on every chip, (None, 1).
+
+    The head runs once a microbatch on the last stage's output, outside
+    the executors' ``stage_vmap``, so pp is free there to hold a share of
+    the vocabulary beside tp: unsplit, every chip of a pp x tp mesh
+    computes the whole [tokens, V] product, logits and loss, and carries
+    the whole kernel through the optimizer. This is the one place a
+    layer's module names pp (a region inside a stage may not)."""
+    sizes = state.mesh.shape if state.initialized else {}
+    tp, pp = sizes.get(TP_AXIS, 1), sizes.get(PP_AXIS, 1)
+    for axes, shards in (((TP_AXIS, PP_AXIS), tp * pp), (TP_AXIS, tp)):
+        if shards > 1 and vocab_size % shards == 0:
+            return axes, shards
+    return None, 1
+
+
 class DistributedTransformerLMHead(nn.Module):
     """Embeddings + DistributedTransformer + LM head.
 
@@ -1474,9 +1495,15 @@ class DistributedTransformerLMHead(nn.Module):
                 epsilon=self.layernorm_epsilon, name="ln_f", **rms
             )
         if self.add_lm_head and not self.tie_input_output_embedding:
+            vocab_axes, _ = _lm_head_vocab_split(self.vocab_size)
             self.lm_head = nn.Dense(
                 self.vocab_size, use_bias=self.use_lm_head_bias,
-                kernel_init=_init(self.initializer_range),
+                kernel_init=axis_partitioned(
+                    _init(self.initializer_range), (None, vocab_axes)
+                ),
+                bias_init=axis_partitioned(
+                    nn.initializers.zeros_init(), (vocab_axes,)
+                ),
                 name="lm_head",
             )
         if self.decode:
@@ -1601,7 +1628,17 @@ class DistributedTransformerLMHead(nn.Module):
         if self.tie_input_output_embedding:
             logits = self.word_embedding.attend(x)
         else:
+            from smdistributed_modelparallel_tpu.utils.telemetry import (
+                record_lm_head_vocab_shards,
+            )
+
             logits = self.lm_head(x)
+            vocab_axes, shards = _lm_head_vocab_split(self.vocab_size)
+            record_lm_head_vocab_shards(shards)
+            if vocab_axes is not None:
+                logits = shard_activation(
+                    logits, BATCH_AXES, CP_AXIS, vocab_axes
+                )
         if targets is None:
             return logits
         from smdistributed_modelparallel_tpu.nn.cross_entropy import (

@@ -821,6 +821,19 @@ def record_pipeline_occupancy(schedule, num_stages, num_microbatches,
     return measured
 
 
+def record_lm_head_vocab_shards(shards):
+    """How many ways the untied LM head's vocabulary is split over the
+    model-parallel axes (``nn/transformer.DistributedTransformerLMHead``):
+    tp x pp where the vocabulary divides, tp where only that does, 1 where
+    the head is whole on every chip. Set while the head is traced, so a
+    run's report says whether the split engaged; a gauge for the reason
+    ``record_pipeline_occupancy`` gives."""
+    telemetry.gauge(
+        "smp_lm_head_vocab_shards",
+        "ways the untied LM head's vocabulary is split over tp and pp",
+    ).set(shards)
+
+
 def record_loss_scale(event, scale):
     """One fp16 loss-scale event ("overflow" | "growth" | "static_overflow"):
     counter + current-scale gauge + a flight-recorder health event — the

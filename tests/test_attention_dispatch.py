@@ -234,7 +234,11 @@ def test_no_region_inside_a_stage_names_pp():
     region a stage holds, and ``shard_map`` refuses a region whose own specs
     name the vmap's axis ("spmd_axis_name cannot appear in shard_map
     in_specs"). The kernels' and layers' modules therefore never name pp;
-    the executors alone do (``parallel/pipeline*.py``)."""
+    the executors alone do (``parallel/pipeline*.py``). The one exception
+    is outside every stage: the untied LM head, which the executors run on
+    the last stage's output, splits its vocabulary over tp and pp
+    (``nn/transformer._lm_head_vocab_split``, PR 30)."""
+    import ast
     import pathlib
 
     root = pathlib.Path(smp.__file__).parent
@@ -243,4 +247,13 @@ def test_no_region_inside_a_stage_names_pp():
         for sub in ("ops", "nn") for path in sorted((root / sub).rglob("*.py"))
         if "PP_AXIS" in path.read_text()
     ]
-    assert naming_pp == []
+    assert naming_pp == ["nn/transformer.py"]
+    tree = ast.parse((root / "nn" / "transformer.py").read_text())
+    (split,) = [node for node in ast.walk(tree)
+                if isinstance(node, ast.FunctionDef)
+                and node.name == "_lm_head_vocab_split"]
+    uses = [node.lineno for node in ast.walk(tree)
+            if isinstance(node, ast.Name) and node.id == "PP_AXIS"]
+    assert uses and all(
+        split.lineno <= line <= split.end_lineno for line in uses
+    )

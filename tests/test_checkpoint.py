@@ -36,10 +36,10 @@ TINY = dict(
 )
 
 
-def _setup(cfg=None):
+def _setup(cfg=None, **head):
     smp.shutdown()
     smp.init(cfg or {"microbatches": 2})
-    m = DistributedTransformerLMHead(**TINY)
+    m = DistributedTransformerLMHead(**dict(TINY, **head))
     model = smp.DistributedModel(m)
     opt = smp.DistributedOptimizer(optax.adamw(1e-3), model)
 
@@ -79,11 +79,24 @@ class TestSaveLoad:
 
 
 class TestSaveCheckpointDir:
-    def test_roundtrip_with_newest(self, tmp_path):
-        model, opt, step_fn, ids = _setup()
+    @pytest.mark.parametrize(
+        "cfg,head",
+        [
+            (None, {}),
+            # The untied head's kernel is one more sharded leaf: split on
+            # its vocabulary over tp x pp (PR 30), it comes back whole.
+            ({"pipeline_parallel_degree": 2, "tensor_parallel_degree": 2,
+              "ddp": True, "microbatches": 2},
+             {"tie_input_output_embedding": False}),
+        ],
+        ids=["one_device", "pp2_tp2_untied_head"],
+    )
+    def test_roundtrip_with_newest(self, tmp_path, cfg, head):
+        model, opt, step_fn, ids = _setup(cfg, **head)
         step_fn(model, ids)
         opt.step()
         loss_before = float(step_fn(model, ids).reduce_mean())
+        saved = jax.device_get(model.state_dict())
         smp.save_checkpoint(str(tmp_path), tag="t1", user_content={"epoch": 3})
 
         assert (tmp_path / "newest").read_text() == "t1"
@@ -96,6 +109,14 @@ class TestSaveCheckpointDir:
         assert user == {"epoch": 3}
         loss_after = float(step_fn(model, ids).reduce_mean())
         np.testing.assert_allclose(loss_before, loss_after, atol=1e-5)
+        restored = jax.device_get(model.state_dict())
+        assert set(restored) == set(saved)
+        for name, leaf in saved.items():
+            np.testing.assert_array_equal(restored[name], leaf, err_msg=name)
+        if head:
+            assert model.params["lm_head"]["kernel"].sharding.spec[1] \
+                == ("tp", "pp")
+            assert saved["lm_head/kernel"].shape == (16, 64)
 
     def test_retention_gc(self, tmp_path):
         model, opt, step_fn, ids = _setup()

@@ -135,6 +135,74 @@ def test_flash_attention_under_the_stage_vmap_on_a_mesh(topo, monkeypatch):
                 for axis in entry["axes"] if "pp" in axis]
 
 
+def test_untied_lm_head_vjp_keeps_its_vocabulary_split_on_a_mesh(topo):
+    """Pythia-1.4B's head and loss (T 2048, d 2048, V 50,304 = 4 x 12,576,
+    bf16 in, the cell's fp32 loss) as a backward tick differentiates them at
+    pp 2 x tp 2. ``lm_head`` is born split on the vocabulary over tp and pp
+    and its logits are held to that, so each chip's three products are
+    12,576 columns wide and the log-sum-exp and the target logit are
+    all-reduced over the four chips; unsplit, every chip ran all 50,304
+    (PR 30). The failure sign is a gathered ``f32[1,2047,50304]``."""
+    import re
+
+    import flax.linen as nn
+    from flax.core import meta
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import smdistributed_modelparallel_tpu as smp
+    from benchmark.builders import neox_tp
+    from benchmark.loader import Manifest
+    from smdistributed_modelparallel_tpu.backend.state import state
+    from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+    cfg = dict(Manifest().cell("pythia-1.4b.train-pp2tp2").config,
+               num_hidden_layers=2)
+    seq, d, vocab = 2048, cfg["hidden_size"], cfg["vocab_size"]
+    smp.init({"pipeline_parallel_degree": 2, "tensor_parallel_degree": 2,
+              "ddp": True, "microbatches": 8}, devices=topo.devices[:4])
+    module = neox_tp.module(cfg)
+    key = jax.random.key(0)            # eager: outside the described mesh
+    with jax.set_mesh(state.mesh):
+        born = jax.eval_shape(
+            module.init, key, jax.ShapeDtypeStruct((1, seq), jnp.int32),
+        )["params"]
+    born = {name: born[name] for name in ("ln_f", "lm_head")}
+    specs = nn.get_partition_spec(born)
+    assert specs["lm_head"]["kernel"] == P(None, ("tp", "pp"))
+
+    def on_mesh(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(state.mesh, spec))
+
+    head_params = jax.tree_util.tree_map(
+        lambda leaf, spec: on_mesh(leaf.shape, jnp.bfloat16, spec),
+        meta.unbox(born), specs,
+    )
+
+    def loss(params, hidden, ids):
+        logits = module.apply({"params": params}, hidden, method="head")
+        logits = logits[:, :-1].astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, ids[:, 1:, None], axis=-1)[..., 0]
+        return jnp.mean(lse - tgt)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        head_params, on_mesh((1, seq, d), jnp.bfloat16, P()),
+        on_mesh((1, seq), jnp.int32, P()),
+    ).compile().as_text()
+
+    products = [line for line in text.splitlines()
+                if re.search(r" (convolution|dot)\(", line)]
+    assert len(products) == 3           # logits, d hidden, d kernel
+    assert all("lm_head/dot_general" in line for line in products)
+    dims = {int(n) for shape in re.findall(r"\b\w+\[([\d,]+)\]", text)
+            for n in shape.split(",")}
+    assert vocab // 4 in dims
+    assert not {vocab, vocab // 2} & dims
+    census = hlo_audit.collective_census(text, state.mesh)
+    assert set(census) == {"all-reduce"}
+
+
 @pytest.mark.parametrize(
     "d_model,vocab", [(768, 50257), (1600, 50257), (4096, 50400)],
     ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],

@@ -155,23 +155,38 @@ class TestNnFamilyGreedyParity:
 
 
 class TestDistributedParity:
-    def test_tp4_matches_single_device(self):
-        # The same weights must generate the same tokens on a tp4 mesh as
+    @pytest.mark.parametrize(
+        "tp,head,vocab_shards",
+        [
+            (4, {}, None),
+            # An untied head's logits arrive split on the vocabulary over
+            # tp (PR 30); sampling reads them whole.
+            (2, {"tie_input_output_embedding": False, "vocab_size": 96}, 2),
+        ],
+        ids=["tied_tp4", "untied_vocab_split_tp2"],
+    )
+    def test_tp_matches_single_device(self, tp, head, vocab_shards):
+        # The same weights must generate the same tokens on a tp mesh as
         # on one device (parity-tier pattern used across the suite).
+        from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
         smp.init({})
-        mod = self._nn_head()
-        ids = jax.random.randint(jax.random.key(5), (2, 6), 0, 97)
+        mod = self._nn_head(**head)
+        ids = jax.random.randint(jax.random.key(5), (2, 6), 0, 96)
         params = mod.init(jax.random.key(0), ids)["params"]
         single = np.asarray(smp.generate(mod, ids, 5, params=params))
 
         smp.reset()
-        smp.init({"tensor_parallel_degree": 4, "ddp": True})
+        smp.init({"tensor_parallel_degree": tp, "ddp": True})
         got = np.asarray(smp.generate(mod, ids, 5, params=params))
         np.testing.assert_array_equal(got, single)
+        if vocab_shards is not None:
+            gauge = telemetry.report()["metrics"]["smp_lm_head_vocab_shards"]
+            assert [s["value"] for s in gauge["series"]] == [vocab_shards]
 
     @staticmethod
-    def _nn_head():
-        return DistributedTransformerLMHead(
+    def _nn_head(**kw):
+        kw = dict(dict(
             num_layers=2,
             num_attention_heads=4,
             attention_head_size=8,
@@ -184,7 +199,8 @@ class TestDistributedParity:
             hidden_dropout_prob=0.0,
             embedding_dropout_prob=0.0,
             deterministic=True,
-        )
+        ), **kw)
+        return DistributedTransformerLMHead(**kw)
 
     def test_wrapped_model_generate(self):
         smp.init({"tensor_parallel_degree": 2, "ddp": True})

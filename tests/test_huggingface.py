@@ -89,13 +89,21 @@ def _hf_logits(name, hf, ids):
 
 class TestLogitsParity:
     @pytest.mark.parametrize(
-        "name", ["gpt2", "gptj", "gptneox", "bert", "gptneo", "roberta"]
+        "name,mesh",
+        [(name, {}) for name in
+         ("gpt2", "gptj", "gptneox", "bert", "gptneo", "roberta")]
+        # The untied head's kernel split on its vocabulary over tp x pp
+        # (PR 30): loaded from, and exported to, the whole [d, V] tensor.
+        + [("gptneox", {"pipeline_parallel_degree": 2,
+                        "tensor_parallel_degree": 2, "ddp": True})],
+        ids=lambda v: v if isinstance(v, str) else
+        ("pp2_tp2" if v else "one_device"),
     )
-    def test_forward_matches_hf(self, name):
+    def test_forward_matches_hf(self, name, mesh):
         config = _tiny_configs()[name]
         hf = _hf_model(name, config)
         smp.reset()
-        smp.init({})
+        smp.init(mesh)
         model = smp.from_hf(hf, deterministic=True)
         ids = jax.random.randint(jax.random.key(0), (2, 16), 0, 64)
         if name in ("bert", "roberta"):
@@ -106,6 +114,16 @@ class TestLogitsParity:
             ours = np.asarray(model(ids))
         ref = _hf_logits(name, hf, ids)
         np.testing.assert_allclose(ours, ref, atol=2e-4, rtol=2e-3)
+        if mesh:
+            from jax.sharding import PartitionSpec as P
+
+            kernel = model.params["lm_head"]["kernel"]
+            assert kernel.sharding.spec == P(None, ("tp", "pp"))
+            exported = model._translate_functions[0](model.state_dict())
+            np.testing.assert_array_equal(
+                np.asarray(exported["embed_out.weight"]),
+                hf.state_dict()["embed_out.weight"].numpy(),
+            )
 
     def test_roberta_padded_positions_match_hf(self):
         """Pad-aware position ids (HF create_position_ids_from_input_ids):
