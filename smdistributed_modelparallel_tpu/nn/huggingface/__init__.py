@@ -55,14 +55,14 @@ def _target_class(target):
 
 def _families():
     from smdistributed_modelparallel_tpu.nn.huggingface import (
-        bert, gpt2, gptj, gptneo, gptneox, laguna, roberta, t5, vit,
+        bert, gpt2, gptj, gptneo, gptneox, laguna, mellum, roberta, t5, vit,
     )
 
     fams = {}
     for name, mod in (
         ("gpt2", gpt2), ("gptj", gptj), ("gptneo", gptneo),
         ("gptneox", gptneox), ("bert", bert), ("roberta", roberta),
-        ("vit", vit), ("t5", t5), ("laguna", laguna),
+        ("vit", vit), ("t5", t5), ("laguna", laguna), ("mellum", mellum),
     ):
         fams[name] = HFFamily(
             name=name,

@@ -24,6 +24,13 @@ key names come from — ``self_attn.{q,k,v,o}_proj``, the head gate as
 ``rotate_half``). A chip's share: ``config.experts_held = (first, count)``
 (not an HF key) keeps only those experts' tensors, under their published
 indices.
+
+What a sibling family with a patterned stack shares lives here too
+(``nn/huggingface/mellum.py`` imports it): the rotary of a layer type, the
+attention and expert fields of a layer kind, the decoder's kwargs, the
+tensor functions (a head gate and per-head q/k norm scales are taken where
+the state dict has them) and both translators, which take the family's
+``layer_plan``.
 """
 
 import numpy as np
@@ -64,6 +71,42 @@ def _rope(config, layer_type):
     return out
 
 
+def attention_kind(config, heads, window, **fields):
+    """A layer kind's attention fields: ``heads`` query heads on the
+    config's KV heads, the window and the rotary of its layer type, and
+    what the family adds (``head_gate``, ``qk_norm``)."""
+    Hkv = _get(config, "num_key_value_heads")
+    if heads == Hkv:
+        raise SMPValidationError(
+            "as many KV heads as query heads is not a shape the patterned "
+            "families have; the translator expects grouped KV heads."
+        )
+    return dict(
+        num_attention_heads=heads, num_key_value_heads=Hkv,
+        window_size=_get(config, "sliding_window") if window else None,
+        **_rope(config, "sliding_attention" if window else "full_attention"),
+        **fields,
+    )
+
+
+def experts_kind(config):
+    """A sparse layer kind's MLP fields: the dropless expert layer at the
+    router's published width, told the ``experts_held`` range."""
+    held = _get(config, "experts_held")
+    return dict(
+        intermediate_size=_get(config, "moe_intermediate_size"),
+        num_experts=_get(config, "num_experts"),
+        moe_top_k=_get(config, "num_experts_per_tok"),
+        moe_dropless=True,
+        moe_held=tuple(held) if held is not None else None,
+        moe_shared_intermediate_size=_get(
+            config, "shared_expert_intermediate_size", 0) or 0,
+        moe_norm_topk=bool(_get(config, "norm_topk_prob", True)),
+        moe_routed_scaling=float(
+            _get(config, "moe_routed_scaling_factor", 1.0)),
+    )
+
+
 def layer_plan(config):
     """``(pattern, kinds)``: a kind name for each layer and each kind's
     overrides of ``DistributedTransformerLayer``'s fields. Sparse layers
@@ -85,24 +128,11 @@ def layer_plan(config):
             f"laguna: gating {gating!r} is not the per-head output gate."
         )
     first_sparse = min((i for i in range(L) if i not in dense), default=L)
-    Hkv = _get(config, "num_key_value_heads")
-    held = _get(config, "experts_held")
     pattern, kinds = [], {}
     for i in range(L):
         window = types[i] == "sliding_attention"
         attn = "window" if window else "full"
-        if heads[i] == Hkv:
-            raise SMPValidationError(
-                "laguna: as many KV heads as query heads is not a shape "
-                "this family has; the translator expects grouped KV heads."
-            )
-        kw = dict(
-            num_attention_heads=heads[i], num_key_value_heads=Hkv,
-            head_gate=True,
-            window_size=_get(config, "sliding_window") if window else None,
-            **_rope(config,
-                    "sliding_attention" if window else "full_attention"),
-        )
+        kw = attention_kind(config, heads[i], window, head_gate=True)
         if i in dense:
             name = ("lead_dense" + ("_window" if window else "")
                     if i < first_sparse else f"{attn}_dense")
@@ -110,18 +140,7 @@ def layer_plan(config):
                       num_experts=0)
         else:
             name = attn
-            kw.update(
-                intermediate_size=_get(config, "moe_intermediate_size"),
-                num_experts=_get(config, "num_experts"),
-                moe_top_k=_get(config, "num_experts_per_tok"),
-                moe_dropless=True,
-                moe_held=tuple(held) if held is not None else None,
-                moe_shared_intermediate_size=_get(
-                    config, "shared_expert_intermediate_size", 0) or 0,
-                moe_norm_topk=bool(_get(config, "norm_topk_prob", True)),
-                moe_routed_scaling=float(
-                    _get(config, "moe_routed_scaling_factor", 1.0)),
-            )
+            kw.update(experts_kind(config))
         if kinds.get(name, kw) != kw:
             name = f"{name}_h{heads[i]}"
         if kinds.setdefault(name, kw) != kw:
@@ -140,7 +159,13 @@ def config_to_smp(config):
     if _get(config, "moe_router_logit_softcapping", 0):
         raise SMPValidationError(
             "laguna: router logit soft-capping is not supported.")
-    pattern, kinds = layer_plan(config)
+    return decoder_kwargs(config, *layer_plan(config))
+
+
+def decoder_kwargs(config, pattern, kinds):
+    """``DistributedTransformerLMHead`` kwargs of an RMSNorm pre-norm
+    decoder with no biases, a gated MLP and rotary on halves, its stack
+    built from ``pattern`` and ``kinds``."""
     return {
         "num_layers": _get(config, "num_hidden_layers"),
         "num_attention_heads": _get(config, "num_attention_heads"),
@@ -191,30 +216,45 @@ def _t(x):
 
 def attention_from_hf(q, k, v, o, g, hd, xp=np):
     """``q_proj`` [.., H*hd, D], ``k_proj`` / ``v_proj`` [.., Hkv*hd, D],
-    ``o_proj`` [.., D, H*hd], ``g_proj`` [.., H, D] -> the attention
-    layer's ``query``, ``key_value``, ``dense`` and ``gate`` kernels."""
+    ``o_proj`` [.., D, H*hd], ``g_proj`` [.., H, D] or ``None`` -> the
+    attention layer's ``query``, ``key_value``, ``dense`` and (with a
+    ``g_proj``) ``gate`` kernels."""
     lead, D = q.shape[:-2], q.shape[-1]
     heads = lambda w: _t(w).reshape(*lead, D, -1, hd)   # noqa: E731
-    return {
+    out = {
         "attention/query/kernel": heads(q),
         "attention/key_value/kernel": xp.stack(
             [heads(k), heads(v)], axis=len(lead) + 1),
         "attention/dense/kernel": _t(o).reshape(*lead, -1, hd, D),
-        "attention/gate/kernel": _t(g),
     }
+    if g is not None:
+        out["attention/gate/kernel"] = _t(g)
+    return out
 
 
 def attention_to_hf(layer):
-    """Inverse of ``attention_from_hf``: ``(q, k, v, o, g)``."""
+    """Inverse of ``attention_from_hf``: ``(q, k, v, o, g)``, ``g`` ``None``
+    for a layer without a head gate."""
     query, kv = layer["attention/query/kernel"], layer["attention/key_value/kernel"]
     dense = layer["attention/dense/kernel"]
     lead, D = query.shape[:-3], query.shape[-3]
     flat = lambda w: _t(w.reshape(*lead, D, -1))        # noqa: E731
     n = len(lead)
     k, v = kv[(slice(None),) * (n + 1) + (0,)], kv[(slice(None),) * (n + 1) + (1,)]
+    gate = layer.get("attention/gate/kernel")
     return (flat(query), flat(k), flat(v),
             _t(dense.reshape(*lead, -1, D)),
-            _t(layer["attention/gate/kernel"]))
+            None if gate is None else _t(gate))
+
+
+# A layer's vectors: Hugging Face name under the layer -> the module's.
+LAYER_VECTORS = {
+    "input_layernorm.weight": "attention/layernorm/scale",
+    "post_attention_layernorm.weight": "output/layernorm/scale",
+    # per-head q/k RMSNorm scales [hd] of a family that has them
+    "self_attn.q_norm.weight": "attention/q_norm/scale",
+    "self_attn.k_norm.weight": "attention/k_norm/scale",
+}
 
 
 def gated_mlp_from_hf(gate, up, down, prefix):
@@ -246,9 +286,10 @@ def _layer_from_hf(sd, p, config, sparse):
     out = attention_from_hf(
         sd[a + "q_proj.weight"], sd[a + "k_proj.weight"],
         sd[a + "v_proj.weight"], sd[a + "o_proj.weight"],
-        sd[a + "g_proj.weight"], hd)
-    out["attention/layernorm/scale"] = sd[f"{p}.input_layernorm.weight"]
-    out["output/layernorm/scale"] = sd[f"{p}.post_attention_layernorm.weight"]
+        sd.get(a + "g_proj.weight"), hd)
+    for theirs, ours in LAYER_VECTORS.items():
+        if f"{p}.{theirs}" in sd:
+            out[ours] = sd[f"{p}.{theirs}"]
     m = f"{p}.mlp."
     if not sparse:
         out.update(gated_mlp_from_hf(
@@ -295,12 +336,13 @@ def _stack_by_path(per_layer, pattern):
     return out
 
 
-def translate_hf_state_dict(sd, config=None):
-    """HF Laguna state dict -> flat '/'-keyed smp param dict."""
+def translate_hf_state_dict(sd, config=None, plan=layer_plan):
+    """HF state dict -> flat '/'-keyed smp param dict; ``plan`` is the
+    family's ``layer_plan``."""
     if config is None:
         raise SMPValidationError("config required for the layer pattern.")
     sd = {k: c.to_np(v) for k, v in sd.items()}
-    pattern, kinds = layer_plan(config)
+    pattern, kinds = plan(config)
     per_layer = [
         _layer_from_hf(sd, f"model.layers.{i}", config,
                        kinds[kind]["num_experts"] > 0)
@@ -314,15 +356,16 @@ def translate_hf_state_dict(sd, config=None):
     return out
 
 
-def translate_state_dict_to_hf(flat, config=None):
-    """Flat smp param dict -> HF Laguna naming ([out, in] weights)."""
+def translate_state_dict_to_hf(flat, config=None, plan=layer_plan):
+    """Flat smp param dict -> HF naming ([out, in] weights); ``plan`` is
+    the family's ``layer_plan``."""
     if config is None:
         raise SMPValidationError("config required for the layer pattern.")
     from smdistributed_modelparallel_tpu.nn.transformer import (
         pattern_layer_paths,
     )
 
-    pattern, kinds = layer_plan(config)
+    pattern, kinds = plan(config)
     out = {
         "model.embed_tokens.weight": np.asarray(flat[c.WTE]),
         "model.norm.weight": np.asarray(flat[f"{c.LN_F}/scale"]),
@@ -335,12 +378,12 @@ def translate_state_dict_to_hf(flat, config=None):
         layer = {k[len(prefix):]: np.asarray(v)[index]
                  for k, v in flat.items() if k.startswith(prefix)}
         p = f"model.layers.{i}"
-        q, k, v, o, g = attention_to_hf(layer)
-        for name, w in zip("qkvog", (q, k, v, o, g)):
-            out[f"{p}.self_attn.{name}_proj.weight"] = w
-        out[f"{p}.input_layernorm.weight"] = layer["attention/layernorm/scale"]
-        out[f"{p}.post_attention_layernorm.weight"] = \
-            layer["output/layernorm/scale"]
+        for name, w in zip("qkvog", attention_to_hf(layer)):
+            if w is not None:
+                out[f"{p}.self_attn.{name}_proj.weight"] = w
+        for theirs, ours in LAYER_VECTORS.items():
+            if ours in layer:
+                out[f"{p}.{theirs}"] = layer[ours]
         m = f"{p}.mlp."
 
         def gated(prefix_ours, prefix_hf):

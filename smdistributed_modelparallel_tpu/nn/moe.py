@@ -31,10 +31,11 @@ absent from ``smdistributed.modelparallel`` v1.12.1.
 - assignments are sorted by expert (one ``argsort``), and the held
   experts' rows go through a grouped matrix product
   (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
-  grouped-matmul kernel) in chunks of ``ROWS_PER_CHUNK`` sorted rows. The
-  loop runs over the chunks that hold rows, so the work follows the rows
-  that landed here and no assignment is ever dropped: the row buffer's
-  bound is the worst case, every token on every held expert;
+  grouped-matmul kernel) in chunks of sorted rows (``_chunk_rows``:
+  ``ROWS_PER_CHUNK``, or a third of an even router's load where that is
+  more). The loop runs over the chunks that hold rows, so the work follows
+  the rows that landed here and no assignment is ever dropped: the row
+  buffer's bound is the worst case, every token on every held expert;
 - gated experts without biases (``act(x W_gate) * (x W_up)) W_down``),
   top-k weights renormalised over all ``top_k`` (held or not) and scaled
   by ``routed_scaling``;
@@ -247,10 +248,25 @@ def moe_aux_losses(intermediates):
 # ----------------------------------------------------------------------
 
 
-# Sorted rows a grouped product takes at a time: large enough to fill the
-# MXU over a handful of experts, small enough that the loop's work follows
-# the rows that landed here. Not tuned per model; tests shrink it.
+# Sorted rows a grouped product takes at a time, at least: large enough to
+# fill the MXU over a handful of experts, small enough that the loop's work
+# follows the rows that landed here. Not tuned per model; tests shrink it.
 ROWS_PER_CHUNK = 1024
+
+
+def _chunk_rows(tokens, top_k, count, experts):
+    """Rows a chunk: ``ROWS_PER_CHUNK``, in as many multiples as make an
+    even router's load here (``tokens x top_k x count / experts``) about
+    three chunks. A chunk costs much the same full or nearly empty (each
+    one's weight gradients are [count, D, 2F] and [count, F, D] whatever
+    its rows), so a share whose even load is a whole number of chunks (8
+    of 64 a token on 16 held over 8,192 tokens: 16 x 1,024) would run or
+    skip a seventeenth, nearly empty chunk on each call by the draw of the
+    weights, and pay sixteen chunks' fixed cost where three carry the
+    rows. Three chunks keep the loop's work within a third of the even
+    load of the rows that landed here."""
+    even = tokens * top_k * count // experts
+    return ROWS_PER_CHUNK * max(1, -(-even // (3 * ROWS_PER_CHUNK)))
 
 
 def _expert_ffn(rows, w_gate_up, w_down, group_sizes, weights, activation):
@@ -440,9 +456,10 @@ class DistributedDroplessMoE(nn.Module):
                 top_weight = top_weight / jnp.sum(
                     top_weight, axis=-1, keepdims=True)
             top_weight = top_weight * self.routed_scaling
+        rows = _chunk_rows(B * T, K, count, E)
         with jax.named_scope("smp/moe/dispatch"):
             tokens, weights, offsets, loads, dropped = route_to_held(
-                top_idx, top_weight, first, count, ROWS_PER_CHUNK)
+                top_idx, top_weight, first, count, rows)
         self.sow("intermediates", "moe_stats",
                  jnp.concatenate([loads, dropped[None]]))
 
@@ -453,7 +470,7 @@ class DistributedDroplessMoE(nn.Module):
         out = held_experts_output(
             x, gate_up.astype(x.dtype).reshape(count, D, 2 * F),
             down.astype(x.dtype), weights, tokens, offsets,
-            self.activation, ROWS_PER_CHUNK).reshape(B, T, D)
+            self.activation, rows).reshape(B, T, D)
         if self.shared_intermediate_size:
             with jax.named_scope("smp/moe/shared"):
                 shared = DistributedTransformerOutputLayer(

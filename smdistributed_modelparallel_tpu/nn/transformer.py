@@ -244,6 +244,11 @@ class DistributedAttentionLayer(nn.Module):
     # Per-head output gate: head h's attention output is multiplied by
     # sigmoid(x W_g)[h] before the output projection.
     head_gate: bool = False
+    # RMSNorm over attention_head_size on each query and each key head,
+    # with a learned scale shared by the heads, before rotary (epsilon:
+    # qk_norm_epsilon).
+    qk_norm: bool = False
+    qk_norm_epsilon: float = 1e-6
     # KV-cache decoding for smp.generate (nn/utils.DecodeKVCache); only
     # self-attention caches (cross-attention K/V are recomputed from the
     # encoder states passed each step).
@@ -498,6 +503,14 @@ class DistributedAttentionLayer(nn.Module):
             pos_offset = (
                 cache.index if row_off is None else cache.index + row_off
             )
+
+        if self.qk_norm:
+            head_norm = lambda name: DistributedLayerNorm(  # noqa: E731
+                epsilon=self.qk_norm_epsilon, rms=True, use_bias=False,
+                name=name,
+            )
+            with jax.named_scope("smp/attn/qk_norm"):
+                q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
 
         if self.rotary_dim is not None and not self.cross_attention:
             # The cache stores POST-rotary K: chunk q/k rotate once at
@@ -833,6 +846,7 @@ class DistributedTransformerLayer(nn.Module):
     rotary_yarn: Optional[tuple] = None
     num_key_value_heads: Optional[int] = None
     head_gate: bool = False
+    qk_norm: bool = False
     # ... its expert layer: dropless (nn/moe.DistributedDroplessMoE) with
     # the ``(first, count)`` range of the ``num_experts`` it holds, a shared
     # expert's width, renormalised top-k weights and their scale ...
@@ -842,8 +856,9 @@ class DistributedTransformerLayer(nn.Module):
     moe_norm_topk: bool = True
     moe_routed_scaling: float = 1.0
     # ... and the kind's name, which a patterned stack always sets: the
-    # layer's ops then trace under ``smp/layer/<kind>`` and its attention
-    # under ``smp/attn/window`` or ``smp/attn/full``.
+    # layer's ops then trace under ``smp/layer/<kind>``, its attention
+    # under ``smp/attn/window`` or ``smp/attn/full`` and, inside that, the
+    # q/k norms under ``smp/attn/qk_norm``.
     kind: Optional[str] = None
     decode: bool = False
     decode_cache_len: Optional[int] = None
@@ -891,6 +906,8 @@ class DistributedTransformerLayer(nn.Module):
             window_size=self.window_size,
             num_key_value_heads=self.num_key_value_heads,
             head_gate=self.head_gate,
+            qk_norm=self.qk_norm,
+            qk_norm_epsilon=self.layernorm_epsilon,
             decode=self.decode,
             decode_cache_len=self.decode_cache_len,
             deterministic=self.deterministic,

@@ -203,6 +203,24 @@ def test_untied_lm_head_vjp_keeps_its_vocabulary_split_on_a_mesh(topo):
     assert set(census) == {"all-reduce"}
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_flash_attention_eight_query_heads_on_one_kv_head(one_chip, window):
+    """Mellum's share of a layer at the cell's shapes: 8 query heads of 128
+    on one KV head over 8,192 positions (the dkv pass keeps 8 fp32
+    partials), at the published window of 1,024 and with none."""
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, causal=True, window=window))
+
+    q, kv = (1, 8192, 8, 128), (1, 8192, 1, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert name in text
+
+
 @pytest.mark.parametrize(
     "d_model,vocab", [(768, 50257), (1600, 50257), (4096, 50400)],
     ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],

@@ -461,10 +461,13 @@ _FAMILY_KWARGS = {
 }
 
 
-@pytest.fixture()
+@pytest.fixture(autouse=True)
 def one_device_mesh():
-    """The text depends on the mesh the last ``smp.init`` of the process
-    left behind (its sharding constraints): pin one device."""
+    """Every test starts on a mesh of one device: ``smp.reset()`` keeps the
+    mesh of the last ``smp.init``, so the lowered text depends on what an
+    earlier test left behind (its sharding constraints), and a tp mesh left
+    by an earlier file in this worker boxes the parameters that the
+    helpers here name flat (``.../value``)."""
     import smdistributed_modelparallel_tpu as smp
 
     smp.reset()
