@@ -36,13 +36,25 @@ absent from ``smdistributed.modelparallel`` v1.12.1.
   more). The loop runs over the chunks that hold rows, so the work follows
   the rows that landed here and no assignment is ever dropped: the row
   buffer's bound is the worst case, every token on every held expert;
+- the experts' weight gradients are summed where they are made: the
+  backward pass (``_held_bwd``) carries one fp32 sum a layer call for each
+  of the two weight tensors, and a chunk's
+  ``ops/pallas_grouped_wgrad.grouped_wgrad`` adds to the blocks of the
+  experts it holds rows of and touches no other. The kernel stands aside
+  (``_wgrad_kernel_engages``: not on a TPU, a chunk that is not whole row
+  tiles, a width that is not a multiple of 128, a mesh of more than one
+  device) for the grouped products' own transposes, which make a
+  [held, D, 2F] and a [held, F, D] product a chunk in the operands' dtype
+  that is converted and added afterwards.
+  ``smp_moe_wgrad_kernel_engaged{layer}`` says which was traced in;
 - gated experts without biases (``act(x W_gate) * (x W_up)) W_down``),
   top-k weights renormalised over all ``top_k`` (held or not) and scaled
   by ``routed_scaling``;
 - per layer it sows ``moe_stats`` (held experts' loads, dropped count)
   into ``intermediates``; ``DistributedModel.moe_stats()`` hands them to
   the step function, which returns them beside the loss, and
-  ``record_moe_stats`` reads them back into ``smp_moe_*`` gauges.
+  ``record_moe_stats`` reads them back into ``smp_moe_*`` gauges (the
+  share of the held experts a chunk visits among them).
 
 The one-hot router's load-balancing auxiliary loss (Switch-style
 ``E * sum(fraction_routed * mean_gate)``) is sown into the
@@ -269,6 +281,29 @@ def _chunk_rows(tokens, top_k, count, experts):
     return ROWS_PER_CHUNK * max(1, -(-even // (3 * ROWS_PER_CHUNK)))
 
 
+def _first_product(rows, w_gate_up, group_sizes, valid):
+    """The grouped gate/up product of masked ``rows``: [R, 2F], rows past
+    the groups left as the memory was."""
+    return jax.lax.ragged_dot(jnp.where(valid, rows, 0), w_gate_up,
+                              group_sizes)
+
+
+def _activated(h, valid, activation, dtype):
+    """``act(gate) * up`` of the first product, masked: [R, F]."""
+    gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
+    return (_activation(activation)(gate) * up).astype(dtype)
+
+
+def _weighted(y, weights, valid):
+    """The second product, masked, times the rows' combine weights."""
+    return jnp.where(valid, y, 0).astype(jnp.float32) * jnp.where(
+        valid[:, 0], weights, 0.0)[:, None]
+
+
+def _valid_rows(rows, group_sizes):
+    return (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+
+
 def _expert_ffn(rows, w_gate_up, w_down, group_sizes, weights, activation):
     """``weights * E_e(rows)`` for sorted ``rows`` [R, D] whose first
     ``sum(group_sizes)`` rows belong, group by group, to the held experts;
@@ -277,14 +312,72 @@ def _expert_ffn(rows, w_gate_up, w_down, group_sizes, weights, activation):
     memory was (on the chip: anything), so they are masked going in, in
     the middle and coming out: nothing of them reaches a result or, through
     the transposes, a gradient."""
-    valid = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
-    rows = jnp.where(valid, rows, 0)
-    h = jax.lax.ragged_dot(rows, w_gate_up, group_sizes)
-    gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
-    h = (_activation(activation)(gate) * up).astype(rows.dtype)
+    valid = _valid_rows(rows.shape[0], group_sizes)
+    h = _first_product(rows, w_gate_up, group_sizes, valid)
+    h = _activated(h, valid, activation, rows.dtype)
     y = jax.lax.ragged_dot(h, w_down, group_sizes)
-    return jnp.where(valid, y, 0).astype(jnp.float32) * jnp.where(
-        valid[:, 0], weights, 0.0)[:, None]
+    return _weighted(y, weights, valid)
+
+
+def _wgrad_kernel_engages(rows, w_gate_up, w_down):
+    """Whether ``_held_bwd`` sums the weight gradients inside
+    ``ops/pallas_grouped_wgrad`` (on the TPU, whole row tiles a chunk,
+    lane-aligned widths, the weights whole on one device: a Mosaic call
+    cannot be partitioned over a mesh) or takes the grouped products'
+    transposes and adds them up. From what the trace can see; no knob."""
+    from smdistributed_modelparallel_tpu.backend.state import state
+    from smdistributed_modelparallel_tpu.ops.pallas_grouped_wgrad import (
+        grouped_wgrad_ok,
+    )
+
+    if state.initialized and state.mesh.devices.size > 1:
+        return False
+    _, D, F2 = w_gate_up.shape
+    return (w_gate_up.dtype == w_down.dtype
+            and grouped_wgrad_ok(rows, D, F2)
+            and grouped_wgrad_ok(rows, F2 // 2, D))
+
+
+def _chunk_grads(picked, w_gate_up, w_down, sizes, w, g, activation, sums):
+    """One chunk of ``_held_bwd``: the gradients of ``_expert_ffn``'s sum
+    against ``g`` [R, D] for the rows and the combine weights, and the
+    running fp32 sums ``(dgu, dd)`` of the weights' with this chunk's
+    added. The chain is ``_expert_ffn``'s, cut at the two products so that
+    the cotangents there (``d_h`` [R, 2F], ``d_y`` [R, D], both masked by
+    the chain's own ``where``s) can be handed to the kernel."""
+    dgu, dd = sums
+    kernel = _wgrad_kernel_engages(picked.shape[0], w_gate_up, w_down)
+    valid = _valid_rows(picked.shape[0], sizes)
+
+    def product_vjp(product, rows, weight):
+        """Over the rows alone where the kernel sums the weight's gradient
+        (the weight closed over: no [held, ., .] product is asked for)."""
+        if kernel:
+            return jax.vjp(lambda r: product(r, weight), rows)
+        return jax.vjp(product, rows, weight)
+
+    h, first_vjp = product_vjp(
+        lambda r, a: _first_product(r, a, sizes, valid), picked, w_gate_up)
+    h_act, act_vjp = jax.vjp(
+        lambda h: _activated(h, valid, activation, picked.dtype), h)
+    y, second_vjp = product_vjp(
+        lambda h, b: jax.lax.ragged_dot(h, b, sizes), h_act, w_down)
+    _, out_vjp = jax.vjp(lambda y, w: _weighted(y, w, valid), y, w)
+    d_y, dwc = out_vjp(g)
+    d_h_act, *db = second_vjp(d_y)
+    d_h, = act_vjp(d_h_act)
+    dr, *da = first_vjp(d_h)
+    if kernel:
+        from smdistributed_modelparallel_tpu.ops.pallas_grouped_wgrad import (
+            grouped_wgrad,
+        )
+
+        dgu = grouped_wgrad(jnp.where(valid, picked, 0), d_h, sizes, dgu)
+        dd = grouped_wgrad(h_act, d_y, sizes, dd)
+    else:
+        dgu = dgu + da[0].astype(jnp.float32)
+        dd = dd + db[0].astype(jnp.float32)
+    return dr, dwc, (dgu, dd)
 
 
 def _chunk(c, tokens, weights, offsets, rows):
@@ -346,11 +439,9 @@ def _held_bwd(activation, rows, res, g):
             t, w, sizes = _chunk(c, tokens, weights, offsets, rows)
             picked, g_picked = x[t], g[t]
         with jax.named_scope("smp/moe/experts"):
-            _, vjp = jax.vjp(
-                lambda r, a, b, w: _expert_ffn(r, a, b, sizes, w, activation),
-                picked, w_gate_up, w_down, w)
-            dr, da, db, dwc = vjp(g_picked)
-            dgu, dd = dgu + da.astype(jnp.float32), dd + db.astype(jnp.float32)
+            dr, dwc, (dgu, dd) = _chunk_grads(
+                picked, w_gate_up, w_down, sizes, w, g_picked, activation,
+                (dgu, dd))
         with jax.named_scope("smp/moe/combine"):
             return (dx.at[t].add(dr.astype(jnp.float32)), dgu, dd,
                     jax.lax.dynamic_update_slice(dw, dwc, (c * rows,)))
@@ -466,10 +557,13 @@ class DistributedDroplessMoE(nn.Module):
         gate_up = self.param(
             "experts/gate_up/kernel", init, (count, D, 2, F), dtype)
         down = self.param("experts/down/kernel", init, (count, F, D), dtype)
+        w_gate_up = gate_up.astype(x.dtype).reshape(count, D, 2 * F)
+        w_down = down.astype(x.dtype)
+        _record_trace("/".join(self.path), rows,
+                      _wgrad_kernel_engages(rows, w_gate_up, w_down))
         # Its own scopes inside: gather, grouped FFN, scatter-add.
         out = held_experts_output(
-            x, gate_up.astype(x.dtype).reshape(count, D, 2 * F),
-            down.astype(x.dtype), weights, tokens, offsets,
+            x, w_gate_up, w_down, weights, tokens, offsets,
             self.activation, rows).reshape(B, T, D)
         if self.shared_intermediate_size:
             with jax.named_scope("smp/moe/shared"):
@@ -487,6 +581,35 @@ class DistributedDroplessMoE(nn.Module):
             out = out.astype(hidden.dtype)
         memory_opt = _cfg("optimize", "speed") == "memory"
         return shard_activation(out, *_hidden_spec(memory_opt))
+
+
+# Rows a chunk of each expert layer traced so far, by the layer's path (the
+# key ``collect_moe_stats`` gives its counters): ``record_moe_stats`` cuts
+# the recorded loads into the chunks the step ran.
+_TRACED_CHUNK_ROWS = {}
+
+
+def _record_trace(layer, rows, engaged):
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    _TRACED_CHUNK_ROWS[layer] = rows
+    telemetry.gauge(
+        "smp_moe_wgrad_kernel_engaged",
+        "1 where the expert layer's weight gradients are summed inside the "
+        "grouped_wgrad kernel, 0 where the grouped products' transposes are "
+        "converted and added; set while the layer is traced",
+    ).labels(layer=layer).set(int(engaged))
+
+
+def _experts_visited(loads, rows):
+    """``(visits, chunks x held)`` of one layer call: over the chunks of
+    ``rows`` sorted rows that the held experts' ``loads`` fill, how many
+    (chunk, expert) pairs share a row."""
+    offsets = np.concatenate([[0], np.cumsum(loads)])
+    first = np.arange(-(-offsets[-1] // rows))[:, None] * rows
+    shares = (np.minimum(offsets[1:], first + rows)
+              > np.maximum(offsets[:-1], first))
+    return int(shares.sum()), shares.size
 
 
 def collect_moe_stats(intermediates):
@@ -509,19 +632,28 @@ def record_moe_stats(stats):
     """Read a step's ``moe_stats`` back (a host transfer: call it outside
     a timed path) into ``smp_moe_local_assignments`` (the assignments that
     landed on held experts, summed over layers and microbatches),
-    ``smp_moe_dropped_assignments`` and, for each expert layer,
-    ``smp_moe_expert_load_max_over_mean{layer}``. ``stats``: what the step
-    function returned from ``model.moe_stats()`` (arrays or the
-    ``StepOutput`` holding them). Returns ``{"local", "dropped",
-    "max_over_mean": {layer: value}}``."""
+    ``smp_moe_dropped_assignments``, for each expert layer
+    ``smp_moe_expert_load_max_over_mean{layer}``, and
+    ``smp_moe_wgrad_experts_visited_share``: over every chunk of every
+    layer call recorded, experts with rows in the chunk / held experts,
+    which is the share of the weight gradients' blocks the backward pass
+    reads and writes (layers traced in this process; None without one).
+    ``stats``: what the step function returned from ``model.moe_stats()``
+    (arrays or the ``StepOutput`` holding them). Returns ``{"local",
+    "dropped", "max_over_mean": {layer: value}, "wgrad_visited_share"}``."""
     from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
 
     if hasattr(stats, "stack"):
         stats = stats.stack()
     local, dropped, ratios = 0, 0, {}
+    visits = pairs = 0
     for path, leaf in sorted(stats.items()):
         leaf = np.asarray(leaf)
         count = leaf.shape[-1] - 1
+        if path in _TRACED_CHUNK_ROWS:
+            for call in leaf.reshape(-1, count + 1):
+                v, p = _experts_visited(call[:count], _TRACED_CHUNK_ROWS[path])
+                visits, pairs = visits + v, pairs + p
         # [microbatches, (scans ...,) layers of this run, count + 1]
         per_layer = leaf.reshape(leaf.shape[0], -1, count + 1).sum(axis=0)
         for i, row in enumerate(per_layer):
@@ -547,4 +679,13 @@ def record_moe_stats(stats):
     )
     for layer, value in ratios.items():
         ratio_gauge.labels(layer=layer).set(value)
-    return {"local": local, "dropped": dropped, "max_over_mean": ratios}
+    share = visits / pairs if pairs else None
+    if share is not None:
+        telemetry.gauge(
+            "smp_moe_wgrad_experts_visited_share",
+            "experts with rows in a chunk / held experts, over the chunks "
+            "of the last recorded step: the share of the weight gradients' "
+            "blocks the backward pass reads and writes",
+        ).set(share)
+    return {"local": local, "dropped": dropped, "max_over_mean": ratios,
+            "wgrad_visited_share": share}

@@ -300,3 +300,60 @@ def test_bias_gelu_refuses_what_cannot_fit():
         assert not pallas_gelu.bias_gelu_ok("gelu", features=wide_odd)
     finally:
         pallas_gelu.FORCE_INTERPRET = was
+
+
+@pytest.mark.parametrize(
+    "tokens,D,F,held,rows",
+    [(8192, 2304, 896, 16, 6144), (8192, 3072, 1024, 8, 1024)],
+    ids=["mellum_held_16", "laguna_held_8"],
+)
+def test_held_experts_backward_sums_weight_gradients_in_the_kernel(
+        one_chip, monkeypatch, tokens, D, F, held, rows):
+    """The backward of ``held_experts_output`` at both expert cells' held
+    shapes: the two ``smp_grouped_wgrad`` calls are in the chunk loop with
+    the fp32 sums as aliased operands, and no grouped product or fusion
+    there makes a weight-shaped array (the ``ragged-dot`` transposes and
+    the ``convert_add_fusion``s are gone). What is left of shape
+    ``bf16[held, D, 2F]`` are the weights themselves, their layout copies
+    and the final cast of the sum. The dispatch asks
+    ``jax.default_backend()``, which is the CPU here: the test says TPU,
+    after compiling the CPU's answer (the products) to see the check live."""
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.nn import moe
+
+    # The kernel stands aside on a mesh of several devices, and the mesh
+    # test above leaves one behind.
+    smp.shutdown()
+    assignments = tokens * 8
+
+    def loss(x, w_gate_up, w_down, weights, tok, offsets):
+        return jnp.sum(moe.held_experts_output(
+            x, w_gate_up, w_down, weights, tok, offsets, "silu", rows))
+
+    def compiled():
+        return _compile(
+            jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+            (tokens, D), (held, D, 2 * F), (held, F, D),
+            ((assignments,), jnp.float32), ((assignments,), jnp.int32),
+            ((held + 1,), jnp.int32))
+
+    def weight_products(text):
+        """Lines whose grouped product or convert-and-add fusion makes an
+        array of a weight tensor's shape."""
+        shapes = (f"[{held},{D},{2 * F}]", f"[{held},{F},{D}]")
+        return [
+            line for line in text.splitlines()
+            if ("ragged-dot" in line or "convert_add_fusion" in line)
+            and line.split(" = ", 1)[-1].split("{", 1)[0].endswith(shapes)]
+
+    assert len(weight_products(compiled())) == 4     # the check is live
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = compiled()
+    assert weight_products(text) == []
+    calls = [line for line in text.splitlines()
+             if "smp_grouped_wgrad" in line and "custom-call(" in line]
+    assert len(calls) == 2, calls
+    assert any(f"= f32[{held},{D},{2 * F}]" in c for c in calls)
+    assert any(f"= f32[{held},{F},{D}]" in c for c in calls)
+    assert all("output_to_operand_aliasing" in c
+               and "smp/moe/experts/smp_grouped_wgrad" in c for c in calls)

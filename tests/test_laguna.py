@@ -253,6 +253,14 @@ def test_five_layer_model_trains_through_smp_step():
         assert summary["dropped"] == 0
         assert summary["local"] == int(jnp.sum(loads))
         assert len(summary["max_over_mean"]) == 4      # four expert layers
+        # every expert layer's path is the key its counters come back under
+        assert set(summary["max_over_mean"]) == {
+            f"{layer}#{i}" for layer in stats for i in range(
+                int(np.prod(stats[layer].shape[1:-1])))}
+        assert set(stats) <= set(moe._TRACED_CHUNK_ROWS)
+        assert set(stats) <= set(_wgrad_engaged())
+        assert not any(_wgrad_engaged()[layer] for layer in stats)
+        assert 0 < summary["wgrad_visited_share"] <= 1
         report = smp.telemetry.report()["metrics"]
         assert report["smp_moe_local_assignments"]["series"][0]["value"] \
             == summary["local"]
@@ -365,6 +373,7 @@ def test_no_drops_when_every_token_goes_to_one_held_expert(monkeypatch):
     out, mut = program(params, x)
     stats = np.asarray(mut["intermediates"]["moe_stats"][0])
     assert stats[1] == 2 * 48 and stats[4] == 0
+    assert _wgrad_engaged()[""] == 0         # four-row chunks: the products
     want, loads = reference_layer(params, x, 4, 4)
     np.testing.assert_array_equal(stats[:4], np.asarray(loads))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-4)
@@ -378,6 +387,115 @@ def test_no_drops_when_every_token_goes_to_one_held_expert(monkeypatch):
         scale = float(jnp.max(jnp.abs(b))) + 1e-6
         np.testing.assert_allclose(np.asarray(a) / scale,
                                    np.asarray(b) / scale, atol=3e-4)
+
+
+def _wgrad_engaged():
+    """``{layer: 0 | 1}`` of ``smp_moe_wgrad_kernel_engaged``."""
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    series = telemetry.report()["metrics"]["smp_moe_wgrad_kernel_engaged"]
+    return {s["labels"]["layer"]: s["value"] for s in series["series"]}
+
+
+def _dense_held_experts(x, w_gate_up, w_down, weights, tokens, offsets):
+    """``held_experts_output`` one expert at a time in plain products:
+    ``offsets`` are numbers, so each expert's rows are a static slice."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate_up.shape[0]):
+        rows = slice(int(offsets[e]), int(offsets[e + 1]))
+        picked = x[tokens[rows]]
+        gate, up = jnp.split(picked @ w_gate_up[e], 2, axis=-1)
+        y = (jax.nn.silu(gate) * up) @ w_down[e]
+        out = out.at[tokens[rows]].add(y * weights[rows][:, None])
+    return out
+
+
+@pytest.mark.parametrize("D,F,held", [(384, 128, 4), (256, 128, 8)],
+                         ids=["laguna_3_to_1", "eight_held"])
+def test_weight_gradients_summed_in_the_kernel_are_the_products(
+        D, F, held, monkeypatch):
+    """The kernel forced through interpret mode at tile-sized shapes: three
+    512-row chunks of 1,280 sorted rows, so experts lie across the chunk
+    boundaries and the last chunk ends in rows past the groups. The
+    gradients of ``held_experts_output`` for the rows, both weight tensors
+    and the combine weights are those of the product path (the kernel
+    standing aside) and of a dense expert-by-expert reference."""
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.ops import pallas_grouped_wgrad as gw
+
+    tokens_n, top_k, rows = 640, 2, 512
+    keys = jax.random.split(jax.random.key(D + held), 6)
+    top_idx = jnp.argsort(
+        jax.random.uniform(keys[0], (tokens_n, held)), axis=-1)[:, :top_k]
+    top_weight = jax.nn.softmax(
+        jax.random.normal(keys[1], (tokens_n, top_k)), axis=-1)
+    tokens, weights, offsets, loads, _ = moe.route_to_held(
+        top_idx, top_weight, 0, held, rows)
+    assert int(offsets[-1]) == 1280 and tokens.shape == (1536,)
+    # an expert's rows lie across each chunk boundary
+    assert not set(np.asarray(offsets).tolist()) & {512, 1024}
+    x = jax.random.normal(keys[2], (tokens_n, D))
+    w_gate_up = jax.random.normal(keys[3], (held, D, 2 * F)) * 0.05
+    w_down = jax.random.normal(keys[4], (held, F, D)) * 0.05
+    probe = jax.random.normal(keys[5], (tokens_n, D))
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda x, a, b, w: jnp.sum(fn(x, a, b, w) * probe),
+            argnums=(0, 1, 2, 3)))(x, w_gate_up, w_down, weights)
+
+    program = lambda x, a, b, w: moe.held_experts_output(   # noqa: E731
+        x, a, b, w, tokens, offsets, "silu", rows)
+    smp.reset()
+    smp.init({"microbatches": 1}, devices=jax.devices()[:1])
+    try:
+        assert not moe._wgrad_kernel_engages(rows, w_gate_up, w_down)
+        products = grads(program)
+        monkeypatch.setattr(gw, "FORCE_INTERPRET", True)
+        assert moe._wgrad_kernel_engages(rows, w_gate_up, w_down)
+        assert not moe._wgrad_kernel_engages(8, w_gate_up, w_down)
+        calls = []
+        real = gw.grouped_wgrad
+        monkeypatch.setattr(
+            gw, "grouped_wgrad",
+            lambda *a, **k: calls.append(a[0].shape) or real(*a, **k))
+        kernel = grads(program)
+        assert calls == [(rows, D), (rows, F)]       # traced once a tensor
+    finally:
+        smp.reset()
+    dense = grads(lambda x, a, b, w: _dense_held_experts(
+        x, a, b, w, tokens, np.asarray(offsets)))
+    for got, same, want in zip(kernel, products, dense):
+        scale = float(jnp.max(jnp.abs(want))) + 1e-6
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(same) / scale, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(want) / scale, atol=2e-5)
+
+
+def test_visited_share_of_planted_loads():
+    """Eight-row chunks over four held experts: loads 8, 0, 4, 4 fill two
+    chunks that hold one and two experts (3 of 8 pairs); loads 3, 3, 3, 3
+    fill two chunks that hold three and two (5 of 8). The gauge is the two
+    calls together; a layer that was not traced here adds nothing."""
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    assert moe._experts_visited(np.array([8, 0, 4, 4]), 8) == (3, 8)
+    assert moe._experts_visited(np.array([3, 3, 3, 3]), 8) == (5, 8)
+    assert moe._experts_visited(np.array([0, 0, 0, 0]), 8) == (0, 0)
+    assert moe._experts_visited(np.array([0, 40, 0, 0]), 8) == (5, 20)
+    stats = {"planted": np.array([[[8, 0, 4, 4, 0]], [[3, 3, 3, 3, 0]]]),
+             "never_traced": np.array([[[1, 1, 1, 1, 0]]])}
+    try:
+        moe._TRACED_CHUNK_ROWS["planted"] = 8
+        summary = record_moe_stats(stats)
+    finally:
+        del moe._TRACED_CHUNK_ROWS["planted"]
+    assert summary["wgrad_visited_share"] == 0.5
+    assert summary["local"] == 32
+    series = telemetry.report()["metrics"][
+        "smp_moe_wgrad_experts_visited_share"]["series"]
+    assert [s["value"] for s in series] == [0.5]
 
 
 def test_dropped_counts_what_a_smaller_buffer_would_lose(monkeypatch):

@@ -313,6 +313,60 @@ def test_the_four_chips_shares_add_up_to_the_uncut_layer(monkeypatch):
     assert landed == 2 * 24 * K            # every assignment landed once
     np.testing.assert_allclose(np.asarray(h + routed), np.asarray(want),
                                atol=3e-4)
+    assert _wgrad_engaged()[""] == 0       # eight-row chunks: the products
+
+
+def _wgrad_engaged():
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    series = telemetry.report()["metrics"]["smp_moe_wgrad_kernel_engaged"]
+    return {s["labels"]["layer"]: s["value"] for s in series["series"]}
+
+
+def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
+    """Mellum's share at tile-sized widths (16 of 64 experts, 8 a token,
+    no shared expert; D 256, F 128, 1,024 tokens): chunks of 1,024 rows,
+    whole row tiles, so with interpret mode forced the layer's backward
+    pass sums its weight gradients in the kernel and says so; without, it
+    takes the products and says that. Both give the same gradients for
+    the input and every parameter, over two or more chunks a call."""
+    from smdistributed_modelparallel_tpu.ops import pallas_grouped_wgrad as gw
+
+    monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 512)
+    D, F, E, K, held = 256, 128, 64, 8, 16
+    assert moe._chunk_rows(1024, K, held, E) == 1024
+    layer = moe.DistributedDroplessMoE(
+        hidden_size=D, intermediate_size=F, num_experts=E, top_k=K,
+        held=(16, held), initializer_range=0.1)
+    x = jax.random.normal(jax.random.key(0), (2, 512, D))
+    params = layer.init(jax.random.key(1), x)["params"]
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+
+    def grads():
+        def loss(params, x):
+            out, mut = layer.apply({"params": params}, x,
+                                   mutable=["intermediates"])
+            return jnp.sum(out * probe), mut["intermediates"]["moe_stats"][0]
+
+        (_, stats), got = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(params, x)
+        return got, np.asarray(stats)
+
+    products, stats = grads()
+    assert _wgrad_engaged()[""] == 0
+    assert stats[held] == 0 and stats[:held].sum() > 1024     # two chunks
+    monkeypatch.setattr(gw, "FORCE_INTERPRET", True)
+    kernel, _ = grads()
+    assert _wgrad_engaged()[""] == 1
+    for got, want in zip(jax.tree_util.tree_leaves(kernel),
+                         jax.tree_util.tree_leaves(products)):
+        scale = float(jnp.max(jnp.abs(want))) + 1e-6
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(want) / scale, atol=2e-5)
+    visited = moe.record_moe_stats({"": stats[None, None]})
+    visits, pairs = moe._experts_visited(stats[:held], 1024)
+    assert pairs == held * -(-int(stats[:held].sum()) // 1024)
+    assert visited["wgrad_visited_share"] == visits / pairs
 
 
 def test_chunks_are_a_third_of_an_even_routers_load(monkeypatch):

@@ -304,3 +304,92 @@ class TestDispatch:
         np.testing.assert_allclose(
             np.asarray(out_pallas), np.asarray(out_jnp), atol=3e-5
         )
+
+
+# ------------------------------------------------ grouped weight gradients
+
+# (K, N) of the two weight tensors at both expert cells' width ratios, in
+# the smallest multiples of 128 that keep them: Laguna D : 2F : F = 3 : 2 : 1
+# (3072 / 2048 / 1024), Mellum 18 : 14 : 7 (2304 / 1792 / 896; gate/up at
+# half of D, 9 : 7).
+# Third: the block budget planted, in fp32 elements, for several tiles a call.
+_WGRAD_WIDTHS = {
+    "laguna_gate_up": (384, 256, 128 * 256),
+    "laguna_down": (128, 384, 128 * 256),
+    "mellum_gate_up": (1152, 896, 384 * 896),
+    "mellum_down": (896, 2304, 896 * 384),
+}
+# Group sizes over 1,024 sorted rows (two row tiles), four experts.
+_WGRAD_GROUPS = {
+    "an_empty_expert_between": [128, 0, 200, 56],
+    "the_chunk_inside_one_expert": [0, 1024, 0, 0],
+    "all_rows_on_one_expert": [0, 0, 300, 0],
+    "no_rows_at_all": [0, 0, 0, 0],
+    "experts_share_the_row_tiles": [300, 300, 300, 124],
+    "one_expert_over_the_tile_boundary": [0, 700, 0, 40],
+}
+
+
+@pytest.mark.parametrize("groups", list(_WGRAD_GROUPS))
+@pytest.mark.parametrize("widths", list(_WGRAD_WIDTHS))
+def test_grouped_wgrad_adds_only_the_experts_with_rows(
+        widths, groups, monkeypatch):
+    """bf16 operands, an fp32 running sum that is not zero going in, NaN
+    in every row past the groups: the experts with rows get
+    ``sum + rows.T @ grads`` in fp32, the others come out bit for bit as
+    they went in, and nothing of the rows past the groups reaches the
+    output. Several K and N tiles a call (the block is planted small)."""
+    from smdistributed_modelparallel_tpu.ops import pallas_grouped_wgrad as gw
+
+    k, n, block = _WGRAD_WIDTHS[widths]
+    monkeypatch.setattr(gw, "_BLOCK_BYTES", block * 4)
+    sizes = _WGRAD_GROUPS[groups]
+    m, landed = 1024, sum(sizes)
+    tk, tn = gw._col_tiles(k, n)
+    assert k % tk == 0 and n % tn == 0 and (k // tk) * (n // tn) > 1
+    keys = jax.random.split(jax.random.key(len(groups) + k), 3)
+    lhs = jax.random.normal(keys[0], (m, k), jnp.bfloat16)
+    rhs = jax.random.normal(keys[1], (m, n), jnp.bfloat16)
+    lhs, rhs = lhs.at[landed:].set(jnp.nan), rhs.at[landed:].set(jnp.nan)
+    acc = jax.random.normal(keys[2], (len(sizes), k, n), jnp.float32)
+
+    got = np.asarray(jax.jit(
+        lambda *a: gw.grouped_wgrad(*a, interpret=True)
+    )(lhs, rhs, jnp.asarray(sizes, jnp.int32), acc))
+
+    assert np.isfinite(got).all()
+    a, b = np.asarray(lhs, np.float32), np.asarray(rhs, np.float32)
+    start = 0
+    for e, size in enumerate(sizes):
+        if size == 0:
+            np.testing.assert_array_equal(got[e], np.asarray(acc[e]))
+        else:
+            want = np.asarray(acc[e]) + a[start:start + size].T @ b[
+                start:start + size]
+            np.testing.assert_allclose(got[e], want, atol=2e-4, rtol=1e-5)
+        start += size
+    if landed:
+        np.testing.assert_allclose(
+            got, np.asarray(gw.reference_grouped_wgrad(
+                jnp.nan_to_num(lhs), jnp.nan_to_num(rhs),
+                jnp.asarray(sizes), acc)), atol=2e-4, rtol=1e-5)
+
+
+def test_grouped_wgrad_tiles_and_preconditions(monkeypatch):
+    """Tiles from the shapes: the fp32 block inside its budget, the
+    operands read least; the kernel only where a chunk is whole row tiles
+    and the widths are lane multiples, on the TPU or forced."""
+    from smdistributed_modelparallel_tpu.ops import pallas_grouped_wgrad as gw
+
+    assert gw._col_tiles(2304, 1792) == (1152, 896)      # Mellum gate/up
+    assert gw._col_tiles(896, 2304) == (896, 1152)       # Mellum down
+    assert gw._col_tiles(3072, 2048) == (1024, 1024)     # Laguna gate/up
+    assert gw._col_tiles(1024, 3072) == (1024, 1024)     # Laguna down
+    assert gw._col_tiles(128, 256) == (128, 256)
+    assert not gw.grouped_wgrad_ok(6144, 2304, 1792)     # the CPU
+    monkeypatch.setattr(gw, "FORCE_INTERPRET", True)
+    assert gw.grouped_wgrad_ok(6144, 2304, 1792)
+    assert gw.grouped_wgrad_ok(1024, 1024, 3072)
+    assert not gw.grouped_wgrad_ok(8, 2304, 1792)        # the shrunk chunks
+    assert not gw.grouped_wgrad_ok(1024, 32, 32)
+    assert not gw.grouped_wgrad_ok(1024, 2304, 1800)
