@@ -36,6 +36,11 @@ from smdistributed_modelparallel_tpu.nn.transformer import (
     DistributedTransformerLMHead,
     DistributedTransformerOutputLayer,
 )
+from smdistributed_modelparallel_tpu.nn.diffusion import (
+    masked_diffusion_loss,
+    record_diffusion_stats,
+    two_copy_stream,
+)
 from smdistributed_modelparallel_tpu.nn.moe import (
     DistributedDroplessMoE,
     DistributedMoE,

@@ -55,7 +55,8 @@ def _target_class(target):
 
 def _families():
     from smdistributed_modelparallel_tpu.nn.huggingface import (
-        bert, gpt2, gptj, gptneo, gptneox, laguna, mellum, roberta, t5, vit,
+        bert, gpt2, gptj, gptneo, gptneox, laguna, mellum, roberta, sdar, t5,
+        vit,
     )
 
     fams = {}
@@ -63,6 +64,7 @@ def _families():
         ("gpt2", gpt2), ("gptj", gptj), ("gptneo", gptneo),
         ("gptneox", gptneox), ("bert", bert), ("roberta", roberta),
         ("vit", vit), ("t5", t5), ("laguna", laguna), ("mellum", mellum),
+        ("sdarmoe", sdar),
     ):
         fams[name] = HFFamily(
             name=name,

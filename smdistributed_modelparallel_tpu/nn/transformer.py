@@ -154,8 +154,13 @@ def yarn_inv_freq(rotary_dim, base, factor, original_max_position,
 
 
 def apply_rotary(q, k, rotary_dim, base=10000.0, neox_style=False, offset=0,
-                 yarn=None):
+                 yarn=None, positions=None):
     """Rotary position embedding on the first ``rotary_dim`` channels.
+
+    Where a token stands: ``positions`` ([T] or [B, T], int or float)
+    gives each token's own position, for a stream that does not count
+    0..T-1 (the two-copy stream of block diffusion: position i stands at
+    i mod L); without it, 0..T-1 counted from ``offset``.
 
     ``yarn``: ``(factor, original_max_position, beta_fast, beta_slow,
     attention_factor)`` switches the frequencies to ``yarn_inv_freq`` and
@@ -177,8 +182,12 @@ def apply_rotary(q, k, rotary_dim, base=10000.0, neox_style=False, offset=0,
             freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
         else:
             freqs = jnp.asarray(yarn_inv_freq(d, base, *yarn[:4]), jnp.float32)
-        off = jnp.asarray(offset, jnp.float32)
-        t = off[..., None] + jnp.arange(T, dtype=jnp.float32)  # [T] or [B,T]
+        if positions is not None:
+            t = jnp.asarray(positions, jnp.float32)
+        else:
+            off = jnp.asarray(offset, jnp.float32)
+            t = off[..., None] + jnp.arange(T, dtype=jnp.float32)
+        # t: [T] or [B, T]
         angles = t[..., None] * freqs                 # [.., T, half]
         cos = jnp.cos(angles)[..., None, :]
         sin = jnp.sin(angles)[..., None, :]
@@ -237,6 +246,11 @@ class DistributedAttentionLayer(nn.Module):
     # attention_factor): YaRN frequencies for this layer's rotary.
     rotary_yarn: Optional[tuple] = None
     window_size: Optional[int] = None
+    # Block diffusion: the block length B. The layer's input is then a
+    # two-copy stream [noisy ; clean] of 2L positions, position i stands
+    # at i mod L (rotary), and attention runs under
+    # ``ops.attention.block_diffusion_mask`` in place of the causal one.
+    block_diffusion: Optional[int] = None
     # Grouped KV heads: K and V have this many heads (it divides
     # num_attention_heads); query head h reads KV head h // group. None:
     # as many as query heads, in one fused [D, 3, H, hd] kernel.
@@ -522,6 +536,8 @@ class DistributedAttentionLayer(nn.Module):
                 offset=pos_offset,
                 **({} if self.rotary_yarn is None
                    else {"yarn": tuple(self.rotary_yarn)}),
+                positions=(None if self.block_diffusion is None
+                           else jnp.arange(T) % (T // 2)),
             )
 
         if cache is not None:
@@ -584,6 +600,7 @@ class DistributedAttentionLayer(nn.Module):
             q, k, v,
             causal=causal,
             window=self.window_size if decode_mask is None else None,
+            block_diffusion=self.block_diffusion,
             local_select=local_select,
             scale=scale,
             extra_scale=extra_scale,
@@ -847,6 +864,7 @@ class DistributedTransformerLayer(nn.Module):
     num_key_value_heads: Optional[int] = None
     head_gate: bool = False
     qk_norm: bool = False
+    block_diffusion: Optional[int] = None
     # ... its expert layer: dropless (nn/moe.DistributedDroplessMoE) with
     # the ``(first, count)`` range of the ``num_experts`` it holds, a shared
     # expert's width, renormalised top-k weights and their scale ...
@@ -857,8 +875,9 @@ class DistributedTransformerLayer(nn.Module):
     moe_routed_scaling: float = 1.0
     # ... and the kind's name, which a patterned stack always sets: the
     # layer's ops then trace under ``smp/layer/<kind>``, its attention
-    # under ``smp/attn/window`` or ``smp/attn/full`` and, inside that, the
-    # q/k norms under ``smp/attn/qk_norm``.
+    # under ``smp/attn/block_diffusion``, ``smp/attn/window`` or
+    # ``smp/attn/full`` and, inside that, the q/k norms under
+    # ``smp/attn/qk_norm``.
     kind: Optional[str] = None
     decode: bool = False
     decode_cache_len: Optional[int] = None
@@ -908,6 +927,7 @@ class DistributedTransformerLayer(nn.Module):
             head_gate=self.head_gate,
             qk_norm=self.qk_norm,
             qk_norm_epsilon=self.layernorm_epsilon,
+            block_diffusion=self.block_diffusion,
             decode=self.decode,
             decode_cache_len=self.decode_cache_len,
             deterministic=self.deterministic,
@@ -918,7 +938,8 @@ class DistributedTransformerLayer(nn.Module):
 
         def attn(*args, **kwargs):
             with _named_scope(self.kind and (
-                    "smp/attn/window" if self.window_size
+                    "smp/attn/block_diffusion" if self.block_diffusion
+                    else "smp/attn/window" if self.window_size
                     else "smp/attn/full")):
                 return attention(*args, **kwargs)
 
@@ -1452,6 +1473,12 @@ class DistributedTransformerLMHead(nn.Module):
     use_qkv_bias: bool = True
     use_attn_dense_bias: bool = True
     window_size: Optional[int] = None
+    # The share of the positions the stack ran that the head is asked for,
+    # in (0, 1]: the final norm, the head and the logits (or losses) are
+    # made for that leading part alone (0.5: the noisy half of block
+    # diffusion's two-copy stream, whose clean half carries no loss).
+    # None: all of them.
+    head_positions: Optional[float] = None
     final_layernorm: bool = False
     tie_input_output_embedding: bool = True
     single_pre_layernorm: bool = False
@@ -1626,6 +1653,18 @@ class DistributedTransformerLMHead(nn.Module):
 
     def head(self, carry, targets=None):
         x, _, _ = carry if isinstance(carry, tuple) else (carry, None, None)
+        if self.head_positions is not None:
+            # The final norm, the head and the logits for the leading
+            # positions alone: what follows them in the stream only fed
+            # the stack (a two-copy stream's clean half).
+            from smdistributed_modelparallel_tpu.utils.telemetry import (
+                record_lm_head_positions,
+            )
+
+            T = x.shape[1]
+            n = int(round(T * self.head_positions))
+            record_lm_head_positions(n, T)
+            x = x[:, :n]
         if self.final_layernorm or self.pre_layernorm:
             x = self.ln_f(x)
         if not self.add_lm_head:
@@ -1671,7 +1710,8 @@ class DistributedTransformerLMHead(nn.Module):
         """ids -> logits; with ``targets`` ([B, T] int, -100 = ignored) ->
         per-token fp32 losses via the fused LM-head CE. Loss mode
         requires pp == 1 (the pipeline head protocol carries no
-        targets)."""
+        targets). With ``head_positions`` set, logits (or losses) for the
+        leading positions it names only; the stack still runs them all."""
         if targets is not None:
             if state.cfg is not None and state.cfg.pipeline_parallel_degree > 1:
                 raise SMPValidationError(

@@ -37,6 +37,23 @@ def causal_window_mask(T, S, window=None, dtype=jnp.bool_):
     return mask.astype(dtype)
 
 
+def block_diffusion_mask(T, block, dtype=jnp.bool_):
+    """[T, T] mask of a two-copy stream [noisy ; clean] of T = 2L
+    positions cut into blocks of ``block``: position i stands at sequence
+    position i mod L, in block (i mod L) // block. A noisy query sees its
+    own noisy block, both ways, and the clean blocks before it; a clean
+    query the clean blocks up to its own; nothing else (block diffusion:
+    the noisy copy of a block is predicted from the clean text before it
+    and from its own noisy tokens)."""
+    half = T // 2
+    idx = jnp.arange(T)
+    clean, blk = idx >= half, (idx % half) // block
+    rc, cc = clean[:, None], clean[None, :]
+    rb, cb = blk[:, None], blk[None, :]
+    mask = jnp.where(cc, jnp.where(rc, cb <= rb, cb < rb), ~rc & (rb == cb))
+    return mask.astype(dtype)
+
+
 def _fold_scale_and_seed(q, scale, dropout_rate, dropout_rng):
     """Shared prologue of the Pallas and CP fast paths: fold a traced scale
     into q (their scale arguments are static; keep q's dtype so a traced
@@ -64,6 +81,7 @@ def attention_core(
     *,
     causal: bool = True,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
     local_select=None,
     scale: Optional[float] = None,
     extra_scale=None,
@@ -83,6 +101,9 @@ def attention_core(
 
     Args:
       causal/window: static masking (window = local attention band).
+      block_diffusion: a block length B puts the block-diffusion mask
+        (``block_diffusion_mask``) in place of causal and window: q, k, v
+        hold a two-copy stream of 2L positions, B dividing L.
       local_select: optional traced bool scalar — when given, the window
         band applies only if True (per-layer local/global selection under
         ``lax.scan``, GPT-Neo ``attention_layers_type``).
@@ -115,9 +136,17 @@ def attention_core(
     # through to the GSPMD path (allgather-KV semantics).
     from smdistributed_modelparallel_tpu.ops.context_parallel import cp_size
 
+    if block_diffusion is not None:
+        from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+            _bd_checked,
+        )
+
+        causal, window = _bd_checked(q, k, causal, window, block_diffusion)
+
     cp_kpad = _as_key_padding_bias(mask, mask_value) if cp_size() > 1 else None
     if (
         cp_size() > 1
+        and block_diffusion is None
         and bias is None
         and (mask is None or cp_kpad is not None)
         and local_select is None
@@ -149,7 +178,7 @@ def attention_core(
     )
     if (
         use_pallas
-        and _pallas_ok(q, k, v)
+        and _pallas_ok(q, k, v, block_diffusion)
         and bias is None
         and (mask is None or kpad is not None)
         and local_select is None
@@ -162,7 +191,8 @@ def attention_core(
         # Block sizes resolve inside the kernel entry (explicit arg ->
         # pallas_attn_block_{q,k} config -> default).
         return _flash_on_mesh(
-            qq, k, v, kpad, seed, kernel_scale, causal, window, rate
+            qq, k, v, kpad, seed, kernel_scale, causal, window, rate,
+            block_diffusion,
         )
 
     T, S = q.shape[1], k.shape[1]
@@ -190,6 +220,10 @@ def attention_core(
             else:
                 cmask = causal_window_mask(T, S, window)
         scores = jnp.where(cmask[None, None], scores, mask_value)
+    elif block_diffusion is not None:
+        scores = jnp.where(
+            block_diffusion_mask(T, block_diffusion)[None, None], scores,
+            mask_value)
     elif window is not None:
         # Non-causal local attention: symmetric band of width `window`.
         rows = jnp.arange(T)[:, None]
@@ -212,7 +246,8 @@ def attention_core(
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
-def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
+def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate,
+                   block_diffusion=None):
     """Run the flash kernel where the operands live.
 
     GSPMD cannot partition a Mosaic kernel ("wrap the call in a
@@ -238,10 +273,15 @@ def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
         flash_attention,
     )
 
+    # Only a call that has the pattern names it: the others' jaxprs, and
+    # so their compiled programs, stay what they were.
+    pattern = ({} if block_diffusion is None
+               else {"block_diffusion": block_diffusion})
     mesh = state.mesh if state.initialized else None
     if mesh is None or mesh.devices.size == 1:
         return flash_attention(
-            q, k, v, kpad, seed, None, scale, causal, window, rate
+            q, k, v, kpad, seed, None, scale, causal, window, rate,
+            **pattern,
         )
     from jax.sharding import PartitionSpec as P
 
@@ -285,7 +325,7 @@ def _flash_on_mesh(q, k, v, kpad, seed, scale, causal, window, rate):
                 ) * jnp.asarray(-1640531535, seed_l.dtype)
         return flash_attention(
             q, k, v, kpad_l, seed_l, head0, scale, causal, window, rate,
-            head_total=H,
+            head_total=H, **pattern,
         )
 
     return jax.shard_map(
@@ -313,11 +353,14 @@ def _as_key_padding_bias(mask, mask_value):
     return reduced.astype(jnp.float32)
 
 
-def _pallas_ok(q, k, v):
+def _pallas_ok(q, k, v, block_diffusion=None):
     """Pallas flash kernel preconditions: TPU backend and q/kv sequences
     short enough that K/V (dq pass) or Q/dO (dkv pass) fit VMEM per
     (batch, head) — the kernels pad hd/T/S to tile boundaries themselves
-    (``pallas_attention._prep``)."""
+    (``pallas_attention._prep``). Under the default scoped limit that is
+    8,192 positions; a call under the block-diffusion mask asks for the
+    VMEM its two-copy stream takes, so it passes while that fits a core
+    (``pallas_attention.bd_fits_vmem``)."""
     import os
 
     if os.environ.get("SMP_DISABLE_PALLAS_ATTN", "0") == "1":
@@ -331,4 +374,12 @@ def _pallas_ok(q, k, v):
         # them via its own promotion.
         return False
     T, S, hd = q.shape[1], k.shape[1], q.shape[-1]
-    return T >= 128 and S >= 128 and T <= 8192 and S <= 8192 and hd <= 256
+    if block_diffusion is None:
+        fits = max(T, S) <= 8192
+    else:
+        from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+            bd_fits_vmem,
+        )
+
+        fits = bd_fits_vmem(max(T, S), hd, q.dtype.itemsize)
+    return T >= 128 and S >= 128 and fits and hd <= 256

@@ -13,6 +13,18 @@ Supported feature surface (all combinations):
   - causal and non-causal attention, T != S (cross-attention offsets);
   - windowed (local/banded) attention, causal band or symmetric band
     (reference ``torch/nn/transformer.py:1331-1352``);
+  - the block-diffusion mask (``block_diffusion=B``) over a two-copy
+    stream [noisy ; clean] of 2L positions cut into blocks of B: a noisy
+    row sees its own noisy block (both ways) and the clean blocks before
+    it, a clean row the clean blocks up to its own, nothing else (the
+    definition is at ``_bd_mask``). The mask is made from the indices in
+    the kernel, and a program steps only into tiles that hold a live
+    pair: its keys (or, in the dkv pass, its queries) lie in two disjoint
+    ranges of tiles (``_bd_kv_ranges``, ``_bd_q_ranges``), walked one
+    after the other by the same tile body, so about a quarter of the
+    (2L)^2 pairs' tiles are visited at any L that B divides and any tile
+    sizes. ``smp_flash_tiles_visited{pass}`` and
+    ``smp_flash_tiles_live{pass}`` count both for each call traced;
   - additive key-padding bias [B, S] (the broadcastable form of HF-style
     attention masks; arbitrary [.., T, S] biases fall back to jnp);
   - dropout on the attention probabilities, replayed exactly in the
@@ -122,6 +134,188 @@ def _q_bounds(k_lo, k_hi, *, q_len, kv_len, causal, window, block_q, num_q):
 
 
 # ----------------------------------------------------------------------
+# The block-diffusion mask over a two-copy stream
+# ----------------------------------------------------------------------
+#
+# 2 x half positions: [0, half) the noisy copy, [half, 2 half) the clean
+# one; position i stands at sequence position i mod half, in block
+# (i mod half) // blk. Query i sees key j iff
+#     noisy on noisy:  same block;
+#     noisy on clean:  the key's block is before the query's;
+#     clean on clean:  the key's block is not after the query's;
+#     clean on noisy:  never.
+
+
+def _bd_mask(q_offset, k_offset, block_q, block_k, *, half, blk):
+    """The [block_q, block_k] mask of the tile whose first row and first
+    key stand at ``q_offset`` and ``k_offset`` (whatever lies at or past
+    2 x half is padding)."""
+    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    r_clean, c_clean = rows >= half, cols >= half
+    r_pos = jnp.where(r_clean, rows - half, rows)
+    c_pos = jnp.where(c_clean, cols - half, cols)
+    r_start = r_pos - r_pos % blk                  # the row's block starts
+    # Clean keys: before the row's block (noisy row) or through it (clean).
+    reach = r_start + jnp.where(r_clean, blk, 0)
+    # Noisy keys: the row's own block, for a noisy row (no key is at or
+    # past 2 x half, where a clean row's range is put). Selects are of
+    # integers on a row or a column; the tile sees compares, and, or.
+    own = jnp.where(r_clean, 2 * half, r_start)
+    keep = (c_clean & (c_pos < reach)) | (
+        ~c_clean & (c_pos >= own) & (c_pos < own + blk))
+    return keep & (rows < 2 * half) & (cols < 2 * half)
+
+
+def _tiles_of(lo, hi, block, xp):
+    """The tiles of ``block`` positions that [lo, hi) touches, as a
+    [first, past-the-last) pair; (0, 0) for an empty interval."""
+    live = hi > lo
+    return (xp.where(live, lo // block, 0),
+            xp.where(live, (hi - 1) // block + 1, 0))
+
+
+def _bd_kv_ranges(q_lo, q_hi, *, half, blk, block_k, xp=jnp):
+    """The two [lo, hi) ranges of kv tiles that hold a key some query row
+    of [q_lo, q_hi) sees: the noisy rows' own blocks, then the clean
+    prefix (noisy rows: the blocks before theirs; clean rows: through
+    theirs). Every tile of both holds a live pair; the second starts
+    where the first ends if they would share a tile. ``xp``: ``jnp`` on
+    a program's traced indices, ``numpy`` for the host's count."""
+    n_hi = xp.minimum(q_hi, half)                  # noisy rows [q_lo, n_hi)
+    noisy = q_lo < n_hi
+    last_start = (n_hi - 1) // blk * blk           # last noisy row's block
+    a_lo, a_hi = _tiles_of(
+        xp.where(noisy, q_lo // blk * blk, 0),
+        xp.where(noisy, last_start + blk, 0), block_k, xp)
+    c_lo, c_hi = xp.maximum(q_lo, half), xp.minimum(q_hi, 2 * half)
+    reach = xp.maximum(
+        xp.where(noisy, last_start, 0),
+        xp.where(c_lo < c_hi, (c_hi - 1 - half) // blk * blk + blk, 0))
+    b_lo, b_hi = _tiles_of(half, half + reach, block_k, xp)
+    b_lo = xp.maximum(b_lo, a_hi)
+    return (a_lo, a_hi), (b_lo, xp.maximum(b_hi, b_lo))
+
+
+def _bd_q_ranges(k_lo, k_hi, *, half, blk, block_q, xp=jnp):
+    """The two [lo, hi) ranges of q tiles that hold a row seeing some key
+    of [k_lo, k_hi): noisy rows (the noisy keys' own blocks; for clean
+    keys every block after the first key's), then clean rows (from the
+    first clean key's block on)."""
+    n_hi = xp.minimum(k_hi, half)                  # noisy keys [k_lo, n_hi)
+    noisy = k_lo < n_hi
+    c_lo = xp.maximum(k_lo, half)                  # clean keys [c_lo, c_hi)
+    clean = c_lo < xp.minimum(k_hi, 2 * half)
+    first_start = (c_lo - half) // blk * blk       # first clean key's block
+    after = xp.where(clean & (first_start + blk < half),
+                     first_start + blk, half)      # noisy rows [after, half)
+    own_lo = xp.where(noisy, k_lo // blk * blk, half)
+    own_hi = xp.where(noisy, (n_hi - 1) // blk * blk + blk, 0)
+    a_lo, a_hi = _tiles_of(
+        xp.minimum(own_lo, after),
+        xp.where(after < half, half, own_hi), block_q, xp)
+    b_lo, b_hi = _tiles_of(
+        xp.where(clean, half + first_start, 0),
+        xp.where(clean, 2 * half, 0), block_q, xp)
+    b_lo = xp.maximum(b_lo, a_hi)
+    return (a_lo, a_hi), (b_lo, xp.maximum(b_hi, b_lo))
+
+
+def _bd_tile_counts(half, blk, block_q, block_k, t_pad):
+    """``{pass: (visited, live)}`` of one head's kernels over the
+    two-copy stream: tiles the programs' ranges step into, and tiles with
+    a live pair counted from the mask's definition row by row (a row's
+    keys are at most two intervals), independently of the ranges."""
+    T = 2 * half
+    num_q, num_kv = t_pad // block_q, -(-T // block_k)
+    live = np.zeros((num_q, num_kv + 1), np.int64)
+    rows = np.arange(T)
+    pos = rows % half
+    start = pos - pos % blk
+    noisy = rows < half
+    for lo, hi in (
+        (np.where(noisy, start, 0), np.where(noisy, start + blk, 0)),
+        (np.full(T, half), half + start + np.where(noisy, 0, blk)),
+    ):
+        t_lo, t_hi = _tiles_of(lo, hi, block_k, np)
+        np.add.at(live, (rows // block_q, t_lo), 1)
+        np.add.at(live, (rows // block_q, t_hi), -1)
+    n_live = int((np.cumsum(live, axis=1)[:, :num_kv] > 0).sum())
+
+    def visited(ranges, n, block):
+        lo = np.arange(n) * block
+        (a, b), (c, d) = ranges(lo, lo + block)
+        return int((b - a + d - c).sum())
+
+    by_q = visited(functools.partial(
+        _bd_kv_ranges, half=half, blk=blk, block_k=block_k, xp=np),
+        num_q, block_q)
+    by_kv = visited(functools.partial(
+        _bd_q_ranges, half=half, blk=blk, block_q=block_q, xp=np),
+        num_kv, block_k)
+    return {"fwd": (by_q, n_live), "dq": (by_q, n_live),
+            "dkv": (by_kv, n_live)}
+
+
+def _record_bd_tiles(passes, half, blk, block_q, block_k, t_pad):
+    from smdistributed_modelparallel_tpu.utils.telemetry import (
+        record_flash_tiles,
+    )
+
+    counts = _bd_tile_counts(half, blk, block_q, block_k, t_pad)
+    for name in passes:
+        record_flash_tiles(name, *counts[name])
+
+
+def _walk(ranges, body, init):
+    """``body`` over each [lo, hi) of ``ranges`` in turn, one carry."""
+    for lo, hi in ranges:
+        init = jax.lax.fori_loop(lo, hi, body, init)
+    return init
+
+
+def _kv_ranges(q_offset, block_q, num_kv, has_ids, bd, *, q_len, kv_len,
+               causal, window, block_k):
+    """The [lo, hi) ranges of kv tiles a program of the forward or the dq
+    pass walks for its ``block_q`` rows from ``q_offset``: two under the
+    block-diffusion mask, every tile where global ids decide at run time,
+    else the one range the static mask leaves."""
+    if bd is not None:
+        return _bd_kv_ranges(q_offset, q_offset + block_q, half=q_len // 2,
+                             blk=bd, block_k=block_k)
+    if has_ids:
+        return [(0, num_kv)]
+    return [_kv_bounds(
+        q_offset, q_offset + block_q, q_len=q_len, kv_len=kv_len,
+        causal=causal, window=window, block_k=block_k, num_kv=num_kv)]
+
+
+_VMEM_CAP = 100 << 20          # of a v5e core's 128 MiB
+_VMEM_TILES = 16 << 20         # room for the tiles: the default scoped limit
+
+
+def _bd_vmem_bytes(length, hd, itemsize):
+    """Scoped VMEM a call under the block-diffusion mask asks for: the
+    whole K and V (or Q and dO) of a head over ``length`` positions sit in
+    VMEM, twice for the pipeline's two buffers (16 MiB at 16,384
+    positions of 128 in bfloat16, the whole of the default scoped limit),
+    and the tiles beside them."""
+    return 2 * (2 * length * hd * itemsize) + _VMEM_TILES
+
+
+def bd_fits_vmem(length, hd, itemsize):
+    """Whether a head's two-copy stream of ``length`` positions can be
+    held (``ops/attention.py``'s gate for such a call)."""
+    return _bd_vmem_bytes(length, -(-hd // 128) * 128, itemsize) <= _VMEM_CAP
+
+
+def _bd_compiler_params(length, hd_pad, itemsize):
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(
+            _bd_vmem_bytes(length, hd_pad, itemsize), _VMEM_CAP))}
+
+
+# ----------------------------------------------------------------------
 # Forward
 # ----------------------------------------------------------------------
 
@@ -168,7 +362,7 @@ def _bh_remap(b, h_local, head_total, head0_ref):
 
 def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
                 window, rate, has_kpm, has_seed, s_total, has_ids=False,
-                h_local=None, head_total=None, has_head0=False):
+                h_local=None, head_total=None, has_head0=False, bd=None):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     kpm_ref = next(it) if has_kpm else None
@@ -212,6 +406,10 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
             keep = _ids_mask(rows, cols, hrows, hcols,
                              q_len=q_len, kv_len=kv_len, causal=causal,
                              window=window)
+        elif bd is not None:
+            hrows, hcols = rows, cols
+            keep = _bd_mask(q_offset, j * block_k, block_q, block_k,
+                            half=q_len // 2, blk=bd)
         else:
             hrows, hcols = rows, cols
             keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
@@ -245,18 +443,14 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     else:
         body = compute
 
-    num_kv = k_ref.shape[1] // block_k
-    if has_ids:
-        lo, hi = 0, num_kv
-    else:
-        lo, hi = _kv_bounds(
-            q_offset, q_offset + block_q, q_len=q_len, kv_len=kv_len,
-            causal=causal, window=window, block_k=block_k, num_kv=num_kv,
-        )
+    ranges = _kv_ranges(
+        q_offset, block_q, k_ref.shape[1] // block_k, has_ids, bd,
+        q_len=q_len, kv_len=kv_len, causal=causal, window=window,
+        block_k=block_k)
     acc0 = jnp.zeros((block_q, hd), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
+    acc, m, l = _walk(ranges, body, (acc0, m0, l0))
     inv_keep = 1.0 / (1.0 - rate) if rate > 0.0 else 1.0
     o_ref[0] = (acc * inv_keep / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse = jnp.where(
@@ -272,7 +466,7 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
 
 def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
                    window, rate, has_kpm, has_seed, s_total, has_ids=False,
-                   h_local=None, head_total=None, has_head0=False):
+                   h_local=None, head_total=None, has_head0=False, bd=None):
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (next(it) for _ in range(6))
     kpm_ref = next(it) if has_kpm else None
@@ -313,6 +507,10 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
             keep = _ids_mask(rows, cols, hrows, hcols,
                              q_len=q_len, kv_len=kv_len, causal=causal,
                              window=window)
+        elif bd is not None:
+            hrows, hcols = rows, cols
+            keep = _bd_mask(q_offset, j * block_k, block_q, block_k,
+                            half=q_len // 2, blk=bd)
         else:
             hrows, hcols = rows, cols
             keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
@@ -342,22 +540,17 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     else:
         body = compute
 
-    num_kv = k_ref.shape[1] // block_k
-    if has_ids:
-        lo, hi = 0, num_kv
-    else:
-        lo, hi = _kv_bounds(
-            q_offset, q_offset + block_q, q_len=q_len, kv_len=kv_len,
-            causal=causal, window=window, block_k=block_k, num_kv=num_kv,
-        )
-    dq0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    dq = jax.lax.fori_loop(lo, hi, body, dq0)
+    ranges = _kv_ranges(
+        q_offset, block_q, k_ref.shape[1] // block_k, has_ids, bd,
+        q_len=q_len, kv_len=kv_len, causal=causal, window=window,
+        block_k=block_k)
+    dq = _walk(ranges, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
                     window, rate, has_kpm, has_seed, s_total, has_ids=False,
-                    h_local=None, head_total=None, has_head0=False):
+                    h_local=None, head_total=None, has_head0=False, bd=None):
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (next(it) for _ in range(6))
     kpm_ref = next(it) if has_kpm else None
@@ -403,6 +596,10 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
             keep = _ids_mask(rows, cols, hrows, hcols,
                              q_len=q_len, kv_len=kv_len, causal=causal,
                              window=window)
+        elif bd is not None:
+            hrows, hcols = rows, cols
+            keep = _bd_mask(i * block_q, k_offset, block_q, block_k,
+                            half=q_len // 2, blk=bd)
         else:
             hrows, hcols = rows, cols
             keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
@@ -441,16 +638,20 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         body = compute
 
     num_q = q_ref.shape[1] // block_q
-    if has_ids:
-        lo, hi = 0, num_q
+    if bd is not None:
+        ranges = _bd_q_ranges(
+            k_offset, k_offset + block_k, half=q_len // 2, blk=bd,
+            block_q=block_q)
+    elif has_ids:
+        ranges = [(0, num_q)]
     else:
-        lo, hi = _q_bounds(
+        ranges = [_q_bounds(
             k_offset, k_offset + block_k, q_len=q_len, kv_len=kv_len,
             causal=causal, window=window, block_q=block_q, num_q=num_q,
-        )
+        )]
     hd = k_blk.shape[-1]
     z = jnp.zeros((block_k, hd), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, hi, body, (z, z))
+    dk, dv = _walk(ranges, body, (z, z))
     # ds carries exactly one *scale factor and q_blk is raw (unscaled), so
     # dk = ds^T.q is already correct.
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -580,7 +781,7 @@ def _ids_extra(q_ids, kv_ids, t_pad, s_pad):
 def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
                     dropout_rate, block_q, block_k, interpret,
                     q_ids=None, kv_ids=None, head0=None, head_total=None,
-                    counter_len=None):
+                    counter_len=None, bd=None):
     qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad) = _prep(
         q, k, v, block_q, block_k
     )
@@ -591,6 +792,11 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
     if has_ids:
         id_in, id_specs = _ids_extra(q_ids, kv_ids, t_pad, s_pad)
         extra, extra_specs = extra + id_in, extra_specs + id_specs
+    more, more_call = {}, {}
+    if bd is not None:
+        _record_bd_tiles(("fwd",), T // 2, bd, block_q, block_k, t_pad)
+        more = {"bd": bd}
+        more_call = _bd_compiler_params(s_pad, hd_pad, qt.dtype.itemsize)
     grid = (B * H, t_pad // block_q)
     kv_whole, _ = _kv_index(_group_of(q, k))
     kern = functools.partial(
@@ -600,7 +806,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         has_kpm=has_kpm, has_seed=has_seed,
         s_total=counter_len if counter_len is not None else s_pad,
         has_ids=has_ids, h_local=H, head_total=head_total or H,
-        has_head0=has_head0,
+        has_head0=has_head0, **more,
     )
     out, lse = pl.pallas_call(
         kern,
@@ -626,6 +832,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         ],
         name="smp_flash_fwd",
         interpret=interpret or FORCE_INTERPRET,
+        **more_call,
     )(qt, kt, vt, *extra)
     o = out[:, :T, :hd].reshape(B, H, T, hd).transpose(0, 2, 1, 3)
     return o, lse
@@ -634,7 +841,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
 def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
                     window, dropout_rate, block_q, block_k, interpret,
                     q_ids=None, kv_ids=None, head0=None, head_total=None,
-                    counter_len=None):
+                    counter_len=None, bd=None):
     qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad) = _prep(
         q, k, v, block_q, block_k
     )
@@ -664,6 +871,12 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         has_ids=has_ids, h_local=H, head_total=head_total or H,
         has_head0=has_head0,
     )
+    more_call = {}
+    if bd is not None:
+        _record_bd_tiles(("dq", "dkv"), T // 2, bd, block_q, block_k, t_pad)
+        common["bd"] = bd
+        more_call = _bd_compiler_params(
+            max(s_pad, t_pad), hd_pad, qt.dtype.itemsize)
     res_spec_q = pl.BlockSpec((1, t_pad, hd_pad), lambda b, i: (b, 0, 0))
     row_spec = pl.BlockSpec((1, 1, t_pad), lambda b, i: (b, 0, 0))
     group = _group_of(q, k)
@@ -690,6 +903,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         ),
         name="smp_flash_bwd_dq",
         interpret=interpret or FORCE_INTERPRET,
+        **more_call,
     )(qt, kt, vt, gt, lse, delta, *extra)
 
     dk, dv = pl.pallas_call(
@@ -722,6 +936,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         ],
         name="smp_flash_bwd_dkv",
         interpret=interpret or FORCE_INTERPRET,
+        **more_call,
     )(qt, kt, vt, gt, lse, delta, *extra)
 
     def from_bht(x, L):
@@ -742,12 +957,12 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
 # ----------------------------------------------------------------------
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14)
+    jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15)
 )
 def flash_attention(q, k, v, kpad_bias=None, seed=None, head0=None,
                     scale=None, causal=True, window=None, dropout_rate=0.0,
                     block_q=None, block_k=None, interpret=False,
-                    head_total=None, counter_len=None):
+                    head_total=None, counter_len=None, block_diffusion=None):
     """Flash attention over [B, T, H, hd] q and [B, S, H_kv, hd] k/v
     (H a multiple of H_kv; query head h reads KV head h // (H / H_kv)).
 
@@ -760,39 +975,63 @@ def flash_attention(q, k, v, kpad_bias=None, seed=None, head0=None,
     local hash, bh = flat program index, stride = padded S). Fully-masked
     rows produce an undefined (zero-ish) output, matching
     softmax-of-all-masked degeneracy in the jnp path.
+
+    ``block_diffusion``: a block length B puts the block-diffusion mask
+    of a two-copy stream in place of ``causal`` and ``window`` (q, k and
+    v hold 2L positions, [noisy ; clean], B dividing L): see the header.
     """
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    causal, window = _bd_checked(q, k, causal, window, block_diffusion)
     block_q, block_k = resolve_blocks(block_q, block_k)
     block_q = _clamp_block(block_q, q.shape[1])
     block_k = _clamp_block(block_k, k.shape[1])
     o, _ = _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
                            dropout_rate, block_q, block_k, interpret,
                            head0=head0, head_total=head_total,
-                           counter_len=counter_len)
+                           counter_len=counter_len, bd=block_diffusion)
     return o
+
+
+def _bd_checked(q, k, causal, window, block_diffusion):
+    """``(causal, window)`` as the kernels (and ``attention_core``'s jnp
+    path) take them: under the block-diffusion mask neither applies (the
+    mask is the whole relation), and the stream must be two copies of
+    whole blocks."""
+    if block_diffusion is None:
+        return causal, window
+    T = q.shape[1]
+    if k.shape[1] != T or T % (2 * block_diffusion) or window is not None:
+        raise ValueError(
+            f"block-diffusion attention: q and k of {T} and {k.shape[1]} "
+            f"positions, block length {block_diffusion}, window {window}: "
+            "wants one stream of two copies of whole blocks and no window."
+        )
+    return False, None
 
 
 def _fa_fwd(q, k, v, kpad_bias, seed, head0, scale, causal, window,
             dropout_rate, block_q, block_k, interpret, head_total,
-            counter_len):
+            counter_len, block_diffusion):
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    causal, window = _bd_checked(q, k, causal, window, block_diffusion)
     block_q, block_k = resolve_blocks(block_q, block_k)
     block_q = _clamp_block(block_q, q.shape[1])
     block_k = _clamp_block(block_k, k.shape[1])
     o, lse = _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
                              dropout_rate, block_q, block_k, interpret,
                              head0=head0, head_total=head_total,
-                             counter_len=counter_len)
+                             counter_len=counter_len, bd=block_diffusion)
     return o, (q, k, v, o, lse, kpad_bias, seed, head0)
 
 
 def _fa_bwd(scale, causal, window, dropout_rate, block_q, block_k, interpret,
-            head_total, counter_len, res, g):
+            head_total, counter_len, block_diffusion, res, g):
     q, k, v, o, lse, kpad_bias, seed, head0 = res
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    causal, window = _bd_checked(q, k, causal, window, block_diffusion)
     block_q, block_k = resolve_blocks(block_q, block_k)
     block_q = _clamp_block(block_q, q.shape[1])
     block_k = _clamp_block(block_k, k.shape[1])
@@ -800,6 +1039,7 @@ def _fa_bwd(scale, causal, window, dropout_rate, block_q, block_k, interpret,
         q, k, v, o, g, lse, kpad_bias, seed, scale, causal, window,
         dropout_rate, block_q, block_k, interpret,
         head0=head0, head_total=head_total, counter_len=counter_len,
+        bd=block_diffusion,
     )
     return dq, dk, dv, None, None, None
 

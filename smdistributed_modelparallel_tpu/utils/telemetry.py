@@ -834,6 +834,39 @@ def record_lm_head_vocab_shards(shards):
     ).set(shards)
 
 
+def record_flash_tiles(kernel_pass, visited, live):
+    """Tiles of one head that a flash kernel call's programs step into
+    (``smp_flash_tiles_visited{pass}``) and tiles that hold a live pair
+    under the call's mask (``smp_flash_tiles_live{pass}``), ``pass`` one
+    of ``fwd``, ``dq``, ``dkv``. Set while the call is traced, for calls
+    under the block-diffusion mask (``ops/pallas_attention.py``: the only
+    mask whose live tiles are not one contiguous range a program); equal
+    counts mean every dead tile is skipped."""
+    telemetry.gauge(
+        "smp_flash_tiles_visited",
+        "tiles a flash kernel call steps into, per head (block-diffusion "
+        "mask)",
+    ).labels(**{"pass": kernel_pass}).set(visited)
+    telemetry.gauge(
+        "smp_flash_tiles_live",
+        "tiles with a live query-key pair under the call's mask, per head",
+    ).labels(**{"pass": kernel_pass}).set(live)
+
+
+def record_lm_head_positions(computed, given):
+    """Positions a sequence the LM head made logits for
+    (``smp_lm_head_positions{which="computed"}``) of those the stack ran
+    (``{which="input"}``): a caller whose loss reads a part of the stream
+    (``head_positions``) pays for that part's logits only. Set while the
+    head is traced."""
+    gauge = telemetry.gauge(
+        "smp_lm_head_positions",
+        "positions a sequence: the LM head's logits, and the stack's input",
+    )
+    gauge.labels(which="computed").set(computed)
+    gauge.labels(which="input").set(given)
+
+
 def record_loss_scale(event, scale):
     """One fp16 loss-scale event ("overflow" | "growth" | "static_overflow"):
     counter + current-scale gauge + a flight-recorder health event — the

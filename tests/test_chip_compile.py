@@ -221,6 +221,37 @@ def test_flash_attention_eight_query_heads_on_one_kv_head(one_chip, window):
         assert name in text
 
 
+def test_flash_attention_under_the_block_diffusion_mask(one_chip):
+    """SDAR's attention as one chip of the eight-chip group runs it: 4
+    query heads on 1 KV head of 128 over a two-copy stream of 16,384
+    positions, blocks of 4. Each program keeps a head's whole K and V (in
+    the dkv pass Q and dO) in VMEM, 16 MiB with the pipeline's second
+    buffers, so the three calls ask for their scoped limit; the mask is
+    made from indices on a row and a column (the compiler refuses a select
+    between boolean tiles, which interpret mode accepts)."""
+    import re
+
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, block_diffusion=4))
+
+    q, kv = (1, 16384, 4, 128), (1, 16384, 1, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        call, = [c for c in calls if name in c.split(" = ")[0]]
+        limit, = re.findall(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)
+        assert int(limit) == 2 * 2 * 16384 * 128 * 2 + (16 << 20)
+        # no [2L, 2L] array: the call's operands are q, k, v and rows
+        assert "16384,16384" not in call
+    assert "16384,16384" not in text
+
+
 @pytest.mark.parametrize(
     "d_model,vocab", [(768, 50257), (1600, 50257), (4096, 50400)],
     ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],

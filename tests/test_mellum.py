@@ -239,46 +239,78 @@ def test_four_layer_model_trains_three_steps_as_the_reference_does():
 
 # ----------------------------------------------------- the shares add up
 
-def test_the_four_chips_shares_add_up_to_the_uncut_layer(monkeypatch):
-    """16 query heads on 4 KV heads and 16 experts over 4 shares: each
-    share's attention output (its 4 query heads on its KV head, through its
-    rows of W_o) and each share's held experts' output, with the layer
-    norms, the q/k norm scales and the router counted once, sum to the
-    uncut reference's layer."""
-    monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 8)
-    D, hd, H, Hkv, E, K, F = 32, 8, 16, 4, 16, 4, 16
+def _mellum_shares():
+    """16 query heads on 4 KV heads and 16 experts over 4 shares, each its
+    4 query heads on its own KV head."""
     cfg = mellumtiny.config(
         layer_types=["full_attention"], mlp_layer_types=["sparse"],
-        num_attention_heads=H, num_key_value_heads=Hkv, num_experts=E,
+        num_attention_heads=16, num_key_value_heads=4, num_experts=16,
         experts_held_first=0)
-    w = jax.jit(lambda s: mellum_weights.make_weights(cfg, s))(np.uint32(4))
+    run, = reference.layer_runs(cfg)
+    return dict(
+        cfg=cfg, weights=mellum_weights, shares=4, kv_head=lambda s: s,
+        layer=lambda x, lw: reference.layer(cfg, x, lw, run, "float32"))
+
+
+def _sdar_shares():
+    """32 query heads on 4 KV heads (the published group of 8) and 16
+    experts over 8 shares, each 4 query heads on the KV head it shares
+    with its neighbour, over a two-copy stream under the block-diffusion
+    mask."""
+    import sdartiny
+    from benchmark import sdar_weights
+    from benchmark.reference import sdar as sdar_reference
+
+    cfg = sdartiny.config(
+        layer_types=["full_attention"], num_attention_heads=32,
+        num_key_value_heads=4, num_experts=16, experts_held_first=0)
+    return dict(
+        cfg=cfg, weights=sdar_weights, shares=8, kv_head=lambda s: s // 2,
+        layer=lambda x, lw: sdar_reference.layer(cfg, x, lw, "float32"))
+
+
+@pytest.mark.parametrize("family", [_mellum_shares, _sdar_shares],
+                         ids=["mellum_4_chips", "sdar_8_chips"])
+def test_the_chips_shares_add_up_to_the_uncut_layer(monkeypatch, family):
+    """The guide's test of a chip's share: each share's attention output
+    (its query heads on its KV head, through its rows of W_o) and each
+    share's held experts' output, with the layer norms, the q/k norm
+    scales and the router counted once, sum to the uncut reference's
+    layer."""
+    monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 8)
+    case = family()
+    cfg, weights_of, n = case["cfg"], case["weights"], case["shares"]
+    D, hd, K, F = 32, 8, 4, 16
+    H, Hkv, E = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                 cfg["num_experts"])
+    w = jax.jit(lambda s: weights_of.make_weights(cfg, s))(np.uint32(4))
     lw = {k[len("model.layers.full."):]: v[0] for k, v in w.items()
           if k.startswith("model.layers.full.")}
-    run, = reference.layer_runs(cfg)
     x = jax.random.normal(jax.random.key(0), (2, 24, D))
-    want, loads = reference.layer(cfg, x, lw, run, "float32")
+    want, loads = case["layer"](x, lw)
 
     eps = cfg["rms_norm_eps"]
-    kinds = mellum_weights.plan(cfg)[1]["full"]
+    kinds = weights_of.plan(cfg)[1]["full"]
     normed = shared.rms_norm(x, lw["input_layernorm.weight"], eps)
     a = "self_attn."
     rows = lambda m, s, n: m[s * n * hd:(s + 1) * n * hd]   # noqa: E731
     attended = jnp.zeros_like(x)
-    for s in range(4):
+    for s in range(n):
         layer = transformer.DistributedAttentionLayer(
-            num_attention_heads=H // 4, num_key_value_heads=Hkv // 4,
+            num_attention_heads=H // n, num_key_value_heads=1,
             attention_head_size=hd, hidden_size=D, qk_norm=True,
             qk_norm_epsilon=eps, rotary_dim=kinds["rotary_dim"],
             rotary_emb_base=kinds["rotary_emb_base"],
             rotary_yarn=kinds["rotary_yarn"], gpt_neox_type_rotary=True,
+            block_diffusion=kinds.get("block_diffusion"),
             causal_mask_size=64, use_qkv_bias=False,
             use_attn_dense_bias=False, attention_dropout_prob=0.0,
             hidden_dropout_prob=0.0)
         flat = laguna.attention_from_hf(
-            rows(lw[a + "q_proj.weight"], s, H // 4),
-            rows(lw[a + "k_proj.weight"], s, Hkv // 4),
-            rows(lw[a + "v_proj.weight"], s, Hkv // 4),
-            rows(lw[a + "o_proj.weight"].T, s, H // 4).T, None, hd, xp=jnp)
+            rows(lw[a + "q_proj.weight"], s, H // n),
+            rows(lw[a + "k_proj.weight"], case["kv_head"](s), 1),
+            rows(lw[a + "v_proj.weight"], case["kv_head"](s), 1),
+            rows(lw[a + "o_proj.weight"].T, s, H // n).T, None, hd, xp=jnp)
         flat["attention/q_norm/scale"] = lw[a + "q_norm.weight"]
         flat["attention/k_norm/scale"] = lw[a + "k_norm.weight"]
         flat = {k[len("attention/"):]: v for k, v in flat.items()}
@@ -288,16 +320,16 @@ def test_the_four_chips_shares_add_up_to_the_uncut_layer(monkeypatch):
             {"params": unflatten(flat, shapes)}, normed)
     h = x + attended
     normed = shared.rms_norm(h, lw["post_attention_layernorm.weight"], eps)
-    routed, landed = jnp.zeros_like(x), 0
-    for s in range(4):
-        first = 4 * s
+    routed, landed, held = jnp.zeros_like(x), 0, E // n
+    for s in range(n):
+        first = held * s
         layer = moe.DistributedDroplessMoE(
             hidden_size=D, intermediate_size=F, num_experts=E, top_k=K,
-            held=(first, 4))
+            held=(first, held))
         part = laguna.experts_from_hf(
-            lw["mlp.experts.gate_proj.weight"][first:first + 4],
-            lw["mlp.experts.up_proj.weight"][first:first + 4],
-            lw["mlp.experts.down_proj.weight"][first:first + 4], xp=jnp)
+            lw["mlp.experts.gate_proj.weight"][first:first + held],
+            lw["mlp.experts.up_proj.weight"][first:first + held],
+            lw["mlp.experts.down_proj.weight"][first:first + held], xp=jnp)
         part = {k[len("output/"):]: v for k, v in part.items()}
         part["router/kernel"] = lw["mlp.gate.weight"].T
         shapes = jax.eval_shape(layer.init, jax.random.key(0), x)["params"]
@@ -306,9 +338,9 @@ def test_the_four_chips_shares_add_up_to_the_uncut_layer(monkeypatch):
                                mutable=["intermediates"])
         stats = mut["intermediates"]["moe_stats"][0]
         np.testing.assert_array_equal(
-            np.asarray(stats[:4]), np.asarray(loads[first:first + 4]))
-        assert int(stats[4]) == 0
-        landed += int(jnp.sum(stats[:4]))
+            np.asarray(stats[:held]), np.asarray(loads[first:first + held]))
+        assert int(stats[held]) == 0
+        landed += int(jnp.sum(stats[:held]))
         routed = routed + out
     assert landed == 2 * 24 * K            # every assignment landed once
     np.testing.assert_allclose(np.asarray(h + routed), np.asarray(want),

@@ -125,6 +125,126 @@ class TestFlashAttention:
         assert att._pallas_ok(q, q, q)
 
 
+# (data length L, block length B, query heads, KV heads, tile of rows,
+# tile of keys): grouped KV heads, an L that is no multiple of a tile (so a
+# tile straddles the two copies, and the last one is padded), unequal
+# tiles, a block as long as a tile.
+_BD_CASES = {
+    "four_heads_on_one": (96, 4, 4, 1, 128, 128),
+    "L_not_in_tiles": (200, 4, 4, 1, 128, 256),
+    "tiles_of_rows_wider": (320, 8, 2, 2, 256, 128),
+    "block_of_a_tile": (256, 128, 2, 1, 128, 128),
+}
+
+
+class TestFlashBlockDiffusion:
+    """The three kernels under the block-diffusion mask of a two-copy
+    stream against the jnp mask (``ops.attention.block_diffusion_mask``),
+    and the tiles they step into against the tiles that hold a live
+    pair."""
+
+    @staticmethod
+    def _case(name):
+        L, blk, H, Hkv, bq, bk = _BD_CASES[name]
+        q, k, v = _rand_qkv(jax.random.key(3), (2, 2 * L, H, 32),
+                            (2, 2 * L, Hkv, 32))
+        flash = lambda q, k, v: flash_attention(    # noqa: E731
+            q, k, v, block_diffusion=blk, block_q=bq, block_k=bk,
+            interpret=True)
+        plain = lambda q, k, v: attention_core(     # noqa: E731
+            q, k, v, block_diffusion=blk, use_pallas=False, mask_value=-1e30)
+        return (q, k, v), flash, plain
+
+    @pytest.mark.parametrize("name", list(_BD_CASES))
+    def test_forward_is_the_jnp_masks(self, name):
+        qkv, flash, plain = self._case(name)
+        np.testing.assert_allclose(
+            np.asarray(flash(*qkv)), np.asarray(plain(*qkv)), atol=2e-5)
+
+    @pytest.mark.parametrize("name", list(_BD_CASES))
+    def test_both_gradients_are_the_jnp_masks(self, name):
+        qkv, flash, plain = self._case(name)
+        probe = jax.random.normal(jax.random.key(4), qkv[0].shape)
+        got = jax.grad(lambda *a: jnp.sum(flash(*a) * probe), (0, 1, 2))(*qkv)
+        want = jax.grad(lambda *a: jnp.sum(plain(*a) * probe), (0, 1, 2))(*qkv)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+    @pytest.mark.parametrize("name", list(_BD_CASES))
+    def test_every_tile_visited_is_live_and_every_live_tile_visited(
+            self, name):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+        from smdistributed_modelparallel_tpu.ops.attention import (
+            block_diffusion_mask,
+        )
+        from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+        L, blk, _, _, bq, bk = _BD_CASES[name]
+        T = 2 * L
+        bq, bk = pa._clamp_block(bq, T), pa._clamp_block(bk, T)
+        t_pad, s_pad = -(-T // bq) * bq, -(-T // bk) * bk
+        mask = np.zeros((t_pad, s_pad), bool)
+        mask[:T, :T] = np.asarray(block_diffusion_mask(T, blk))
+        live = mask.reshape(t_pad // bq, bq, s_pad // bk, bk).any(axis=(1, 3))
+        # the ranges a program walks, tile for tile
+        lo = np.arange(t_pad // bq) * bq
+        by_q = np.zeros_like(live)
+        for r, ((a, b), (c, d)) in enumerate(zip(*[
+                zip(*pair) for pair in pa._bd_kv_ranges(
+                    lo, lo + bq, half=L, blk=blk, block_k=bk, xp=np)])):
+            assert b <= c or c == d
+            by_q[r, a:b] = by_q[r, c:d] = True
+        np.testing.assert_array_equal(by_q, live)
+        lo = np.arange(s_pad // bk) * bk
+        by_kv = np.zeros_like(live)
+        for r, ((a, b), (c, d)) in enumerate(zip(*[
+                zip(*pair) for pair in pa._bd_q_ranges(
+                    lo, lo + bk, half=L, blk=blk, block_q=bq, xp=np)])):
+            assert b <= c or c == d
+            by_kv[a:b, r] = by_kv[c:d, r] = True
+        np.testing.assert_array_equal(by_kv, live)
+        # and the gauges a traced call sets
+        telemetry.reset()
+        qkv, flash, _ = self._case(name)
+        jax.grad(lambda *a: jnp.sum(flash(*a)), (0, 1, 2))(*qkv)
+        metrics = telemetry.report()["metrics"]
+        for gauge in ("smp_flash_tiles_visited", "smp_flash_tiles_live"):
+            by_pass = {s["labels"]["pass"]: s["value"]
+                       for s in metrics[gauge]["series"]}
+            assert by_pass == {"fwd": live.sum(), "dq": live.sum(),
+                               "dkv": live.sum()}
+
+    def test_a_quarter_of_the_tiles_at_the_cells_size(self):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        counts = pa._bd_tile_counts(8192, 4, 256, 512, 16384)
+        assert counts == {"fwd": (576, 576), "dq": (576, 576),
+                          "dkv": (576, 576)}
+        assert 576 / (64 * 32) < 0.29
+
+    def test_other_masks_set_no_tile_gauges(self):
+        from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+        telemetry.reset()
+        q, k, v = _rand_qkv(jax.random.key(5), (1, 128, 2, 32))
+        _flash(q, k, v)
+        assert "smp_flash_tiles_visited" not in telemetry.report()["metrics"]
+
+    def test_dispatch_takes_a_stream_twice_as_long(self, monkeypatch):
+        import smdistributed_modelparallel_tpu.ops.attention as att
+
+        monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
+        q = jnp.ones((1, 16384, 1, 128), jnp.bfloat16)
+        assert not att._pallas_ok(q, q, q)
+        assert att._pallas_ok(q, q, q, 4)
+        # What the kernels ask for decides: K and V of a head twice and
+        # room for the tiles, under a core's cap.
+        assert att._pallas_ok(*[q.astype(jnp.float32)] * 3, 4)
+        longer = jnp.ones((1, 65536, 1, 128), jnp.float32)
+        assert not att._pallas_ok(longer, longer, longer, 4)
+
+
 def _rand_qkv(key, qshape, kvshape=None):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], qshape)
@@ -246,7 +366,7 @@ class TestDispatch:
         # another test file would route attention_core into the CP branch
         # instead of the flash kernels under test.
         smp.shutdown()
-        monkeypatch.setattr(att, "_pallas_ok", lambda q, k, v: True)
+        monkeypatch.setattr(att, "_pallas_ok", lambda *a: True)
         monkeypatch.setattr(pa, "FORCE_INTERPRET", True)
         calls = []
         real = pa.flash_attention
