@@ -47,6 +47,17 @@ absent from ``smdistributed.modelparallel`` v1.12.1.
   [held, D, 2F] and a [held, F, D] product a chunk in the operands' dtype
   that is converted and added afterwards.
   ``smp_moe_wgrad_kernel_engaged{layer}`` says which was traced in;
+- a chunk's rows are summed back to their tokens where the sum is: the
+  forward pass carries one fp32 [tokens, D] sum a layer call (``out``) and
+  the backward pass one (``dx``), and a chunk's
+  ``ops/pallas_row_scatter_add.row_scatter_add`` streams the sum through
+  VMEM once, adding each row that belongs to its token's row, with the
+  combine weights applied inside (forward). It stands aside
+  (``_combine_kernel_engages``: not on a TPU, a mesh of more than one
+  device, a width that is not a multiple of 128, chunks too sparse over
+  the tokens for a stream of the whole sum to pay) for XLA's scatter-add
+  of the same fp32 terms, one row at a time.
+  ``smp_moe_combine_kernel_engaged{layer}`` says which was traced in;
 - gated experts without biases (``act(x W_gate) * (x W_up)) W_down``),
   top-k weights renormalised over all ``top_k`` (held or not) and scaled
   by ``routed_scaling``;
@@ -304,19 +315,20 @@ def _valid_rows(rows, group_sizes):
     return (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
 
 
-def _expert_ffn(rows, w_gate_up, w_down, group_sizes, weights, activation):
-    """``weights * E_e(rows)`` for sorted ``rows`` [R, D] whose first
-    ``sum(group_sizes)`` rows belong, group by group, to the held experts;
-    rows past them give zeros. ``w_gate_up`` [n, D, 2F], ``w_down``
-    [n, F, D]. A grouped product leaves the rows past its groups as the
-    memory was (on the chip: anything), so they are masked going in, in
-    the middle and coming out: nothing of them reaches a result or, through
-    the transposes, a gradient."""
+def _expert_ffn(rows, w_gate_up, w_down, group_sizes, activation):
+    """``(E_e(rows), valid)`` for sorted ``rows`` [R, D] whose first
+    ``sum(group_sizes)`` rows belong, group by group, to the held experts:
+    the second product [R, D] in the rows' dtype and the mask of the rows
+    that belong. ``w_gate_up`` [n, D, 2F], ``w_down`` [n, F, D]. A grouped
+    product leaves the rows past its groups as the memory was (on the
+    chip: anything), so they are masked going in and in the middle, and
+    whoever sums the result masks them coming out (``_weighted``, or the
+    kernel that adds the rows that belong and no other): nothing of them
+    reaches a result or, through the transposes, a gradient."""
     valid = _valid_rows(rows.shape[0], group_sizes)
     h = _first_product(rows, w_gate_up, group_sizes, valid)
     h = _activated(h, valid, activation, rows.dtype)
-    y = jax.lax.ragged_dot(h, w_down, group_sizes)
-    return _weighted(y, weights, valid)
+    return jax.lax.ragged_dot(h, w_down, group_sizes), valid
 
 
 def _wgrad_kernel_engages(rows, w_gate_up, w_down):
@@ -325,17 +337,38 @@ def _wgrad_kernel_engages(rows, w_gate_up, w_down):
     lane-aligned widths, the weights whole on one device: a Mosaic call
     cannot be partitioned over a mesh) or takes the grouped products'
     transposes and adds them up. From what the trace can see; no knob."""
-    from smdistributed_modelparallel_tpu.backend.state import state
     from smdistributed_modelparallel_tpu.ops.pallas_grouped_wgrad import (
         grouped_wgrad_ok,
     )
 
-    if state.initialized and state.mesh.devices.size > 1:
-        return False
     _, D, F2 = w_gate_up.shape
-    return (w_gate_up.dtype == w_down.dtype
+    return (_on_one_device()
+            and w_gate_up.dtype == w_down.dtype
             and grouped_wgrad_ok(rows, D, F2)
             and grouped_wgrad_ok(rows, F2 // 2, D))
+
+
+def _on_one_device():
+    from smdistributed_modelparallel_tpu.backend.state import state
+
+    return not (state.initialized and state.mesh.devices.size > 1)
+
+
+def _combine_kernel_engages(x, rows):
+    """Whether a chunk's rows are summed back to their tokens inside
+    ``ops/pallas_row_scatter_add`` (on the TPU, one device, a lane-aligned
+    width, whole row blocks a chunk, a tile that divides the tokens, and
+    rows enough for a stream of the whole sum to pay: its conditions are
+    not the weight gradients' kernel's, hence a predicate of its own) or
+    by XLA's scatter-add, forward and backward alike. ``x`` [N, D]: the
+    layer's tokens; the rows are of its dtype. From what the trace can
+    see; no knob."""
+    from smdistributed_modelparallel_tpu.ops.pallas_row_scatter_add import (
+        row_scatter_add_ok,
+    )
+
+    return _on_one_device() and row_scatter_add_ok(
+        *x.shape, rows, x.dtype.itemsize)
 
 
 def _chunk_grads(picked, w_gate_up, w_down, sizes, w, g, activation, sums):
@@ -415,14 +448,23 @@ def held_experts_output(x, w_gate_up, w_down, weights, tokens, offsets,
 
 def _held_fwd(x, w_gate_up, w_down, weights, tokens, offsets, activation,
               rows):
+    from smdistributed_modelparallel_tpu.ops.pallas_row_scatter_add import (
+        row_scatter_add,
+    )
+
+    kernel = _combine_kernel_engages(x, rows)
+
     def body(c, out):
         with jax.named_scope("smp/moe/dispatch"):
             t, w, sizes = _chunk(c, tokens, weights, offsets, rows)
             picked = x[t]
         with jax.named_scope("smp/moe/experts"):
-            y = _expert_ffn(picked, w_gate_up, w_down, sizes, w, activation)
+            y, valid = _expert_ffn(picked, w_gate_up, w_down, sizes,
+                                   activation)
         with jax.named_scope("smp/moe/combine"):
-            return out.at[t].add(y)
+            if kernel:
+                return row_scatter_add(out, y, t, sizes, w)
+            return out.at[t].add(_weighted(y, w, valid))
 
     out = jax.lax.fori_loop(
         0, _used_chunks(offsets, rows), body,
@@ -431,7 +473,12 @@ def _held_fwd(x, w_gate_up, w_down, weights, tokens, offsets, activation,
 
 
 def _held_bwd(activation, rows, res, g):
+    from smdistributed_modelparallel_tpu.ops.pallas_row_scatter_add import (
+        row_scatter_add,
+    )
+
     x, w_gate_up, w_down, weights, tokens, offsets = res
+    kernel = _combine_kernel_engages(x, rows)
 
     def body(c, carry):
         dx, dgu, dd, dw = carry
@@ -443,7 +490,9 @@ def _held_bwd(activation, rows, res, g):
                 picked, w_gate_up, w_down, sizes, w, g_picked, activation,
                 (dgu, dd))
         with jax.named_scope("smp/moe/combine"):
-            return (dx.at[t].add(dr.astype(jnp.float32)), dgu, dd,
+            dx = (row_scatter_add(dx, dr, t, sizes) if kernel
+                  else dx.at[t].add(dr.astype(jnp.float32)))
+            return (dx, dgu, dd,
                     jax.lax.dynamic_update_slice(dw, dwc, (c * rows,)))
 
     dx, dgu, dd, dw = jax.lax.fori_loop(
@@ -560,7 +609,8 @@ class DistributedDroplessMoE(nn.Module):
         w_gate_up = gate_up.astype(x.dtype).reshape(count, D, 2 * F)
         w_down = down.astype(x.dtype)
         _record_trace("/".join(self.path), rows,
-                      _wgrad_kernel_engages(rows, w_gate_up, w_down))
+                      _wgrad_kernel_engages(rows, w_gate_up, w_down),
+                      _combine_kernel_engages(x, rows))
         # Its own scopes inside: gather, grouped FFN, scatter-add.
         out = held_experts_output(
             x, w_gate_up, w_down, weights, tokens, offsets,
@@ -589,7 +639,7 @@ class DistributedDroplessMoE(nn.Module):
 _TRACED_CHUNK_ROWS = {}
 
 
-def _record_trace(layer, rows, engaged):
+def _record_trace(layer, rows, wgrad_engaged, combine_engaged):
     from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
 
     _TRACED_CHUNK_ROWS[layer] = rows
@@ -598,7 +648,13 @@ def _record_trace(layer, rows, engaged):
         "1 where the expert layer's weight gradients are summed inside the "
         "grouped_wgrad kernel, 0 where the grouped products' transposes are "
         "converted and added; set while the layer is traced",
-    ).labels(layer=layer).set(int(engaged))
+    ).labels(layer=layer).set(int(wgrad_engaged))
+    telemetry.gauge(
+        "smp_moe_combine_kernel_engaged",
+        "1 where the expert layer's routed rows are summed back to their "
+        "tokens inside the row_scatter_add kernel, forward and backward, 0 "
+        "where XLA's scatter-add does it; set while the layer is traced",
+    ).labels(layer=layer).set(int(combine_engaged))
 
 
 def _experts_visited(loads, rows):

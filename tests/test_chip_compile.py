@@ -388,3 +388,57 @@ def test_held_experts_backward_sums_weight_gradients_in_the_kernel(
     assert any(f"= f32[{held},{F},{D}]" in c for c in calls)
     assert all("output_to_operand_aliasing" in c
                and "smp/moe/experts/smp_grouped_wgrad" in c for c in calls)
+
+
+@pytest.mark.parametrize(
+    "tokens,D,F,held,rows,engages",
+    [(8192, 2304, 896, 16, 6144, True), (16384, 2048, 768, 16, 6144, True),
+     (8192, 3072, 1024, 8, 1024, False)],
+    ids=["mellum_held_16", "sdar_held_16", "laguna_held_8"],
+)
+def test_held_experts_sum_their_rows_back_in_the_kernel(
+        one_chip, monkeypatch, tokens, D, F, held, rows, engages):
+    """``held_experts_output`` forward and backward at the three expert
+    cells' held shapes. Mellum's and SDAR's chunks (6,144 rows for 8,192
+    and 16,384 tokens): one ``smp_row_scatter_add`` call in each chunk
+    loop under ``smp/moe/combine``, the fp32 sum its aliased operand, and
+    no scatter left under that scope. Laguna's 1,024-row chunks would
+    stream 197 KB of the sum a row: the kernel stands aside and XLA's two
+    scatter-adds stay. As above the test says TPU after compiling the
+    CPU's answer to see the check live."""
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.nn import moe
+
+    smp.shutdown()
+    assignments = tokens * 8
+
+    def loss(x, w_gate_up, w_down, weights, tok, offsets):
+        return jnp.sum(moe.held_experts_output(
+            x, w_gate_up, w_down, weights, tok, offsets, "silu", rows))
+
+    def compiled():
+        return _compile(
+            jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+            (tokens, D), (held, D, 2 * F), (held, F, D),
+            ((assignments,), jnp.float32), ((assignments,), jnp.int32),
+            ((held + 1,), jnp.int32))
+
+    def scatters(text):
+        return [line for line in text.splitlines()
+                if " scatter(" in line and "smp/moe/combine" in line]
+
+    assert len(scatters(compiled())) == 2            # the check is live
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = compiled()
+    calls = [line for line in text.splitlines()
+             if "smp_row_scatter_add" in line and "custom-call(" in line]
+    if not engages:
+        assert calls == [] and len(scatters(text)) == 2
+        return
+    assert scatters(text) == []
+    assert len(calls) == 2, calls
+    assert all(f"= f32[{tokens},{D}]" in c
+               and "output_to_operand_aliasing={{}: (" in c
+               and "while/body" in c
+               and "smp/moe/combine/smp_row_scatter_add" in c for c in calls)
+    assert sum("transpose(" in c for c in calls) == 1    # one a pass

@@ -410,6 +410,30 @@ def _dense_held_experts(x, w_gate_up, w_down, weights, tokens, offsets):
     return out
 
 
+def _three_chunks_of_sorted_rows(D, F, held):
+    """640 tokens at two of ``held`` experts each, sorted into 512-row
+    chunks: 1,280 rows in three chunks of a 1,536-row buffer, so experts
+    lie across the chunk boundaries, the last chunk ends in rows past the
+    groups and every token is in two groups. Returns the routing, the
+    operands of ``held_experts_output`` and a probe for its output."""
+    tokens_n, top_k, rows = 640, 2, 512
+    keys = jax.random.split(jax.random.key(D + held), 6)
+    top_idx = jnp.argsort(
+        jax.random.uniform(keys[0], (tokens_n, held)), axis=-1)[:, :top_k]
+    top_weight = jax.nn.softmax(
+        jax.random.normal(keys[1], (tokens_n, top_k)), axis=-1)
+    tokens, weights, offsets, _, _ = moe.route_to_held(
+        top_idx, top_weight, 0, held, rows)
+    assert int(offsets[-1]) == 1280 and tokens.shape == (1536,)
+    # an expert's rows lie across each chunk boundary
+    assert not set(np.asarray(offsets).tolist()) & {512, 1024}
+    x = jax.random.normal(keys[2], (tokens_n, D))
+    w_gate_up = jax.random.normal(keys[3], (held, D, 2 * F)) * 0.05
+    w_down = jax.random.normal(keys[4], (held, F, D)) * 0.05
+    probe = jax.random.normal(keys[5], (tokens_n, D))
+    return rows, tokens, weights, offsets, x, w_gate_up, w_down, probe
+
+
 @pytest.mark.parametrize("D,F,held", [(384, 128, 4), (256, 128, 8)],
                          ids=["laguna_3_to_1", "eight_held"])
 def test_weight_gradients_summed_in_the_kernel_are_the_products(
@@ -423,21 +447,8 @@ def test_weight_gradients_summed_in_the_kernel_are_the_products(
     import smdistributed_modelparallel_tpu as smp
     from smdistributed_modelparallel_tpu.ops import pallas_grouped_wgrad as gw
 
-    tokens_n, top_k, rows = 640, 2, 512
-    keys = jax.random.split(jax.random.key(D + held), 6)
-    top_idx = jnp.argsort(
-        jax.random.uniform(keys[0], (tokens_n, held)), axis=-1)[:, :top_k]
-    top_weight = jax.nn.softmax(
-        jax.random.normal(keys[1], (tokens_n, top_k)), axis=-1)
-    tokens, weights, offsets, loads, _ = moe.route_to_held(
-        top_idx, top_weight, 0, held, rows)
-    assert int(offsets[-1]) == 1280 and tokens.shape == (1536,)
-    # an expert's rows lie across each chunk boundary
-    assert not set(np.asarray(offsets).tolist()) & {512, 1024}
-    x = jax.random.normal(keys[2], (tokens_n, D))
-    w_gate_up = jax.random.normal(keys[3], (held, D, 2 * F)) * 0.05
-    w_down = jax.random.normal(keys[4], (held, F, D)) * 0.05
-    probe = jax.random.normal(keys[5], (tokens_n, D))
+    rows, tokens, weights, offsets, x, w_gate_up, w_down, probe = \
+        _three_chunks_of_sorted_rows(D, F, held)
 
     def grads(fn):
         return jax.jit(jax.grad(
@@ -469,6 +480,65 @@ def test_weight_gradients_summed_in_the_kernel_are_the_products(
         scale = float(jnp.max(jnp.abs(want))) + 1e-6
         np.testing.assert_allclose(np.asarray(got) / scale,
                                    np.asarray(same) / scale, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(want) / scale, atol=2e-5)
+
+
+@pytest.mark.parametrize("D,F,held", [(384, 128, 4), (256, 128, 8)],
+                         ids=["laguna_3_to_1", "eight_held"])
+def test_rows_summed_back_in_the_kernel_are_the_scatter_adds(
+        D, F, held, monkeypatch):
+    """The row scatter-add kernel forced through interpret mode on the same
+    three 512-row chunks of 1,280 sorted rows over 640 tokens (two token
+    tiles of 320; experts across the chunk boundaries, the last chunk ends
+    in rows past the groups, every token in two groups): the output of
+    ``held_experts_output`` and its gradients for the rows, both weight
+    tensors and the combine weights are those of XLA's scatter-add (the
+    kernel standing aside) and of a dense expert-by-expert reference. On
+    a mesh of two devices it stands aside though forced."""
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.ops import pallas_row_scatter_add as rs
+
+    rows, tokens, weights, offsets, x, w_gate_up, w_down, probe = \
+        _three_chunks_of_sorted_rows(D, F, held)
+
+    def out_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda x, a, b, w: jnp.sum(fn(x, a, b, w) * probe),
+            argnums=(0, 1, 2, 3)))(x, w_gate_up, w_down, weights)
+
+    program = lambda x, a, b, w: moe.held_experts_output(   # noqa: E731
+        x, a, b, w, tokens, offsets, "silu", rows)
+    smp.reset()
+    smp.init({"microbatches": 1}, devices=jax.devices()[:1])
+    try:
+        assert not moe._combine_kernel_engages(x, rows)
+        scatter = out_and_grads(program)
+        monkeypatch.setattr(rs, "FORCE_INTERPRET", True)
+        assert moe._combine_kernel_engages(x, rows)
+        assert not moe._combine_kernel_engages(x, 8)     # the shrunk chunks
+        assert not moe._combine_kernel_engages(x[:, :100], rows)
+        calls = []
+        real = rs.row_scatter_add
+        monkeypatch.setattr(
+            rs, "row_scatter_add",
+            lambda *a, **k: calls.append(a[1].shape) or real(*a, **k))
+        kernel = out_and_grads(program)
+        assert calls == [(rows, D), (rows, D)]   # traced once a pass
+        smp.reset()
+        smp.init({"microbatches": 1, "ddp": True},
+                 devices=jax.devices()[:2])
+        assert not moe._combine_kernel_engages(x, rows)
+    finally:
+        smp.reset()
+    dense = out_and_grads(lambda x, a, b, w: _dense_held_experts(
+        x, a, b, w, tokens, np.asarray(offsets)))
+    for got, same, want in zip(jax.tree_util.tree_leaves(kernel),
+                               jax.tree_util.tree_leaves(scatter),
+                               jax.tree_util.tree_leaves(dense)):
+        scale = float(jnp.max(jnp.abs(want))) + 1e-6
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(same) / scale, atol=2e-6)
         np.testing.assert_allclose(np.asarray(got) / scale,
                                    np.asarray(want) / scale, atol=2e-5)
 

@@ -348,11 +348,15 @@ def test_the_chips_shares_add_up_to_the_uncut_layer(monkeypatch, family):
     assert _wgrad_engaged()[""] == 0       # eight-row chunks: the products
 
 
-def _wgrad_engaged():
+def _engaged(gauge):
     from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
 
-    series = telemetry.report()["metrics"]["smp_moe_wgrad_kernel_engaged"]
+    series = telemetry.report()["metrics"][gauge]
     return {s["labels"]["layer"]: s["value"] for s in series["series"]}
+
+
+def _wgrad_engaged():
+    return _engaged("smp_moe_wgrad_kernel_engaged")
 
 
 def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
@@ -399,6 +403,58 @@ def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
     visits, pairs = moe._experts_visited(stats[:held], 1024)
     assert pairs == held * -(-int(stats[:held].sum()) // 1024)
     assert visited["wgrad_visited_share"] == visits / pairs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_layers_rows_summed_back_through_the_kernel(dtype, monkeypatch):
+    """The same share of Mellum's layer, two 1,024-row chunks over 1,024
+    tokens (two token tiles): with interpret mode forced the layer sums
+    its routed rows back to their tokens in the kernel, forward and
+    backward, and says so; without, XLA's scatter-add does and the gauge
+    says that. Output and every gradient agree to the rounding of an fp32
+    sum whose terms may come in another order."""
+    from smdistributed_modelparallel_tpu.ops import pallas_row_scatter_add as rs
+
+    monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 512)
+    D, F, E, K, held = 256, 128, 64, 8, 16
+    layer = moe.DistributedDroplessMoE(
+        hidden_size=D, intermediate_size=F, num_experts=E, top_k=K,
+        held=(16, held), initializer_range=0.1, dtype=dtype)
+    x = jax.random.normal(jax.random.key(0), (2, 512, D), dtype)
+    params = layer.init(jax.random.key(1), x)["params"]
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+
+    def run():
+        def loss(params, x):
+            out, mut = layer.apply({"params": params}, x,
+                                   mutable=["intermediates"])
+            return (jnp.sum(out.astype(jnp.float32) * probe),
+                    (out, mut["intermediates"]["moe_stats"][0]))
+
+        (_, (out, stats)), got = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(params, x)
+        return (out, got), np.asarray(stats)
+
+    scatter, stats = run()
+    assert _engaged("smp_moe_combine_kernel_engaged")[""] == 0
+    assert stats[held] == 0 and stats[:held].sum() > 1024     # two chunks
+    monkeypatch.setattr(rs, "FORCE_INTERPRET", True)
+    calls = []
+    real = rs.row_scatter_add
+    monkeypatch.setattr(
+        rs, "row_scatter_add",
+        lambda *a, **k: calls.append(len(a) == 5) or real(*a, **k))
+    kernel, _ = run()
+    assert _engaged("smp_moe_combine_kernel_engaged")[""] == 1
+    assert _wgrad_engaged()[""] == 0          # the other kernel stood aside
+    assert sorted(calls) == [False, True]     # dx without weights, out with
+    for got, want in zip(jax.tree_util.tree_leaves(kernel),
+                         jax.tree_util.tree_leaves(scatter)):
+        assert got.dtype == want.dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        scale = float(np.max(np.abs(want))) + 1e-6
+        np.testing.assert_allclose(got / scale, want / scale, atol=2e-6)
 
 
 def test_chunks_are_a_third_of_an_even_routers_load(monkeypatch):

@@ -513,3 +513,87 @@ def test_grouped_wgrad_tiles_and_preconditions(monkeypatch):
     assert not gw.grouped_wgrad_ok(8, 2304, 1792)        # the shrunk chunks
     assert not gw.grouped_wgrad_ok(1024, 32, 32)
     assert not gw.grouped_wgrad_ok(1024, 2304, 1800)
+
+
+# Group sizes over 64 sorted rows (four row blocks) that name 96 tokens in
+# three tiles of 32.
+_SCATTER_GROUPS = {
+    "a_token_in_several_groups": [20, 20, 20],
+    "an_empty_group_between": [24, 0, 30, 5],
+    "no_rows_at_all": [0, 0, 0, 0],
+    "every_row_lands": [16, 16, 16, 16],
+    "a_group_over_the_block_boundaries": [7, 33, 3],
+}
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weights", "no_weights"])
+@pytest.mark.parametrize("width", [2304, 2048])
+@pytest.mark.parametrize("groups", list(_SCATTER_GROUPS))
+def test_row_scatter_add_is_the_scatter_add(groups, width, weighted,
+                                            monkeypatch):
+    """bf16 rows, an fp32 running sum that is not zero going in, NaN in
+    every row past the groups and a token there that would show it: the
+    tokens a group names get ``sum + float32(row) * weight`` once for each
+    group that names them, as XLA's scatter-add of the fp32 terms gives
+    them; the tokens no row names come out bit for bit as they went in;
+    nothing past the groups reaches the output. Three token tiles a call
+    (the tile is planted small)."""
+    from smdistributed_modelparallel_tpu.ops import pallas_row_scatter_add as rs
+
+    monkeypatch.setattr(rs, "_TILE_TOKENS", 32)
+    sizes = _SCATTER_GROUPS[groups]
+    n, r, landed = 96, 64, sum(sizes)
+    assert rs._token_tile(n) == 32
+    rng = np.random.default_rng(len(groups) + width)
+    tokens = np.concatenate(
+        [np.sort(rng.choice(n, size, replace=False)) for size in sizes]
+        + [rng.integers(0, n, r - landed)]).astype(np.int32)
+    named, counts = np.unique(tokens[:landed], return_counts=True)
+    if groups == "a_token_in_several_groups":
+        assert counts.max() >= 2
+    keys = jax.random.split(jax.random.key(width + landed), 3)
+    rows = jax.random.normal(keys[0], (r, width), jnp.bfloat16)
+    rows = rows.at[landed:].set(jnp.nan)
+    weights = jax.random.uniform(keys[1], (r,)) if weighted else None
+    acc = jax.random.normal(keys[2], (n, width), jnp.float32)
+    args = (rows, jnp.asarray(tokens), jnp.asarray(sizes, jnp.int32), weights)
+
+    got = np.asarray(jax.jit(
+        lambda acc, *a: rs.row_scatter_add(acc, *a, interpret=True),
+        donate_argnums=0)(acc + 0.0, *args))
+
+    assert np.isfinite(got).all()
+    want = np.asarray(rs.reference_row_scatter_add(acc, *args))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-6)
+    untouched = np.setdiff1d(np.arange(n), named)
+    assert len(untouched) or landed == r
+    np.testing.assert_array_equal(got[untouched], np.asarray(acc)[untouched])
+    if landed:
+        assert np.abs(got[named] - np.asarray(acc)[named]).max() > 1e-3
+
+
+def test_row_scatter_add_runs_and_preconditions(monkeypatch):
+    """A run's start is counted from the rows' group and tile; the kernel
+    only where a chunk is whole row blocks, the width a lane multiple, a
+    tile divides the tokens, the operands fit the VMEM asked for and the
+    chunk has rows for a fair share of the tokens, on the TPU or forced."""
+    from smdistributed_modelparallel_tpu.ops import pallas_row_scatter_add as rs
+
+    # two groups over tokens 0..15 in two tiles of 8; three rows past them
+    tokens = jnp.asarray([1, 7, 8, 9, 15, 0, 8, 3, 3, 3], jnp.int32)
+    starts = rs._run_starts(tokens, jnp.asarray([5, 2]), 8, 2)
+    assert starts.tolist() == [0, 2, 5, 6, 7]
+    assert rs._run_starts(tokens, jnp.asarray([0, 0]), 8, 2).tolist() == [0] * 5
+    assert rs._token_tile(8192) == 512 and rs._token_tile(640) == 320
+    assert rs._token_tile(8191) is None and rs._token_tile(4) is None
+    assert not rs.row_scatter_add_ok(8192, 2304, 6144)      # the CPU
+    monkeypatch.setattr(rs, "FORCE_INTERPRET", True)
+    assert rs.row_scatter_add_ok(8192, 2304, 6144)          # Mellum
+    assert rs.row_scatter_add_ok(16384, 2048, 6144)         # SDAR
+    assert not rs.row_scatter_add_ok(8192, 3072, 1024)      # Laguna: sparse
+    assert not rs.row_scatter_add_ok(8192, 2300, 6144)      # an odd width
+    assert not rs.row_scatter_add_ok(8192, 2304, 6152)      # half a row block
+    assert not rs.row_scatter_add_ok(8191, 2304, 6144)      # no token tile
+    assert not rs.row_scatter_add_ok(8192, 2304, 6144, 4)   # fp32 rows: VMEM
+    assert rs.row_scatter_add_ok(1024, 256, 1024, 4)
