@@ -21,6 +21,7 @@ from dataclasses import field
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from smdistributed_modelparallel_tpu.parallel.pipeline import PipelineSpec
@@ -67,12 +68,21 @@ class CausalSelfAttention(nn.Module):
         B, T, D = x.shape
         H = self.n_heads
         hd = D // H
-        qkv = nn.Dense(3 * D, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, H, hd)
-        k = k.reshape(B, T, H, hd)
-        v = v.reshape(B, T, H, hd)
+        with jax.named_scope("smp/attn/qkv"):
+            qkv = nn.Dense(3 * D, name="qkv")(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, T, H, hd)
+            k = k.reshape(B, T, H, hd)
+            v = v.reshape(B, T, H, hd)
+        with jax.named_scope("smp/attn/core"):
+            out = self._attend(q, k, v, attn_bias, paged)
+        with jax.named_scope("smp/attn/out"):
+            return nn.Dense(D, name="proj")(out)
 
+    @nn.nowrap
+    def _attend(self, q, k, v, attn_bias, paged):
+        """Rotary, the cache (decode, paged) and the attention itself."""
+        B, T, H, hd = q.shape
         pos_offset = 0
         cache = None
         decode_mask = None
@@ -115,7 +125,7 @@ class CausalSelfAttention(nn.Module):
         drop_rng = None
         if self.dropout > 0.0 and not self.deterministic:
             drop_rng = self.make_rng("dropout")
-        out = attention_core(
+        return attention_core(
             q, k, v,
             causal=decode_mask is None,
             window=self.window if decode_mask is None else None,
@@ -124,8 +134,7 @@ class CausalSelfAttention(nn.Module):
             attention_in_fp32=self.attention_in_fp32,
             dropout_rate=self.dropout if not self.deterministic else 0.0,
             dropout_rng=drop_rng,
-        ).reshape(B, T, D)
-        return nn.Dense(D, name="proj")(out)
+        ).reshape(B, T, H * hd)
 
 
 class TransformerLayer(nn.Module):
@@ -151,7 +160,15 @@ class TransformerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, paged=None):
-        attn = CausalSelfAttention(
+        # The scopes ``utils/profiling.SCOPES`` lists: the layer, its
+        # attention (``full`` or ``window``) and its feed-forward; the
+        # norms stay charged to the layer.
+        with jax.named_scope("smp/layer/block"):
+            return self._block(x, paged)
+
+    @nn.nowrap
+    def _block(self, x, paged):
+        attention = CausalSelfAttention(
             self.d_model, self.n_heads, self.dropout, self.attention_in_fp32,
             self.rotary, self.rotary_dim, self.window, self.deterministic,
             self.decode, self.decode_cache_len,
@@ -159,11 +176,16 @@ class TransformerLayer(nn.Module):
             name="attn",
         )
 
+        def attn(h, paged):
+            with jax.named_scope(
+                    "smp/attn/window" if self.window else "smp/attn/full"):
+                return attention(h, paged=paged)
+
         def mlp(h):
-            h = nn.Dense(self.d_ff, name="fc")(h)
-            h = _gelu(h)
-            h = nn.Dense(self.d_model, name="proj")(h)
-            return h
+            with jax.named_scope("smp/mlp/dense"):
+                h = nn.Dense(self.d_ff, name="fc")(h)
+                h = _gelu(h)
+                return nn.Dense(self.d_model, name="proj")(h)
 
         if self.parallel_block:
             h = nn.LayerNorm(epsilon=self.ln_eps, name="ln1")(x)
@@ -280,6 +302,10 @@ class TransformerLM(nn.Module):
     # -- pipeline decomposition ----------------------------------------
 
     def embed(self, ids, paged=None):
+        with jax.named_scope("smp/model/embed"):
+            return self._embed(ids, paged)
+
+    def _embed(self, ids, paged):
         x = self.wte(ids)
         if self.pos_type == "learned":
             if paged is not None:
@@ -297,7 +323,8 @@ class TransformerLM(nn.Module):
         return x
 
     def head(self, x, targets=None):
-        x = self.ln_f(x)
+        with jax.named_scope("smp/head/norm"):
+            x = self.ln_f(x)
         if targets is not None and self.tie_weights:
             # Fused LM-head CE (TPU extension): per-token losses without
             # the [.., V] logits intermediate (nn/cross_entropy.py).
@@ -309,7 +336,9 @@ class TransformerLM(nn.Module):
                 x, self.wte.embedding, targets,
                 label_smoothing=self.label_smoothing,
             )
-        logits = self.wte.attend(x) if self.tie_weights else self.lm_head(x)
+        with jax.named_scope("smp/head/logits"):
+            logits = (self.wte.attend(x) if self.tie_weights
+                      else self.lm_head(x))
         if targets is None:
             return logits
         from smdistributed_modelparallel_tpu.nn.cross_entropy import (
@@ -337,7 +366,8 @@ class TransformerLM(nn.Module):
                     "pipeline parallelism; compute the loss from logits."
                 )
         x = self.embed(ids, paged=paged)
-        x = self._apply_layers(x, paged=paged)
+        with jax.named_scope("smp/model/stack"):
+            x = self._apply_layers(x, paged=paged)
         return self.head(x, targets)
 
     def _apply_layers(self, x, paged=None):

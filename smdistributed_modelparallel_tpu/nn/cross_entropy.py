@@ -19,6 +19,11 @@ from smdistributed_modelparallel_tpu.backend.topology import TP_AXIS
 from smdistributed_modelparallel_tpu.nn.utils import shard_activation
 
 
+#: The scope of the three entry points below (``utils/profiling.SCOPES``):
+#: a device trace then says what the loss costs, whoever calls it.
+LOSS_SCOPE = "smp/head/loss"
+
+
 def vocab_parallel_cross_entropy(logits, targets, label_smoothing=0.0):
     """Per-token cross-entropy loss.
 
@@ -28,6 +33,11 @@ def vocab_parallel_cross_entropy(logits, targets, label_smoothing=0.0):
     Returns:
       [...] per-token losses (fp32).
     """
+    with jax.named_scope(LOSS_SCOPE):
+        return _cross_entropy(logits, targets, label_smoothing)
+
+
+def _cross_entropy(logits, targets, label_smoothing):
     vocab = logits.shape[-1]
     spec = [None] * (logits.ndim - 1) + [TP_AXIS]
     logits = shard_activation(logits, *spec)
@@ -53,12 +63,11 @@ def masked_vocab_parallel_cross_entropy(logits, targets, ignore_index=-100,
                                         label_smoothing=0.0):
     """``vocab_parallel_cross_entropy`` with HF-convention ignored labels:
     ``ignore_index`` positions contribute 0 loss and no gradient."""
-    valid = targets != ignore_index
-    per = vocab_parallel_cross_entropy(
-        logits, jnp.where(valid, targets, 0),
-        label_smoothing=label_smoothing,
-    )
-    return jnp.where(valid, per, 0.0)
+    with jax.named_scope(LOSS_SCOPE):
+        valid = targets != ignore_index
+        per = _cross_entropy(
+            logits, jnp.where(valid, targets, 0), label_smoothing)
+        return jnp.where(valid, per, 0.0)
 
 
 def _build_tp_fused_ce(mesh, v_global, block_n, block_v, interpret,
@@ -135,6 +144,10 @@ def fused_lm_head_cross_entropy(hidden, embedding_table, targets,
       targets: [...] int ids; ``ignore_index`` entries contribute 0 loss
         and no gradient.
     Returns: fp32 per-token losses shaped like ``targets``.
+
+    Scopes: the blockwise kernels, which hold the head's product inside
+    them, trace under ``smp/head/loss``; on the materialized path the
+    product is ``smp/head/logits`` and what follows it the loss.
     """
     from smdistributed_modelparallel_tpu.backend.state import state
     from smdistributed_modelparallel_tpu.ops import pallas_ce as pc
@@ -144,8 +157,9 @@ def fused_lm_head_cross_entropy(hidden, embedding_table, targets,
     D = hidden.shape[-1]
     x = hidden.reshape(-1, D)
     t = targets.reshape(-1)
-    valid = t != ignore_index
-    t_safe = jnp.where(valid, t, 0)
+    with jax.named_scope(LOSS_SCOPE):
+        valid = t != ignore_index
+        t_safe = jnp.where(valid, t, 0)
     tp = state.mesh.shape.get(TP_AXIS, 1) if state.initialized else 1
     want = _want_fused_ce(x, embedding_table, tp)
     V = embedding_table.shape[0]
@@ -155,9 +169,10 @@ def fused_lm_head_cross_entropy(hidden, embedding_table, targets,
     if want and can:
         bn, bv = pc.auto_blocks(D, block_n, block_v)
         if tp == 1:
-            per = pc.fused_lm_head_ce(x, embedding_table, t_safe,
-                                      bn, bv, False,
-                                      float(label_smoothing))
+            with jax.named_scope(LOSS_SCOPE):
+                per = pc.fused_lm_head_ce(x, embedding_table, t_safe,
+                                          bn, bv, False,
+                                          float(label_smoothing))
         else:
             # Vocab-parallel: per-shard kernels on the local [V/tp, D]
             # slice, pmax/psum-combined inside a tp manual region — the
@@ -167,7 +182,8 @@ def fused_lm_head_cross_entropy(hidden, embedding_table, targets,
             fn = _build_tp_fused_ce(
                 state.mesh, V, bn, bv, interp, float(label_smoothing)
             )
-            per = fn(x, embedding_table, t_safe)
+            with jax.named_scope(LOSS_SCOPE):
+                per = fn(x, embedding_table, t_safe)
     else:
         if want and not can and state.initialized \
                 and getattr(state.cfg, "fused_ce", "auto") is True:
@@ -191,11 +207,13 @@ def fused_lm_head_cross_entropy(hidden, embedding_table, targets,
                 "(%s) — materializing [%d, %d] logits instead.",
                 why, x.shape[0], embedding_table.shape[0],
             )
-        logits = x @ embedding_table.T.astype(x.dtype)
+        with jax.named_scope("smp/head/logits"):
+            logits = x @ embedding_table.T.astype(x.dtype)
         per = vocab_parallel_cross_entropy(
             logits, t_safe, label_smoothing=label_smoothing
         )
-    per = jnp.where(valid, per, 0.0)
+    with jax.named_scope(LOSS_SCOPE):
+        per = jnp.where(valid, per, 0.0)
     return per.reshape(lead)
 
 

@@ -39,7 +39,14 @@ def two_copy_stream(clean_ids, noisy_ids):
 def masked_diffusion_loss(logits, clean_ids, noisy_ids, rates, mask_id):
     """The loss above from the noisy half's ``logits`` [B, L, V] (any
     float dtype; the sums are float32), and its counters ``{"loss_tokens":
-    masked positions, "data_tokens": B L}`` as int32 scalars."""
+    masked positions, "data_tokens": B L}`` as int32 scalars. Traced under
+    the scope ``smp/head/loss``."""
+    with jax.named_scope("smp/head/loss"):
+        return _masked_diffusion_loss(
+            logits, clean_ids, noisy_ids, rates, mask_id)
+
+
+def _masked_diffusion_loss(logits, clean_ids, noisy_ids, rates, mask_id):
     B, L = clean_ids.shape
     logits = logits.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)

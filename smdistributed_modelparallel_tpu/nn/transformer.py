@@ -29,7 +29,6 @@ query-key-layer-scaling and GPT-Neo-style alternating local/global
 attention.
 """
 
-import contextlib
 from typing import Any, Optional
 
 import numpy as np
@@ -94,11 +93,6 @@ def _seq_parallel(memory_opt):
     """The residual stream is sequence-sharded over tp: explicitly via
     optimize='memory', or implicitly by the overlapped-tp ring."""
     return memory_opt or _ring_active()
-
-
-def _named_scope(name):
-    """``jax.named_scope(name)``, or nothing for ``None``."""
-    return contextlib.nullcontext() if name is None else jax.named_scope(name)
 
 
 def _init(range_, use_normal=True):
@@ -308,10 +302,58 @@ class DistributedAttentionLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, cross_states=None, attention_mask=None, xs=None):
+        """The three parts of any attention, each under its scope (whatever
+        scope the layer put round the whole): the q/k/v projections, the
+        core (rotary, cache, the flash kernels or the plain path) and the
+        output projection; the q/k norms keep their own between them."""
+        hd = self.attention_head_size
+        B = hidden.shape[0]
+        with jax.named_scope("smp/attn/qkv"):
+            q, k, v = self._project_qkv(hidden, cross_states)
+
+        cache = None
+        pos_offset = 0
+        if self.decode and not self.cross_attention:
+            from smdistributed_modelparallel_tpu.nn.utils import (
+                DecodeKVCache,
+                pad_row_offset,
+            )
+
+            if self.causal_mask_size is None:
+                raise SMPValidationError(
+                    "decode=True requires causal self-attention "
+                    "(causal_mask_size set); BERT-family encoders do not "
+                    "decode."
+                )
+            cache = DecodeKVCache(
+                self, (B, self.decode_cache_len, k.shape[2], hd), k.dtype
+            )
+
+            # Left-padded prompts: each row's absolute positions shift
+            # back by its pad count (see nn/utils.pad_row_offset).
+            row_off = pad_row_offset(attention_mask)
+            pos_offset = (
+                cache.index if row_off is None else cache.index + row_off
+            )
+
+        if self.qk_norm:
+            head_norm = lambda name: DistributedLayerNorm(  # noqa: E731
+                epsilon=self.qk_norm_epsilon, rms=True, use_bias=False,
+                name=name,
+            )
+            with jax.named_scope("smp/attn/qk_norm"):
+                q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
+
+        with jax.named_scope("smp/attn/core"):
+            ctx = self._attend(q, k, v, cache, pos_offset, attention_mask, xs)
+        with jax.named_scope("smp/attn/out"):
+            return self._project_out(ctx, hidden)
+
+    @nn.nowrap
+    def _project_qkv(self, hidden, cross_states):
         H, hd, D = self.num_attention_heads, self.attention_head_size, self.hidden_size
         B, T = hidden.shape[0], hidden.shape[1]
         dtype = self.dtype or hidden.dtype
-        memory_opt = _cfg("optimize", "speed") == "memory"
         init = _init(self.initializer_range)
 
         if self.cross_attention:
@@ -488,44 +530,14 @@ class DistributedAttentionLayer(nn.Module):
                     v = v + qkv_bias[2].astype(v.dtype)
 
         head_spec = (BATCH_AXES, CP_AXIS, TP_AXIS, None)
-        q = shard_activation(q, *head_spec)
-        k = shard_activation(k, *head_spec)
-        v = shard_activation(v, *head_spec)
+        return (shard_activation(q, *head_spec),
+                shard_activation(k, *head_spec),
+                shard_activation(v, *head_spec))
 
-        cache = None
-        pos_offset = 0
+    @nn.nowrap
+    def _attend(self, q, k, v, cache, pos_offset, attention_mask, xs):
+        hd, T = self.attention_head_size, q.shape[1]
         decode_mask = None
-        if self.decode and not self.cross_attention:
-            from smdistributed_modelparallel_tpu.nn.utils import (
-                DecodeKVCache,
-                pad_row_offset,
-            )
-
-            if self.causal_mask_size is None:
-                raise SMPValidationError(
-                    "decode=True requires causal self-attention "
-                    "(causal_mask_size set); BERT-family encoders do not "
-                    "decode."
-                )
-            cache = DecodeKVCache(
-                self, (B, self.decode_cache_len, k.shape[2], hd), k.dtype
-            )
-
-            # Left-padded prompts: each row's absolute positions shift
-            # back by its pad count (see nn/utils.pad_row_offset).
-            row_off = pad_row_offset(attention_mask)
-            pos_offset = (
-                cache.index if row_off is None else cache.index + row_off
-            )
-
-        if self.qk_norm:
-            head_norm = lambda name: DistributedLayerNorm(  # noqa: E731
-                epsilon=self.qk_norm_epsilon, rms=True, use_bias=False,
-                name=name,
-            )
-            with jax.named_scope("smp/attn/qk_norm"):
-                q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
-
         if self.rotary_dim is not None and not self.cross_attention:
             # The cache stores POST-rotary K: chunk q/k rotate once at
             # their absolute (cache-slot) positions.
@@ -596,7 +608,7 @@ class DistributedAttentionLayer(nn.Module):
 
             q = _quant.fake_quant(q, "attn_q.x")
             k = _quant.fake_quant(k, "attn_k.x")
-        ctx = attention_core(
+        return attention_core(
             q, k, v,
             causal=causal,
             window=self.window_size if decode_mask is None else None,
@@ -613,6 +625,12 @@ class DistributedAttentionLayer(nn.Module):
             use_pallas=_cfg("use_pallas_kernels", True),
         )
 
+    @nn.nowrap
+    def _project_out(self, ctx, hidden):
+        H, hd, D = self.num_attention_heads, self.attention_head_size, self.hidden_size
+        dtype = self.dtype or hidden.dtype
+        memory_opt = _cfg("optimize", "speed") == "memory"
+        init = _init(self.initializer_range)
         if self.head_gate:
             gate_kernel = self.param(
                 "gate/kernel", partitioned(init, (None, TP_AXIS)), (D, H),
@@ -874,10 +892,11 @@ class DistributedTransformerLayer(nn.Module):
     moe_norm_topk: bool = True
     moe_routed_scaling: float = 1.0
     # ... and the kind's name, which a patterned stack always sets: the
-    # layer's ops then trace under ``smp/layer/<kind>``, its attention
-    # under ``smp/attn/block_diffusion``, ``smp/attn/window`` or
-    # ``smp/attn/full`` and, inside that, the q/k norms under
-    # ``smp/attn/qk_norm``.
+    # layer's ops trace under ``smp/layer/<kind>`` (``smp/layer/block``
+    # with no kind), its attention under ``smp/attn/block_diffusion``,
+    # ``smp/attn/window`` or ``smp/attn/full`` (inside that the parts
+    # ``smp/attn/{qkv,qk_norm,core,out}``) and a dense feed-forward under
+    # ``smp/mlp/dense``; the norms stay charged to their layer.
     kind: Optional[str] = None
     decode: bool = False
     decode_cache_len: Optional[int] = None
@@ -886,7 +905,8 @@ class DistributedTransformerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, cross_states=None, attention_mask=None, xs=None):
-        with _named_scope(self.kind and f"smp/layer/{self.kind}"):
+        with jax.named_scope("smp/layer/block" if self.kind is None
+                             else f"smp/layer/{self.kind}"):
             return self._block(hidden, cross_states, attention_mask, xs)
 
     @nn.nowrap
@@ -937,10 +957,10 @@ class DistributedTransformerLayer(nn.Module):
         attention = attn
 
         def attn(*args, **kwargs):
-            with _named_scope(self.kind and (
+            with jax.named_scope(
                     "smp/attn/block_diffusion" if self.block_diffusion
                     else "smp/attn/window" if self.window_size
-                    else "smp/attn/full")):
+                    else "smp/attn/full"):
                 return attention(*args, **kwargs)
 
         if self.num_experts > 0 and self.moe_dropless:
@@ -992,6 +1012,11 @@ class DistributedTransformerLayer(nn.Module):
                 dtype=self.dtype,
                 name="output",
             )
+            dense = mlp
+
+            def mlp(h):
+                with jax.named_scope("smp/mlp/dense"):
+                    return dense(h)
 
         res_dtype = jnp.float32 if self.fp32_residual_addition else hidden.dtype
         x = hidden
@@ -1354,6 +1379,12 @@ class DistributedTransformer(nn.Module):
         return built
 
     def __call__(self, hidden, cross_states=None, attention_mask=None):
+        # Innermost on the scans' own work alone: a layer's slice of the
+        # stacked parameters, the residuals stacked for the backward pass.
+        with jax.named_scope("smp/model/stack"):
+            return self._stack(hidden, cross_states, attention_mask)
+
+    def _stack(self, hidden, cross_states, attention_mask):
         if self.layer_pattern is not None:
             carry = (hidden, cross_states, attention_mask)
             for module, xs in self.segments:
@@ -1608,6 +1639,10 @@ class DistributedTransformerLMHead(nn.Module):
     # -- pipeline decomposition (PipelineSpec protocol) -----------------
 
     def embed(self, input_ids, token_type_ids=None, attention_mask=None):
+        with jax.named_scope("smp/model/embed"):
+            return self._embed(input_ids, token_type_ids, attention_mask)
+
+    def _embed(self, input_ids, token_type_ids, attention_mask):
         x = self.word_embedding(input_ids)
         if self.use_positional_embedding:
             if self.position_ids_from_padding is not None:
@@ -1666,7 +1701,8 @@ class DistributedTransformerLMHead(nn.Module):
             record_lm_head_positions(n, T)
             x = x[:, :n]
         if self.final_layernorm or self.pre_layernorm:
-            x = self.ln_f(x)
+            with jax.named_scope("smp/head/norm"):
+                x = self.ln_f(x)
         if not self.add_lm_head:
             return x
         if targets is not None and self.tie_input_output_embedding:
@@ -1681,20 +1717,8 @@ class DistributedTransformerLMHead(nn.Module):
                 x, self.word_embedding.embedding, targets,
                 label_smoothing=self.label_smoothing,
             )
-        if self.tie_input_output_embedding:
-            logits = self.word_embedding.attend(x)
-        else:
-            from smdistributed_modelparallel_tpu.utils.telemetry import (
-                record_lm_head_vocab_shards,
-            )
-
-            logits = self.lm_head(x)
-            vocab_axes, shards = _lm_head_vocab_split(self.vocab_size)
-            record_lm_head_vocab_shards(shards)
-            if vocab_axes is not None:
-                logits = shard_activation(
-                    logits, BATCH_AXES, CP_AXIS, vocab_axes
-                )
+        with jax.named_scope("smp/head/logits"):
+            logits = self._logits(x)
         if targets is None:
             return logits
         from smdistributed_modelparallel_tpu.nn.cross_entropy import (
@@ -1704,6 +1728,22 @@ class DistributedTransformerLMHead(nn.Module):
         return masked_vocab_parallel_cross_entropy(
             logits, targets, label_smoothing=self.label_smoothing
         )
+
+    def _logits(self, x):
+        if self.tie_input_output_embedding:
+            return self.word_embedding.attend(x)
+        from smdistributed_modelparallel_tpu.utils.telemetry import (
+            record_lm_head_vocab_shards,
+        )
+
+        logits = self.lm_head(x)
+        vocab_axes, shards = _lm_head_vocab_split(self.vocab_size)
+        record_lm_head_vocab_shards(shards)
+        if vocab_axes is not None:
+            logits = shard_activation(
+                logits, BATCH_AXES, CP_AXIS, vocab_axes
+            )
+        return logits
 
     def __call__(self, input_ids, token_type_ids=None, attention_mask=None,
                  targets=None):

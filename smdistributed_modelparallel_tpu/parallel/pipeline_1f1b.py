@@ -446,14 +446,15 @@ def _zb_segments(f_span, b_span, w_span, n_ticks):
 
 
 def _zb_segment_region(do_fwd, do_bwd, do_wgt):
-    """Profiler region name for a ZB schedule segment."""
+    """Profiler region name for a ZB schedule segment (``_zb_segments``
+    keeps none without a pass, so the last is the weight-only drain)."""
     if do_fwd and not do_bwd:
         return "smp/pipeline/warmup"
     if do_fwd:
         return "smp/pipeline/steady"
     if do_bwd:
         return "smp/pipeline/cooldown"
-    return "smp/pipeline/cooldown_weight" if do_wgt else "smp/pipeline/idle"
+    return "smp/pipeline/cooldown_weight"
 
 
 def _run_in_span(in_span, spans_all_ticks, substep, ops):
@@ -1233,8 +1234,14 @@ def _finish_run(run, to_layers, dlay, drep, dembed, dsides, losses, outs):
     """After the last tick: the embedding backward from the collected
     stage-0 input cotangents, the stage-accumulated layer gradients back
     to [L, ...] (``to_layers``: the executor's, per leaf), and the full
-    gradient tree in the parameters' dtypes. Returns what
-    ``pipeline_1f1b`` does."""
+    gradient tree in the parameters' dtypes, all under the scope
+    ``smp/pipeline/finish``. Returns what ``pipeline_1f1b`` does."""
+    with named_region("smp/pipeline/finish"):
+        return _finish(run, to_layers, dlay, drep, dembed, dsides, losses,
+                       outs)
+
+
+def _finish(run, to_layers, dlay, drep, dembed, dsides, losses, outs):
     model, module, spec = run.model, run.module, run.spec
 
     def embed_bwd(acc, xs):

@@ -667,7 +667,7 @@ class StepFunction:
             model._begin_step_trace(run_params, rngs)
             try:
                 args, kwargs = reconstruct(mb_scan_leaves, bcast_leaves)
-                out = fn(*args, **kwargs)
+                out = _user(fn, args, kwargs)
             finally:
                 loss = model._end_step_trace()
             if has_backward and loss is None:
@@ -960,7 +960,7 @@ class StepFunction:
                 model._begin_capture(out_aval)
                 try:
                     args, kwargs = reconstruct(mb_leaves, bcast_leaves)
-                    fn(*args, **kwargs)
+                    _user(fn, args, kwargs)
                 finally:
                     model._end_step_trace()
                 captured = model._last_captured
@@ -996,7 +996,7 @@ class StepFunction:
                     model._begin_force(run_p, rngs, out)
                     try:
                         args, kwargs = reconstruct(mb_leaves, bcast_leaves)
-                        user_out = fn(*args, **kwargs)
+                        user_out = _user(fn, args, kwargs)
                     finally:
                         loss = model._end_step_trace()
                     if loss is None:
@@ -1054,7 +1054,7 @@ class StepFunction:
                     model._begin_force(run_p, rngs, out)
                     try:
                         args, kwargs = reconstruct(mb_leaves, bcast_leaves)
-                        user_out = fn(*args, **kwargs)
+                        user_out = _user(fn, args, kwargs)
                     finally:
                         loss = model._end_step_trace()
                     if has_backward and loss is None:
@@ -1281,7 +1281,7 @@ def _make_runner(step_impl, name, scan_meta, fused_update, model,
                                 source = "disk_cache"
                                 run.hlo_audit = cached_audit
                         if compiled is None:
-                            compiled = lowered.compile()
+                            compiled = _compile_keyed_on_names(lowered)
                     t_compile = time.perf_counter() - t0
                     state.last_compile_report = one_time_compile_report(
                         name, compiled
@@ -1362,6 +1362,36 @@ def _make_runner(step_impl, name, scan_meta, fused_update, model,
     run.raw_divisor = raw_divisor if fused_update is not None else None
     run.health_schema = schema_box
     return run
+
+
+_CACHE_KEY_METADATA = "jax_compilation_cache_include_metadata_in_key"
+
+
+def _compile_keyed_on_names(lowered):
+    """``lowered.compile()``; with the op index on, under a persistent
+    cache key that holds the module's metadata too. JAX keys its compile
+    cache on the module stripped of debug info, so a cache directory an
+    older build filled hands this build that build's executable, the same
+    program under the older ``op_name``s, and every scope added since
+    reads nothing (``hlo_audit.op_index`` is built from those names). A
+    second run of one tree lowers the same metadata and still hits."""
+    if not (hlo_audit.enabled() and hasattr(jax.config, _CACHE_KEY_METADATA)):
+        return lowered.compile()
+    before = getattr(jax.config, _CACHE_KEY_METADATA)
+    jax.config.update(_CACHE_KEY_METADATA, True)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update(_CACHE_KEY_METADATA, before)
+
+
+def _user(fn, args, kwargs):
+    """The user's step function as the step program traces it, under the
+    outermost scope of the tree (``utils/profiling.SCOPES``): an operation
+    whose innermost scope is this one ran code the user wrote (a loss
+    written out in the step function), not the library's."""
+    with profiling.named_region("smp/step/user"):
+        return fn(*args, **kwargs)
 
 
 def _accumulate(acc, grads):

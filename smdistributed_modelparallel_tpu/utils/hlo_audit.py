@@ -352,6 +352,125 @@ def scope_of(op_name):
     return found[-1] if found else None
 
 
+#: The scope round the user's step function (``step.py``): outermost, and
+#: alone on what the step function itself wrote.
+USER_SCOPE = "smp/step/user"
+
+# A kernel the TPU compiler makes of an instruction by itself (each
+# ``lax.ragged_dot`` and the group metadata it reads): a Mosaic custom-call
+# whose ``op_name`` is the compiler's own word for it, with no path of the
+# program in it (``metadata={op_name="ragged-dot-none"}``).
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+# Instructions that only move what they are given: a nameless kernel's
+# neighbours are looked for through them.
+_PASS_THROUGH_RE = re.compile(
+    r"^(?:\([^()]*\)|\S+)\s+(?:get-tuple-element|bitcast|copy|tuple|"
+    r"reshape|transpose|opt-barrier|copy-start|copy-done|slice-start|"
+    r"slice-done)\(")
+# Instructions that take no time of their own: nobody asks what they are
+# near.
+_FREE_RE = re.compile(
+    r"^(?:\([^()]*\)|\S+)\s+(?:parameter|constant|get-tuple-element|"
+    r"tuple|bitcast)\(")
+_HOPS = 4
+
+
+def _compiler_kernel(line, op_name):
+    """The ``kernel`` mark of an instruction the compiler turned into a
+    kernel of its own, from what it keeps on the line; ``None`` for every
+    other instruction (a Pallas kernel's ``op_name`` is a path)."""
+    if _KERNEL_TARGET not in line or "/" in op_name:
+        return None
+    if "ragged_dot_tiling=" in line:      # its frontend attribute
+        return "ragged_dot"
+    return op_name.replace("-", "_") or "unnamed"
+
+
+def _common_scopes(names, records, through, past_kernels, seen=None,
+                   hops=_HOPS):
+    """The scope path common to the marked instructions among ``names``
+    (the longest common prefix of their ``scopes``), looking through an
+    unmarked instruction that only moves data, and through a kernel of
+    the compiler's that has no scopes (or, with ``past_kernels``, any: what
+    it inherited is not its neighbour's own), to that one's neighbours in
+    the same direction (``through``: name -> names), ``hops`` deep."""
+    seen = set() if seen is None else seen
+    found = []
+    for name in names:
+        if name in seen:
+            continue
+        seen.add(name)
+        rec = records.get(name)
+        if rec is None:
+            continue
+        scopes = rec.get("scopes") or (
+            (rec["scope"],) if rec.get("scope") else ())
+        if hops and ("kernel" in rec and (past_kernels or not scopes)
+                     or not scopes and rec.get("_moves")):
+            scopes = _common_scopes(through.get(name, ()), records, through,
+                                    past_kernels, seen, hops - 1)
+        if scopes:
+            found.append(tuple(scopes))
+    if not found:
+        return ()
+    common = found[0]
+    for other in found[1:]:
+        n = 0
+        while n < min(len(common), len(other)) and common[n] == other[n]:
+            n += 1
+        common = common[:n]
+    return common
+
+
+def _neighbours_scopes(name, records, operands, users):
+    """The scopes common to an instruction's marked operands, else (none,
+    or the user's function alone) to its marked users."""
+    scopes = _common_scopes(
+        operands.get(name, ()), records, operands, past_kernels=True)
+    if not scopes or scopes[-1] == USER_SCOPE:
+        after = _common_scopes(
+            users.get(name, ()), records, users, past_kernels=False)
+        if len(after) > len(scopes):
+            scopes = after
+    return scopes
+
+
+def _inherit_kernel_scopes(records, kernels, operands, users):
+    """Give each compiler-made kernel (``kernels``: names, in text order)
+    the scopes of the code that made it: those common to its marked
+    operands, else (none, or the user's function alone) to its marked
+    users. Operands go first: a grouped product's rows are made ready by
+    the scope that calls it (the masked gather, the gated activation),
+    while its result may leave that scope at once (the second product
+    feeds ``smp/moe/combine``'s kernel); the products whose rows come
+    straight from a buffer the compiler allocated are found from their
+    users. Later kernels first, and among users a kernel counts with what
+    it inherited: the group metadata, whose only operand is a parameter,
+    takes the scopes of the products it feeds."""
+    for name in reversed(kernels):
+        scopes = _neighbours_scopes(name, records, operands, users)
+        if not scopes:
+            continue
+        rec = records[name]
+        rec["scope"] = scopes[-1]
+        rec["inherited"] = True
+        if len(scopes) > 1:
+            rec["scopes"] = scopes
+
+
+def _mark_what_the_unscoped_are_near(records, loose, operands, users):
+    """``near`` on each instruction the compiler made with no name of the
+    program's at all (``loose``: a layout copy, an asynchronous copy's
+    halves, a relayout fused by the compiler): the scopes of its
+    neighbours by the kernels' rule. It stays unscoped (``scope`` is
+    ``None``: no reader of scopes counts it); ``seconds_by_scope`` says
+    beside which scopes the unscoped seconds lie."""
+    for name in loose:
+        near = _neighbours_scopes(name, records, operands, users)
+        if near:
+            records[name]["near"] = near
+
+
 def _collective_axis(op, line, mesh, maps):
     use_global = "use_global_device_ids=true" in line
     if op == "collective-permute":
@@ -381,11 +500,23 @@ def op_records(hlo_text, mesh=None):
     one whose ``op_name`` holds no marker takes the phase of its first
     operand that has one. Both hold for an executable that a compile
     cache filled by an older build hands back, whose metadata is that
-    build's and lacks any scope added since."""
+    build's and lacks any scope added since.
+
+    A kernel the compiler made of one instruction by itself (each
+    ``lax.ragged_dot``; ``_compiler_kernel``) keeps no path of the program
+    in its ``op_name``. Its record is marked ``kernel`` (``"ragged_dot"``)
+    and takes the scopes of the code that made it from its neighbours
+    (``_inherit_kernel_scopes``: ``scope`` and ``scopes`` as if they were
+    its own, and ``inherited: True``); with no marked neighbour it stays
+    without a scope. Every other instruction that has no scope and can
+    take time keeps ``scope: None`` and is told what it is ``near`` (the
+    same rule's answer, for ``seconds_by_scope``'s account of the
+    unscoped seconds)."""
     records = {}
     maps = _mesh_coord_maps(mesh)
     comp = None
     comp_op_name = {}    # computation -> its root's op_name, else the first
+    kernels, loose, operands, users = [], [], {}, {}
     for lineno, line in enumerate(hlo_text.splitlines()):
         header = _COMP_HEADER_RE.match(line)
         if header is not None:
@@ -414,16 +545,32 @@ def op_records(hlo_text, mesh=None):
                "scope": scopes[-1] if scopes else None}
         if len(scopes) > 1:
             rec["scopes"] = scopes
-        if rec["phase"] == "other" and named is not None:
+        refs = _REF_RE.findall(line[named.end():]) if named else ()
+        if rec["phase"] == "other":
             # No marker of its own (a compiler-made copy, the add that
             # accumulates gradients): it works on what its first marked
             # operand produced. Operands come first in the text, so this
             # follows a chain (copy of get-tuple-element of a while).
-            for ref in _REF_RE.findall(line[named.end():]):
+            for ref in refs:
                 phase = records.get(ref, rec)["phase"]
                 if phase != "other":
                     rec["phase"] = phase
                     break
+        kernel = _compiler_kernel(line, op_name)
+        if kernel is not None:
+            rec["kernel"] = kernel
+            kernels.append(name)
+        if named is not None:
+            # What a nameless instruction's neighbours are found from: who
+            # reads whom.
+            operands[name] = refs
+            for ref in refs:
+                users.setdefault(ref, []).append(name)
+            rhs = line[named.end():]
+            if not scopes and kernel is None and not _FREE_RE.match(rhs):
+                loose.append(name)
+            if not scopes and _PASS_THROUGH_RE.match(rhs):
+                rec["_moves"] = True
         if coll is not None:
             op = coll.group("op")
             if coll.group("suffix") == "-done":
@@ -435,6 +582,10 @@ def op_records(hlo_text, mesh=None):
                 rec.update(op=op, bytes=_shape_bytes(coll.group("shape")),
                            axis=_collective_axis(op, line, mesh, maps))
         records[name] = rec
+    _inherit_kernel_scopes(records, kernels, operands, users)
+    _mark_what_the_unscoped_are_near(records, loose, operands, users)
+    for rec in records.values():
+        rec.pop("_moves", None)
     return records
 
 
@@ -1547,7 +1698,7 @@ def audit_compiled(name, compiled, key=None, params=None,
         # pass over a candidate executable (exec-cache load) must not
         # register a program that may then be rejected — republish()
         # registers it after the veto point.
-        audits[name] = audit
+        _register(audit)
         _publish(audit)
     if persist:
         _persist(audit)
@@ -1610,15 +1761,21 @@ def republish(audit, seconds=0.0):
     with the program fingerprint. The executable-cache hit path calls
     this AFTER fingerprint verification so a warm start never silently
     bypasses the drift gates."""
-    audits[audit.name] = audit
+    _register(audit)
     _publish(audit)
     _persist(audit)
     _count_audit(audit, seconds)
 
 
-#: Latest audit per program name (``step``, ``step_pipeline_1f1b``, ...).
-#: Process-global: it outlives ``smp.shutdown()``.
+#: Latest audit per program name (``step``, ``step_pipeline_1f1b``, ...),
+#: the program audited last at the end. Process-global: it outlives
+#: ``smp.shutdown()``.
 audits = {}
+
+
+def _register(audit):
+    audits.pop(audit.name, None)
+    audits[audit.name] = audit
 
 
 def op_index(name):
@@ -1628,6 +1785,84 @@ def op_index(name):
     that program was never audited (``SMP_HLO_AUDIT=off``)."""
     audit = audits.get(name)
     return audit.op_index if audit is not None else {}
+
+
+def step_program():
+    """The name of the ``step*`` program audited last (``step``,
+    ``step_pipeline_1f1b``, ...), or ``None``."""
+    names = [n for n in audits if n.startswith("step")]
+    return names[-1] if names else None
+
+
+def seconds_by_scope(op_seconds, program=None):
+    """The one join of a device's time to the program's scope tree.
+
+    ``op_seconds``: ``{instruction name: self seconds}`` of one device,
+    whatever produced them (a trace reduced by the benchmark, or by
+    ``profiling.device_op_seconds``); ``program``: an op index
+    (``op_records``), the name of an audited program, or ``None`` for the
+    ``step*`` program audited last. Returns ``None`` without an index,
+    else one record whose parts sum to ``busy_s``:
+
+    - ``busy_s``: the sum of ``op_seconds``;
+    - ``tree``: self seconds by scope path, the outermost-first tuple of
+      an instruction's ``scopes``, so the paths under any prefix, or that
+      hold any one scope, sum to that subtree;
+    - ``user_only_s``: the seconds whose innermost scope is ``USER_SCOPE``:
+      code the step function wrote itself (its part of ``tree``);
+    - ``unscoped``: ``{"seconds", "top", "near"}``, the seconds under no
+      scope (an instruction the index lacks among them), their ten largest
+      instructions as ``[name, seconds, phase]``, and those seconds by the
+      scope path the index says each is ``near`` (``()``: near none);
+    - ``by_phase``: seconds by ``phase_of``'s phases (``other`` for a name
+      the index lacks);
+    - ``by_axis``: a collective's seconds by the mesh axis of its groups;
+    - ``kernels``: seconds by the index's ``kernel`` mark.
+    """
+    if program is None:
+        program = step_program()
+    index = op_index(program) if isinstance(program, str) else program
+    if not index:
+        return None
+    tree, by_phase, by_axis, kernels, loose, near = {}, {}, {}, {}, [], {}
+    user_only = 0.0
+    for name, seconds in op_seconds.items():
+        rec = index.get(name) or {}
+        phase = rec.get("phase", "other")
+        by_phase[phase] = by_phase.get(phase, 0.0) + seconds
+        if "axis" in rec:
+            by_axis[rec["axis"]] = by_axis.get(rec["axis"], 0.0) + seconds
+        if "kernel" in rec:
+            kernels[rec["kernel"]] = kernels.get(rec["kernel"], 0.0) + seconds
+        path = tuple(rec.get("scopes") or
+                     ((rec["scope"],) if rec.get("scope") else ()))
+        if not path:
+            loose.append([name, seconds, phase])
+            beside = tuple(rec.get("near", ()))
+            near[beside] = near.get(beside, 0.0) + seconds
+            continue
+        tree[path] = tree.get(path, 0.0) + seconds
+        if path[-1] == USER_SCOPE:
+            user_only += seconds
+    loose.sort(key=lambda row: -row[1])
+    return {
+        "busy_s": sum(op_seconds.values()),
+        "tree": tree,
+        "user_only_s": user_only,
+        "unscoped": {"seconds": sum(row[1] for row in loose),
+                     "top": loose[:10], "near": near},
+        "by_phase": by_phase,
+        "by_axis": by_axis,
+        "kernels": kernels,
+    }
+
+
+def seconds_under(tree, *scopes):
+    """Seconds of ``seconds_by_scope``'s ``tree`` in the paths that hold a
+    scope starting with one of ``scopes`` (``"smp/attn/"``: every kind of
+    attention, whatever layer or pipeline tick lies round it)."""
+    return sum(seconds for path, seconds in tree.items()
+               if any(scope.startswith(scopes) for scope in path))
 
 
 def of_step_function(step_fn):

@@ -36,16 +36,17 @@ ad-hoc probes. Four cooperating pieces:
      ``serve/decode_step``, ``serve/prefill_chunk``.
 
    ``named_region(name)`` is the in-graph twin: a ``jax.named_scope``
-   whose name lands in the compiled HLO's op metadata, tagging pipeline
-   warmup/steady/cooldown phases, per-tick sub-steps — with the pass
-   coordinate under split-backward schedules: ``smp/pipeline/tick_fwd``,
-   ``tick_bwd`` (fused executors) vs ``tick_bwd_input`` /
-   ``tick_bwd_weight`` (zero-bubble), plus the ZB-only
-   ``cooldown_weight`` drain segment — the optimizer update
-   (``smp/optimizer/update``), gradient accumulation
-   (``smp/step/accumulate``) and the half-precision parameter cast
-   (``smp/step/cast_params``) inside the device timeline.
-   ``hlo_audit.op_index`` reads them back per instruction.
+   whose name lands in the compiled HLO's op metadata. ``SCOPES`` lists
+   every one the package writes (``smp/<subsystem>/<name>``) with the
+   round it is put: the user's step function outermost
+   (``smp/step/user``), embeddings, head and loss, each layer, its
+   attention and its parts, the dense feed-forward, the expert layer's
+   parts, the pipeline executors' segments and per-tick sub-steps (with
+   the pass coordinate under split-backward schedules: ``tick_bwd`` vs
+   ``tick_bwd_input`` / ``tick_bwd_weight``), gradient accumulation, the
+   half-precision parameter cast and the optimizer update.
+   ``hlo_audit.op_index`` reads them back per instruction, and
+   ``hlo_audit.seconds_by_scope`` joins a device's time to the tree.
 
 2. **On-demand capture** — ``SMP_PROFILE=steps=N:M`` brackets
    ``jax.profiler.start_trace``/``stop_trace`` around exactly steps
@@ -54,7 +55,14 @@ ad-hoc probes. Four cooperating pieces:
    on a live run. Disarmed cost is one attribute test per step edge; the
    start/stop overhead of an actual capture is recorded in
    ``smp_profile_overhead_seconds_total`` so always-on cost stays
-   measurably zero.
+   measurably zero. When a window stops, the program reduces its own
+   trace (``device_op_seconds``: the first device's op line, self
+   times), joins it to the step's op index and writes
+   ``scope_report.json`` beside the ``.xplane.pb``: busy seconds, self
+   seconds by scope path, the user's own, the unscoped with their ten
+   largest ops, by phase, by mesh axis, by compiler-made kernel; the
+   tree's first two levels are logged as a table, and the reduction is
+   charged to the same overhead counter.
 
 3. **Roofline / MFU attribution** — ``roofline(...)`` joins compiled-HLO
    ``cost_analysis``/``memory_analysis`` (FLOPs, bytes accessed) with a
@@ -110,6 +118,79 @@ PEAK_GBPS_ENV = "SMP_PEAK_GBPS"
 #   host phases:    smp_phase/<name>   (region())
 #   in-graph scopes: smp/<subsystem>/<name>  (named_region())
 REGION_PREFIX = "smp_phase/"
+
+
+#: Every in-graph scope the package writes (``named_region`` /
+#: ``jax.named_scope``; ``tests/test_profiling.py`` greps), with the round
+#: each is put. ``hlo_audit.op_index`` gives every instruction of a
+#: compiled step the scopes round it, outermost first, and
+#: ``hlo_audit.seconds_by_scope`` joins a device's time to them.
+SCOPES = {
+    "smp/step/user": "the user's step function as @smp.step traces it, "
+                     "outermost; innermost only on what the function "
+                     "wrote itself (a loss written out there)",
+    "smp/step/cast_params": "the half-precision copy of the parameters "
+                            "the forward reads",
+    "smp/step/accumulate": "a microbatch's gradients added into the "
+                           "accumulator",
+    "smp/optimizer/update": "the optimizer update fused into the step",
+    "smp/model/embed": "token, position and type embeddings, their norm "
+                       "and dropout",
+    "smp/model/stack": "the layer stack at pp = 1; innermost on the "
+                       "scans' own work (a layer's slice of the stacked "
+                       "parameters, the residuals stacked for the "
+                       "backward pass)",
+    "smp/head/norm": "the final norm",
+    "smp/head/logits": "the LM head's product (tied attend, untied "
+                       "lm_head, its vocabulary split)",
+    "smp/head/loss": "nn/cross_entropy's entry points and "
+                     "nn/diffusion.masked_diffusion_loss",
+    "smp/layer/<kind>": "a layer of a patterned stack, by its kind's name",
+    "smp/layer/block": "a layer of a stack with no kind",
+    "smp/attn/full": "a layer's attention with no window",
+    "smp/attn/window": "a layer's attention under a window",
+    "smp/attn/block_diffusion": "a layer's attention under the "
+                                "block-diffusion mask",
+    "smp/attn/qkv": "inside any attention: the q/k/v projections",
+    "smp/attn/qk_norm": "inside any attention: the per-head norms of q "
+                        "and k",
+    "smp/attn/core": "inside any attention: rotary, the cache, the flash "
+                     "kernels or the plain path",
+    "smp/attn/out": "inside any attention: the head gate and the output "
+                    "projection",
+    "smp/mlp/dense": "the dense feed-forward of a layer",
+    "smp/moe/route": "dropless expert layer: router product, softmax, "
+                     "top-k",
+    "smp/moe/dispatch": "dropless expert layer: the sort by held expert "
+                        "and each chunk's gathers",
+    "smp/moe/experts": "dropless expert layer: each chunk's grouped gated "
+                       "FFN, forward and written-out backward",
+    "smp/moe/shared": "dropless expert layer: the shared expert",
+    "smp/moe/combine": "dropless expert layer: the routed rows summed "
+                       "back to their tokens, the sum with the shared "
+                       "expert, the final cast",
+    "smp/pipeline/embed": "pipeline executors: the embedding of every "
+                          "microbatch before the tick loop",
+    "smp/pipeline/head": "pipeline executors: head and loss of a "
+                         "microbatch on the last stage's output",
+    "smp/pipeline/tick_fwd": "a tick's forward sub-step",
+    "smp/pipeline/tick_bwd": "a tick's backward sub-step (fused "
+                             "executors)",
+    "smp/pipeline/tick_bwd_input": "zero-bubble: a tick's input-gradient "
+                                   "sub-step",
+    "smp/pipeline/tick_bwd_weight": "zero-bubble: a tick's "
+                                    "weight-gradient sub-step",
+    "smp/pipeline/warmup": "the tick loop's forward-only segment",
+    "smp/pipeline/steady": "the tick loop's segment with forward and "
+                           "backward sub-steps",
+    "smp/pipeline/cooldown": "the tick loop's backward-only segment",
+    "smp/pipeline/cooldown_weight": "zero-bubble: the segment that "
+                                    "drains weight gradients",
+    "smp/pipeline/fill_drain": "the fill-drain executor's tick loop",
+    "smp/pipeline/finish": "1F1B executors, after the last tick: the "
+                           "embedding's backward over the microbatches "
+                           "and the gradient tree laid out by layer",
+}
 
 
 def _timeline():
@@ -265,6 +346,7 @@ class ProfileCapture:
         self._installed = False
         self.active = False
         self.last_window = None      # (first, last) of the last capture
+        self.last_report = None      # path of its scope_report.json
         self._started_at = None
         self._last_step = None       # most recent step edge seen
         self._forced_dir = None      # per-capture base dir override
@@ -419,6 +501,15 @@ class ProfileCapture:
                 "profiler capture stopped: steps %d..%d -> %s",
                 first, step, self.rank_dir(),
             )
+            # The window's own report: after stop_trace, outside any
+            # step, charged to the capture's overhead like its start/stop.
+            t0 = time.perf_counter()
+            try:
+                self.last_report = write_scope_report(
+                    self.rank_dir(), window=(first, step))
+            except Exception as e:  # a report must never fail the run
+                logger.warning("scope report of the capture failed: %s", e)
+            self._record_overhead(time.perf_counter() - t0)
             self._forced_dir = None
 
     @staticmethod
@@ -446,6 +537,7 @@ class ProfileCapture:
         self._window = None
         self._sig_request = False
         self.last_window = None
+        self.last_report = None
         self._started_at = None
         self._last_step = None
         self._forced_dir = None
@@ -453,6 +545,153 @@ class ProfileCapture:
 
 capture = ProfileCapture()
 atexit.register(capture.stop_if_active)
+
+
+# ----------------------------------------------------------------------
+# A capture's own report: device time by scope (scope_report.json)
+# ----------------------------------------------------------------------
+
+REPORT_NAME = "scope_report.json"
+_OP_LINE = "XLA Ops"
+
+
+def _self_seconds(events):
+    """``{name: seconds}`` of one line's ``(name, start_ns, dur_ns)``
+    events, each charged its duration less what the events nested inside
+    it cover (a ``while`` is left what its body does not take), so the
+    values sum to the line's busy time."""
+    totals = {}
+    stack = []          # [end, name, self_ns]
+
+    def close(done):
+        totals[done[1]] = totals.get(done[1], 0) + done[2]
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][0] <= start:
+            close(stack.pop())
+        if stack:
+            stack[-1][2] -= min(dur, stack[-1][0] - start)
+        stack.append([start + dur, name, dur])
+    for done in stack:
+        close(done)
+    return {name: ns / 1e9 for name, ns in totals.items()}
+
+
+def device_op_seconds(xplane_path):
+    """``(plane name, {HLO instruction name: self seconds})`` of the first
+    device in a profiler trace (``.xplane.pb``): the op line of the
+    lowest-numbered ``/device:`` plane, as the device's own clock timed
+    it. A CPU run has no device plane; there the host plane's events that
+    carry an ``hlo_op`` stat stand in (the CPU client's threads), so the
+    join can be tried without a chip. ``(None, {})`` when neither is
+    there."""
+    from jax.profiler import ProfileData
+
+    def short(full):
+        # A device op's event name is its whole instruction text.
+        return full.split(" = ", 1)[0].lstrip("%")
+
+    def ordinal(plane):
+        tail = plane.name.rsplit(":", 1)[-1].split()
+        return int(tail[0]) if tail and tail[0].isdigit() else 0
+
+    planes = list(ProfileData.from_file(xplane_path).planes)
+    for plane in sorted((p for p in planes if p.name.startswith("/device:")),
+                        key=ordinal):
+        for line in plane.lines:
+            if line.name == _OP_LINE:
+                return plane.name, _self_seconds(
+                    (short(ev.name), int(ev.start_ns), int(ev.duration_ns))
+                    for ev in line.events)
+    seconds = {}
+    for plane in planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            ops = [(ev.name, int(ev.start_ns), int(ev.duration_ns))
+                   for ev in line.events
+                   if any(key == "hlo_op" for key, _ in ev.stats)]
+            for name, s in _self_seconds(ops).items():
+                seconds[name] = seconds.get(name, 0.0) + s
+        if seconds:
+            return plane.name, seconds
+    return None, {}
+
+
+def newest_xplane(trace_dir):
+    """The newest ``.xplane.pb`` a capture wrote under ``trace_dir``."""
+    import glob
+
+    found = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def scope_report(xplane_path, program=None):
+    """``hlo_audit.seconds_by_scope`` of a trace's first device as plain
+    data (``tree`` as rows ``{"path", "seconds"}``, largest first), with
+    the plane and program it joined; ``None`` where the trace holds no
+    device ops or no step program was audited (``SMP_HLO_AUDIT=off``)."""
+    from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+    if program is None:
+        program = hlo_audit.step_program()
+    plane, seconds = device_op_seconds(xplane_path)
+    joined = hlo_audit.seconds_by_scope(seconds, program) if seconds else None
+    if joined is None:
+        return None
+    tree = joined.pop("tree")
+    joined["unscoped"]["near"] = [
+        {"path": list(path), "seconds": s} for path, s in sorted(
+            joined["unscoped"]["near"].items(), key=lambda kv: -kv[1])]
+    return {
+        "version": 1, "trace": os.path.basename(xplane_path),
+        "device": plane, "program": program, **joined,
+        "tree": [{"path": list(path), "seconds": s} for path, s in
+                 sorted(tree.items(), key=lambda kv: -kv[1])],
+    }
+
+
+def scope_table(report):
+    """The first two levels of a report's tree as text: one row a scope
+    path, its seconds (subtree) and share of busy, then the seconds under
+    no scope and the user's own."""
+    busy = report["busy_s"] or 1.0
+    levels = {}
+    for row in report["tree"]:
+        for n in range(1, min(2, len(row["path"])) + 1):
+            key = tuple(row["path"][:n])
+            levels[key] = levels.get(key, 0.0) + row["seconds"]
+    rows = [("  " * (len(path) - 1) + path[-1], s)
+            for path, s in sorted(levels.items())]
+    rows += [("(no scope)", report["unscoped"]["seconds"]),
+             ("(user's own code)", report["user_only_s"])]
+    width = max(len(name) for name, _ in rows)
+    return "\n".join(
+        f"{name:<{width}}  {s:10.4f} s  {100.0 * s / busy:6.2f} %"
+        for name, s in rows)
+
+
+def write_scope_report(trace_dir, window=None):
+    """Reduce the newest trace under ``trace_dir`` (``scope_report``),
+    write ``scope_report.json`` beside its ``.xplane.pb`` and log the
+    tree's first two levels. Returns the file's path, or ``None`` where
+    there was nothing to report."""
+    t0 = time.perf_counter()
+    path = newest_xplane(trace_dir)
+    report = scope_report(path) if path else None
+    if report is None:
+        return None
+    report["window"] = list(window) if window else None
+    report["reduce_seconds"] = time.perf_counter() - t0
+    out = os.path.join(os.path.dirname(path), REPORT_NAME)
+    with open(out, "w", encoding="utf-8") as f:
+        json.dump(report, f, indent=1)
+    logger.info(
+        "device time by scope, %s of program %s, steps %s (%.3f s busy) "
+        "-> %s\n%s", report["device"], report["program"], report["window"],
+        report["busy_s"], out, scope_table(report))
+    return out
 
 
 # ----------------------------------------------------------------------

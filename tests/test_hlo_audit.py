@@ -629,6 +629,326 @@ class TestOpIndex:
 
 
 # ----------------------------------------------------------------------
+# Kernels the compiler names itself, and the join of a trace to the tree
+# ----------------------------------------------------------------------
+
+_W = _J + "while/body/smp/step/user/layer/smp/layer/full/output/while/body/"
+_KERNEL = ('custom_call_target="tpu_custom_call", frontend_attributes={'
+           'mosaic_fusion_entry_point="true",ragged_dot_tiling="512,256,256"}'
+           ', metadata={op_name="ragged-dot-none"}')
+
+
+def _chunk_body(product_operands, product_user_scope,
+                product=_KERNEL, rows_scope="smp/moe/experts"):
+    """A chunk loop's body as the TPU compiler leaves it: the group
+    metadata kernel, its elements, the rows made ready under
+    ``rows_scope``, the grouped product, its user."""
+    return (
+        "%body (p: (f32[8], f32[8])) -> f32[8] {\n"
+        "  %sizes = s32[4]{0} parameter(0)\n"
+        "  %meta.1 = (s32[5]{0}, s32[1]{0}) custom-call(%sizes), "
+        'custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-metadata"}\n'
+        "  %gte.1 = s32[5]{0} get-tuple-element(%meta.1), index=0\n"
+        "  %weights = bf16[4,8,8]{2,1,0} get-tuple-element(%p), index=1\n"
+        "  %buffer = bf16[16,8]{1,0} custom-call(), "
+        'custom_call_target="AllocateBuffer"\n'
+        + _instr("rows.1", _W + rows_scope + "/select_n",
+                 "bf16[16,8]{1,0} fusion(%x), kind=kLoop")
+        + f"  %ragged-dot-none.7 = bf16[16,8]{{1,0}} custom-call(%gte.1, "
+        f"{product_operands}), {product}\n"
+        + _instr("user.1", _W + product_user_scope + "/add",
+                 "f32[16,8]{1,0} fusion(%ragged-dot-none.7), kind=kLoop")
+        + "}\n")
+
+
+class TestCompilerKernels:
+    """B of PR 39: a kernel the compiler made of one instruction keeps no
+    path in its ``op_name``; the index marks it and gives it the scopes
+    of the code that made it."""
+
+    def test_takes_the_scopes_of_the_rows_it_is_given_not_of_its_user(self):
+        """The second product of the expert FFN: its rows are made under
+        ``smp/moe/experts``, its result feeds ``smp/moe/combine``."""
+        index = hlo_audit.op_records(
+            _chunk_body("%rows.1, %weights", "smp/moe/combine"))
+        assert index["ragged-dot-none.7"] == {
+            "phase": "other", "kernel": "ragged_dot", "inherited": True,
+            "scope": "smp/moe/experts",
+            "scopes": ("smp/step/user", "smp/layer/full",
+                       "smp/moe/experts")}
+        # the rows and the user keep what their own op_name says
+        assert index["rows.1"]["scope"] == "smp/moe/experts"
+        assert index["user.1"]["scope"] == "smp/moe/combine"
+        assert "inherited" not in index["rows.1"]
+
+    def test_rows_from_a_buffer_of_the_compilers_are_found_from_the_user(
+            self):
+        index = hlo_audit.op_records(
+            _chunk_body("%buffer, %weights", "smp/moe/experts",
+                        rows_scope="smp/moe/dispatch"))
+        rec = index["ragged-dot-none.7"]
+        assert (rec["scope"], rec["inherited"]) == ("smp/moe/experts", True)
+        assert rec["scopes"][-2:] == ("smp/layer/full", "smp/moe/experts")
+
+    def test_the_metadata_kernel_is_found_through_what_it_feeds(self):
+        """Its operand is a parameter and its users are tuple elements:
+        looked through to the product, itself still unmarked, and on to
+        the product's user."""
+        index = hlo_audit.op_records(
+            _chunk_body("%rows.1, %weights", "smp/moe/experts"))
+        assert index["meta.1"]["kernel"] == "ragged_dot_metadata"
+        assert index["meta.1"]["scope"] == "smp/moe/experts"
+        assert index["meta.1"]["inherited"] is True
+        assert index["gte.1"]["scope"] is None      # only kernels inherit
+
+    def test_with_no_marked_neighbour_it_stays_unscoped(self):
+        text = (
+            "  %a = bf16[16,8]{1,0} parameter(0)\n"
+            f"  %ragged-dot-none.2 = bf16[16,8]{{1,0}} custom-call(%a), "
+            f"{_KERNEL}\n"
+            "  %b = bf16[16,8]{1,0} copy(%ragged-dot-none.2)\n")
+        index = hlo_audit.op_records(text)
+        assert index["ragged-dot-none.2"] == {
+            "phase": "other", "scope": None, "kernel": "ragged_dot"}
+        assert set(index["b"]) == {"phase", "scope"}
+
+    def test_neighbours_that_disagree_leave_what_they_share(self):
+        """Rows from one layer's scope and a user... both operands marked,
+        under different expert-layer scopes: the layer is what is left."""
+        text = _chunk_body("%rows.1, %rows.2", "smp/moe/combine").replace(
+            "  %ragged-dot-none.7", _instr(
+                "rows.2", _W + "smp/moe/dispatch/gather",
+                "bf16[16,8]{1,0} fusion(%x), kind=kLoop")
+            + "  %ragged-dot-none.7")
+        rec = hlo_audit.op_records(text)["ragged-dot-none.7"]
+        assert rec["scopes"] == ("smp/step/user", "smp/layer/full")
+        assert rec["scope"] == "smp/layer/full"
+
+    def test_user_code_alone_on_one_side_defers_to_the_other(self):
+        text = (
+            _instr("rows.1", _J + "smp/step/user/mul",
+                   "bf16[16,8]{1,0} fusion(%x), kind=kLoop")
+            + f"  %ragged-dot-none.3 = bf16[16,8]{{1,0}} custom-call("
+            f"%rows.1), {_KERNEL}\n"
+            + _instr("user.1", _J + "smp/step/user/smp/moe/experts/add",
+                     "f32[16,8]{1,0} fusion(%ragged-dot-none.3), kind=kLoop"))
+        rec = hlo_audit.op_records(text)["ragged-dot-none.3"]
+        assert rec["scopes"] == ("smp/step/user", "smp/moe/experts")
+
+    def test_a_compilers_copy_stays_unscoped_and_is_told_what_it_is_near(
+            self):
+        """Only kernels inherit: a layout copy beside the products, the
+        halves of an asynchronous copy and a fusion the compiler made with
+        no name inside keep ``scope: None`` (no reader of scopes counts
+        them anew) and carry ``near`` for the account of the unscoped."""
+        text = _chunk_body("%copy.5, %weights", "smp/moe/experts").replace(
+            "  %ragged-dot-none.7",
+            "  %copy.5 = bf16[16,8]{0,1} copy(%rows.1)\n"
+            "  %copy-start.6 = (bf16[16,8]{1,0}, u32[]) copy-start(%copy.5)\n"
+            "  %copy-done.6 = bf16[16,8]{1,0} copy-done(%copy-start.6)\n"
+            "  %fusion.9 = f32[128]{0} fusion(%copy-done.6), kind=kCustom, "
+            "calls=%nameless\n"
+            "  %ragged-dot-none.7")
+        index = hlo_audit.op_records(text)
+        path = ("smp/step/user", "smp/layer/full", "smp/moe/experts")
+        for name in ("copy.5", "copy-done.6", "fusion.9"):
+            assert index[name]["scope"] is None, name
+            assert "scopes" not in index[name], name
+            assert index[name]["near"] == path, name
+        # the kernel still finds its rows through the copy
+        assert index["ragged-dot-none.7"]["scopes"] == path
+        assert "near" not in index["ragged-dot-none.7"]
+        # free instructions and the marked are told nothing
+        assert "near" not in index["weights"] and "near" not in index["sizes"]
+        assert "near" not in index["rows.1"]
+        joined = hlo_audit.seconds_by_scope(
+            {"copy.5": 2.0, "fusion.9": 1.0, "weights": 0.5, "gone.1": 0.25,
+             "ragged-dot-none.7": 4.0}, index)
+        assert joined["unscoped"]["seconds"] == 3.75
+        assert joined["unscoped"]["near"] == {path: 3.0, (): 0.75}
+        assert joined["tree"] == {path: 4.0}
+
+    def test_a_kernel_whose_op_name_is_a_path_is_the_programs_own(self):
+        """A Pallas kernel is a ``tpu_custom_call`` too; its ``op_name``
+        holds the program's path and scopes, and nothing is inherited."""
+        text = _instr(
+            "smp_flash_fwd.3",
+            _J + "smp/step/user/smp/attn/full/smp/attn/core/smp_flash_fwd",
+            'bf16[8]{0} custom-call(%q), '
+            'custom_call_target="tpu_custom_call"')
+        (rec,) = hlo_audit.op_records(text).values()
+        assert "kernel" not in rec and "inherited" not in rec
+        assert rec["scope"] == "smp/attn/core"
+
+
+class TestCacheKeyedOnNames:
+    """A compile cache keyed without metadata hands a build the names of
+    whichever build filled it (PR 24 met this on the chip); the step is
+    compiled under a key that holds them."""
+
+    @pytest.fixture
+    def cache_dir(self, tmp_path):
+        from jax.experimental.compilation_cache import (
+            compilation_cache as cc,
+        )
+
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        before = {n: getattr(jax.config, n) for n in names}
+        jax.config.update(names[0], str(tmp_path))
+        jax.config.update(names[1], 0.0)
+        jax.config.update(names[2], -1)
+        cc.reset_cache()
+        try:
+            yield tmp_path
+        finally:
+            for n, v in before.items():
+                jax.config.update(n, v)
+            cc.reset_cache()
+
+    @staticmethod
+    def _lowered(scope):
+        def full_impl(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x).sum()
+
+        return jax.jit(full_impl).lower(jnp.ones((64, 64)))
+
+    def test_a_second_build_gets_its_own_names_and_a_rerun_still_hits(
+            self, cache_dir):
+        import importlib
+
+        step_mod = importlib.import_module(
+            "smdistributed_modelparallel_tpu.step")
+        entries = lambda: len([  # noqa: E731
+            f for f in os.listdir(cache_dir)
+            if f.startswith("jit_full_impl") and f.endswith("-cache")])
+        # The fault, with JAX's own key: the second build's program is the
+        # first's but for a scope's name, and it is handed the first's.
+        self._lowered("smp/head/loss").compile()
+        stale = self._lowered("smp/head/logits").compile().as_text()
+        assert "smp/head/loss" in stale and "smp/head/logits" not in stale
+        assert entries() == 1
+        # The step's compile: each build its own entry, and a second run
+        # of one build (the same lines of the same files: the key holds
+        # them too, so all three are lowered from one line) finds its own.
+        texts = [step_mod._compile_keyed_on_names(
+            self._lowered(scope)).as_text() for scope in (
+                "smp/head/loss", "smp/head/logits", "smp/head/logits")]
+        assert "smp/head/loss" in texts[0]
+        for text in texts[1:]:
+            assert "smp/head/logits" in text and "smp/head/loss" not in text
+        assert entries() == 1 + 2
+        assert jax.config.jax_compilation_cache_include_metadata_in_key \
+            is False
+
+    def test_without_the_op_index_the_key_is_jaxs_own(self, cache_dir,
+                                                      monkeypatch):
+        import importlib
+
+        step_mod = importlib.import_module(
+            "smdistributed_modelparallel_tpu.step")
+        monkeypatch.setenv("SMP_HLO_AUDIT", "off")
+        step_mod._compile_keyed_on_names(self._lowered("smp/head/loss"))
+        other = step_mod._compile_keyed_on_names(
+            self._lowered("smp/head/logits"))
+        assert "smp/head/loss" in other.as_text()    # nobody reads them
+
+
+class TestSecondsByScope:
+    """C of PR 39: one join from ``{instruction: self seconds}`` to the
+    tree, whose parts sum to the busy time."""
+
+    _INDEX = {
+        "fusion.1": {"phase": "forward", "scope": "smp/attn/core",
+                     "scopes": ("smp/step/user", "smp/layer/block",
+                                "smp/attn/full", "smp/attn/core")},
+        "fusion.2": {"phase": "backward", "scope": "smp/mlp/dense",
+                     "scopes": ("smp/step/user", "smp/layer/block",
+                                "smp/mlp/dense")},
+        "fusion.3": {"phase": "forward", "scope": "smp/step/user"},
+        "fusion.4": {"phase": "backward", "scope": "smp/step/user",
+                     "scopes": ("smp/pipeline/steady", "smp/pipeline/head",
+                                "smp/step/user")},
+        "fusion.5": {"phase": "backward", "scope": "smp/step/accumulate"},
+        "copy.6": {"phase": "other", "scope": None},
+        "ragged-dot-none.7": {"phase": "recompute", "kernel": "ragged_dot",
+                              "inherited": True, "scope": "smp/moe/experts",
+                              "scopes": ("smp/step/user", "smp/layer/full",
+                                         "smp/moe/experts")},
+        "ragged-dot-none.8": {"phase": "other", "kernel": "ragged_dot",
+                              "scope": None},
+        "all-reduce.9": {"phase": "backward", "scope": "smp/pipeline/steady",
+                         "op": "all-reduce", "axis": "tp", "bytes": 64},
+    }
+    _SECONDS = {"fusion.1": 1.0, "fusion.2": 2.0, "fusion.3": 0.25,
+                "fusion.4": 0.5, "fusion.5": 0.125, "copy.6": 0.0625,
+                "ragged-dot-none.7": 4.0, "ragged-dot-none.8": 0.5,
+                "all-reduce.9": 1.5, "copy.99": 0.03125}
+
+    def _joined(self):
+        return hlo_audit.seconds_by_scope(self._SECONDS, self._INDEX)
+
+    def test_the_parts_sum_to_busy(self):
+        rec = self._joined()
+        assert rec["busy_s"] == sum(self._SECONDS.values())
+        assert sum(rec["tree"].values()) + rec["unscoped"]["seconds"] \
+            == rec["busy_s"]
+        assert sum(rec["by_phase"].values()) == rec["busy_s"]
+
+    def test_a_name_the_index_lacks_is_unscoped_and_other(self):
+        rec = self._joined()
+        assert rec["unscoped"]["seconds"] == 0.0625 + 0.5 + 0.03125
+        assert rec["unscoped"]["top"] == [
+            ["ragged-dot-none.8", 0.5, "other"],
+            ["copy.6", 0.0625, "other"],
+            ["copy.99", 0.03125, "other"]]
+        assert rec["by_phase"]["other"] == 0.0625 + 0.5 + 0.03125
+
+    def test_a_prefix_or_one_scope_sums_its_subtree(self):
+        tree = self._joined()["tree"]
+        assert tree[("smp/step/user", "smp/layer/block", "smp/attn/full",
+                     "smp/attn/core")] == 1.0
+        assert hlo_audit.seconds_under(tree, "smp/layer/block") == 3.0
+        assert hlo_audit.seconds_under(tree, "smp/attn/") == 1.0
+        assert hlo_audit.seconds_under(tree, "smp/moe/", "smp/mlp/") == 6.0
+        assert hlo_audit.seconds_under(tree, "smp/step/user") == 7.75
+        assert hlo_audit.seconds_under(tree, "smp/head/") == 0
+
+    def test_user_only_is_the_innermost_scope_under_a_pipeline_too(self):
+        rec = self._joined()
+        assert rec["user_only_s"] == 0.25 + 0.5
+        assert rec["tree"][("smp/step/user",)] == 0.25
+
+    def test_kernels_axes_and_the_ten_largest(self):
+        rec = self._joined()
+        assert rec["kernels"] == {"ragged_dot": 4.5}
+        assert rec["by_axis"] == {"tp": 1.5}
+        many = {f"copy.{i}": float(i) for i in range(1, 15)}
+        top = hlo_audit.seconds_by_scope(many, self._INDEX)["unscoped"]["top"]
+        assert [row[0] for row in top] == [
+            f"copy.{i}" for i in range(14, 4, -1)]
+
+    def test_nothing_without_an_index(self, monkeypatch):
+        monkeypatch.setattr(hlo_audit, "audits", {})
+        assert hlo_audit.step_program() is None
+        assert hlo_audit.seconds_by_scope(self._SECONDS) is None
+        assert hlo_audit.seconds_by_scope(self._SECONDS, "step") is None
+        assert hlo_audit.seconds_by_scope(self._SECONDS, {}) is None
+
+    def test_the_program_defaults_to_the_step_audited_last(self, monkeypatch):
+        audit = lambda name: hlo_audit.ProgramAudit(  # noqa: E731
+            name, "k", {}, {"fraction": 0.0}, {}, [], 1.0, 2.0, "sha",
+            {"pp": 1}, op_index=self._INDEX)
+        monkeypatch.setattr(hlo_audit, "audits", {
+            "step": audit("step"), "serve_decode": audit("serve_decode")})
+        assert hlo_audit.step_program() == "step"
+        assert hlo_audit.seconds_by_scope(self._SECONDS) == self._joined()
+
+
+# ----------------------------------------------------------------------
 # End-to-end: real pipeline compiles
 # ----------------------------------------------------------------------
 

@@ -277,6 +277,280 @@ class TestCapture:
 
 
 # ----------------------------------------------------------------------
+# The scope vocabulary, whole (PR 39)
+# ----------------------------------------------------------------------
+
+_PACKAGE = os.path.join(_REPO, "smdistributed_modelparallel_tpu")
+_TINY = dict(
+    num_layers=2, hidden_size=32, num_attention_heads=2,
+    attention_head_size=16, intermediate_size=64, vocab_size=64,
+    num_positions=16, attention_dropout_prob=0.0, hidden_dropout_prob=0.0,
+    embedding_dropout_prob=0.0, causal_mask_size=16, pre_layernorm=True,
+    post_layernorm=False, final_layernorm=True)
+
+
+def _scope_strings():
+    """Every quoted ``smp/<subsystem>/<name>`` in the package's source
+    (an f-string's field reads ``<kind>``), but the vocabulary's own."""
+    import re
+
+    found = {}
+    for folder, _, files in os.walk(_PACKAGE):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            with open(path, encoding="utf-8") as f:
+                text = f.read()
+            if path.endswith(os.path.join("utils", "profiling.py")):
+                text = text.replace(
+                    text[text.index("SCOPES = {"):text.index("def _timeline")],
+                    "")
+            for quoted in re.findall(
+                    r"""["'](smp/[\w\-]+/[\w\-{}.' ]+?)["']""", text):
+                scope = re.sub(r"\{[^}]*\}", "<kind>", quoted)
+                found.setdefault(scope, os.path.relpath(path, _REPO))
+    return found
+
+
+def _step_scopes(cfg, module, loss_mode=False, seq=8):
+    """Every scope in the op index of one compiled tiny step."""
+    from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+    smp.reset()
+    smp.init(cfg)
+    model = smp.DistributedModel(module)
+    opt = smp.DistributedOptimizer(optax.sgd(0.1), model)
+
+    @smp.step
+    def step_fn(model, ids):
+        if loss_mode:
+            loss = jnp.mean(model(ids, targets=ids))
+        else:
+            logits = model(ids).astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(
+                logits, ids[..., :logits.shape[1], None], axis=-1)[..., 0]
+            loss = jnp.mean(lse - picked)
+        model.backward(loss)
+        return loss
+
+    step_fn(model, jax.random.randint(jax.random.key(0), (4, seq), 0, 64))
+    opt.step()
+    index = hlo_audit.op_index(hlo_audit.step_program())
+    smp.reset()
+    return {scope for rec in index.values()
+            for scope in rec.get("scopes") or (rec["scope"],) if scope}
+
+
+@pytest.fixture(scope="module")
+def compiled_scopes():
+    """The scopes of a handful of compiled tiny steps: both stacks at
+    pp = 1, the CPU mesh's pp = 2 under each executor."""
+    from smdistributed_modelparallel_tpu.models.transformer_lm import (
+        TransformerLM,
+    )
+    from smdistributed_modelparallel_tpu.nn.transformer import (
+        DistributedTransformerLMHead,
+    )
+
+    expert = dict(num_experts=4, moe_top_k=2, moe_dropless=True,
+                  intermediate_size=16, use_mlp_bias=False)
+    pp2 = {"pipeline_parallel_degree": 2, "ddp": True, "microbatches": 4}
+    tp_stack = lambda **kw: DistributedTransformerLMHead(  # noqa: E731
+        **dict(_TINY, **kw))
+    seen = {
+        "zoo": _step_scopes(
+            {"microbatches": 2, "bf16": True},
+            TransformerLM(vocab_size=64, max_len=16, d_model=32, n_layers=2,
+                          n_heads=2, window=4), loss_mode=True),
+        "tp_stack": _step_scopes(
+            {"microbatches": 2, "bf16": True}, tp_stack(), loss_mode=True),
+        "patterned": _step_scopes(
+            {"microbatches": 2, "bf16": True}, tp_stack(
+                num_layers=3, tie_input_output_embedding=False,
+                head_positions=0.5, layernorm_type="rms",
+                layer_pattern=("lead", "routed", "noisy"),
+                layer_kinds={
+                    "lead": {},
+                    "routed": dict(expert, window_size=4, qk_norm=True,
+                                   num_key_value_heads=1,
+                                   moe_shared_intermediate_size=16),
+                    "noisy": dict(expert, block_diffusion=2)})),
+        "1f1b": _step_scopes(pp2, tp_stack()),
+        "virtual": _step_scopes(
+            dict(pp2, virtual_pipeline_degree=2), tp_stack(num_layers=4)),
+        "zero_bubble": _step_scopes(
+            dict(pp2, pipeline="zero_bubble"), tp_stack()),
+        "simple": _step_scopes(dict(pp2, pipeline="simple"), tp_stack()),
+    }
+    return seen
+
+
+class TestScopeVocabulary:
+    def test_every_scope_string_in_the_package_is_listed(self):
+        found = _scope_strings()
+        assert len(found) >= 30
+        missing = {s: where for s, where in found.items()
+                   if s not in profiling.SCOPES}
+        assert not missing, f"scopes not in profiling.SCOPES: {missing}"
+
+    def test_every_listed_scope_is_written_somewhere(self):
+        found = _scope_strings()
+        assert sorted(s for s in profiling.SCOPES if s not in found) == []
+        for scope, round_ in profiling.SCOPES.items():
+            assert scope.startswith("smp/") and scope.count("/") == 2
+            assert round_ and "\n" not in round_
+
+    @pytest.mark.parametrize("scope", sorted(profiling.SCOPES))
+    def test_a_listed_scope_appears_in_a_compiled_tiny_step(
+            self, compiled_scopes, scope):
+        """Of the stack that should write it: the kind of a patterned
+        stack stands for ``<kind>``."""
+        want = "smp/layer/routed" if scope == "smp/layer/<kind>" else scope
+        where = {name for name, scopes in compiled_scopes.items()
+                 if want in scopes}
+        if scope == "smp/pipeline/cooldown_weight":
+            # The zero-bubble executor names a weight-only last segment so;
+            # no schedule its builder makes today (2 or 4 stages, 2 to 8
+            # microbatches, any window, 1 or 2 chunks) ends in one.
+            assert not where
+            return
+        assert where, f"{scope} is in no compiled step's op index"
+        if scope.startswith("smp/pipeline/"):
+            assert where <= {"1f1b", "virtual", "zero_bubble", "simple"}
+        if scope == "smp/model/stack":      # the executors run the layers
+            assert where == {"zoo", "tp_stack", "patterned"}
+        if scope.startswith(("smp/attn/q", "smp/attn/core", "smp/attn/out",
+                             "smp/head/", "smp/model/", "smp/mlp/")):
+            # both stacks write the parts of a layer and the head
+            assert {"zoo", "tp_stack"} <= where or scope == "smp/attn/qk_norm"
+
+    def test_both_stacks_write_the_same_tree_at_pp_1(self, compiled_scopes):
+        common = {"smp/step/user", "smp/step/cast_params",
+                  "smp/step/accumulate", "smp/optimizer/update",
+                  "smp/model/embed", "smp/model/stack", "smp/head/norm",
+                  "smp/head/logits",
+                  "smp/head/loss", "smp/layer/block", "smp/attn/qkv",
+                  "smp/attn/core", "smp/attn/out", "smp/mlp/dense"}
+        assert compiled_scopes["zoo"] == common | {"smp/attn/window"}
+        assert compiled_scopes["tp_stack"] == common | {"smp/attn/full"}
+        # a kind keeps its name, and the user's own loss is no head's
+        assert "smp/layer/block" not in compiled_scopes["patterned"]
+        assert "smp/head/loss" not in compiled_scopes["patterned"]
+
+
+# ----------------------------------------------------------------------
+# A capture's own report (PR 39)
+# ----------------------------------------------------------------------
+
+
+class TestScopeReport:
+    _TRACE = os.path.join(_REPO, "benchmark", "testdata",
+                          "train-1chip.scopes.trimmed.xplane.pb")
+
+    def test_the_reduction_agrees_with_the_benchmarks_on_a_chip_trace(self):
+        """Two reductions written apart, one recorded TPU trace: device
+        0's self seconds by instruction, to the nanosecond."""
+        sys.path.insert(0, _REPO)
+        try:
+            from benchmark import trace_reduce
+        finally:
+            sys.path.remove(_REPO)
+        plane, seconds = profiling.device_op_seconds(self._TRACE)
+        theirs = trace_reduce.reduce(self._TRACE)
+        assert plane == theirs["devices"][0] == "/device:TPU:0"
+        assert set(seconds) == set(theirs["op_self_s"]) and len(seconds) > 100
+        for name, s in seconds.items():
+            assert abs(s - theirs["op_self_s"][name]) < 1e-12, name
+        assert abs(sum(seconds.values())
+                   - theirs["busy_s_by_device"][0]) < 1e-9
+
+    def test_self_seconds_leave_a_while_what_its_body_does_not_take(self):
+        events = [("while.1", 0, 100), ("fusion.2", 10, 30),
+                  ("fusion.2", 50, 30), ("copy.3", 55, 5),
+                  ("fusion.4", 120, 10)]
+        assert profiling._self_seconds(events) == {
+            "while.1": 40e-9, "fusion.2": 55e-9, "copy.3": 5e-9,
+            "fusion.4": 10e-9}
+
+    def test_report_of_a_recorded_trace_sums_to_busy(self):
+        with open(os.path.join(os.path.dirname(self._TRACE),
+                               "train-1chip.op_index.json")) as f:
+            index = json.load(f)["op_index"]
+        report = profiling.scope_report(self._TRACE, program=index)
+        assert report["device"] == "/device:TPU:0"
+        parts = sum(row["seconds"] for row in report["tree"]) \
+            + report["unscoped"]["seconds"]
+        assert abs(parts - report["busy_s"]) < 1e-9 and report["busy_s"] > 0
+        assert report["tree"][0]["seconds"] >= report["tree"][-1]["seconds"]
+        table = profiling.scope_table(report)
+        assert "(no scope)" in table and "smp/optimizer/update" in table
+        json.dumps(report)                       # plain data
+
+    def test_nothing_to_report_without_a_trace_or_an_index(
+            self, tmp_path, monkeypatch):
+        from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+        assert profiling.newest_xplane(str(tmp_path)) is None
+        assert profiling.write_scope_report(str(tmp_path)) is None
+        monkeypatch.setattr(hlo_audit, "audits", {})
+        assert profiling.scope_report(self._TRACE) is None
+
+    def test_a_capture_on_the_cpu_writes_its_report(self, tmp_path,
+                                                    monkeypatch):
+        """``SMP_PROFILE=steps=1:2`` over a tiny step: the report lands
+        beside the ``.xplane.pb``, its parts sum to busy, the tree holds
+        the step's scopes, and the reduction is charged to the capture."""
+        from smdistributed_modelparallel_tpu.models.transformer_lm import (
+            TransformerLM,
+        )
+
+        monkeypatch.setenv(profiling.PROFILE_ENV, "steps=1:2")
+        monkeypatch.setenv(profiling.PROFILE_PATH_ENV, str(tmp_path))
+        profiling.capture.reset()
+        smp.reset()
+        smp.init({"microbatches": 2})
+        model = smp.DistributedModel(TransformerLM(
+            vocab_size=32, max_len=12, d_model=16, n_layers=2, n_heads=2))
+        opt = smp.DistributedOptimizer(optax.sgd(0.1), model)
+
+        @smp.step
+        def train(model, ids):
+            loss = jnp.mean(model(ids, targets=ids))
+            model.backward(loss)
+            return loss
+
+        ids = jax.random.randint(jax.random.key(0), (4, 12), 0, 32)
+        for _ in range(4):
+            train(model, ids)
+            opt.step()
+        assert profiling.capture.last_window == (1, 2)
+        path = profiling.capture.last_report
+        assert path and os.path.basename(path) == profiling.REPORT_NAME
+        assert path.startswith(os.path.join(str(tmp_path), "rank0"))
+        assert [f for f in os.listdir(os.path.dirname(path))
+                if f.endswith(".xplane.pb")]
+        with open(path) as f:
+            report = json.load(f)
+        assert report["program"] == "step" and report["window"] == [1, 2]
+        assert report["busy_s"] > 0
+        parts = sum(row["seconds"] for row in report["tree"]) \
+            + report["unscoped"]["seconds"]
+        assert abs(parts - report["busy_s"]) < 1e-9
+        paths = {tuple(row["path"]) for row in report["tree"]}
+        assert any(p[0] == "smp/step/user" and p[-1] == "smp/attn/core"
+                   for p in paths)
+        assert ("smp/optimizer/update",) in paths
+        assert abs(sum(report["by_phase"].values())
+                   - report["busy_s"]) < 1e-9
+        overhead = _gauge(telemetry.report(),
+                          "smp_profile_overhead_seconds_total")
+        assert overhead >= report["reduce_seconds"] > 0
+        profiling.capture.reset()
+
+
+# ----------------------------------------------------------------------
 # Roofline / MFU attribution
 # ----------------------------------------------------------------------
 

@@ -407,10 +407,15 @@ def test_attention_ops_carry_the_patterns_scope_forward_and_backward():
     assert any("transpose(" not in n for n in under)
     assert not any("smp/attn/full" in n or "smp/attn/window" in n
                    for n in names)
+    # Since PR 39 the attention's parts carry scopes of their own inside
+    # the pattern's, and the stack one round the layers: the innermost is
+    # a part's, the pattern's scope stays between the layer's and it.
     assert all(hlo_audit.scope_of(n) in (
-        "smp/attn/block_diffusion", "smp/attn/qk_norm") for n in under)
+        "smp/attn/qkv", "smp/attn/qk_norm", "smp/attn/core", "smp/attn/out")
+        for n in under)
     assert any(hlo_audit.scopes_of(n) == (
-        "smp/layer/full", "smp/attn/block_diffusion") for n in under)
+        "smp/model/stack", "smp/layer/full", "smp/attn/block_diffusion",
+        "smp/attn/core") for n in under)
 
 
 # ---------------------------------------------------------- the translator
