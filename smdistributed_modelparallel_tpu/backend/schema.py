@@ -545,8 +545,9 @@ SCHEMA = {
         "options": ["full", "stash_weight", "stash_all", "auto"],
         "description": "TPU extension: memory-budgeted recompute planner "
         "(env alias SMP_RECOMPUTE). 'full' (default): every backward pass "
-        "re-runs its chunk forward (activation recomputation; the compiled "
-        "program is byte-identical to older builds). 'stash_weight': the "
+        "re-runs its chunk forward (activation recomputation; a checkpointed "
+        "layer keeps the flash forward kernel's output and logsumexp, in "
+        "this mode and in every other). 'stash_weight': the "
         "zero-bubble executor's B pass captures per-layer jax.vjp "
         "residuals so the deferred W pass consumes them instead of "
         "re-running the forward — a single forward per microbatch. "

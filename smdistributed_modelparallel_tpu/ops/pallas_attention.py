@@ -55,8 +55,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from smdistributed_modelparallel_tpu.parallel.memory import (
+    FLASH_LSE_NAME,
+    FLASH_OUT_NAME,
+)
 
 NEG_INF = -1e30
 _LSE_MASKED = 1e30  # lse sentinel for fully-masked rows -> p == 0 in bwd
@@ -1023,6 +1029,10 @@ def _fa_fwd(q, k, v, kpad_bias, seed, head0, scale, causal, window,
                              dropout_rate, block_q, block_k, interpret,
                              head0=head0, head_total=head_total,
                              counter_len=counter_len, bd=block_diffusion)
+    # The two values a checkpointed layer keeps (``remat_policy``), so its
+    # backward pass does not run the forward kernel again for them.
+    o = checkpoint_name(o, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o, (q, k, v, o, lse, kpad_bias, seed, head0)
 
 

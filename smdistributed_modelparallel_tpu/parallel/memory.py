@@ -23,6 +23,11 @@ from smdistributed_modelparallel_tpu.utils.logger import get_logger
 logger = get_logger()
 
 LAYER_ACT_NAME = "smp_layer_act"
+# The flash forward kernel's two outputs, named by ``ops/pallas_attention.
+# _fa_fwd``: every policy below keeps them.
+FLASH_OUT_NAME = "smp_flash_out"
+FLASH_LSE_NAME = "smp_flash_lse"
+_KEPT_NAMES = (FLASH_OUT_NAME, FLASH_LSE_NAME)
 _warned_offload = False
 
 
@@ -41,30 +46,45 @@ def remat_policy():
     """Checkpoint policy for layer remat, honoring offload_activations
     and the ``recompute`` knob.
 
-    ``recompute: "full"`` (the default) returns exactly what the pre-knob
-    build returned — None (full remat) or the offload policy — so default
-    programs stay byte-identical. The stash modes map onto the
-    ``dots_with_no_batch_dims_saveable`` policy family: non-pipeline runs
-    (pp=1 microbatch scan, fill-drain) have no schedule for the recompute
-    planner to stash against, so the same memory-for-FLOPs trade is taken
-    one level down, inside ``jax.checkpoint``: ``stash_weight``/``auto``
-    save the weight-matmul outputs (the dominant recompute), ``stash_all``
+    Every mode keeps the flash forward kernel's output and logsumexp
+    (``smp_flash_out``, ``smp_flash_lse``): they are the residuals of
+    ``flash_attention``'s backward, and recomputing them is a whole kernel
+    (no other value of a layer costs as much time a byte kept) where q, k
+    and v are three cheap projections. The price is memory that grows with
+    the stack: ``B*T*H*hd*2 + B*H*T*4`` bytes a layer a microbatch in flight,
+    ``H*hd / d_model`` of the layer boundary that is kept anyway (all of
+    it again where one chip holds every head). A function with no flash
+    call carries neither name and is rematerialized as before.
+
+    ``recompute: "full"`` (the default) keeps nothing else, and under
+    ``offload_activations`` the layer boundary goes to the host besides.
+    The stash modes map onto the ``dots_with_no_batch_dims_saveable``
+    policy family: non-pipeline runs (pp=1 microbatch scan, fill-drain)
+    have no schedule for the recompute planner to stash against, so the
+    same memory-for-FLOPs trade is taken one level down, inside
+    ``jax.checkpoint``: ``stash_weight``/``auto`` save the weight-matmul
+    outputs (the dominant recompute) with the two names, ``stash_all``
     saves everything (checkpoint becomes a no-op boundary). Offloading
     takes precedence — an offload policy already saves the layer boundary
     to host, and combining the two would double-store.
     """
     global _warned_offload
+    policies = jax.checkpoint_policies
+    keep_flash = policies.save_only_these_names(*_KEPT_NAMES)
     cfg = state.cfg
-    if cfg is None or not cfg.offload_activations:
-        if cfg is not None:
-            from smdistributed_modelparallel_tpu.parallel import remat_plan
+    if cfg is None:
+        return keep_flash
+    if not cfg.offload_activations:
+        from smdistributed_modelparallel_tpu.parallel import remat_plan
 
-            mode = remat_plan.resolve(cfg)
-            if mode in ("stash_weight", "auto"):
-                return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            if mode == "stash_all":
-                return jax.checkpoint_policies.everything_saveable
-        return None  # full remat
+        mode = remat_plan.resolve(cfg)
+        if mode in ("stash_weight", "auto"):
+            return policies.save_from_both_policies(
+                policies.dots_with_no_batch_dims_saveable, keep_flash
+            )
+        if mode == "stash_all":
+            return policies.everything_saveable
+        return keep_flash
     if not offload_supported():
         if not _warned_offload:
             logger.warning(
@@ -72,9 +92,9 @@ def remat_policy():
                 "pinned_host memory; falling back to plain rematerialization."
             )
             _warned_offload = True
-        return None
-    return jax.checkpoint_policies.save_and_offload_only_these_names(
-        names_which_can_be_saved=[],
+        return keep_flash
+    return policies.save_and_offload_only_these_names(
+        names_which_can_be_saved=list(_KEPT_NAMES),
         names_which_can_be_offloaded=[LAYER_ACT_NAME],
         offload_src="device",
         offload_dst="pinned_host",

@@ -5,8 +5,9 @@ re-runs the chunk forward (activation recomputation — the seed behavior)
 or reads stashed ``jax.vjp`` residuals captured by an earlier pass. The
 knob (config ``recompute``, env alias ``SMP_RECOMPUTE``):
 
-- ``"full"``    — recompute everywhere; every executor's compiled program
-  is byte-identical to the pre-knob build (the untouched old code path).
+- ``"full"``    — recompute everywhere (the untouched old code path of
+  every executor; a checkpointed layer keeps the flash forward kernel's
+  two outputs, as in every mode: ``memory.remat_policy``).
 - ``"stash_weight"`` — zero-bubble only: the B (input-grad) pass captures
   per-layer vjp residuals + per-layer output cotangents into stash rings
   sized by ``memory.recompute_ring_plan``; the deferred W (weight-grad)

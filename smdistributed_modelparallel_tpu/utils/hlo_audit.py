@@ -386,6 +386,16 @@ def _compiler_kernel(line, op_name):
     return op_name.replace("-", "_") or "unnamed"
 
 
+_PALLAS_NAME_RE = re.compile(r"smp_[a-z0-9_]+")
+
+
+def _pallas_kernel(op_name):
+    """The name a Pallas kernel of this library was given (``pallas_call``'s
+    ``name``, the last segment of its kind in the ``op_name``'s path)."""
+    names = _PALLAS_NAME_RE.findall(op_name.split(";", 1)[0])
+    return names[-1] if names else "unnamed"
+
+
 def _common_scopes(names, records, through, past_kernels, seen=None,
                    hops=_HOPS):
     """The scope path common to the marked instructions among ``names``
@@ -511,7 +521,9 @@ def op_records(hlo_text, mesh=None):
     without a scope. Every other instruction that has no scope and can
     take time keeps ``scope: None`` and is told what it is ``near`` (the
     same rule's answer, for ``seconds_by_scope``'s account of the
-    unscoped seconds)."""
+    unscoped seconds). A Pallas kernel's ``op_name`` is a path like any
+    other instruction's; its record says which kernel it is, ``pallas``
+    (``"smp_flash_fwd"``), for ``kernel_census``."""
     records = {}
     maps = _mesh_coord_maps(mesh)
     comp = None
@@ -560,6 +572,8 @@ def op_records(hlo_text, mesh=None):
         if kernel is not None:
             rec["kernel"] = kernel
             kernels.append(name)
+        elif _KERNEL_TARGET in line:
+            rec["pallas"] = _pallas_kernel(op_name)
         if named is not None:
             # What a nameless instruction's neighbours are found from: who
             # reads whom.
@@ -602,6 +616,30 @@ def census_of(records):
         ax = ent["axes"].setdefault(rec["axis"], {"count": 0, "bytes": 0})
         ax["count"] += 1
         ax["bytes"] += rec["bytes"]
+    return census
+
+
+#: The phases every kernel of ``kernel_census`` is counted under, found
+#: or not: a kernel that stopped being recomputed reads 0, not nothing.
+_KERNEL_PHASES = ("forward", "recompute", "backward")
+
+
+def kernel_census(records):
+    """``{kernel: {phase: count}}`` over the Mosaic kernels of
+    ``op_records``, the program's own (``pallas``) and the compiler's
+    (``kernel``): instructions of the compiled program, each once whatever
+    its loop's trip count. Every kernel holds ``forward``, ``recompute``
+    and ``backward``, so ``smp_flash_fwd`` under ``recompute`` reads 0
+    where a checkpointed layer kept the kernel's outputs
+    (``parallel/memory.remat_policy``) and as many as under ``forward``
+    where its backward pass runs it again."""
+    census = {}
+    for rec in records.values():
+        kernel = rec.get("pallas") or rec.get("kernel")
+        if kernel is None:
+            continue
+        phases = census.setdefault(kernel, dict.fromkeys(_KERNEL_PHASES, 0))
+        phases[rec["phase"]] = phases.get(rec["phase"], 0) + 1
     return census
 
 
@@ -2024,6 +2062,13 @@ def _publish(audit):
                 "per-device collective result bytes in the compiled "
                 "program, by op kind and attributed mesh axis",
             ).labels(op=op, axis=axis, **lab).set(ax["bytes"])
+    for kernel, phases in kernel_census(audit.op_index).items():
+        for phase, count in phases.items():
+            telemetry.gauge(
+                "smp_kernel_calls",
+                "Mosaic kernel instructions in the compiled program, by "
+                "kernel name and phase of the step",
+            ).labels(kernel=kernel, phase=phase, **lab).set(count)
     telemetry.gauge(
         "smp_hlo_replicated_bytes",
         "estimated per-device bytes wasted to detected replication",

@@ -252,6 +252,46 @@ def test_flash_attention_under_the_block_diffusion_mask(one_chip):
     assert "16384,16384" not in text
 
 
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "full_remat"])
+def test_a_checkpointed_layer_compiles_one_flash_forward(one_chip, kept):
+    """The chip's compiler on a checkpointed layer scan at Mellum's
+    attention shapes (8 query heads of 128 on one KV head over 8,192
+    positions, d 2304): under ``remat_policy`` the compiled step holds the
+    forward kernel once, under the ``forward`` phase, where full remat
+    holds it a second time under ``recompute``; ``hlo_audit.kernel_census``
+    reads that off the text, as ``smp_kernel_calls`` publishes it."""
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+    from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
+    from smdistributed_modelparallel_tpu.utils import hlo_audit
+
+    T, H, hd, D = 8192, 8, 128, 2304
+
+    def layer(x, w):
+        w_qkv, w_out = w
+        q, k, v = jnp.split(x @ w_qkv, [H * hd, (H + 1) * hd], axis=-1)
+        o = flash_attention(q.reshape(1, T, H, hd), k.reshape(1, T, 1, hd),
+                            v.reshape(1, T, 1, hd), causal=True)
+        return x + o.reshape(1, T, H * hd) @ w_out
+
+    body = jax.checkpoint(layer, policy=remat_policy() if kept else None)
+
+    def loss(w_qkv, w_out, x):
+        y, _ = jax.lax.scan(lambda c, w: (body(c, w), None), x,
+                            (w_qkv, w_out))
+        return _sum32(y)
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1)), one_chip,
+        (2, D, (H + 2) * hd), (2, H * hd, D), (1, T, D))
+    census = hlo_audit.kernel_census(hlo_audit.op_records(text))
+    assert census["smp_flash_fwd"] == {
+        "forward": 1, "recompute": 0 if kept else 1, "backward": 0}
+    for name in ("smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert census[name] == {"forward": 0, "recompute": 0, "backward": 1}
+
+
 @pytest.mark.parametrize(
     "d_model,vocab", [(768, 50257), (1600, 50257), (4096, 50400)],
     ids=["gpt2_124m", "gpt2_1p5b", "gptj_6b"],

@@ -782,6 +782,78 @@ class TestCompilerKernels:
         assert rec["scope"] == "smp/attn/core"
 
 
+_FLASH = _J + ("while/body/smp/step/user/{}/smp/layer/full/smp/attn/full/"
+                "smp/attn/core/{}")
+_PALLAS = 'bf16[8]{0} custom-call(%q), custom_call_target="tpu_custom_call"'
+
+
+def _flash_step(recomputed):
+    """The flash kernels of a checkpointed layer as the TPU compiler
+    leaves them: the forward under a plain ``jvp(`` path, the two
+    backward kernels transposed and, where the layer's backward pass runs
+    the forward again, a second forward kernel under
+    ``rematted_computation``; a grouped product of the compiler's beside
+    them."""
+    text = _instr("smp_flash_fwd.3", _FLASH.format(
+        "jvp(layer)", "smp_flash_fwd"), _PALLAS)
+    if recomputed:
+        text += _instr("smp_flash_fwd.4", _FLASH.format(
+            "transpose(jvp(layer))/checkpoint/rematted_computation",
+            "smp_flash_fwd"), _PALLAS)
+    for name in ("smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        text += _instr(name + ".5", _FLASH.format(
+            "transpose(jvp(layer))", name), _PALLAS)
+    return text + _chunk_body("%rows.1, %weights", "smp/moe/experts")
+
+
+class TestKernelCensus:
+    """``smp_kernel_calls{kernel, phase}``: how often a Mosaic kernel stands
+    in the compiled step, by phase, so the program says itself whether a
+    checkpointed layer's backward pass runs the flash forward again."""
+
+    @pytest.mark.parametrize("recomputed", [False, True])
+    def test_a_kernel_is_counted_under_its_phase(self, recomputed):
+        index = hlo_audit.op_records(_flash_step(recomputed))
+        assert index["smp_flash_fwd.3"]["pallas"] == "smp_flash_fwd"
+        assert "kernel" not in index["smp_flash_fwd.3"]
+        census = hlo_audit.kernel_census(index)
+        assert census["smp_flash_fwd"] == {
+            "forward": 1, "recompute": int(recomputed), "backward": 0}
+        for name in ("smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+            assert census[name] == {
+                "forward": 0, "recompute": 0, "backward": 1}
+        # the compiler's own kernels are Mosaic kernels too
+        assert sum(census["ragged_dot"].values()) == 1
+        assert sum(census["ragged_dot_metadata"].values()) == 1
+        assert set(census) == {
+            "smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv",
+            "ragged_dot", "ragged_dot_metadata"}
+
+    def test_a_kernel_with_no_name_of_the_librarys_is_unnamed(self):
+        index = hlo_audit.op_records(_instr(
+            "custom-call.9", _J + "jvp(layer)/pallas_call", _PALLAS))
+        assert hlo_audit.kernel_census(index) == {
+            "unnamed": {"forward": 1, "recompute": 0, "backward": 0}}
+
+    @pytest.mark.parametrize("recomputed", [False, True])
+    def test_the_gauge_reads_zero_where_nothing_is_recomputed(
+            self, recomputed):
+        telemetry.reset()
+        audit = hlo_audit.ProgramAudit(
+            "step", "k", {}, {"fraction": 0.0}, {}, [], 1.0, 2.0, "sha",
+            {"pp": 1},
+            op_index=hlo_audit.op_records(_flash_step(recomputed)))
+        hlo_audit._publish(audit)
+        series = telemetry.report()["metrics"]["smp_kernel_calls"]["series"]
+        read = {(s["labels"]["kernel"], s["labels"]["phase"]): s["value"]
+                for s in series if s["labels"]["step"] == "step"}
+        assert read[("smp_flash_fwd", "forward")] == 1
+        assert read[("smp_flash_fwd", "recompute")] == int(recomputed)
+        assert read[("smp_flash_bwd_dkv", "backward")] == 1
+        assert read[("smp_flash_bwd_dkv", "recompute")] == 0
+        telemetry.reset()
+
+
 class TestCacheKeyedOnNames:
     """A compile cache keyed without metadata hands a build the names of
     whichever build filled it (PR 24 met this on the chip); the step is

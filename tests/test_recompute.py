@@ -278,23 +278,76 @@ class TestKnobPlumbing:
 
         assert remat_plan.active_for(Full()) is None
 
-    def test_remat_policy_mapping(self):
-        """Non-pipeline paths: the knob maps onto jax.checkpoint
-        policies; 'full' stays the untouched None (full remat)."""
+    @pytest.mark.parametrize("cfg,recomputed", [
+        ({"recompute": "full"}, {"exp", "dot_general"}),
+        ({"recompute": "stash_weight"}, {"exp"}),
+        ({"recompute": "auto"}, {"exp"}),
+        ({"recompute": "stash_all"}, set()),
+        ({"offload_activations": True}, {"exp", "dot_general"}),
+        (None, {"exp", "dot_general"}),
+    ], ids=["full", "stash_weight", "auto", "stash_all", "offload",
+            "uninitialized"])
+    def test_remat_policy_mapping(self, cfg, recomputed):
+        """What each mode keeps across a checkpointed function, by
+        behaviour: every mode keeps the two values the flash forward
+        names; the stash modes the weight products besides, ``stash_all``
+        everything; an elementwise value is made again by all but that
+        one. A primitive counts as recomputed where the gradient's jaxpr
+        holds it once more than that of the function left unwrapped."""
         from smdistributed_modelparallel_tpu.parallel.memory import (
+            FLASH_LSE_NAME,
+            FLASH_OUT_NAME,
             remat_policy,
         )
+        from jax.ad_checkpoint import checkpoint_name
+
+        def probe(x, w):
+            out = checkpoint_name(jnp.sin(x), FLASH_OUT_NAME)
+            lse = checkpoint_name(jnp.log1p(x * x), FLASH_LSE_NAME)
+            # a product of the four, so the backward pass wants each
+            return jnp.sum(out * lse * (x @ w) * jnp.exp(x))
 
         smp.reset()
-        smp.init({"recompute": "stash_weight"})
-        assert (remat_policy()
-                is jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        if cfg is not None:
+            smp.init(cfg)
+        policy = remat_policy()
         smp.reset()
-        smp.init({"recompute": "stash_all"})
-        assert remat_policy() is jax.checkpoint_policies.everything_saveable
+        x, w = jnp.ones((4, 4)), jnp.ones((4, 4))
+
+        def count(fn):
+            text = str(jax.make_jaxpr(jax.grad(fn, (0, 1)))(x, w))
+            return {p: text.count(f" {p}") for p in (
+                "sin", "log1p", "dot_general", "exp")}
+
+        plain = count(probe)
+        kept = count(jax.checkpoint(probe, policy=policy))
+        assert {p for p in plain if kept[p] == plain[p] + 1} == recomputed
+        assert all(kept[p] - plain[p] in (0, 1) for p in plain)
+        # and with nothing kept, all four are made again: the count tells
+        everything = count(jax.checkpoint(probe, policy=None))
+        assert all(everything[p] == plain[p] + 1 for p in plain)
+
+    def test_no_flash_call_is_rematerialized_as_before(self):
+        """A function that carries neither name is, under
+        ``smp.checkpoint``, the program it is under full remat: the same
+        gradient jaxpr but for the policy's own repr."""
+        import re
+
+        def fn(x, w):
+            h = jnp.tanh(x @ w)
+            return jnp.sum(jnp.exp(h) * h)
+
         smp.reset()
         smp.init({"recompute": "full"})
-        assert remat_policy() is None
+        x, w = jnp.ones((4, 8)), jnp.ones((8, 8))
+
+        def text(wrapped):
+            jaxpr = jax.make_jaxpr(jax.grad(wrapped, (0, 1)))(x, w)
+            return re.sub(r"policy=.*", "policy=_", str(jaxpr))
+
+        assert text(smp.checkpoint(fn)) == text(
+            jax.checkpoint(fn, policy=None))
+        assert "remat" in text(smp.checkpoint(fn))
         smp.reset()
 
     def test_step_key_canonicalization(self):
