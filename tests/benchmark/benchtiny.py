@@ -35,7 +35,8 @@ SERVE_ENTRIES = {
                         ("serve.out_tokens_per_s", "tokens/s", "higher"))],
     "per_layer": [
         {"name": n, "unit": "x", "better": "lower", "source": "host_clock",
-         "layer": "serving engine", "moves": "serve.out_tokens_per_s"}
+         "layer": "serving engine", "moves": "serve.out_tokens_per_s",
+         "workloads": [SERVE_CELL]}
         for n in ("engine.tick_ms", "engine.batch_occupancy",
                   "engine.prefill_tokens_per_tick")],
 }
@@ -59,9 +60,93 @@ TINY_SERVE_LIMITS = {
 }
 
 
-def manifest_data():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def manifest_data(root=ROOT):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+# What a later PR brings with a model, each entry the last of its list: a
+# configuration, a cell, the cell's name in the rate's list, two per-layer
+# entries with their readers. ``grow`` makes the additions in a copy, and
+# the tests hold every assertion about the manifest on that copy too.
+RATE = "train.tokens_per_s_per_chip"
+GROWN_CELL = "dummy-model.dummy-mix"
+GROWTH = {
+    "configs": [{
+        "name": "dummy-model", "source": "https://example.org/dummy",
+        "file": "benchmark/configs/dummy-model.json", "reduced": [],
+        "why": "dummy"}],
+    "workloads": [{
+        "name": GROWN_CELL, "config": "dummy-model", "traffic": "dummy-mix",
+        "chips": 1, "why": "dummy"}],
+    "per_layer": [
+        {"name": name, "unit": "steps", "better": "higher",
+         "source": "program_counter", "layer": "dummy", "moves": RATE,
+         "workloads": [GROWN_CELL]}
+        for name in ("dummy.twice", "dummy.absent")],
+}
+GROWN_FILES = {
+    "configs/dummy-model.json": json.dumps({
+        "source": "https://example.org/dummy", "reduced": {}, "assumed": {},
+        "deployment": "none", "builder": "dummy_builder", "smp": {},
+        "width": 8}),
+    "builders/dummy_builder.py":
+        "def module(cfg):\n    return ('dummy', cfg['width'])\n",
+    "traffic/dummy-mix.json": json.dumps({"kind": "dummy_kind", "steps": 3}),
+    "drivers/dummy_kind.py": (
+        "def run(run):\n"
+        "    n = run.cell.traffic['steps']\n"
+        "    return {'correct': True, 'attempted': n, 'failed': 0,\n"
+        "            'end_to_end': {'%s': float(n)},\n"
+        "            'context': {'steps': n}}\n" % RATE),
+    "limits/%s.json" % GROWN_CELL: json.dumps({"limits": {}}),
+    "metrics/dummy.twice.py": "def read(ctx):\n    return 2 * ctx['steps']\n",
+    "metrics/dummy.absent.py": "def read(ctx):\n    return None\n",
+}
+
+
+def grown(data):
+    """``data`` with ``GROWTH`` appended, each entry last in its list."""
+    data = json.loads(json.dumps(data))
+    for group, entries in GROWTH.items():
+        data[group] += entries
+    rate = next(m for m in data["end_to_end"] if m["name"] == RATE)
+    rate["workloads"].append(GROWN_CELL)
+    return data
+
+
+def grow(root):
+    """Make the additions in the copy of the benchmark under ``root``:
+    new files, and entries appended to its ``BENCHMARK.json``."""
+    for name, text in GROWN_FILES.items():
+        with open(os.path.join(root, "benchmark", name), "w") as f:
+            f.write(text)
+    data = grown(manifest_data(root))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(data, f)
+    return root
+
+
+def grown_root(tmp_path):
+    """A copy of ``BENCHMARK.json`` and ``benchmark/`` as committed, grown."""
+    root = str(tmp_path / "root")
+    shutil.copytree(os.path.join(ROOT, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    return grow(root)
+
+
+def entry_listing(manifest, metric, cells):
+    """The per-layer entry ``metric`` without its ``workloads``, found by
+    name wherever it stands, once it is seen to list ``cells`` (others too,
+    maybe: ``test_benchmark_manifest.py`` holds the rule on those) and each
+    of them to report it."""
+    entry = dict(manifest._entry("per_layer", metric))
+    assert set(cells) <= set(entry.pop("workloads")), metric
+    for cell in cells:
+        assert metric in {m["name"] for m in manifest.cell(cell).per_layer()}
+    return entry
 
 
 def _rewrite(path, **changes):

@@ -1,5 +1,6 @@
 """``moe.combine_time_share`` (PR 38): the reader on a made-up context, on a
-program without the scope, and the cells it is listed for."""
+program without the scope, and its entry, which lists the cells it was
+written for wherever it stands in ``per_layer``."""
 
 import types
 
@@ -11,7 +12,6 @@ from benchmark import loader
 METRIC = "moe.combine_time_share"
 CELLS = ["laguna-s-2.1.train-8k-1chip",
          "mellum2-12b-a2.5b.train-8k-group-1chip"]
-SDAR = "sdar-30b-a3b.train-8k-block-diffusion-1chip"
 
 
 def reader_context():
@@ -68,17 +68,8 @@ def test_reader_returns_nothing_without_the_scope(monkeypatch, index):
     assert read(ctx) is None
 
 
-def test_metric_is_listed_for_the_mellum_and_laguna_cells_alone():
-    data = benchtiny.manifest_data()
-    assert data["per_layer"][-1]["name"] == METRIC
-    metric = data["per_layer"][-1]
-    assert metric == {
+def test_metric_is_listed_for_the_mellum_and_laguna_cells(manifest):
+    assert benchtiny.entry_listing(manifest, METRIC, CELLS) == {
         "name": METRIC, "unit": "%", "better": "lower",
         "source": "device_trace", "layer": "expert layers",
-        "moves": "train.tokens_per_s_per_chip", "workloads": CELLS}
-    manifest = loader.Manifest()
-    for cell in data["workloads"]:
-        reported = {m["name"] for m in manifest.cell(cell["name"]).per_layer()}
-        assert (METRIC in reported) == (cell["name"] in CELLS), cell["name"]
-    assert METRIC not in {
-        m["name"] for m in manifest.cell(SDAR).per_layer()}
+        "moves": "train.tokens_per_s_per_chip"}

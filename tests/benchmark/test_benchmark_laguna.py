@@ -390,17 +390,14 @@ def test_load_reader_reads_the_programs_gauges(monkeypatch):
     assert read({}) is None
 
 
-def test_new_metrics_are_listed_for_the_new_cell_only():
-    data = benchtiny.manifest_data()
+def test_new_metrics_are_listed_for_the_new_cell(manifest):
     new = {"moe.time_share", "moe.dispatch_time_share",
            "moe.grouped_matmul_roofline", "moe.expert_load_max_over_mean",
            "flash.window_time_share", "step.lead_dense_time_share",
            "moe.rows_per_step"}
-    for metric in data["per_layer"]:
-        if metric["name"] in new:
-            assert metric["workloads"] == [lagunatiny.CELL]
-            assert metric["moves"] == "train.tokens_per_s_per_chip"
-    cell = loader.Manifest().cell(lagunatiny.CELL)
-    assert new <= {m["name"] for m in cell.per_layer()}
+    for name in new:
+        metric = benchtiny.entry_listing(manifest, name, [lagunatiny.CELL])
+        assert metric["moves"] == "train.tokens_per_s_per_chip"
+    cell = manifest.cell(lagunatiny.CELL)
     assert cell.chips == 1 and cell.traffic["kind"] == "train_steps_experts"
     assert cell.traffic["batch"] * cell.traffic["seq"] == 32768

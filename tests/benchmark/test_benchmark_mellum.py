@@ -392,28 +392,21 @@ def test_rows_per_token_reader():
     assert read({"moe": {"rows_per_step": 0}}) is None
 
 
-def test_new_metrics_are_listed_for_the_new_cell_only():
-    data = benchtiny.manifest_data()
-    listed = {m["name"]: m for m in data["per_layer"]}
+def test_new_metrics_are_listed_for_the_new_cell(manifest):
     for name, layer in NEW_METRICS.items():
-        metric = listed[name]
-        assert metric["workloads"] == [mellumtiny.CELL]
+        metric = benchtiny.entry_listing(manifest, name, [mellumtiny.CELL])
         assert metric["moves"] == "train.tokens_per_s_per_chip"
         assert metric["better"] == "lower" and metric["layer"] == layer
-    assert listed["moe.row_time_us"]["unit"] == "us"
-    for name in ("flash.time_share", "flash_roofline"):
-        assert listed[name]["workloads"][-1] == mellumtiny.CELL
-    cell = loader.Manifest().cell(mellumtiny.CELL)
+    assert manifest._entry("per_layer", "moe.row_time_us")["unit"] == "us"
+    cell = manifest.cell(mellumtiny.CELL)
     reported = {m["name"] for m in cell.per_layer()}
     assert set(NEW_METRICS) | {"flash.time_share", "flash_roofline",
                                "step.mfu", "device.idle_share.train",
                                "device.hbm_peak_gb.train"} <= reported
-    assert not reported & {"moe.time_share", "flash.window_time_share",
-                           "step.lead_dense_time_share"}
     assert cell.chips == 1
     assert cell.traffic["kind"] == "train_steps_expert_family"
     assert cell.traffic["batch"] * cell.traffic["seq"] == 32768
-    laguna = loader.Manifest().cell("laguna-s-2.1.train-8k-1chip").traffic
+    laguna = manifest.cell("laguna-s-2.1.train-8k-1chip").traffic
     assert {k: v for k, v in cell.traffic.items()
             if k not in ("kind", "why")} == \
         {k: v for k, v in laguna.items() if k not in ("kind", "why")}

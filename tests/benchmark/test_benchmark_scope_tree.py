@@ -1,8 +1,8 @@
 """The eight readers of the program's scope tree (PR 39;
 ``benchmark/metrics/_tree.py``): each on a made-up context, on a program
-without the index, the join or the scope, the entries they wait for in
-``BENCHMARK.json`` (none is listed yet: see ``PERF.md`` section 7), and on a
-recorded pair: one step of a small
+without the index, the join or the scope, their entries in
+``BENCHMARK.json`` (listed by PR 41), and on a recorded pair: one step of a
+small
 Mellum-family stack traced on the chip with the op index of that compile
 beside it, so the rule that gives the grouped products their scope is held
 to real instruction names."""
@@ -22,18 +22,21 @@ PYTHIA = "pythia-1.4b.train-pp2tp2"
 LAGUNA = "laguna-s-2.1.train-8k-1chip"
 MELLUM = "mellum2-12b-a2.5b.train-8k-group-1chip"
 SDAR = "sdar-30b-a3b.train-8k-block-diffusion-1chip"
-FOUR = [GPT2, PYTHIA, LAGUNA, MELLUM]
-# metric -> (layer, the cells it is listed for)
+FIVE = [GPT2, PYTHIA, LAGUNA, MELLUM, SDAR]
+# metric -> (layer, the cells it was listed for: a `--trace 1` run of each
+# on the chip printed a value, ``PERF.md`` section 6, PR 41)
 ENTRIES = {
-    "step.unscoped_time_share": ("model step", FOUR),
-    "step.user_code_time_share": ("model step", FOUR),
-    "head.time_share": ("model step", FOUR),
-    "attn.time_share": ("model step", FOUR),
+    "step.unscoped_time_share": ("model step", FIVE),
+    "step.user_code_time_share": ("model step", FIVE),
+    "head.time_share": ("model step", FIVE),
+    "attn.time_share": ("model step", FIVE),
+    # the SDAR and Mellum cells have no dense MLP
     "mlp.time_share": ("model step", [GPT2, PYTHIA, LAGUNA]),
     # the 1F1B executors add gradients up inside their ticks, under no
     # ``smp/step/accumulate``: nothing to read in the four-chip cell
-    "step.accumulate_time_share": ("model step", [GPT2, LAGUNA, MELLUM]),
-    "moe.grouped_products_time_share": ("kernels", [LAGUNA, MELLUM]),
+    "step.accumulate_time_share": (
+        "model step", [GPT2, LAGUNA, MELLUM, SDAR]),
+    "moe.grouped_products_time_share": ("kernels", [LAGUNA, MELLUM, SDAR]),
     "pipeline.glue_time_share": (
         "pipeline executors and TP layers", [PYTHIA]),
 }
@@ -209,37 +212,22 @@ def test_reader_returns_nothing_without_its_scope(monkeypatch, metric):
 
 
 @pytest.mark.parametrize("metric", sorted(ENTRIES))
-def test_reader_waits_for_the_entry_a_benchmark_pr_appends(metric):
-    """``BENCHMARK.json`` lists none of the eight yet: a PR that changes the
-    program may only append to ``per_layer``, and
-    ``test_benchmark_combine.py`` wants ``moe.combine_time_share`` last.
-    The reader's file is there; once a ``benchmark`` PR lists it, the entry
-    is ``ENTRIES``'s and the cells that report it are those."""
+def test_entry_lists_the_cells_the_reader_was_read_in(manifest, metric):
+    """The entry is ``ENTRIES``'s wherever it stands in ``per_layer``, and
+    the cells that printed a value for it report it."""
     assert os.path.isfile(os.path.join(
         benchtiny.ROOT, "benchmark", "metrics", metric + ".py"))
-    data = benchtiny.manifest_data()
-    listed = {m["name"]: m for m in data["per_layer"]}
-    if metric not in listed:
-        return
     layer, cells = ENTRIES[metric]
-    assert listed[metric] == {
+    assert benchtiny.entry_listing(manifest, metric, cells) == {
         "name": metric, "unit": "%", "better": "lower",
         "source": "device_trace", "layer": layer,
-        "moves": "train.tokens_per_s_per_chip", "workloads": cells}
-    manifest = loader.Manifest()
-    for cell in data["workloads"]:
-        reported = {m["name"] for m in manifest.cell(cell["name"]).per_layer()}
-        assert (metric in reported) == (cell["name"] in cells), cell["name"]
+        "moves": "train.tokens_per_s_per_chip"}
 
 
-def test_no_reader_takes_the_name_of_a_listed_metric():
-    """The eight are new names, and ``test_benchmark_sdar.py`` holds the
-    SDAR cell's set: no entry of ``ENTRIES`` lists that cell."""
-    names = [m["name"] for m in benchtiny.manifest_data()["per_layer"]]
+def test_no_reader_takes_the_name_of_a_listed_metric(manifest):
+    names = [m["name"] for m in manifest.data["per_layer"]]
     assert len(names) == len(set(names))
-    assert all(SDAR not in cells for _, cells in ENTRIES.values())
-    reported = {m["name"] for m in loader.Manifest().cell(SDAR).per_layer()}
-    assert not reported & set(ENTRIES)
+    assert set(ENTRIES) <= set(names)
 
 
 # ----------------------------------------------------------------------

@@ -214,17 +214,12 @@ def test_host_time_outside_dispatch_sums_the_phase_medians(monkeypatch):
 
 
 @pytest.mark.parametrize("metric", sorted(NEW))
-def test_manifest_resolves_every_new_entry(metric):
-    manifest = loader.Manifest()
-    entry = manifest._entry("per_layer", metric)
+def test_manifest_resolves_every_new_entry(manifest, metric):
+    cells = [FOUR] if metric in FOUR_CHIP_ONLY else [ONE, FOUR]
+    entry = benchtiny.entry_listing(manifest, metric, cells)
     assert entry["layer"] == NEW[metric]
     assert entry["moves"] == "train.tokens_per_s_per_chip"
     assert entry["better"] == "lower"
-    cells = [FOUR] if metric in FOUR_CHIP_ONLY else [ONE, FOUR]
-    assert entry["workloads"] == cells
-    for cell in (ONE, FOUR):
-        listed = [m["name"] for m in manifest.cell(cell).per_layer()]
-        assert (metric in listed) == (cell in cells)
     assert callable(manifest.cell(FOUR).metric_reader(metric))
     path = os.path.join(manifest.dir, "metrics", metric + ".py")
     with open(path) as f:

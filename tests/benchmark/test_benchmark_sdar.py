@@ -24,11 +24,8 @@ NEW_METRICS = {
     "flash.block_diffusion_tiles_visited_over_live": (
         "kernels", "lower", "ratio"),
     "diffusion.head_positions_share": ("model step", "lower", "%"),
-    "moe.block_diffusion_time_share": ("expert layers", "lower", "%"),
     "moe.block_diffusion_rows_per_position": (
         "expert layers", "lower", "rows/position"),
-    "moe.block_diffusion_load_max_over_mean": (
-        "expert layers", "lower", "ratio"),
 }
 
 
@@ -501,25 +498,43 @@ def test_roofline_is_flash_rooflines_arithmetic_under_the_scope(monkeypatch):
     assert read(ctx) is None
 
 
-def test_expert_layers_share_counts_the_scopes_and_the_products(monkeypatch):
+def test_expert_layers_share_counts_the_products_once(monkeypatch):
+    """The cell's own ``moe.block_diffusion_time_share`` went with PR 41:
+    ``moe.time_share`` lists the cell and reads the same seconds (35.66
+    both in one traced run on the chip), now that the op index puts the
+    grouped products under ``smp/moe/experts``; ``moe.experts_time_share``
+    counts a product once, by its scope or, on an index that gives it none,
+    by its name."""
     cell = loader.Manifest().cell(sdartiny.CELL)
-    read = cell.metric_reader("moe.block_diffusion_time_share")
+    share = cell.metric_reader("moe.time_share")
+    experts = cell.metric_reader("moe.experts_time_share")
     trace, index = reader_context()
     trace["op_self_s"] = dict(
         trace["op_self_s"], **{"fusion.20": 3.0, "ragged-dot-7": 2.5})
     index = dict(index, **{
-        "fusion.20": {"scopes": ("smp/layer/full", "smp/moe/combine")}})
-    for module in (read.__globals__["_moe"],
-                   read.__globals__["_experts"]._moe):
-        monkeypatch.setattr(module._scopes, "step_index", lambda: index)
-    # combine 3.0 + experts 1.0 under the scopes, the products 2.5 beside
-    assert read({"trace": trace}) == pytest.approx(100 * 6.5 / 20)
-    # the parent on an old cell's trace: no expert layer, nothing raised
-    for module in (read.__globals__["_moe"],
-                   read.__globals__["_experts"]._moe):
-        monkeypatch.setattr(module._scopes, "step_index", lambda: {
-            k: {"scopes": ("smp/attn/full",)} for k in index})
-    assert read({"trace": trace}) is None
+        "fusion.20": {"scopes": ("smp/layer/full", "smp/moe/combine")},
+        "ragged-dot-7": {"scopes": ("smp/layer/full", "smp/moe/experts"),
+                         "scope": "smp/moe/experts", "kernel": "ragged_dot",
+                         "inherited": True}})
+
+    def use(step_index):
+        for module in (share.__globals__["_moe"],
+                       experts.__globals__["_experts"]._moe):
+            monkeypatch.setattr(module._scopes, "step_index",
+                                lambda: step_index)
+
+    use(index)
+    # combine 3.0 + experts 1.0 + the product 2.5, all under the scopes
+    assert share({"trace": trace}) == pytest.approx(100 * 6.5 / 20)
+    assert experts({"trace": trace}) == pytest.approx(100 * 3.5 / 20)
+    # an index of before PR 39: the product has no scope
+    use(dict(index, **{"ragged-dot-7": {"phase": "backward", "scope": None}}))
+    assert share({"trace": trace}) == pytest.approx(100 * 4.0 / 20)
+    assert experts({"trace": trace}) == pytest.approx(100 * 3.5 / 20)
+    # a program with no expert layer: nothing to read, nothing raised
+    use({k: {"scopes": ("smp/attn/full",)} for k in index})
+    assert share({"trace": trace}) is None
+    assert experts({"trace": trace}) is None
 
 
 def test_rows_and_load_readers_read_the_drivers_count():
@@ -527,7 +542,8 @@ def test_rows_and_load_readers_read_the_drivers_count():
 
     cell = loader.Manifest().cell(sdartiny.CELL)
     rows = cell.metric_reader("moe.block_diffusion_rows_per_position")
-    load = cell.metric_reader("moe.block_diffusion_load_max_over_mean")
+    # its twin of PR 37 went with PR 41: the same gauges, 4.76 both
+    load = cell.metric_reader("moe.expert_load_max_over_mean")
     ctx = {"cell": cell, "tokens_per_step": 32768,
            "moe": {"rows_per_step": 327680.0}}
     assert rows(ctx) == pytest.approx(1.0)        # five layers, both copies
@@ -568,25 +584,20 @@ def test_counter_readers_read_the_programs_gauges():
     t.telemetry.closed_report = None
 
 
-def test_new_metrics_are_listed_for_the_new_cell_only():
-    data = benchtiny.manifest_data()
-    listed = {m["name"]: m for m in data["per_layer"]}
+def test_new_metrics_are_listed_for_the_new_cell(manifest):
+    data = manifest.data
     for name, (layer, better, unit) in NEW_METRICS.items():
-        metric = listed[name]
-        assert metric["workloads"] == [sdartiny.CELL]
+        metric = benchtiny.entry_listing(manifest, name, [sdartiny.CELL])
         assert metric["moves"] == "train.tokens_per_s_per_chip"
         assert (metric["layer"], metric["better"], metric["unit"]) == (
             layer, better, unit)
-    rate = [m for m in data["end_to_end"]
-            if m["name"] == "train.tokens_per_s_per_chip"][0]
-    assert rate["workloads"][-1] == sdartiny.CELL
-    # the lists other tests pin keep the cells they had
-    for name in ("flash.time_share", "flash_roofline"):
-        assert sdartiny.CELL not in listed[name]["workloads"]
-    cell = loader.Manifest().cell(sdartiny.CELL)
+    rate = manifest._entry("end_to_end", "train.tokens_per_s_per_chip")
+    assert sdartiny.CELL in rate["workloads"]
+    cell = manifest.cell(sdartiny.CELL)
     reported = {m["name"] for m in cell.per_layer()}
-    assert reported == set(NEW_METRICS) | {
+    assert set(NEW_METRICS) | {
         "step.mfu", "step.dispatch_ms", "device.idle_share.train",
-        "device.hbm_peak_gb.train"}
-    assert data["workloads"][-1]["name"] == sdartiny.CELL
-    assert data["configs"][-1]["name"] == "sdar-30b-a3b-chat-5l-ep8"
+        "device.hbm_peak_gb.train"} <= reported
+    assert sdartiny.CELL in [w["name"] for w in data["workloads"]]
+    assert cell.config_entry in data["configs"]
+    assert cell.config_entry["name"] == "sdar-30b-a3b-chat-5l-ep8"
