@@ -36,6 +36,7 @@ from smdistributed_modelparallel_tpu.nn.transformer import (
     DistributedTransformerLMHead,
     DistributedTransformerOutputLayer,
 )
+from smdistributed_modelparallel_tpu.nn.conv import DistributedShortConv
 from smdistributed_modelparallel_tpu.nn.diffusion import (
     masked_diffusion_loss,
     record_diffusion_stats,

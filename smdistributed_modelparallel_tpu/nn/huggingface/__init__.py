@@ -55,8 +55,8 @@ def _target_class(target):
 
 def _families():
     from smdistributed_modelparallel_tpu.nn.huggingface import (
-        bert, gpt2, gptj, gptneo, gptneox, laguna, mellum, roberta, sdar, t5,
-        vit,
+        bert, gpt2, gptj, gptneo, gptneox, laguna, lfm2_moe, mellum, roberta,
+        sdar, t5, vit,
     )
 
     fams = {}
@@ -64,7 +64,7 @@ def _families():
         ("gpt2", gpt2), ("gptj", gptj), ("gptneo", gptneo),
         ("gptneox", gptneox), ("bert", bert), ("roberta", roberta),
         ("vit", vit), ("t5", t5), ("laguna", laguna), ("mellum", mellum),
-        ("sdarmoe", sdar),
+        ("sdarmoe", sdar), ("lfm2moe", lfm2_moe),
     ):
         fams[name] = HFFamily(
             name=name,

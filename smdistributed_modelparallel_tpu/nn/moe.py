@@ -280,16 +280,22 @@ ROWS_PER_CHUNK = 1024
 def _chunk_rows(tokens, top_k, count, experts):
     """Rows a chunk: ``ROWS_PER_CHUNK``, in as many multiples as make an
     even router's load here (``tokens x top_k x count / experts``) about
-    three chunks. A chunk costs much the same full or nearly empty (each
-    one's weight gradients are [count, D, 2F] and [count, F, D] whatever
-    its rows), so a share whose even load is a whole number of chunks (8
-    of 64 a token on 16 held over 8,192 tokens: 16 x 1,024) would run or
-    skip a seventeenth, nearly empty chunk on each call by the draw of the
-    weights, and pay sixteen chunks' fixed cost where three carry the
-    rows. Three chunks keep the loop's work within a third of the even
-    load of the rows that landed here."""
+    three chunks, and one multiple more where that load would end exactly
+    on a chunk's edge. A chunk costs much the same full or nearly empty
+    (each one's weight gradients are [count, D, 2F] and [count, F, D]
+    whatever its rows), so a share whose even load is a whole number of
+    chunks (8 of 64 a token on 16 held over 8,192 tokens: 16 x 1,024; 4
+    of 64 on 8 held: 2 x 2,048) would run or skip one more, nearly empty
+    chunk on each call by the draw of the weights, and the step's time
+    would follow the draw (2.0 us a row at 4 of 64 on 8 held, PERF.md PR
+    42), and the first pays sixteen chunks' fixed cost where three carry
+    the rows. Three chunks, or two of the next size, keep the loop's work
+    within a third of the even load of the rows that landed here."""
     even = tokens * top_k * count // experts
-    return ROWS_PER_CHUNK * max(1, -(-even // (3 * ROWS_PER_CHUNK)))
+    multiples = max(1, -(-even // (3 * ROWS_PER_CHUNK)))
+    if even and even % (multiples * ROWS_PER_CHUNK) == 0:
+        multiples += 1
+    return ROWS_PER_CHUNK * multiples
 
 
 def _first_product(rows, w_gate_up, group_sizes, valid):
@@ -554,6 +560,18 @@ class DistributedDroplessMoE(nn.Module):
     shared_intermediate_size: int = 0
     norm_topk: bool = True
     routed_scaling: float = 1.0
+    # The router's scoring law: "softmax" over all ``num_experts`` outputs,
+    # or "sigmoid" of each (LFM2's: the chosen scores renormalised over
+    # their sum + 1e-6, as its class writes it) ...
+    score: str = "softmax"
+    # ... and a per-expert bias that enters the selection and not the
+    # weights (``router/selection_bias`` [num_experts], float32): the k
+    # largest of score + bias are chosen, the weights are their scores.
+    # A leaf the training step leaves as loaded: no gradient reaches it
+    # and ``DistributedOptimizer`` gives it no update
+    # (``nn/utils.FIXED_PARAM_NAMES``); whoever balances the load sets it
+    # between steps.
+    selection_bias: bool = False
     activation: str = "silu"
     initializer_range: float = 0.02
     dtype: Optional[Any] = None
@@ -574,6 +592,11 @@ class DistributedDroplessMoE(nn.Module):
                 f"dropless moe: top_k {K} of num_experts {E}, held "
                 f"({first}, {count}) must lie inside them."
             )
+        if self.score not in ("softmax", "sigmoid"):
+            raise SMPValidationError(
+                f"dropless moe: score {self.score!r} is neither 'softmax' "
+                "nor 'sigmoid'."
+            )
         if state.initialized and state.mesh.shape.get(EP_AXIS, 1) > 1:
             raise SMPValidationError(
                 "DistributedDroplessMoE computes one expert-parallel rank's "
@@ -588,13 +611,24 @@ class DistributedDroplessMoE(nn.Module):
         with jax.named_scope("smp/moe/route"):
             router_kernel = self.param(
                 "router/kernel", init, (D, E), jnp.float32)
-            probs = jax.nn.softmax(jnp.dot(
+            logits = jnp.dot(
                 x.astype(jnp.float32), router_kernel,
-                precision=jax.lax.Precision.HIGHEST), axis=-1)
-            top_weight, top_idx = jax.lax.top_k(probs, K)
+                precision=jax.lax.Precision.HIGHEST)
+            sigmoid = self.score == "sigmoid"
+            probs = (jax.nn.sigmoid(logits) if sigmoid
+                     else jax.nn.softmax(logits, axis=-1))
+            if self.selection_bias:
+                bias = self.param(
+                    "router/selection_bias", nn.initializers.zeros, (E,),
+                    jnp.float32)
+                _, top_idx = jax.lax.top_k(
+                    probs + jax.lax.stop_gradient(bias), K)
+                top_weight = jnp.take_along_axis(probs, top_idx, axis=-1)
+            else:
+                top_weight, top_idx = jax.lax.top_k(probs, K)
             if self.norm_topk:
-                top_weight = top_weight / jnp.sum(
-                    top_weight, axis=-1, keepdims=True)
+                total = jnp.sum(top_weight, axis=-1, keepdims=True)
+                top_weight = top_weight / (total + 1e-6 if sigmoid else total)
             top_weight = top_weight * self.routed_scaling
         rows = _chunk_rows(B * T, K, count, E)
         with jax.named_scope("smp/moe/dispatch"):
@@ -610,7 +644,8 @@ class DistributedDroplessMoE(nn.Module):
         w_down = down.astype(x.dtype)
         _record_trace("/".join(self.path), rows,
                       _wgrad_kernel_engages(rows, w_gate_up, w_down),
-                      _combine_kernel_engages(x, rows))
+                      _combine_kernel_engages(x, rows),
+                      int(sigmoid and self.selection_bias))
         # Its own scopes inside: gather, grouped FFN, scatter-add.
         out = held_experts_output(
             x, w_gate_up, w_down, weights, tokens, offsets,
@@ -639,10 +674,16 @@ class DistributedDroplessMoE(nn.Module):
 _TRACED_CHUNK_ROWS = {}
 
 
-def _record_trace(layer, rows, wgrad_engaged, combine_engaged):
+def _record_trace(layer, rows, wgrad_engaged, combine_engaged, router_law):
     from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
 
     _TRACED_CHUNK_ROWS[layer] = rows
+    telemetry.gauge(
+        "smp_moe_router_law",
+        "the expert layer's routing law: 0 softmax scores and their k "
+        "largest, 1 sigmoid scores with a bias in the selection; set while "
+        "the layer is traced",
+    ).labels(layer=layer).set(router_law)
     telemetry.gauge(
         "smp_moe_wgrad_kernel_engaged",
         "1 where the expert layer's weight gradients are summed inside the "

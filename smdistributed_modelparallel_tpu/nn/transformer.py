@@ -883,19 +883,26 @@ class DistributedTransformerLayer(nn.Module):
     head_gate: bool = False
     qk_norm: bool = False
     block_diffusion: Optional[int] = None
+    # ... or, in place of attention, the kind's mixer: the taps of a gated
+    # short convolution (nn/conv.DistributedShortConv; None: attention) ...
+    conv_mixer: Optional[int] = None
     # ... its expert layer: dropless (nn/moe.DistributedDroplessMoE) with
     # the ``(first, count)`` range of the ``num_experts`` it holds, a shared
-    # expert's width, renormalised top-k weights and their scale ...
+    # expert's width, renormalised top-k weights and their scale, the
+    # router's scoring law and whether a bias enters its selection ...
     moe_dropless: bool = False
     moe_held: Optional[tuple] = None
     moe_shared_intermediate_size: int = 0
     moe_norm_topk: bool = True
     moe_routed_scaling: float = 1.0
+    moe_score: str = "softmax"
+    moe_selection_bias: bool = False
     # ... and the kind's name, which a patterned stack always sets: the
     # layer's ops trace under ``smp/layer/<kind>`` (``smp/layer/block``
     # with no kind), its attention under ``smp/attn/block_diffusion``,
     # ``smp/attn/window`` or ``smp/attn/full`` (inside that the parts
-    # ``smp/attn/{qkv,qk_norm,core,out}``) and a dense feed-forward under
+    # ``smp/attn/{qkv,qk_norm,core,out}``), a convolution mixer under
+    # ``smp/conv/{in_proj,core,out_proj}`` and a dense feed-forward under
     # ``smp/mlp/dense``; the norms stay charged to their layer.
     kind: Optional[str] = None
     decode: bool = False
@@ -908,6 +915,25 @@ class DistributedTransformerLayer(nn.Module):
         with jax.named_scope("smp/layer/block" if self.kind is None
                              else f"smp/layer/{self.kind}"):
             return self._block(hidden, cross_states, attention_mask, xs)
+
+    @nn.nowrap
+    def _short_conv(self):
+        """``conv_mixer``'s mixer, called as the attention is."""
+        from smdistributed_modelparallel_tpu.nn.conv import (
+            DistributedShortConv,
+        )
+
+        if self.decode:
+            raise SMPValidationError(
+                "a convolution mixer keeps no decode state yet: "
+                "conv_mixer does not take decode=True."
+            )
+        conv = DistributedShortConv(
+            hidden_size=self.hidden_size, kernel_size=self.conv_mixer,
+            initializer_range=self.initializer_range, dtype=self.dtype,
+            name="conv",
+        )
+        return lambda h, attention_mask=None, xs=None: conv(h)
 
     @nn.nowrap
     def _block(self, hidden, cross_states, attention_mask, xs):
@@ -923,45 +949,51 @@ class DistributedTransformerLayer(nn.Module):
             epsilon=self.layernorm_epsilon, rms=rms, use_bias=not rms,
             name=name,
         )
-        attn = DistributedAttentionLayer(
-            num_attention_heads=self.num_attention_heads,
-            attention_head_size=self.attention_head_size,
-            hidden_size=self.hidden_size,
-            attention_dropout_prob=self.attention_dropout_prob,
-            hidden_dropout_prob=self.hidden_dropout_prob,
-            causal_mask_size=self.causal_mask_size,
-            mask_value=self.mask_value,
-            attention_in_fp32=self.attention_in_fp32,
-            query_key_layer_scaling=self.query_key_layer_scaling,
-            scale_attention_scores=self.scale_attention_scores,
-            scale_attn_by_layer_idx=self.scale_attn_by_layer_idx,
-            initializer_range=self.initializer_range,
-            use_qkv_bias=self.use_qkv_bias,
-            use_attn_dense_bias=self.use_attn_dense_bias,
-            rotary_dim=self.rotary_dim,
-            rotary_emb_base=self.rotary_emb_base,
-            gpt_neox_type_rotary=self.gpt_neox_type_rotary,
-            rotary_yarn=self.rotary_yarn,
-            window_size=self.window_size,
-            num_key_value_heads=self.num_key_value_heads,
-            head_gate=self.head_gate,
-            qk_norm=self.qk_norm,
-            qk_norm_epsilon=self.layernorm_epsilon,
-            block_diffusion=self.block_diffusion,
-            decode=self.decode,
-            decode_cache_len=self.decode_cache_len,
-            deterministic=self.deterministic,
-            dtype=self.dtype,
-            name="attention",
-        )
-        attention = attn
+        # The kind's mixer and the name of its norm: attention, or the
+        # gated short convolution (which reads no mask and no layer index).
+        mixer = "conv" if self.conv_mixer else "attention"
+        if self.conv_mixer:
+            attn = self._short_conv()
+        else:
+            attn = DistributedAttentionLayer(
+                num_attention_heads=self.num_attention_heads,
+                attention_head_size=self.attention_head_size,
+                hidden_size=self.hidden_size,
+                attention_dropout_prob=self.attention_dropout_prob,
+                hidden_dropout_prob=self.hidden_dropout_prob,
+                causal_mask_size=self.causal_mask_size,
+                mask_value=self.mask_value,
+                attention_in_fp32=self.attention_in_fp32,
+                query_key_layer_scaling=self.query_key_layer_scaling,
+                scale_attention_scores=self.scale_attention_scores,
+                scale_attn_by_layer_idx=self.scale_attn_by_layer_idx,
+                initializer_range=self.initializer_range,
+                use_qkv_bias=self.use_qkv_bias,
+                use_attn_dense_bias=self.use_attn_dense_bias,
+                rotary_dim=self.rotary_dim,
+                rotary_emb_base=self.rotary_emb_base,
+                gpt_neox_type_rotary=self.gpt_neox_type_rotary,
+                rotary_yarn=self.rotary_yarn,
+                window_size=self.window_size,
+                num_key_value_heads=self.num_key_value_heads,
+                head_gate=self.head_gate,
+                qk_norm=self.qk_norm,
+                qk_norm_epsilon=self.layernorm_epsilon,
+                block_diffusion=self.block_diffusion,
+                decode=self.decode,
+                decode_cache_len=self.decode_cache_len,
+                deterministic=self.deterministic,
+                dtype=self.dtype,
+                name="attention",
+            )
+            attention = attn
 
-        def attn(*args, **kwargs):
-            with jax.named_scope(
-                    "smp/attn/block_diffusion" if self.block_diffusion
-                    else "smp/attn/window" if self.window_size
-                    else "smp/attn/full"):
-                return attention(*args, **kwargs)
+            def attn(*args, **kwargs):
+                with jax.named_scope(
+                        "smp/attn/block_diffusion" if self.block_diffusion
+                        else "smp/attn/window" if self.window_size
+                        else "smp/attn/full"):
+                    return attention(*args, **kwargs)
 
         if self.num_experts > 0 and self.moe_dropless:
             from smdistributed_modelparallel_tpu.nn.moe import (
@@ -977,6 +1009,8 @@ class DistributedTransformerLayer(nn.Module):
                 shared_intermediate_size=self.moe_shared_intermediate_size,
                 norm_topk=self.moe_norm_topk,
                 routed_scaling=self.moe_routed_scaling,
+                score=self.moe_score,
+                selection_bias=self.moe_selection_bias,
                 activation=self.activation,
                 initializer_range=self.initializer_range,
                 dtype=self.dtype,
@@ -1025,7 +1059,7 @@ class DistributedTransformerLayer(nn.Module):
             # Parallel residual: GPT-J style shares one LN
             # (single_pre_layernorm); GPT-NeoX style (pre_layernorm, two
             # LNs) feeds the MLP from its own post-attention layernorm.
-            h = ln("attention/layernorm")(x)
+            h = ln(f"{mixer}/layernorm")(x)
             if self.pre_layernorm and not self.single_pre_layernorm:
                 h_mlp = ln("output/layernorm")(x)
             else:
@@ -1036,13 +1070,13 @@ class DistributedTransformerLayer(nn.Module):
             return x
 
         if self.pre_layernorm or self.single_pre_layernorm:
-            h = ln("attention/layernorm")(x)
+            h = ln(f"{mixer}/layernorm")(x)
         else:
             h = x
         a = attn(h, attention_mask=attention_mask, xs=xs)
         x = (x.astype(res_dtype) + a.astype(res_dtype)).astype(hidden.dtype)
         if self.post_layernorm:
-            x = ln("attention/post_layernorm")(x)
+            x = ln(f"{mixer}/post_layernorm")(x)
 
         if self.add_cross_attention and cross_states is not None:
             cross = DistributedAttentionLayer(
@@ -1352,6 +1386,13 @@ class DistributedTransformer(nn.Module):
                 "layer_pattern gives each layer a static window and shape; "
                 "it takes neither attention_layers_type nor fp8 matmuls."
             )
+        from smdistributed_modelparallel_tpu.utils.telemetry import (
+            record_conv_mixers,
+        )
+
+        for kind in dict.fromkeys(pattern):
+            if dict(self.layer_kinds[kind]).get("conv_mixer"):
+                record_conv_mixers(kind, pattern.count(kind))
         built, at = [], 0
         for n, (repeats, runs) in enumerate(pattern_segments(pattern)):
             period = sum(count for _, count in runs)

@@ -123,6 +123,19 @@ def axis_partitioned(init_fn, names):
     return nn.with_partitioning(init_fn, tuple(names))
 
 
+# Parameter leaves that a training step leaves as loaded, by the leaf's own
+# name (the last part of its path): the module that makes one stops its
+# gradient, and ``DistributedOptimizer`` zeroes whatever update the
+# transformation still gives it (weight decay). Today: the dropless expert
+# layer's ``router/selection_bias``.
+FIXED_PARAM_NAMES = frozenset({"selection_bias"})
+
+
+def is_fixed_param(path):
+    """Whether the '/'-joined parameter ``path`` names a fixed leaf."""
+    return path.rsplit("/", 1)[-1] in FIXED_PARAM_NAMES
+
+
 def tp_ring_active():
     """Whether the overlapped-tp ring path applies right now — the one
     lazy wrapper over ``ops.collective_matmul.tp_overlap_active`` the tp

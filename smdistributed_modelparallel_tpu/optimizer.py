@@ -35,6 +35,18 @@ logger = get_logger()
 _OPTIMIZER_SERIAL = [0]
 
 
+def _hold_fixed(updates):
+    """``updates`` with a zero for every leaf the model keeps as loaded
+    (``nn/utils.is_fixed_param``: no gradient reaches one, and this takes
+    the transformation's own terms, weight decay, off it). A tree with no
+    such leaf is returned as it is."""
+    from smdistributed_modelparallel_tpu.nn.utils import is_fixed_param
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, u: jnp.zeros_like(u)
+        if is_fixed_param(path_key(path)) else u, updates)
+
+
 class DistributedOptimizer:
     def __init__(self, tx, model=None, grad_clip_norm=None):
         # Monotonic serial for step-cache keys: id() can be reused by the
@@ -124,6 +136,7 @@ class DistributedOptimizer:
                     scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
                     grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
                 updates, new_opt_state = tx.update(grads, opt_state, params)
+                updates = _hold_fixed(updates)
                 new_params = optax.apply_updates(params, updates)
             return new_params, new_opt_state
 

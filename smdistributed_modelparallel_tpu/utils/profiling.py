@@ -158,6 +158,12 @@ SCOPES = {
                      "kernels or the plain path",
     "smp/attn/out": "inside any attention: the head gate and the output "
                     "projection",
+    "smp/conv/in_proj": "a short-convolution mixer: the input projection "
+                        "to its three streams",
+    "smp/conv/core": "a short-convolution mixer: the first gate, the "
+                     "causal depthwise convolution, the second gate",
+    "smp/conv/out_proj": "a short-convolution mixer: the output "
+                         "projection",
     "smp/mlp/dense": "the dense feed-forward of a layer",
     "smp/moe/route": "dropless expert layer: router product, softmax, "
                      "top-k",

@@ -867,6 +867,30 @@ def record_lm_head_positions(computed, given):
     gauge.labels(which="input").set(given)
 
 
+def record_conv_core_bytes(by_pass):
+    """``smp_conv_core_bytes{pass}``, ``pass`` ``fwd`` or ``bwd``: the
+    bytes the gate-conv-gate stage of one short-convolution mixer call
+    must move (``nn/conv.conv_core_bytes``: from its shapes, whatever
+    implements it). Set while the mixer is traced."""
+    gauge = telemetry.gauge(
+        "smp_conv_core_bytes",
+        "least bytes one call of a short-convolution mixer's gate-conv-"
+        "gate stage moves, forward and backward",
+    )
+    for kernel_pass, value in by_pass.items():
+        gauge.labels(**{"pass": kernel_pass}).set(value)
+
+
+def record_conv_mixers(kind, layers):
+    """``smp_conv_mixers{kind}``: layers of a patterned stack's ``kind``
+    whose mixer is the short convolution. Set while the stack is built."""
+    telemetry.gauge(
+        "smp_conv_mixers",
+        "layers of a patterned stack whose mixer is a short convolution, "
+        "by layer kind",
+    ).labels(kind=kind).set(layers)
+
+
 def record_loss_scale(event, scale):
     """One fp16 loss-scale event ("overflow" | "growth" | "static_overflow"):
     counter + current-scale gauge + a flight-recorder health event — the

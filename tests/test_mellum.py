@@ -370,7 +370,8 @@ def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
 
     monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 512)
     D, F, E, K, held = 256, 128, 64, 8, 16
-    assert moe._chunk_rows(1024, K, held, E) == 1024
+    rows = moe._chunk_rows(1024, K, held, E)
+    assert rows == 1536     # not 1,024: the even load is two of those
     layer = moe.DistributedDroplessMoE(
         hidden_size=D, intermediate_size=F, num_experts=E, top_k=K,
         held=(16, held), initializer_range=0.1)
@@ -390,7 +391,7 @@ def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
 
     products, stats = grads()
     assert _wgrad_engaged()[""] == 0
-    assert stats[held] == 0 and stats[:held].sum() > 1024     # two chunks
+    assert stats[held] == 0 and stats[:held].sum() > rows     # two chunks
     monkeypatch.setattr(gw, "FORCE_INTERPRET", True)
     kernel, _ = grads()
     assert _wgrad_engaged()[""] == 1
@@ -400,15 +401,15 @@ def test_the_layers_weight_gradients_through_the_kernel(monkeypatch):
         np.testing.assert_allclose(np.asarray(got) / scale,
                                    np.asarray(want) / scale, atol=2e-5)
     visited = moe.record_moe_stats({"": stats[None, None]})
-    visits, pairs = moe._experts_visited(stats[:held], 1024)
-    assert pairs == held * -(-int(stats[:held].sum()) // 1024)
+    visits, pairs = moe._experts_visited(stats[:held], rows)
+    assert pairs == held * -(-int(stats[:held].sum()) // rows)
     assert visited["wgrad_visited_share"] == visits / pairs
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 def test_the_layers_rows_summed_back_through_the_kernel(dtype, monkeypatch):
-    """The same share of Mellum's layer, two 1,024-row chunks over 1,024
+    """The same share of Mellum's layer, two 1,536-row chunks over 1,024
     tokens (two token tiles): with interpret mode forced the layer sums
     its routed rows back to their tokens in the kernel, forward and
     backward, and says so; without, XLA's scatter-add does and the gauge
@@ -438,7 +439,8 @@ def test_the_layers_rows_summed_back_through_the_kernel(dtype, monkeypatch):
 
     scatter, stats = run()
     assert _engaged("smp_moe_combine_kernel_engaged")[""] == 0
-    assert stats[held] == 0 and stats[:held].sum() > 1024     # two chunks
+    rows = moe._chunk_rows(1024, K, held, E)
+    assert stats[held] == 0 and stats[:held].sum() > rows     # two chunks
     monkeypatch.setattr(rs, "FORCE_INTERPRET", True)
     calls = []
     real = rs.row_scatter_add
@@ -466,8 +468,14 @@ def test_chunks_are_a_third_of_an_even_routers_load(monkeypatch):
     for load in (15_800, 16_384, 16_385, 16_950):           # seen by seed
         assert -(-load // 6144) == 3
     assert moe._chunk_rows(1, 1, 1, 64) == 1024             # never less
+    # an even load that would end on a chunk's edge takes the next size:
+    # 4 of 64 a token on 8 held over 8,192 tokens, 4,096 rows, is two
+    # chunks of 2,048 by thirds, and a call a few rows over ran a third
+    assert moe._chunk_rows(8192, 4, 8, 64) == 3072
+    for load in (3_850, 4_096, 4_097, 4_400):
+        assert -(-load // 3072) == 2
     monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 8)
-    assert moe._chunk_rows(48, 4, 4, 16) == 16               # 48 rows
+    assert moe._chunk_rows(48, 4, 4, 16) == 24               # 48 rows
 
 
 # -------------------------------------------------- q/k norms on and off

@@ -368,11 +368,12 @@ def compiled_scopes():
             {"microbatches": 2, "bf16": True}, tp_stack(), loss_mode=True),
         "patterned": _step_scopes(
             {"microbatches": 2, "bf16": True}, tp_stack(
-                num_layers=3, tie_input_output_embedding=False,
+                num_layers=4, tie_input_output_embedding=False,
                 head_positions=0.5, layernorm_type="rms",
-                layer_pattern=("lead", "routed", "noisy"),
+                layer_pattern=("lead", "routed", "noisy", "mixed"),
                 layer_kinds={
                     "lead": {},
+                    "mixed": dict(conv_mixer=3),
                     "routed": dict(expert, window_size=4, qk_norm=True,
                                    num_key_value_heads=1,
                                    moe_shared_intermediate_size=16),
