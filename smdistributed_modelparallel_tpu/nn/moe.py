@@ -36,8 +36,23 @@ absent from ``smdistributed.modelparallel`` v1.12.1.
   more). The loop runs over the chunks that hold rows, so the work follows
   the rows that landed here and no assignment is ever dropped: the row
   buffer's bound is the worst case, every token on every held expert;
+- the backward pass is written out (``_held_bwd``: the loop's trip count
+  is data) and so is a chunk's chain (``_chunk_grads``), which
+  ``jax.vjp`` over the forward's two products used to build: three
+  grouped products over rows a chunk, all in forward form (the first
+  product run again, ``u = g @ w_down^T`` with an fp32 result, ``dr = d_h
+  @ w_gate_up^T``) on weights transposed once a layer call, before the
+  loop. Differentiating the forward's closures ran four (a vjp evaluates
+  its primal) and read each weight tensor two ways in one loop body, so
+  the compiler re-laid both inside it, once a chunk. The second product
+  ``y = h_act @ w_down`` is not formed: its cotangent is ``w * g``
+  whatever ``y`` holds, and its one other reader, the combine weights'
+  gradient ``sum_d y * g``, is ``sum_f h_act * u``, the same double sum
+  taken in the other order. ``g`` enters the chain only as an operand of
+  the rows' dtype, so it is cast once a layer call and gathered in that
+  dtype;
 - the experts' weight gradients are summed where they are made: the
-  backward pass (``_held_bwd``) carries one fp32 sum a layer call for each
+  backward pass carries one fp32 sum a layer call for each
   of the two weight tensors, and a chunk's
   ``ops/pallas_grouped_wgrad.grouped_wgrad`` adds to the blocks of the
   experts it holds rows of and touches no other. The kernel stands aside
@@ -298,13 +313,6 @@ def _chunk_rows(tokens, top_k, count, experts):
     return ROWS_PER_CHUNK * multiples
 
 
-def _first_product(rows, w_gate_up, group_sizes, valid):
-    """The grouped gate/up product of masked ``rows``: [R, 2F], rows past
-    the groups left as the memory was."""
-    return jax.lax.ragged_dot(jnp.where(valid, rows, 0), w_gate_up,
-                              group_sizes)
-
-
 def _activated(h, valid, activation, dtype):
     """``act(gate) * up`` of the first product, masked: [R, F]."""
     gate, up = jnp.split(jnp.where(valid, h, 0), 2, axis=-1)
@@ -330,9 +338,9 @@ def _expert_ffn(rows, w_gate_up, w_down, group_sizes, activation):
     chip: anything), so they are masked going in and in the middle, and
     whoever sums the result masks them coming out (``_weighted``, or the
     kernel that adds the rows that belong and no other): nothing of them
-    reaches a result or, through the transposes, a gradient."""
+    reaches a result. The backward chain (``_chunk_grads``) masks alike."""
     valid = _valid_rows(rows.shape[0], group_sizes)
-    h = _first_product(rows, w_gate_up, group_sizes, valid)
+    h = jax.lax.ragged_dot(jnp.where(valid, rows, 0), w_gate_up, group_sizes)
     h = _activated(h, valid, activation, rows.dtype)
     return jax.lax.ragged_dot(h, w_down, group_sizes), valid
 
@@ -377,45 +385,56 @@ def _combine_kernel_engages(x, rows):
         *x.shape, rows, x.dtype.itemsize)
 
 
-def _chunk_grads(picked, w_gate_up, w_down, sizes, w, g, activation, sums):
-    """One chunk of ``_held_bwd``: the gradients of ``_expert_ffn``'s sum
-    against ``g`` [R, D] for the rows and the combine weights, and the
-    running fp32 sums ``(dgu, dd)`` of the weights' with this chunk's
-    added. The chain is ``_expert_ffn``'s, cut at the two products so that
-    the cotangents there (``d_h`` [R, 2F], ``d_y`` [R, D], both masked by
-    the chain's own ``where``s) can be handed to the kernel."""
+def _chunk_grads(picked, g_picked, w, sizes, w_gate_up, w_down, wt_gate_up,
+                 wt_down, activation, sums):
+    """One chunk of ``_held_bwd``, the chain written out: the gradients of
+    ``sum(_weighted(E(picked), w) * g)`` for the rows [R, D] and the
+    combine weights [R], and the running fp32 sums ``(dgu, dd)`` of the
+    weights' with this chunk's added. ``g_picked`` [R, D]: the output's
+    cotangent at the chunk's tokens, in the rows' dtype; ``wt_gate_up``
+    [n, 2F, D] and ``wt_down`` [n, D, F]: the weights transposed, made by
+    the caller once a layer call.
+
+    Three grouped products over rows, all in forward form: ``h`` (the
+    first product, run again), ``u = g @ w_down^T`` and ``dr = d_h @
+    w_gate_up^T``. The second product ``y = h_act @ w_down`` is not formed:
+    its cotangent is ``d_y = w * g`` whatever ``y`` holds, and its one
+    other reader, the combine weights' gradient ``sum_d y * g``, is
+    ``sum_f h_act * u`` by the same sum taken in the other order. Rows
+    past the groups are masked going in (``picked``, ``g_picked``, ``w``),
+    in the middle (``_activated`` and its transpose) and coming out (``u``,
+    ``dr``): nothing of them reaches a gradient."""
     dgu, dd = sums
-    kernel = _wgrad_kernel_engages(picked.shape[0], w_gate_up, w_down)
+    dtype = picked.dtype
     valid = _valid_rows(picked.shape[0], sizes)
-
-    def product_vjp(product, rows, weight):
-        """Over the rows alone where the kernel sums the weight's gradient
-        (the weight closed over: no [held, ., .] product is asked for)."""
-        if kernel:
-            return jax.vjp(lambda r: product(r, weight), rows)
-        return jax.vjp(product, rows, weight)
-
-    h, first_vjp = product_vjp(
-        lambda r, a: _first_product(r, a, sizes, valid), picked, w_gate_up)
+    x_m = jnp.where(valid, picked, 0)
+    g_m = jnp.where(valid, g_picked, 0)
+    wv = jnp.where(valid[:, 0], w, 0.0)[:, None]
+    h = jax.lax.ragged_dot(x_m, w_gate_up, sizes)
     h_act, act_vjp = jax.vjp(
-        lambda h: _activated(h, valid, activation, picked.dtype), h)
-    y, second_vjp = product_vjp(
-        lambda h, b: jax.lax.ragged_dot(h, b, sizes), h_act, w_down)
-    _, out_vjp = jax.vjp(lambda y, w: _weighted(y, w, valid), y, w)
-    d_y, dwc = out_vjp(g)
-    d_h_act, *db = second_vjp(d_y)
-    d_h, = act_vjp(d_h_act)
-    dr, *da = first_vjp(d_h)
-    if kernel:
+        lambda h: _activated(h, valid, activation, dtype), h)
+    u = jnp.where(valid, jax.lax.ragged_dot(
+        g_m, wt_down, sizes, preferred_element_type=jnp.float32), 0)
+    dwc = jnp.sum(h_act.astype(jnp.float32) * u, axis=-1)
+    d_y = (wv * g_m).astype(dtype)
+    d_h, = act_vjp((wv * u).astype(dtype))
+    dr = jnp.where(valid, jax.lax.ragged_dot(d_h, wt_gate_up, sizes), 0)
+    if _wgrad_kernel_engages(picked.shape[0], w_gate_up, w_down):
         from smdistributed_modelparallel_tpu.ops.pallas_grouped_wgrad import (
             grouped_wgrad,
         )
 
-        dgu = grouped_wgrad(jnp.where(valid, picked, 0), d_h, sizes, dgu)
+        dgu = grouped_wgrad(x_m, d_h, sizes, dgu)
         dd = grouped_wgrad(h_act, d_y, sizes, dd)
     else:
-        dgu = dgu + da[0].astype(jnp.float32)
-        dd = dd + db[0].astype(jnp.float32)
+        # The grouped products' transposes for the weight alone: a
+        # [held, D, 2F] and a [held, F, D] product in the operands' dtype.
+        da, = jax.linear_transpose(
+            lambda a: jax.lax.ragged_dot(x_m, a, sizes), w_gate_up)(d_h)
+        db, = jax.linear_transpose(
+            lambda b: jax.lax.ragged_dot(h_act, b, sizes), w_down)(d_y)
+        dgu = dgu + da.astype(jnp.float32)
+        dd = dd + db.astype(jnp.float32)
     return dr, dwc, (dgu, dd)
 
 
@@ -485,6 +504,13 @@ def _held_bwd(activation, rows, res, g):
 
     x, w_gate_up, w_down, weights, tokens, offsets = res
     kernel = _combine_kernel_engages(x, rows)
+    with jax.named_scope("smp/moe/experts"):
+        # Once a layer call: every grouped product of a chunk then reads
+        # its weight in forward form, and the loop re-lays nothing.
+        wt_gate_up = jnp.swapaxes(w_gate_up, 1, 2)
+        wt_down = jnp.swapaxes(w_down, 1, 2)
+        # ``g`` enters the chain as an operand of the rows' dtype only.
+        g = g.astype(x.dtype)
 
     def body(c, carry):
         dx, dgu, dd, dw = carry
@@ -493,8 +519,8 @@ def _held_bwd(activation, rows, res, g):
             picked, g_picked = x[t], g[t]
         with jax.named_scope("smp/moe/experts"):
             dr, dwc, (dgu, dd) = _chunk_grads(
-                picked, w_gate_up, w_down, sizes, w, g_picked, activation,
-                (dgu, dd))
+                picked, g_picked, w, sizes, w_gate_up, w_down, wt_gate_up,
+                wt_down, activation, (dgu, dd))
         with jax.named_scope("smp/moe/combine"):
             dx = (row_scatter_add(dx, dr, t, sizes) if kernel
                   else dx.at[t].add(dr.astype(jnp.float32)))

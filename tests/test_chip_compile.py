@@ -12,6 +12,7 @@ test's own process; keep these tests in this one file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -373,6 +374,24 @@ def test_bias_gelu_refuses_what_cannot_fit():
         pallas_gelu.FORCE_INTERPRET = was
 
 
+def _compile_held_experts(one_chip, differentiate, tokens, D, F, held, rows):
+    """HLO text of ``differentiate`` (``jax.grad``, ``jax.value_and_grad``)
+    of the sum of ``held_experts_output`` at a cell's held shapes, eight
+    assignments a token, compiled for the described chip."""
+    from smdistributed_modelparallel_tpu.nn import moe
+
+    def loss(x, w_gate_up, w_down, weights, tok, offsets):
+        return jnp.sum(moe.held_experts_output(
+            x, w_gate_up, w_down, weights, tok, offsets, "silu", rows))
+
+    assignments = tokens * 8
+    return _compile(
+        differentiate(loss, argnums=(0, 1, 2, 3)), one_chip,
+        (tokens, D), (held, D, 2 * F), (held, F, D),
+        ((assignments,), jnp.float32), ((assignments,), jnp.int32),
+        ((held + 1,), jnp.int32))
+
+
 @pytest.mark.parametrize(
     "tokens,D,F,held,rows",
     [(8192, 2304, 896, 16, 6144), (8192, 3072, 1024, 8, 1024)],
@@ -390,23 +409,14 @@ def test_held_experts_backward_sums_weight_gradients_in_the_kernel(
     ``jax.default_backend()``, which is the CPU here: the test says TPU,
     after compiling the CPU's answer (the products) to see the check live."""
     import smdistributed_modelparallel_tpu as smp
-    from smdistributed_modelparallel_tpu.nn import moe
 
     # The kernel stands aside on a mesh of several devices, and the mesh
     # test above leaves one behind.
     smp.shutdown()
-    assignments = tokens * 8
-
-    def loss(x, w_gate_up, w_down, weights, tok, offsets):
-        return jnp.sum(moe.held_experts_output(
-            x, w_gate_up, w_down, weights, tok, offsets, "silu", rows))
 
     def compiled():
-        return _compile(
-            jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
-            (tokens, D), (held, D, 2 * F), (held, F, D),
-            ((assignments,), jnp.float32), ((assignments,), jnp.int32),
-            ((held + 1,), jnp.int32))
+        return _compile_held_experts(
+            one_chip, jax.grad, tokens, D, F, held, rows)
 
     def weight_products(text):
         """Lines whose grouped product or convert-and-add fusion makes an
@@ -447,21 +457,12 @@ def test_held_experts_sum_their_rows_back_in_the_kernel(
     scatter-adds stay. As above the test says TPU after compiling the
     CPU's answer to see the check live."""
     import smdistributed_modelparallel_tpu as smp
-    from smdistributed_modelparallel_tpu.nn import moe
 
     smp.shutdown()
-    assignments = tokens * 8
-
-    def loss(x, w_gate_up, w_down, weights, tok, offsets):
-        return jnp.sum(moe.held_experts_output(
-            x, w_gate_up, w_down, weights, tok, offsets, "silu", rows))
 
     def compiled():
-        return _compile(
-            jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), one_chip,
-            (tokens, D), (held, D, 2 * F), (held, F, D),
-            ((assignments,), jnp.float32), ((assignments,), jnp.int32),
-            ((held + 1,), jnp.int32))
+        return _compile_held_experts(
+            one_chip, jax.value_and_grad, tokens, D, F, held, rows)
 
     def scatters(text):
         return [line for line in text.splitlines()
@@ -482,3 +483,61 @@ def test_held_experts_sum_their_rows_back_in_the_kernel(
                and "while/body" in c
                and "smp/moe/combine/smp_row_scatter_add" in c for c in calls)
     assert sum("transpose(" in c for c in calls) == 1    # one a pass
+
+
+def _computations(text):
+    """``{header: body text}`` of a compiled module's computations, the
+    header up to its parameters (``ENTRY %main.42``, ``%region_0.40``)."""
+    found, header = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and " -> " in line and not line.startswith(" "):
+            header = line.split(" (", 1)[0]
+            found[header] = []
+        elif header is not None:
+            found[header].append(line)
+    return {header: "\n".join(body) for header, body in found.items()}
+
+
+@pytest.mark.parametrize(
+    "tokens,D,F,held,rows",
+    [(8192, 2304, 896, 16, 6144), (16384, 2048, 768, 16, 6144),
+     (8192, 3072, 1024, 8, 1024), (8192, 2048, 1536, 8, 3072)],
+    ids=["mellum_held_16", "sdar_held_16", "laguna_held_8", "lfm2_held_8"],
+)
+def test_held_experts_backward_chunk_is_three_products_on_weights_laid_once(
+        one_chip, monkeypatch, tokens, D, F, held, rows):
+    """The backward of ``held_experts_output`` at the four expert cells'
+    held shapes, the chain written out (``_chunk_grads``): the chunk
+    loop's body holds three ``ragged-dot`` kernels (the first product run
+    again, ``g @ w_down^T`` in fp32, ``d_h @ w_gate_up^T``; the second
+    product is not formed) and no ``copy`` of a weight-shaped array: both
+    tensors are re-laid for their transposed use in the entry, once a
+    call. The test says TPU as the tests above do, so the weight
+    gradients are the kernel's and no fourth and fifth product."""
+    import smdistributed_modelparallel_tpu as smp
+
+    smp.shutdown()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    computations = _computations(_compile_held_experts(
+        one_chip, jax.grad, tokens, D, F, held, rows))
+    weight_shaped = tuple(
+        f"[{held},{a},{b}]"
+        for a, b in ((D, 2 * F), (2 * F, D), (F, D), (D, F)))
+
+    def products(body):
+        return re.findall(r"= (\w+\[[\d,]*\])\S* custom-call\(.*"
+                          r"ragged_dot_tiling", body)
+
+    def weight_copies(body):
+        return [line for line in body.splitlines()
+                if re.search(r" = \w+\[[\d,]*\]\S* copy\(", line)
+                and line.split(" = ", 1)[1].split("{", 1)[0].endswith(
+                    weight_shaped)]
+
+    loop, = (body for body in computations.values() if products(body))
+    assert sorted(products(loop)) == sorted([
+        f"bf16[{rows},{2 * F}]", f"f32[{rows},{F}]", f"bf16[{rows},{D}]"])
+    assert weight_copies(loop) == []
+    copied_in = [header for header, body in computations.items()
+                 if weight_copies(body)]
+    assert len(copied_in) == 1 and copied_in[0].startswith("ENTRY")

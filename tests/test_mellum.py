@@ -539,9 +539,12 @@ def test_qk_norm_ops_carry_their_scope_forward_and_backward():
 # model (tests/benchmark/lagunatiny.py: grouped KV heads, gates, the
 # patterned stack, the dropless expert layer) lowered to on the parent
 # commit of the PR that gave the attention layer its q/k norms (6c343d6),
-# under the matmul precision conftest.py pins and a mesh of one device.
+# under the matmul precision conftest.py pins and a mesh of one device,
+# taken again when PR 43 wrote the expert layer's backward chunk out by
+# hand (``nn/moe.py::_chunk_grads``: it held to 0db2c0e8... until then,
+# and that chain is all that differs).
 _LAGUNA_LOWERED_BEFORE = (
-    "0db2c0e8dbc3c1732ab23ac9947ea3c21f840e822fe8858bbea665419c84d1c3")
+    "88ffcea0281c209eb92f6084b07f03df29f17dc3a8ce23463adc42762febd6cc")
 
 
 def test_a_stack_without_the_norms_lowers_as_before():
