@@ -37,6 +37,12 @@ from smdistributed_modelparallel_tpu.nn.transformer import (
     DistributedTransformerOutputLayer,
 )
 from smdistributed_modelparallel_tpu.nn.conv import DistributedShortConv
+from smdistributed_modelparallel_tpu.nn.hyper_connection import (
+    DistributedHyperConnection,
+)
+from smdistributed_modelparallel_tpu.nn.latent_attention import (
+    DistributedLatentAttentionLayer,
+)
 from smdistributed_modelparallel_tpu.nn.diffusion import (
     masked_diffusion_loss,
     record_diffusion_stats,

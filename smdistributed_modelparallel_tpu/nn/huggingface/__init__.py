@@ -56,7 +56,7 @@ def _target_class(target):
 def _families():
     from smdistributed_modelparallel_tpu.nn.huggingface import (
         bert, gpt2, gptj, gptneo, gptneox, laguna, lfm2_moe, mellum, roberta,
-        sdar, t5, vit,
+        sdar, t5, vit, xing4,
     )
 
     fams = {}
@@ -64,7 +64,7 @@ def _families():
         ("gpt2", gpt2), ("gptj", gptj), ("gptneo", gptneo),
         ("gptneox", gptneox), ("bert", bert), ("roberta", roberta),
         ("vit", vit), ("t5", t5), ("laguna", laguna), ("mellum", mellum),
-        ("sdarmoe", sdar), ("lfm2moe", lfm2_moe),
+        ("sdarmoe", sdar), ("lfm2moe", lfm2_moe), ("xing40", xing4),
     ):
         fams[name] = HFFamily(
             name=name,

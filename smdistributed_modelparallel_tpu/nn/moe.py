@@ -295,20 +295,22 @@ ROWS_PER_CHUNK = 1024
 def _chunk_rows(tokens, top_k, count, experts):
     """Rows a chunk: ``ROWS_PER_CHUNK``, in as many multiples as make an
     even router's load here (``tokens x top_k x count / experts``) about
-    three chunks, and one multiple more where that load would end exactly
-    on a chunk's edge. A chunk costs much the same full or nearly empty
-    (each one's weight gradients are [count, D, 2F] and [count, F, D]
+    three chunks, and a multiple more for as long as that load would end
+    exactly on a chunk's edge. A chunk costs much the same full or nearly
+    empty (each one's weight gradients are [count, D, 2F] and [count, F, D]
     whatever its rows), so a share whose even load is a whole number of
     chunks (8 of 64 a token on 16 held over 8,192 tokens: 16 x 1,024; 4
-    of 64 on 8 held: 2 x 2,048) would run or skip one more, nearly empty
-    chunk on each call by the draw of the weights, and the step's time
-    would follow the draw (2.0 us a row at 4 of 64 on 8 held, PERF.md PR
-    42), and the first pays sixteen chunks' fixed cost where three carry
-    the rows. Three chunks, or two of the next size, keep the loop's work
-    within a third of the even load of the rows that landed here."""
+    of 64 on 8 held: 2 x 2,048; the same over 4,096 tokens: 2,048 again
+    after one multiple more, hence as long as) would run or skip one more,
+    nearly empty chunk on each call by the draw of the weights, and the
+    step's time would follow the draw (2.0 us a row at 4 of 64 on 8 held,
+    PERF.md PR 42; 2.09 over 4,096 tokens, PR 44), and the first pays
+    sixteen chunks' fixed cost where three carry the rows. Three chunks, or
+    two of the next size, keep the loop's work within a third of the even
+    load of the rows that landed here."""
     even = tokens * top_k * count // experts
     multiples = max(1, -(-even // (3 * ROWS_PER_CHUNK)))
-    if even and even % (multiples * ROWS_PER_CHUNK) == 0:
+    while even and even % (multiples * ROWS_PER_CHUNK) == 0:
         multiples += 1
     return ROWS_PER_CHUNK * multiples
 

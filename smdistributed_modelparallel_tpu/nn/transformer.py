@@ -886,6 +886,17 @@ class DistributedTransformerLayer(nn.Module):
     # ... or, in place of attention, the kind's mixer: the taps of a gated
     # short convolution (nn/conv.DistributedShortConv; None: attention) ...
     conv_mixer: Optional[int] = None
+    # ... or attention through low-rank latents: the fields of
+    # nn/latent_attention.DistributedLatentAttentionLayer that the layer
+    # does not have itself (the ranks, the three head sizes, the softmax
+    # scale), as a dict or its items (None: DistributedAttentionLayer) ...
+    latent_attention: Optional[Any] = None
+    # ... its residual path: ``{"streams": n, "sinkhorn_iters", "eps",
+    # "clamp"}`` (or its items) makes the layer's input and output
+    # [B, T, n, D] and puts nn/hyper_connection.DistributedHyperConnection
+    # round the attention and the feed-forward; None, or one stream:
+    # ``x + f(norm(x))`` on [B, T, D] ...
+    hyper_connection: Optional[Any] = None
     # ... its expert layer: dropless (nn/moe.DistributedDroplessMoE) with
     # the ``(first, count)`` range of the ``num_experts`` it holds, a shared
     # expert's width, renormalised top-k weights and their scale, the
@@ -901,9 +912,11 @@ class DistributedTransformerLayer(nn.Module):
     # layer's ops trace under ``smp/layer/<kind>`` (``smp/layer/block``
     # with no kind), its attention under ``smp/attn/block_diffusion``,
     # ``smp/attn/window`` or ``smp/attn/full`` (inside that the parts
-    # ``smp/attn/{qkv,qk_norm,core,out}``), a convolution mixer under
-    # ``smp/conv/{in_proj,core,out_proj}`` and a dense feed-forward under
-    # ``smp/mlp/dense``; the norms stay charged to their layer.
+    # ``smp/attn/{qkv,qk_norm,core,out}``, of latent attention
+    # ``smp/latent/*``), a convolution mixer under
+    # ``smp/conv/{in_proj,core,out_proj}``, a dense feed-forward under
+    # ``smp/mlp/dense`` and a hyper-connection under ``smp/mhc/*``; the
+    # norms stay charged to their layer.
     kind: Optional[str] = None
     decode: bool = False
     decode_cache_len: Optional[int] = None
@@ -936,6 +949,64 @@ class DistributedTransformerLayer(nn.Module):
         return lambda h, attention_mask=None, xs=None: conv(h)
 
     @nn.nowrap
+    def _latent_attention(self):
+        """``latent_attention``'s layer, called as the attention is."""
+        from smdistributed_modelparallel_tpu.nn.latent_attention import (
+            DistributedLatentAttentionLayer,
+        )
+
+        latent = DistributedLatentAttentionLayer(
+            num_attention_heads=self.num_attention_heads,
+            hidden_size=self.hidden_size,
+            rotary_emb_base=self.rotary_emb_base or 10000.0,
+            rotary_yarn=self.rotary_yarn,
+            layernorm_epsilon=self.layernorm_epsilon,
+            mask_value=self.mask_value,
+            initializer_range=self.initializer_range,
+            decode=self.decode, dtype=self.dtype, name="attention",
+            **dict(self.latent_attention),
+        )
+
+        def attn(h, attention_mask=None, xs=None):
+            with jax.named_scope("smp/attn/full"):
+                return latent(h, attention_mask=attention_mask, xs=xs)
+
+        return attn
+
+    @nn.nowrap
+    def _connector(self, res_dtype, dtype):
+        """``connect(site, x, f)``: one residual site of the block. With
+        one stream ``x + f(x)``; with ``hyper_connection``'s streams ``x``
+        is [B, T, n, D], ``f`` reads the pre mix of the site's own
+        connection and its output is spread over the mixed streams."""
+        hc = dict(self.hyper_connection or {})
+        if hc.get("streams", 1) == 1:
+            def connect(site, x, f):
+                out = f(x)
+                return (x.astype(res_dtype)
+                        + out.astype(res_dtype)).astype(dtype)
+
+            return connect
+        from smdistributed_modelparallel_tpu.nn import hyper_connection
+
+        if (self.parallel_attn_output or self.add_cross_attention
+                or self.post_layernorm or self.decode):
+            raise SMPValidationError(
+                "hyper_connection streams take a pre-norm block with no "
+                "parallel residual, cross-attention or decode cache."
+            )
+
+        def connect(site, x, f):
+            u, h_post, h_res = hyper_connection.DistributedHyperConnection(
+                hidden_size=self.hidden_size,
+                norm_epsilon=self.layernorm_epsilon,
+                initializer_range=self.initializer_range, dtype=self.dtype,
+                name=f"{site}/hyper_connection", **hc)(x)
+            return hyper_connection.post_res(x, f(u), h_post, h_res)
+
+        return connect
+
+    @nn.nowrap
     def _block(self, hidden, cross_states, attention_mask, xs):
         # attention_mask may be a (self_mask, cross_mask) pair: the stack's
         # carry protocol has one mask slot, and T5-style models need both a
@@ -954,6 +1025,8 @@ class DistributedTransformerLayer(nn.Module):
         mixer = "conv" if self.conv_mixer else "attention"
         if self.conv_mixer:
             attn = self._short_conv()
+        elif self.latent_attention:
+            attn = self._latent_attention()
         else:
             attn = DistributedAttentionLayer(
                 num_attention_heads=self.num_attention_heads,
@@ -1053,6 +1126,7 @@ class DistributedTransformerLayer(nn.Module):
                     return dense(h)
 
         res_dtype = jnp.float32 if self.fp32_residual_addition else hidden.dtype
+        connect = self._connector(res_dtype, hidden.dtype)
         x = hidden
 
         if self.parallel_attn_output:
@@ -1069,12 +1143,10 @@ class DistributedTransformerLayer(nn.Module):
             x = (x.astype(res_dtype) + a.astype(res_dtype) + m.astype(res_dtype)).astype(hidden.dtype)
             return x
 
-        if self.pre_layernorm or self.single_pre_layernorm:
-            h = ln(f"{mixer}/layernorm")(x)
-        else:
-            h = x
-        a = attn(h, attention_mask=attention_mask, xs=xs)
-        x = (x.astype(res_dtype) + a.astype(res_dtype)).astype(hidden.dtype)
+        pre_ln = self.pre_layernorm or self.single_pre_layernorm
+        x = connect(mixer, x, lambda u: attn(
+            ln(f"{mixer}/layernorm")(u) if pre_ln else u,
+            attention_mask=attention_mask, xs=xs))
         if self.post_layernorm:
             x = ln(f"{mixer}/post_layernorm")(x)
 
@@ -1096,21 +1168,17 @@ class DistributedTransformerLayer(nn.Module):
                 dtype=self.dtype,
                 name="crossattention",
             )
-            h = ln("crossattention/layernorm")(x) if self.pre_layernorm else x
-            c = cross(
-                h, cross_states=cross_states,
-                attention_mask=cross_attention_mask,
-            )
-            x = (x.astype(res_dtype) + c.astype(res_dtype)).astype(hidden.dtype)
+            x = connect("crossattention", x, lambda u: cross(
+                ln("crossattention/layernorm")(u) if self.pre_layernorm
+                else u,
+                cross_states=cross_states,
+                attention_mask=cross_attention_mask))
             if self.post_layernorm:
                 x = ln("crossattention/post_layernorm")(x)
 
-        if (self.pre_layernorm and not self.single_pre_layernorm):
-            h = ln("output/layernorm")(x)
-        else:
-            h = x
-        m = mlp(h)
-        x = (x.astype(res_dtype) + m.astype(res_dtype)).astype(hidden.dtype)
+        own_ln = self.pre_layernorm and not self.single_pre_layernorm
+        x = connect("output", x, lambda u: mlp(
+            ln("output/layernorm")(u) if own_ln else u))
         if self.post_layernorm:
             x = ln("output/post_layernorm")(x)
         return x
@@ -1282,6 +1350,10 @@ class DistributedTransformer(nn.Module):
     attention_layers_type: Optional[tuple] = None
     layer_pattern: Optional[tuple] = None
     layer_kinds: Optional[Any] = None
+    # Every layer's residual path (DistributedTransformerLayer's field of
+    # the name): with n > 1 streams the stack copies its input to n
+    # streams, its scans carry [B, T, n, D], and it returns their sum.
+    hyper_connection: Optional[Any] = None
     activation_checkpointing: bool = False
     num_experts: int = 0
     moe_top_k: int = 2
@@ -1290,6 +1362,10 @@ class DistributedTransformer(nn.Module):
     decode_cache_len: Optional[int] = None
     deterministic: Optional[bool] = None
     dtype: Optional[Any] = None
+
+    @nn.nowrap
+    def _streams(self):
+        return dict(self.hyper_connection or {}).get("streams", 1)
 
     @nn.nowrap
     def _kind_kwargs(self, kind):
@@ -1331,6 +1407,7 @@ class DistributedTransformer(nn.Module):
             layernorm_type=self.layernorm_type,
             use_mlp_bias=self.use_mlp_bias,
             gated_mlp=self.gated_mlp,
+            hyper_connection=self.hyper_connection,
             num_experts=self.num_experts,
             moe_top_k=self.moe_top_k,
             moe_capacity_factor=self.moe_capacity_factor,
@@ -1387,12 +1464,19 @@ class DistributedTransformer(nn.Module):
                 "it takes neither attention_layers_type nor fp8 matmuls."
             )
         from smdistributed_modelparallel_tpu.utils.telemetry import (
+            record_attn_latent_layers,
             record_conv_mixers,
+            record_mhc_streams,
         )
 
         for kind in dict.fromkeys(pattern):
-            if dict(self.layer_kinds[kind]).get("conv_mixer"):
+            fields = dict(self.layer_kinds[kind])
+            if fields.get("conv_mixer"):
                 record_conv_mixers(kind, pattern.count(kind))
+            if fields.get("latent_attention"):
+                record_attn_latent_layers(kind, pattern.count(kind))
+            if self._streams() > 1:
+                record_mhc_streams(kind, self._streams())
         built, at = [], 0
         for n, (repeats, runs) in enumerate(pattern_segments(pattern)):
             period = sum(count for _, count in runs)
@@ -1426,6 +1510,16 @@ class DistributedTransformer(nn.Module):
             return self._stack(hidden, cross_states, attention_mask)
 
     def _stack(self, hidden, cross_states, attention_mask):
+        if self._streams() > 1:
+            from smdistributed_modelparallel_tpu.nn import hyper_connection
+
+            out = self._layers(
+                hyper_connection.expand_streams(hidden, self._streams()),
+                cross_states, attention_mask)
+            return hyper_connection.collapse_streams(out)
+        return self._layers(hidden, cross_states, attention_mask)
+
+    def _layers(self, hidden, cross_states, attention_mask):
         if self.layer_pattern is not None:
             carry = (hidden, cross_states, attention_mask)
             for module, xs in self.segments:
@@ -1454,8 +1548,10 @@ class DistributedTransformer(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
-        if self.layer_pattern is not None:
-            return None     # two kinds of layer in a stage: not yet
+        if self.layer_pattern is not None or self._streams() > 1:
+            # Two kinds of layer in a stage, or a carry of several streams
+            # (the executors' embed and head see one): not yet.
+            return None
         return PipelineSpec(
             layer_path="seq_layers/layer",
             num_layers=self.num_layers,
@@ -1538,6 +1634,8 @@ class DistributedTransformerLMHead(nn.Module):
     # A stack of layers that differ in shape: see DistributedTransformer.
     layer_pattern: Optional[tuple] = None
     layer_kinds: Optional[Any] = None
+    # The residual path of every layer: see DistributedTransformer.
+    hyper_connection: Optional[Any] = None
     # "layer" or "rms" (RMSNorm, no bias), for every norm of the model.
     layernorm_type: str = "layer"
     use_mlp_bias: bool = True
@@ -1667,6 +1765,7 @@ class DistributedTransformerLMHead(nn.Module):
             attention_layers_type=self.attention_layers_type,
             layer_pattern=self.layer_pattern,
             layer_kinds=self.layer_kinds,
+            hyper_connection=self.hyper_connection,
             activation_checkpointing=self.activation_checkpointing,
             num_experts=self.num_experts,
             moe_top_k=self.moe_top_k,
@@ -1806,8 +1905,9 @@ class DistributedTransformerLMHead(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
-        if self.layer_pattern is not None:
-            return None     # two kinds of layer in a stage: not yet
+        if (self.layer_pattern is not None
+                or dict(self.hyper_connection or {}).get("streams", 1) > 1):
+            return None     # see DistributedTransformer.pipeline_spec
         return PipelineSpec(
             layer_path="transformer/seq_layers/layer",
             num_layers=self.num_layers,

@@ -42,6 +42,12 @@ flash-attention backward decomposition.
 
 Layout: inputs [B, T, H, hd]; kernels run on [B*H, T, hd].
 
+Value heads of their own size: v (and so o, dO and dv) may be
+[.., hd_v] wide where q and k are [.., hd] (latent attention: 192 against
+128). Each is padded to its own lane width (``_pad_width``) and the blocks
+of v, o, dO and dv take the values' width, so narrower values cost no
+padded product; with hd_v == hd every shape and block is what it was.
+
 Grouped KV heads: k and v may hold fewer heads than q ([B, S, H_kv, hd],
 H a multiple of H_kv); query head h reads KV head h // (H / H_kv). The
 forward and dq kernels reach the shared K/V block through their index map
@@ -386,7 +392,6 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     # fp32 are exact, so post-scaling the fp32 scores keeps score math fp32
     # (N8 parity) at native throughput.
     q = q_ref[0]                                      # [bq, hd]
-    hd = q.shape[-1]
     q_offset = i * block_q
     if has_ids:
         q_ids = qid_ref[0, pl.ds(q_offset, block_q)]
@@ -453,7 +458,7 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         q_offset, block_q, k_ref.shape[1] // block_k, has_ids, bd,
         q_len=q_len, kv_len=kv_len, causal=causal, window=window,
         block_k=block_k)
-    acc0 = jnp.zeros((block_q, hd), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc, m, l = _walk(ranges, body, (acc0, m0, l0))
@@ -655,9 +660,10 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
             k_offset, k_offset + block_k, q_len=q_len, kv_len=kv_len,
             causal=causal, window=window, block_q=block_q, num_q=num_q,
         )]
-    hd = k_blk.shape[-1]
-    z = jnp.zeros((block_k, hd), jnp.float32)
-    dk, dv = _walk(ranges, body, (z, z))
+    z = jnp.zeros((block_k, k_blk.shape[-1]), jnp.float32)
+    zv = (z if v_blk.shape[-1] == k_blk.shape[-1]
+          else jnp.zeros((block_k, v_blk.shape[-1]), jnp.float32))
+    dk, dv = _walk(ranges, body, (z, zv))
     # ds carries exactly one *scale factor and q_blk is raw (unscaled), so
     # dk = ds^T.q is already correct.
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -707,29 +713,40 @@ def _kv_index(group):
     return (lambda b, i: (b // group, 0, 0)), (lambda b, j: (b // group, j, 0))
 
 
+def _pad_width(hd):
+    """The lane width a head size runs at: itself in whole 128s, else the
+    next power of two from 128 up."""
+    return max(128, int(2 ** np.ceil(np.log2(hd)))) if hd % 128 else hd
+
+
 def _prep(q, k, v, block_q, block_k):
+    """``(qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad, hdv,
+    hdv_pad))``: the operands as [B*heads, positions, width] padded to
+    whole blocks and lanes, q and k at the keys' width, v at its own."""
     B, T, H, hd = q.shape
-    S = k.shape[1]
-    if H % k.shape[2] or k.shape[2] != v.shape[2]:
+    S, hdv = k.shape[1], v.shape[-1]
+    if H % k.shape[2] or k.shape[2] != v.shape[2] or k.shape[-1] != hd:
         raise ValueError(
-            f"flash attention: {H} query heads against {k.shape[2]} key and "
-            f"{v.shape[2]} value heads (H must be a multiple of H_kv)."
+            f"flash attention: {H} query heads of {hd} against "
+            f"{k.shape[2]} key heads of {k.shape[-1]} and {v.shape[2]} value "
+            "heads (H must be a multiple of H_kv, q and k of one size)."
         )
 
     def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], x.shape[1], hd)
+        return x.transpose(0, 2, 1, 3).reshape(
+            B * x.shape[2], x.shape[1], x.shape[3])
 
     qt, kt, vt = to_bht(q), to_bht(k), to_bht(v)
-    hd_pad = max(128, int(2 ** np.ceil(np.log2(hd)))) if hd % 128 else hd
+    hd_pad, hdv_pad = _pad_width(hd), _pad_width(hdv)
     t_pad = ((T + block_q - 1) // block_q) * block_q
     s_pad = ((S + block_k - 1) // block_k) * block_k
     if hd_pad != hd or t_pad != T:
         qt = jnp.pad(qt, ((0, 0), (0, t_pad - T), (0, hd_pad - hd)))
     if hd_pad != hd or s_pad != S:
-        pad = ((0, 0), (0, s_pad - S), (0, hd_pad - hd))
-        kt = jnp.pad(kt, pad)
-        vt = jnp.pad(vt, pad)
-    return qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad)
+        kt = jnp.pad(kt, ((0, 0), (0, s_pad - S), (0, hd_pad - hd)))
+    if hdv_pad != hdv or s_pad != S:
+        vt = jnp.pad(vt, ((0, 0), (0, s_pad - S), (0, hdv_pad - hdv)))
+    return qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad, hdv, hdv_pad)
 
 
 def _group_of(q, k):
@@ -788,9 +805,14 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
                     dropout_rate, block_q, block_k, interpret,
                     q_ids=None, kv_ids=None, head0=None, head_total=None,
                     counter_len=None, bd=None):
-    qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad) = _prep(
+    qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad, hdv, hdv_pad) = _prep(
         q, k, v, block_q, block_k
     )
+    from smdistributed_modelparallel_tpu.utils.telemetry import (
+        record_flash_v_head_dim,
+    )
+
+    record_flash_v_head_dim(hdv)
     extra, extra_specs, has_kpm, has_seed, has_head0 = _common_inputs(
         kpad_bias, seed, s_pad, B, H, interpret, head0
     )
@@ -820,18 +842,18 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         in_specs=[
             pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
-            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
+            pl.BlockSpec((1, s_pad, hdv_pad), kv_whole),
             *extra_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, hdv_pad), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             # ids mode feeds the ring's fp32 online-softmax merge: per-step
             # partials must not round-trip through bf16 before accumulating.
             jax.ShapeDtypeStruct(
-                (B * H, t_pad, hd_pad),
+                (B * H, t_pad, hdv_pad),
                 jnp.float32 if has_ids else q.dtype,
             ),
             jax.ShapeDtypeStruct((B * H, 1, t_pad), jnp.float32),
@@ -840,7 +862,7 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         interpret=interpret or FORCE_INTERPRET,
         **more_call,
     )(qt, kt, vt, *extra)
-    o = out[:, :T, :hd].reshape(B, H, T, hd).transpose(0, 2, 1, 3)
+    o = out[:, :T, :hdv].reshape(B, H, T, hdv).transpose(0, 2, 1, 3)
     return o, lse
 
 
@@ -848,12 +870,12 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
                     window, dropout_rate, block_q, block_k, interpret,
                     q_ids=None, kv_ids=None, head0=None, head_total=None,
                     counter_len=None, bd=None):
-    qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad) = _prep(
+    qt, kt, vt, (B, T, S, H, hd, hd_pad, t_pad, s_pad, hdv, hdv_pad) = _prep(
         q, k, v, block_q, block_k
     )
-    gt = g.transpose(0, 2, 1, 3).reshape(B * H, T, hd)
-    if hd_pad != hd or t_pad != T:
-        gt = jnp.pad(gt, ((0, 0), (0, t_pad - T), (0, hd_pad - hd)))
+    gt = g.transpose(0, 2, 1, 3).reshape(B * H, T, hdv)
+    if hdv_pad != hdv or t_pad != T:
+        gt = jnp.pad(gt, ((0, 0), (0, t_pad - T), (0, hdv_pad - hdv)))
     # delta = rowsum(dO * O): one fused elementwise+reduce pass in XLA.
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
@@ -884,6 +906,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         more_call = _bd_compiler_params(
             max(s_pad, t_pad), hd_pad, qt.dtype.itemsize)
     res_spec_q = pl.BlockSpec((1, t_pad, hd_pad), lambda b, i: (b, 0, 0))
+    res_spec_do = pl.BlockSpec((1, t_pad, hdv_pad), lambda b, i: (b, 0, 0))
     row_spec = pl.BlockSpec((1, 1, t_pad), lambda b, i: (b, 0, 0))
     group = _group_of(q, k)
     kv_whole, kv_block = _kv_index(group)
@@ -897,8 +920,8 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
-            pl.BlockSpec((1, s_pad, hd_pad), kv_whole),
-            pl.BlockSpec((1, block_q, hd_pad), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, s_pad, hdv_pad), kv_whole),
+            pl.BlockSpec((1, block_q, hdv_pad), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             *extra_specs,
@@ -918,15 +941,15 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         in_specs=[
             res_spec_q,
             pl.BlockSpec((1, block_k, hd_pad), kv_block),
-            pl.BlockSpec((1, block_k, hd_pad), kv_block),
-            res_spec_q,
+            pl.BlockSpec((1, block_k, hdv_pad), kv_block),
+            res_spec_do,
             row_spec,
             row_spec,
             *extra_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd_pad), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, hd_pad), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, hdv_pad), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
             # ids mode: fp32 per-step gradients for the ring's rotating
@@ -936,7 +959,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
                 jnp.float32 if has_ids or partial_kv else k.dtype,
             ),
             jax.ShapeDtypeStruct(
-                (B * H, s_pad, hd_pad),
+                (B * H, s_pad, hdv_pad),
                 jnp.float32 if has_ids or partial_kv else v.dtype,
             ),
         ],
@@ -945,17 +968,17 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         **more_call,
     )(qt, kt, vt, gt, lse, delta, *extra)
 
-    def from_bht(x, L):
+    def from_bht(x, L, hd=hd):
         return x[:, :L, :hd].reshape(B, H, L, hd).transpose(0, 2, 1, 3)
 
-    def kv_from_bht(x, like):
+    def kv_from_bht(x, like, hd=hd):
         if not partial_kv:
-            return from_bht(x, S)
+            return from_bht(x, S, hd)
         x = x[:, :S, :hd].reshape(B, H // group, group, S, hd).sum(axis=2)
         return x.astype(jnp.float32 if has_ids else like.dtype).transpose(
             0, 2, 1, 3)
 
-    return from_bht(dq, T), kv_from_bht(dk, k), kv_from_bht(dv, v)
+    return (from_bht(dq, T), kv_from_bht(dk, k), kv_from_bht(dv, v, hdv))
 
 
 # ----------------------------------------------------------------------

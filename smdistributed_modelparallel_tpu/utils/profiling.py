@@ -164,6 +164,29 @@ SCOPES = {
                      "causal depthwise convolution, the second gate",
     "smp/conv/out_proj": "a short-convolution mixer: the output "
                          "projection",
+    "smp/latent/q_down": "latent attention: the query's "
+                              "down-projection and its latent's norm",
+    "smp/latent/q_up": "latent attention: the query heads from "
+                            "their latent",
+    "smp/latent/kv_down": "latent attention: the key-value latent "
+                               "and the shared rotary key, one product, "
+                               "and the latent's norm",
+    "smp/latent/kv_up": "latent attention: the heads' keys (no "
+                             "position) and values from the latent",
+    "smp/latent/rope": "latent attention: rotary on the queries' "
+                            "and the shared key's rotary part, and the "
+                            "heads put together",
+    "smp/latent/out": "latent attention: the output projection",
+    "smp/mhc/coeff": "hyper-connection: the streams' norm and the "
+                     "products that give a token its pre, post and "
+                     "residual coefficients",
+    "smp/mhc/sinkhorn": "hyper-connection: the residual coefficients "
+                        "made doubly stochastic",
+    "smp/mhc/pre": "hyper-connection: the streams mixed into a "
+                   "sub-layer's input",
+    "smp/mhc/post_res": "hyper-connection: the streams mixed among "
+                        "themselves and the sub-layer's output spread "
+                        "over them",
     "smp/mlp/dense": "the dense feed-forward of a layer",
     "smp/moe/route": "dropless expert layer: router product, softmax, "
                      "top-k",

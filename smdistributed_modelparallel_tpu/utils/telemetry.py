@@ -891,6 +891,51 @@ def record_conv_mixers(kind, layers):
     ).labels(kind=kind).set(layers)
 
 
+def record_mhc_streams(kind, streams):
+    """``smp_mhc_streams{kind}``: residual streams a layer of a patterned
+    stack's ``kind`` carries (``nn/hyper_connection.py``). Set while the
+    stack is built, for stacks with more than one."""
+    telemetry.gauge(
+        "smp_mhc_streams",
+        "residual streams of a hyper-connected layer, by layer kind",
+    ).labels(kind=kind).set(streams)
+
+
+def record_mhc_bytes(by_pass):
+    """``smp_mhc_bytes{pass}``, ``pass`` ``fwd`` or ``bwd``: the bytes one
+    hyper-connected sub-layer's coefficient read and two mixes must move
+    (``nn/hyper_connection.mhc_bytes``: from its shapes, whatever
+    implements them). Set while the sub-layer is traced."""
+    gauge = telemetry.gauge(
+        "smp_mhc_bytes",
+        "least bytes one hyper-connected sub-layer's coefficient read and "
+        "two stream mixes move, forward and backward",
+    )
+    for kernel_pass, value in by_pass.items():
+        gauge.labels(**{"pass": kernel_pass}).set(value)
+
+
+def record_attn_latent_layers(kind, layers):
+    """``smp_attn_latent_layers{kind}``: layers of a patterned stack's
+    ``kind`` whose attention is latent attention. Set while the stack is
+    built."""
+    telemetry.gauge(
+        "smp_attn_latent_layers",
+        "layers of a patterned stack whose attention projects through "
+        "low-rank latents, by layer kind",
+    ).labels(kind=kind).set(layers)
+
+
+def record_flash_v_head_dim(v_head_dim):
+    """``smp_flash_v_head_dim``: the value heads' size of the last flash
+    kernel call traced (``ops/pallas_attention.py``; the keys' size where
+    a model has one head size)."""
+    telemetry.gauge(
+        "smp_flash_v_head_dim",
+        "value head size of the last flash attention call traced",
+    ).set(v_head_dim)
+
+
 def record_loss_scale(event, scale):
     """One fp16 loss-scale event ("overflow" | "growth" | "static_overflow"):
     counter + current-scale gauge + a flight-recorder health event — the

@@ -91,6 +91,31 @@ def test_flash_attention_forward_backward(one_chip, shape):
         assert name in text
 
 
+def test_flash_attention_with_value_heads_of_their_own_size(one_chip):
+    """Latent attention's heads at their published sizes (4 heads a chip,
+    T 4096): query and key heads of 192 run at 256 lanes, value heads of
+    128 at their own 128, in all three kernels."""
+    import re
+
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, causal=True, scale=0.14468))
+
+    qk, v = (1, 4096, 4, 192), (1, 4096, 4, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qk, qk, v)
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert name in text
+    calls = [line for line in text.split("\n")
+             if "tpu_custom_call" in line and "smp_flash_" in line]
+    # the forward's output and the dkv pass's dv are 128 wide, dq and dk 256
+    shapes = " ".join(re.findall(r"bf16\[4,4096,(\d+)\]", " ".join(
+        line.split(" custom-call(")[0] for line in calls)))
+    assert sorted(shapes.split()) == ["128", "128", "256", "256"]
+
+
 def test_flash_attention_under_the_stage_vmap_on_a_mesh(topo, monkeypatch):
     """Pythia-1.4B's attention (16 heads of 128, T 2048, a microbatch of 1)
     as the pipeline executors run it at pp 2 x tp 2: ``stage_vmap`` over

@@ -474,8 +474,14 @@ def test_chunks_are_a_third_of_an_even_routers_load(monkeypatch):
     assert moe._chunk_rows(8192, 4, 8, 64) == 3072
     for load in (3_850, 4_096, 4_097, 4_400):
         assert -(-load // 3072) == 2
+    # ... and the next again while it still would: the same share over
+    # 4,096 tokens, 2,048 rows, is one chunk of 1,024 by thirds, then one of
+    # 2,048 that a call a few rows over would overflow, then 3,072
+    assert moe._chunk_rows(4096, 4, 8, 64) == 3072
+    for load in (1_900, 2_048, 2_049, 2_300):
+        assert -(-load // 3072) == 1
     monkeypatch.setattr(moe, "ROWS_PER_CHUNK", 8)
-    assert moe._chunk_rows(48, 4, 4, 16) == 24               # 48 rows
+    assert moe._chunk_rows(48, 4, 4, 16) == 32     # 48 rows: 16, 24, then 32
 
 
 # -------------------------------------------------- q/k norms on and off

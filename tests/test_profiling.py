@@ -346,7 +346,8 @@ def _step_scopes(cfg, module, loss_mode=False, seq=8):
 @pytest.fixture(scope="module")
 def compiled_scopes():
     """The scopes of a handful of compiled tiny steps: both stacks at
-    pp = 1, the CPU mesh's pp = 2 under each executor."""
+    pp = 1 (a patterned one, and one of latent-attention layers on two
+    residual streams), the CPU mesh's pp = 2 under each executor."""
     from smdistributed_modelparallel_tpu.models.transformer_lm import (
         TransformerLM,
     )
@@ -378,6 +379,16 @@ def compiled_scopes():
                                    num_key_value_heads=1,
                                    moe_shared_intermediate_size=16),
                     "noisy": dict(expert, block_diffusion=2)})),
+        "streams": _step_scopes(
+            {"microbatches": 2, "bf16": True}, tp_stack(
+                layernorm_type="rms", tie_input_output_embedding=False,
+                hyper_connection={"streams": 2},
+                layer_pattern=("latent", "latent"),
+                layer_kinds={"latent": dict(
+                    rotary_emb_base=10000.0, latent_attention=dict(
+                        q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
+                        qk_rope_head_dim=4, v_head_dim=8,
+                        softmax_scale=0.3))})),
         "1f1b": _step_scopes(pp2, tp_stack()),
         "virtual": _step_scopes(
             dict(pp2, virtual_pipeline_degree=2), tp_stack(num_layers=4)),
@@ -421,7 +432,7 @@ class TestScopeVocabulary:
         if scope.startswith("smp/pipeline/"):
             assert where <= {"1f1b", "virtual", "zero_bubble", "simple"}
         if scope == "smp/model/stack":      # the executors run the layers
-            assert where == {"zoo", "tp_stack", "patterned"}
+            assert where == {"zoo", "tp_stack", "patterned", "streams"}
         if scope.startswith(("smp/attn/q", "smp/attn/core", "smp/attn/out",
                              "smp/head/", "smp/model/", "smp/mlp/")):
             # both stacks write the parts of a layer and the head
