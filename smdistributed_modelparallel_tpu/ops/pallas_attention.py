@@ -25,6 +25,29 @@ Supported feature surface (all combinations):
     (2L)^2 pairs' tiles are visited at any L that B divides and any tile
     sizes. ``smp_flash_tiles_visited{pass}`` and
     ``smp_flash_tiles_live{pass}`` count both for each call traced;
+  - whole tiles walked with no mask, under the block-diffusion mask: five
+    of six tiles a pass visits there hold no dead pair (480 of 576 a head
+    at 2 x 8,192 positions), and making ``_bd_mask`` and selecting by it
+    is a fifth to a third of a tile's time on the chip. A tile is whole if
+    EVERY pair of it is live under the call's mask, padding included: its
+    keys are all clean and before the least reach of its rows, which lie
+    in one copy. A tile among the noisy rows' own blocks, on the clean
+    prefix's diagonal, in the padding, with keys of both copies or under a
+    q tile that straddles the copies' middle is an edge tile. Each range
+    of tiles a program walks is cut into leading edge tiles, whole tiles
+    and trailing edge tiles (``_split``, from ``_bd_kv_whole`` and
+    ``_bd_q_whole``: integer arithmetic on the tile index beside the
+    ranges'), walked in order with one carry by one tile body whose
+    static ``masked`` argument leaves out the iotas, compares and select
+    on the whole ones; a select by an all-true mask returns its first
+    operand, so the results are the masked walk's bit for bit. The causal
+    and band masks are a few compares a tile (0-9% of a kernel's time
+    with all of them compiled out, less than a second loop costs a
+    program at 1,024 positions), so those walks keep every tile masked,
+    as do calls whose global ids decide at run time (the cp ring).
+    ``smp_flash_tiles_whole{pass}`` and ``smp_flash_tiles_masked{pass}``
+    count a head's tiles of each kind for every call traced under a
+    static mask, from the ranges the programs walk;
   - additive key-padding bias [B, S] (the broadcastable form of HF-style
     attention masks; arbitrary [.., T, S] biases fall back to jnp);
   - dropout on the attention probabilities, replayed exactly in the
@@ -113,35 +136,39 @@ def _tile_mask(rows, cols, *, q_len, kv_len, causal, window):
     return keep
 
 
-def _kv_bounds(q_lo, q_hi, *, q_len, kv_len, causal, window, block_k, num_kv):
-    """Traced [lo, hi) kv-block range relevant to q rows [q_lo, q_hi)."""
+def _kv_bounds(q_lo, q_hi, *, q_len, kv_len, causal, window, block_k, num_kv,
+               xp=jnp):
+    """Traced [lo, hi) kv-block range relevant to q rows [q_lo, q_hi)
+    (``xp``: ``jnp`` on a program's traced indices, ``numpy`` for the
+    host's count, here and in every range function below)."""
     offset = kv_len - q_len
     if causal:
-        hi = jnp.minimum(num_kv, (q_hi - 1 + offset) // block_k + 1)
+        hi = xp.minimum(num_kv, (q_hi - 1 + offset) // block_k + 1)
     elif window is not None:
         # Symmetric band: cols < rows + offset + window.
-        hi = jnp.minimum(num_kv, (q_hi - 1 + offset + window - 1) // block_k + 1)
+        hi = xp.minimum(num_kv, (q_hi - 1 + offset + window - 1) // block_k + 1)
     else:
         hi = num_kv
     if window is not None:
-        lo = jnp.maximum(0, (q_lo + offset - window + 1) // block_k)
+        lo = xp.maximum(0, (q_lo + offset - window + 1) // block_k)
     else:
         lo = 0
     return lo, hi
 
 
-def _q_bounds(k_lo, k_hi, *, q_len, kv_len, causal, window, block_q, num_q):
+def _q_bounds(k_lo, k_hi, *, q_len, kv_len, causal, window, block_q, num_q,
+              xp=jnp):
     """Traced [lo, hi) q-block range relevant to kv cols [k_lo, k_hi)."""
     offset = kv_len - q_len
     lo = 0
     hi = num_q
     if causal:
-        lo = jnp.maximum(0, (k_lo - offset) // block_q)
+        lo = xp.maximum(0, (k_lo - offset) // block_q)
         if window is not None:
-            hi = jnp.minimum(num_q, (k_hi - 1 - offset + window - 1) // block_q + 1)
+            hi = xp.minimum(num_q, (k_hi - 1 - offset + window - 1) // block_q + 1)
     elif window is not None:
-        lo = jnp.maximum(0, (k_lo - offset - window + 1) // block_q)
-        hi = jnp.minimum(num_q, (k_hi - 1 - offset + window - 1) // block_q + 1)
+        lo = xp.maximum(0, (k_lo - offset - window + 1) // block_q)
+        hi = xp.minimum(num_q, (k_hi - 1 - offset + window - 1) // block_q + 1)
     return lo, hi
 
 
@@ -233,11 +260,42 @@ def _bd_q_ranges(k_lo, k_hi, *, half, blk, block_q, xp=jnp):
     return (a_lo, a_hi), (b_lo, xp.maximum(b_hi, b_lo))
 
 
-def _bd_tile_counts(half, blk, block_q, block_k, t_pad):
-    """``{pass: (visited, live)}`` of one head's kernels over the
-    two-copy stream: tiles the programs' ranges step into, and tiles with
-    a live pair counted from the mask's definition row by row (a row's
-    keys are at most two intervals), independently of the ranges."""
+def _bd_kv_whole(q_lo, q_hi, *, half, blk, block_k, xp=jnp):
+    """[lo, hi) of the kv tiles every pair of which is live for the rows
+    [q_lo, q_hi): tiles of clean keys alone that end before the least
+    reach of the rows (their first row's: its block's start if noisy, its
+    block's end if clean), for rows of one half; none for rows on both
+    sides of ``half`` or in the padding. They lie in the clean-prefix
+    range of ``_bd_kv_ranges``; the noisy rows' own blocks are all edge."""
+    clean = q_lo >= half
+    start = xp.where(clean, q_lo - half, q_lo) // blk * blk
+    lo = -(-half // block_k)
+    hi = (half + start + xp.where(clean, blk, 0)) // block_k
+    one_half = (q_hi <= half) | (clean & (q_hi <= 2 * half))
+    return lo, xp.where(one_half, hi, lo)
+
+
+def _bd_q_whole(k_lo, k_hi, *, half, blk, block_q, xp=jnp):
+    """``_bd_kv_whole``'s mirror: for the keys [k_lo, k_hi) the [lo, hi) of
+    whole q tiles among the noisy rows, then among the clean rows (one for
+    each range of ``_bd_q_ranges``). Both run to their half's last whole
+    tile, from the first row whose reach passes the last key: the start of
+    the block after that key's for a noisy row, of that key's own for a
+    clean one. None (an empty range at that end) unless every key is
+    clean."""
+    clean = (k_lo >= half) & (k_hi <= 2 * half)
+    after = -(-(k_hi - half) // blk) * blk         # the block after the last key's
+    n_hi, c_hi = half // block_q, 2 * half // block_q
+    n_lo = xp.where(clean, -(-after // block_q), n_hi)
+    c_lo = xp.where(clean, -(-(half + after - blk) // block_q), c_hi)
+    return (n_lo, n_hi), (c_lo, c_hi)
+
+
+def _bd_live_tiles(half, blk, block_q, block_k, t_pad):
+    """Tiles of one head's [t_pad, 2 x half] pairs that hold a live one
+    over the two-copy stream, counted from the mask's definition row by
+    row (a row's keys are at most two intervals), independently of the
+    ranges the programs walk."""
     T = 2 * half
     num_q, num_kv = t_pad // block_q, -(-T // block_k)
     live = np.zeros((num_q, num_kv + 1), np.int64)
@@ -252,54 +310,115 @@ def _bd_tile_counts(half, blk, block_q, block_k, t_pad):
         t_lo, t_hi = _tiles_of(lo, hi, block_k, np)
         np.add.at(live, (rows // block_q, t_lo), 1)
         np.add.at(live, (rows // block_q, t_hi), -1)
-    n_live = int((np.cumsum(live, axis=1)[:, :num_kv] > 0).sum())
-
-    def visited(ranges, n, block):
-        lo = np.arange(n) * block
-        (a, b), (c, d) = ranges(lo, lo + block)
-        return int((b - a + d - c).sum())
-
-    by_q = visited(functools.partial(
-        _bd_kv_ranges, half=half, blk=blk, block_k=block_k, xp=np),
-        num_q, block_q)
-    by_kv = visited(functools.partial(
-        _bd_q_ranges, half=half, blk=blk, block_q=block_q, xp=np),
-        num_kv, block_k)
-    return {"fwd": (by_q, n_live), "dq": (by_q, n_live),
-            "dkv": (by_kv, n_live)}
+    return int((np.cumsum(live, axis=1)[:, :num_kv] > 0).sum())
 
 
-def _record_bd_tiles(passes, half, blk, block_q, block_k, t_pad):
+def _record_tiles(passes, bd, block_q, block_k, t_pad, s_pad, **mask):
+    """The gauges of a call under a static mask, set while it is traced:
+    whole and masked tiles a head for every such mask; under the
+    block-diffusion mask also their sum, the tiles visited, beside the
+    live ones."""
     from smdistributed_modelparallel_tpu.utils.telemetry import (
         record_flash_tiles,
     )
 
-    counts = _bd_tile_counts(half, blk, block_q, block_k, t_pad)
+    counts = _tile_counts(block_q, block_k, t_pad, s_pad, bd, **mask)
+    live = None
+    if bd is not None:
+        live = _bd_live_tiles(mask["q_len"] // 2, bd, block_q, block_k, t_pad)
     for name in passes:
-        record_flash_tiles(name, *counts[name])
+        whole, masked = counts[name]
+        record_flash_tiles(name, None if live is None else whole + masked,
+                           live, whole=whole, masked=masked)
 
 
-def _walk(ranges, body, init):
-    """``body`` over each [lo, hi) of ``ranges`` in turn, one carry."""
-    for lo, hi in ranges:
-        init = jax.lax.fori_loop(lo, hi, body, init)
+def _walk(ranges, body_of, init):
+    """``body_of(masked)`` over each ``(lo, hi, masked)`` of ``ranges`` in
+    turn, one carry."""
+    for lo, hi, masked in ranges:
+        init = jax.lax.fori_loop(lo, hi, body_of(masked), init)
     return init
 
 
+def _split(bounds, whole, xp, lead=True, trail=True):
+    """The range ``bounds`` of tiles as ``(lo, hi, masked)`` triples in
+    walking order: the edge tiles before ``whole`` (clipped into the
+    range), the whole tiles, the edge tiles after. ``lead`` / ``trail``
+    false: the mask's shape leaves no edge tile on that side, so no loop
+    is made for one (an empty ``whole`` then lies at that end). The one
+    place that says a tile needs no mask."""
+    lo, hi = bounds
+    w_lo = xp.minimum(xp.maximum(whole[0], lo), hi)
+    w_hi = xp.minimum(xp.maximum(whole[1], w_lo), hi)
+    return ([(lo, w_lo, True)] if lead else []) + [(w_lo, w_hi, False)] + (
+        [(w_hi, hi, True)] if trail else [])
+
+
 def _kv_ranges(q_offset, block_q, num_kv, has_ids, bd, *, q_len, kv_len,
-               causal, window, block_k):
-    """The [lo, hi) ranges of kv tiles a program of the forward or the dq
-    pass walks for its ``block_q`` rows from ``q_offset``: two under the
-    block-diffusion mask, every tile where global ids decide at run time,
-    else the one range the static mask leaves."""
+               causal, window, block_k, xp=jnp):
+    """The ``(lo, hi, masked)`` ranges of kv tiles a program of the forward
+    or the dq pass walks for its ``block_q`` rows from ``q_offset``, in
+    order. Under the block-diffusion mask the noisy rows' own blocks, all
+    edge tiles, then the clean prefix cut into its edge tiles and its whole
+    ones; where global ids decide at run time every tile; else the one
+    range the static mask leaves, every tile of it masked (the causal and
+    band masks are a few compares a tile, which on the chip cost less than
+    a second loop costs a program: PERF.md section 6, PR 45)."""
+    q_hi = q_offset + block_q
     if bd is not None:
-        return _bd_kv_ranges(q_offset, q_offset + block_q, half=q_len // 2,
-                             blk=bd, block_k=block_k)
+        half = q_len // 2
+        own, prefix = _bd_kv_ranges(
+            q_offset, q_hi, half=half, blk=bd, block_k=block_k, xp=xp)
+        whole = _bd_kv_whole(
+            q_offset, q_hi, half=half, blk=bd, block_k=block_k, xp=xp)
+        return [(*own, True)] + _split(
+            prefix, whole, xp, lead=half % block_k > 0)
     if has_ids:
-        return [(0, num_kv)]
-    return [_kv_bounds(
-        q_offset, q_offset + block_q, q_len=q_len, kv_len=kv_len,
-        causal=causal, window=window, block_k=block_k, num_kv=num_kv)]
+        return [(0, num_kv, True)]
+    return [(*_kv_bounds(
+        q_offset, q_hi, q_len=q_len, kv_len=kv_len, causal=causal,
+        window=window, block_k=block_k, num_kv=num_kv, xp=xp), True)]
+
+
+def _q_ranges(k_offset, block_k, num_q, has_ids, bd, *, q_len, kv_len,
+              causal, window, block_q, xp=jnp):
+    """``_kv_ranges``' mirror: the ranges of q tiles a program of the dkv
+    pass walks for its ``block_k`` keys from ``k_offset``."""
+    k_hi = k_offset + block_k
+    if bd is not None:
+        half = q_len // 2
+        noisy, clean = _bd_q_ranges(
+            k_offset, k_hi, half=half, blk=bd, block_q=block_q, xp=xp)
+        w_noisy, w_clean = _bd_q_whole(
+            k_offset, k_hi, half=half, blk=bd, block_q=block_q, xp=xp)
+        return _split(noisy, w_noisy, xp, trail=half % block_q > 0) + _split(
+            clean, w_clean, xp, trail=q_len % block_q > 0)
+    if has_ids:
+        return [(0, num_q, True)]
+    return [(*_q_bounds(
+        k_offset, k_hi, q_len=q_len, kv_len=kv_len, causal=causal,
+        window=window, block_q=block_q, num_q=num_q, xp=xp), True)]
+
+
+def _tile_counts(block_q, block_k, t_pad, s_pad, bd, **mask):
+    """``{pass: (whole, masked)}`` of one head's kernels under a static
+    mask: the tiles its programs walk with no mask in the body and with
+    one, summed over the programs from the ranges they walk."""
+    def count(ranges, programs):
+        out = [0, 0]
+        for lo, hi, masked in ranges:
+            out[masked] += int(np.broadcast_to(
+                np.maximum(hi - lo, 0), programs).sum())
+        return tuple(out)
+
+    num_q, num_kv = t_pad // block_q, s_pad // block_k
+    by_q = count(_kv_ranges(
+        np.arange(num_q) * block_q, block_q, num_kv, False, bd,
+        block_k=block_k, xp=np, **mask), num_q)
+    by_kv = count(_q_ranges(
+        np.arange(num_kv) * block_k, block_k, num_q, False, bd,
+        block_q=block_q, xp=np, **mask), num_kv)
+    return {"fwd": by_q, "dq": by_q, "dkv": by_kv}
 
 
 _VMEM_CAP = 100 << 20          # of a v5e core's 128 MiB
@@ -361,6 +480,33 @@ def _ids_cmin(kid_ref, k_offset, block_k, kv_len):
     return jnp.min(jnp.where(loc < kv_len, ids, jnp.int32(2**30)))
 
 
+def _tile_keep(masked, hashed, q_offset, k_offset, shape, q_ids, kv_ids,
+               bd, *, q_len, kv_len, causal, window):
+    """``(keep, hrows, hcols)`` of the [block_q, block_k] tile at
+    ``q_offset``, ``k_offset``: its mask, and the coordinates the dropout
+    hash counts by (the global ids where the call carries them). ``keep``
+    is None for a whole tile (``masked`` false), the coordinates unless
+    ``hashed``: a whole tile of a call with no dropout builds neither."""
+    if not (masked or hashed):
+        return None, None, None
+    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    hrows, hcols = rows, cols
+    if q_ids is not None:
+        hrows, hcols = q_ids[:, None], kv_ids[None, :]
+    if not masked:
+        return None, hrows, hcols
+    if q_ids is not None:
+        keep = _ids_mask(rows, cols, hrows, hcols, q_len=q_len,
+                         kv_len=kv_len, causal=causal, window=window)
+    elif bd is not None:
+        keep = _bd_mask(q_offset, k_offset, *shape, half=q_len // 2, blk=bd)
+    else:
+        keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
+                          causal=causal, window=window)
+    return keep, hrows, hcols
+
+
 def _bh_remap(b, h_local, head_total, head0_ref):
     """Flat (batch*local_head) program index -> GLOBAL batch*head id for
     the dropout hash. Identity when heads are unsharded; under Ulysses the
@@ -393,11 +539,14 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     # (N8 parity) at native throughput.
     q = q_ref[0]                                      # [bq, hd]
     q_offset = i * block_q
+    q_ids = None
     if has_ids:
         q_ids = qid_ref[0, pl.ds(q_offset, block_q)]
         r_max = _ids_rmax(qid_ref, q_offset, block_q, q_len)
 
-    def compute(j, carry):
+    mask = dict(q_len=q_len, kv_len=kv_len, causal=causal, window=window)
+
+    def compute(j, carry, masked):
         acc, m, l = carry
         k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
@@ -407,25 +556,14 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         )                                              # [bq, bk]
         if scale != 1.0:
             s = s * scale
-        rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if kpm_ref is not None:
             s = s + kpm_ref[0, pl.ds(j * block_k, block_k)][None, :]
-        if has_ids:
-            kv_ids = kid_ref[0, pl.ds(j * block_k, block_k)]
-            hrows, hcols = q_ids[:, None], kv_ids[None, :]
-            keep = _ids_mask(rows, cols, hrows, hcols,
-                             q_len=q_len, kv_len=kv_len, causal=causal,
-                             window=window)
-        elif bd is not None:
-            hrows, hcols = rows, cols
-            keep = _bd_mask(q_offset, j * block_k, block_q, block_k,
-                            half=q_len // 2, blk=bd)
-        else:
-            hrows, hcols = rows, cols
-            keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
-                              causal=causal, window=window)
-        s = jnp.where(keep, s, NEG_INF)
+        keep, hrows, hcols = _tile_keep(
+            masked, rate > 0.0, q_offset, j * block_k, s.shape, q_ids,
+            kid_ref[0, pl.ds(j * block_k, block_k)] if has_ids else None,
+            bd, **mask)
+        if masked:
+            s = jnp.where(keep, s, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -442,26 +580,28 @@ def _fwd_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         )
         return acc_new, m_new, l_new
 
-    if has_ids and causal:
+    def body_of(masked):
+        if not (has_ids and causal):
+            return functools.partial(compute, masked=masked)
+
         # Data-dependent block skip: the static _kv_bounds cannot see the
         # global ids, so each kv block is skipped at runtime when its
         # minimum col id exceeds every row id in this q block.
         def body(j, carry):
             visible = _ids_cmin(kid_ref, j * block_k, block_k, kv_len) <= r_max
             return jax.lax.cond(
-                visible, lambda c: compute(j, c), lambda c: c, carry
+                visible, lambda c: compute(j, c, masked), lambda c: c, carry
             )
-    else:
-        body = compute
+
+        return body
 
     ranges = _kv_ranges(
         q_offset, block_q, k_ref.shape[1] // block_k, has_ids, bd,
-        q_len=q_len, kv_len=kv_len, causal=causal, window=window,
-        block_k=block_k)
+        block_k=block_k, **mask)
     acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = _walk(ranges, body, (acc0, m0, l0))
+    acc, m, l = _walk(ranges, body_of, (acc0, m0, l0))
     inv_keep = 1.0 / (1.0 - rate) if rate > 0.0 else 1.0
     o_ref[0] = (acc * inv_keep / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse = jnp.where(
@@ -495,11 +635,14 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     delta = delta_ref[0, 0, :][:, None]
     q_offset = i * block_q
     inv_keep = 1.0 / (1.0 - rate) if rate > 0.0 else 1.0
+    q_ids = None
     if has_ids:
         q_ids = qid_ref[0, pl.ds(q_offset, block_q)]
         r_max = _ids_rmax(qid_ref, q_offset, block_q, q_len)
 
-    def compute(j, dq_acc):
+    mask = dict(q_len=q_len, kv_len=kv_len, causal=causal, window=window)
+
+    def compute(j, dq_acc, masked):
         k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = jax.lax.dot_general(
@@ -508,25 +651,15 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         )
         if scale != 1.0:
             s = s * scale
-        rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if kpm_ref is not None:
             s = s + kpm_ref[0, pl.ds(j * block_k, block_k)][None, :]
-        if has_ids:
-            kv_ids = kid_ref[0, pl.ds(j * block_k, block_k)]
-            hrows, hcols = q_ids[:, None], kv_ids[None, :]
-            keep = _ids_mask(rows, cols, hrows, hcols,
-                             q_len=q_len, kv_len=kv_len, causal=causal,
-                             window=window)
-        elif bd is not None:
-            hrows, hcols = rows, cols
-            keep = _bd_mask(q_offset, j * block_k, block_q, block_k,
-                            half=q_len // 2, blk=bd)
-        else:
-            hrows, hcols = rows, cols
-            keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
-                              causal=causal, window=window)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)    # [bq, bk]
+        keep, hrows, hcols = _tile_keep(
+            masked, rate > 0.0, q_offset, j * block_k, s.shape, q_ids,
+            kid_ref[0, pl.ds(j * block_k, block_k)] if has_ids else None,
+            bd, **mask)
+        p = jnp.exp(s - lse)                          # [bq, bk]
+        if masked:
+            p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -542,20 +675,22 @@ def _bwd_dq_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
             preferred_element_type=jnp.float32,
         )
 
-    if has_ids and causal:
+    def body_of(masked):
+        if not (has_ids and causal):
+            return functools.partial(compute, masked=masked)
+
         def body(j, dq_acc):
             visible = _ids_cmin(kid_ref, j * block_k, block_k, kv_len) <= r_max
             return jax.lax.cond(
-                visible, lambda c: compute(j, c), lambda c: c, dq_acc
+                visible, lambda c: compute(j, c, masked), lambda c: c, dq_acc
             )
-    else:
-        body = compute
+
+        return body
 
     ranges = _kv_ranges(
         q_offset, block_q, k_ref.shape[1] // block_k, has_ids, bd,
-        q_len=q_len, kv_len=kv_len, causal=causal, window=window,
-        block_k=block_k)
-    dq = _walk(ranges, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
+        block_k=block_k, **mask)
+    dq = _walk(ranges, body_of, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
@@ -581,11 +716,14 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     kpm_blk = None
     if kpm_ref is not None:
         kpm_blk = kpm_ref[0, pl.ds(k_offset, block_k)][None, :]
+    kv_ids = None
     if has_ids:
         kv_ids = kid_ref[0, pl.ds(k_offset, block_k)]
         c_min = _ids_cmin(kid_ref, k_offset, block_k, kv_len)
 
-    def compute(i, carry):
+    mask = dict(q_len=q_len, kv_len=kv_len, causal=causal, window=window)
+
+    def compute(i, carry, masked):
         dk_acc, dv_acc = carry
         q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
         do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
@@ -597,25 +735,15 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         )                                              # [bq, bk]
         if scale != 1.0:
             s = s * scale
-        rows = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if kpm_blk is not None:
             s = s + kpm_blk
-        if has_ids:
-            q_ids = qid_ref[0, pl.ds(i * block_q, block_q)]
-            hrows, hcols = q_ids[:, None], kv_ids[None, :]
-            keep = _ids_mask(rows, cols, hrows, hcols,
-                             q_len=q_len, kv_len=kv_len, causal=causal,
-                             window=window)
-        elif bd is not None:
-            hrows, hcols = rows, cols
-            keep = _bd_mask(i * block_q, k_offset, block_q, block_k,
-                            half=q_len // 2, blk=bd)
-        else:
-            hrows, hcols = rows, cols
-            keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
-                              causal=causal, window=window)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
+        keep, hrows, hcols = _tile_keep(
+            masked, rate > 0.0, i * block_q, k_offset, s.shape,
+            qid_ref[0, pl.ds(i * block_q, block_q)] if has_ids else None,
+            kv_ids, bd, **mask)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
             do_blk, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -639,31 +767,25 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         )
         return dk_acc, dv_acc
 
-    if has_ids and causal:
+    def body_of(masked):
+        if not (has_ids and causal):
+            return functools.partial(compute, masked=masked)
+
         def body(i, carry):
             visible = c_min <= _ids_rmax(qid_ref, i * block_q, block_q, q_len)
             return jax.lax.cond(
-                visible, lambda c: compute(i, c), lambda c: c, carry
+                visible, lambda c: compute(i, c, masked), lambda c: c, carry
             )
-    else:
-        body = compute
 
-    num_q = q_ref.shape[1] // block_q
-    if bd is not None:
-        ranges = _bd_q_ranges(
-            k_offset, k_offset + block_k, half=q_len // 2, blk=bd,
-            block_q=block_q)
-    elif has_ids:
-        ranges = [(0, num_q)]
-    else:
-        ranges = [_q_bounds(
-            k_offset, k_offset + block_k, q_len=q_len, kv_len=kv_len,
-            causal=causal, window=window, block_q=block_q, num_q=num_q,
-        )]
+        return body
+
+    ranges = _q_ranges(
+        k_offset, block_k, q_ref.shape[1] // block_q, has_ids, bd,
+        block_q=block_q, **mask)
     z = jnp.zeros((block_k, k_blk.shape[-1]), jnp.float32)
     zv = (z if v_blk.shape[-1] == k_blk.shape[-1]
           else jnp.zeros((block_k, v_blk.shape[-1]), jnp.float32))
-    dk, dv = _walk(ranges, body, (z, zv))
+    dk, dv = _walk(ranges, body_of, (z, zv))
     # ds carries exactly one *scale factor and q_blk is raw (unscaled), so
     # dk = ds^T.q is already correct.
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -821,8 +943,10 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
         id_in, id_specs = _ids_extra(q_ids, kv_ids, t_pad, s_pad)
         extra, extra_specs = extra + id_in, extra_specs + id_specs
     more, more_call = {}, {}
+    if not has_ids:
+        _record_tiles(("fwd",), bd, block_q, block_k, t_pad, s_pad, q_len=T,
+                      kv_len=S, causal=causal, window=window)
     if bd is not None:
-        _record_bd_tiles(("fwd",), T // 2, bd, block_q, block_k, t_pad)
         more = {"bd": bd}
         more_call = _bd_compiler_params(s_pad, hd_pad, qt.dtype.itemsize)
     grid = (B * H, t_pad // block_q)
@@ -900,8 +1024,10 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         has_head0=has_head0,
     )
     more_call = {}
+    if not has_ids:
+        _record_tiles(("dq", "dkv"), bd, block_q, block_k, t_pad, s_pad,
+                      q_len=T, kv_len=S, causal=causal, window=window)
     if bd is not None:
-        _record_bd_tiles(("dq", "dkv"), T // 2, bd, block_q, block_k, t_pad)
         common["bd"] = bd
         more_call = _bd_compiler_params(
             max(s_pad, t_pad), hd_pad, qt.dtype.itemsize)

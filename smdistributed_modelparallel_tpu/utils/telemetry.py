@@ -834,23 +834,45 @@ def record_lm_head_vocab_shards(shards):
     ).set(shards)
 
 
-def record_flash_tiles(kernel_pass, visited, live):
-    """Tiles of one head that a flash kernel call's programs step into
-    (``smp_flash_tiles_visited{pass}``) and tiles that hold a live pair
-    under the call's mask (``smp_flash_tiles_live{pass}``), ``pass`` one
-    of ``fwd``, ``dq``, ``dkv``. Set while the call is traced, for calls
-    under the block-diffusion mask (``ops/pallas_attention.py``: the only
-    mask whose live tiles are not one contiguous range a program); equal
-    counts mean every dead tile is skipped."""
-    telemetry.gauge(
-        "smp_flash_tiles_visited",
-        "tiles a flash kernel call steps into, per head (block-diffusion "
-        "mask)",
-    ).labels(**{"pass": kernel_pass}).set(visited)
-    telemetry.gauge(
-        "smp_flash_tiles_live",
-        "tiles with a live query-key pair under the call's mask, per head",
-    ).labels(**{"pass": kernel_pass}).set(live)
+def record_flash_tiles(kernel_pass, visited=None, live=None, whole=None,
+                       masked=None):
+    """One head's tiles in a flash kernel call, ``pass`` one of ``fwd``,
+    ``dq``, ``dkv``, set while the call is traced
+    (``ops/pallas_attention.py``).
+
+    For every call under a static mask (causal, window, band, block
+    diffusion, none): the tiles its programs walk with no mask in the body
+    (``smp_flash_tiles_whole{pass}``) and with one
+    (``smp_flash_tiles_masked{pass}``). A tile is whole if every pair of
+    it is live under the call's mask, padding included, and only the
+    block-diffusion mask's walk tells such tiles apart (its mask is the
+    costly one): there a tile among the noisy rows' own blocks, on the
+    clean prefix's diagonal, in the padding or on both sides of the
+    stream's middle is masked as before, and under the other masks every
+    tile is, so ``whole`` reads 0. A call whose global ids decide at run
+    time (the cp ring) walks no whole tile and sets neither.
+
+    For calls under the block-diffusion mask alone (the only mask whose
+    live tiles are not one contiguous range a program): tiles the
+    programs step into (``smp_flash_tiles_visited{pass}``) and tiles that
+    hold a live pair (``smp_flash_tiles_live{pass}``); equal counts mean
+    every dead tile is skipped."""
+    for name, value, text in (
+        ("smp_flash_tiles_visited", visited,
+         "tiles a flash kernel call steps into, per head (block-diffusion "
+         "mask)"),
+        ("smp_flash_tiles_live", live,
+         "tiles with a live query-key pair under the call's mask, per head"),
+        ("smp_flash_tiles_whole", whole,
+         "tiles a flash kernel call walks with no mask in the body (every "
+         "pair live), per head"),
+        ("smp_flash_tiles_masked", masked,
+         "tiles a flash kernel call walks with the mask built and applied, "
+         "per head"),
+    ):
+        if value is not None:
+            telemetry.gauge(name, text).labels(
+                **{"pass": kernel_pass}).set(value)
 
 
 def record_lm_head_positions(computed, given):

@@ -278,6 +278,88 @@ def test_flash_attention_under_the_block_diffusion_mask(one_chip):
     assert "16384,16384" not in text
 
 
+# (q, kv, mask, whole and masked tiles a head and pass): SDAR's two-copy
+# stream, a stream whose middle no tile of rows or keys ends at (every
+# leading and trailing edge loop present: four loops in the forward and the
+# dq pass, six in the dkv pass), Mellum's window layer and its full one, a
+# GPT-2-XL microbatch.
+_TILE_CASES = {
+    "sdar": ((1, 16384, 4, 128), (1, 16384, 1, 128),
+             dict(block_diffusion=4), (480, 96)),
+    "stream_not_in_tiles": ((1, 8400, 4, 128), (1, 8400, 1, 128),
+                            dict(block_diffusion=4), (84, 101)),
+    "mellum_window": ((1, 8192, 8, 128), (1, 8192, 1, 128),
+                      dict(causal=True, window=1024), (0, 90)),
+    "mellum_full": ((1, 8192, 8, 128), (1, 8192, 1, 128),
+                    dict(causal=True), (0, 272)),
+    "gpt2_xl": ((4, 1024, 25, 64), (4, 1024, 25, 64),
+                dict(causal=True), (0, 6)),
+}
+
+
+def _tile_gauges():
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    metrics = telemetry.report()["metrics"]
+    return {
+        p: tuple(
+            next(s["value"] for s in metrics[f"smp_flash_tiles_{kind}"][
+                "series"] if s["labels"]["pass"] == p)
+            for kind in ("whole", "masked"))
+        for p in ("fwd", "dq", "dkv")}
+
+
+def test_flash_kernels_lower_with_every_edge_tile_loop(one_chip):
+    """Under the block-diffusion mask each pass walks its whole tiles with
+    no mask in the body and its edge tiles with one: three or four loops a
+    kernel at SDAR's shape (the test above), and where no tile ends at the
+    stream's middle, as here, four in the forward and the dq pass and six
+    in the dkv pass, for the parent's two. The chip's compiler takes all
+    three kernels (no body past VMEM, no static range it cannot take), and
+    the gauges a traced call sets read the mask's own counts."""
+    name = "stream_not_in_tiles"
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    q, kv, mask, tiles = _TILE_CASES[name]
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, **mask))
+
+    telemetry.reset()
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    for kernel in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert kernel in text
+    assert _tile_gauges() == {"fwd": tiles, "dq": tiles, "dkv": tiles}
+
+
+@pytest.mark.parametrize(
+    "name", ["sdar", "mellum_window", "mellum_full", "gpt2_xl"])
+def test_flash_tile_gauges_at_the_cells_shapes(name):
+    """``smp_flash_tiles_whole{pass}`` / ``smp_flash_tiles_masked{pass}``
+    of a traced call (nothing compiles) at the default 256 x 512 tiles:
+    480 / 96 a head over SDAR's 2 x 8,192 positions in blocks of 4; under
+    the causal masks every visited tile masked (272 at 8,192, 90 under a
+    window of 1,024, 6 at 1,024)."""
+    from smdistributed_modelparallel_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    q, kv, mask, tiles = _TILE_CASES[name]
+
+    def loss(q, k, v):
+        return _sum32(flash_attention(q, k, v, **mask))
+
+    telemetry.reset()
+    jax.eval_shape(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        *[jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (q, kv, kv)])
+    assert _tile_gauges() == {"fwd": tiles, "dq": tiles, "dkv": tiles}
+
+
 @pytest.mark.parametrize("kept", [True, False], ids=["kept", "full_remat"])
 def test_a_checkpointed_layer_compiles_one_flash_forward(one_chip, kept):
     """The chip's compiler on a checkpointed layer scan at Mellum's

@@ -94,9 +94,13 @@ def test_heads_must_divide():
 # Since PR 40 with two ``name`` equations more, on the forward kernel's
 # output and logsumexp (``parallel/memory.remat_policy`` keeps the two),
 # and the variables after them renamed: nothing else differs from that
-# text (381a496c...1d81), equation for equation.
+# text (381a496c...1d81), equation for equation. Since PR 45 (one
+# ``_tile_keep`` for the three kernels' mask lines) the forward and the dq
+# body multiply the tile index by ``block_k`` before the rows' iota, not
+# after it, and the variables in between are renamed: no other line
+# differs from PR 40's text (6d521284...cb19).
 _MHA_JAXPR_SHA256 = (
-    "6d521284f4c922375d2661e72833fe4997a74c88ccf4131709165146158dcb19")
+    "9f0f0b3e50f8e8ca049d9dd521d120df2b07c987c7e65bb22c5ae9b435f693fe")
 
 
 def test_multi_head_traces_as_before():

@@ -218,9 +218,11 @@ class TestFlashBlockDiffusion:
     def test_a_quarter_of_the_tiles_at_the_cells_size(self):
         from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
 
-        counts = pa._bd_tile_counts(8192, 4, 256, 512, 16384)
-        assert counts == {"fwd": (576, 576), "dq": (576, 576),
-                          "dkv": (576, 576)}
+        walked = pa._tile_counts(256, 512, 16384, 16384, 4, q_len=16384,
+                                 kv_len=16384, causal=False, window=None)
+        assert {name: sum(n) for name, n in walked.items()} == {
+            "fwd": 576, "dq": 576, "dkv": 576}
+        assert pa._bd_live_tiles(8192, 4, 256, 512, 16384) == 576
         assert 576 / (64 * 32) < 0.29
 
     def test_other_masks_set_no_tile_gauges(self):
@@ -252,6 +254,216 @@ def _rand_qkv(key, qshape, kvshape=None):
     k = jax.random.normal(ks[1], kv)
     v = jax.random.normal(ks[2], kv)
     return q, k, v
+
+
+# (T, S, causal, window, block length of the block-diffusion mask, tile of
+# rows, tile of keys): every static mask the kernels take, with lengths the
+# tiles divide and lengths that pad, tiles from 128 x 128 to 256 x 512.
+# Only the block-diffusion mask's walk tells whole tiles from edge tiles.
+_TILE_CASES = {
+    "causal": (1024, 1024, True, None, None, 256, 512),
+    "causal_small_tiles": (1024, 1024, True, None, None, 128, 128),
+    "causal_window": (2048, 2048, True, 300, None, 128, 128),
+    "causal_window_wide_tiles": (4096, 4096, True, 1024, None, 256, 512),
+    "band": (2048, 2048, False, 300, None, 128, 256),
+    "no_mask": (512, 768, False, None, None, 128, 256),
+    "more_keys_than_rows": (512, 1536, True, None, None, 128, 256),
+    "more_keys_than_rows_window": (512, 1536, True, 400, None, 256, 128),
+    "more_rows_than_keys": (1536, 512, True, None, None, 256, 128),
+    "band_more_keys": (640, 1024, False, 200, None, 128, 128),
+    "ragged_300": (300, 300, True, None, None, 128, 128),
+    "ragged_1100": (1100, 1100, True, None, None, 256, 512),
+    "ragged_1100_window": (1100, 1100, True, 500, None, 128, 256),
+    "ragged_band": (1100, 700, False, 300, None, 256, 128),
+    "ragged_no_mask": (300, 1100, False, None, None, 128, 512),
+    "bd_half_in_tiles": (2048, 2048, False, None, 4, 256, 512),
+    "bd_half_in_small_tiles": (1024, 1024, False, None, 32, 128, 128),
+    "bd_half_not_in_tiles": (400, 400, False, None, 4, 128, 256),
+    "bd_rows_wider": (640, 640, False, None, 32, 256, 128),
+    "bd_one_tile_a_half": (512, 512, False, None, 4, 256, 256),
+    "bd_half_in_rows_not_keys": (1536, 1536, False, None, 32, 256, 512),
+    "bd_half_in_keys_not_rows": (1536, 1536, False, None, 4, 512, 256),
+    "bd_padded_tiles_of_keys": (1160, 1160, False, None, 4, 128, 512),
+}
+
+
+def _dense_mask(T, S, causal, window, bd):
+    """The mask from its definition, [T, S] of bool."""
+    from smdistributed_modelparallel_tpu.ops.attention import (
+        block_diffusion_mask,
+    )
+
+    if bd is not None:
+        return np.asarray(block_diffusion_mask(T, bd))
+    rows, cols = np.arange(T)[:, None], np.arange(S)[None, :]
+    keep = np.ones((T, S), bool)
+    if causal:
+        keep &= cols <= rows + S - T
+        if window is not None:
+            keep &= rows + S - T - cols < window
+    elif window is not None:
+        keep &= np.abs(rows + S - T - cols) < window
+    return keep
+
+
+def _no_tile_is_whole(bounds, whole, xp, lead=True, trail=True):
+    """``pallas_attention._split`` as the kernels walked before they told
+    whole tiles from edge tiles: one range, every tile masked."""
+    return [(*bounds, True)]
+
+
+class TestFlashWholeTiles:
+    """A pass walks the tiles its mask leaves whole with no mask in the
+    body: which tiles those are against the masks' definitions, and the
+    results against the same kernels with every tile masked."""
+
+    @pytest.mark.parametrize("name", list(_TILE_CASES))
+    def test_whole_tiles_are_all_live_and_skipped_tiles_all_dead(self, name):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        T, S, causal, window, bd, bq, bk = _TILE_CASES[name]
+        bq, bk = pa._clamp_block(bq, T), pa._clamp_block(bk, S)
+        num_q, num_kv = -(-T // bq), -(-S // bk)
+        mask = np.zeros((num_q * bq, num_kv * bk), bool)
+        mask[:T, :S] = _dense_mask(T, S, causal, window, bd)
+        tiles = mask.reshape(num_q, bq, num_kv, bk)
+        all_live, any_live = tiles.all(axis=(1, 3)), tiles.any(axis=(1, 3))
+        kw = dict(q_len=T, kv_len=S, causal=causal, window=window)
+
+        def walked(ranges, n, block, num, **other):
+            """[n, num] of 0 (skipped), 1 (masked), 2 (whole), from the
+            ranges each of the ``n`` programs walks, which must ascend."""
+            lo = np.arange(n) * block
+            out = np.zeros((n, num), int)
+            ends = np.zeros(n, int)
+            for a, b, masked in ranges(lo, block, num, False, bd, xp=np,
+                                       **kw, **other):
+                a, b = (np.broadcast_to(x, lo.shape) for x in (a, b))
+                for r in range(n):
+                    if b[r] > a[r]:
+                        assert a[r] >= ends[r] and b[r] <= num
+                        out[r, a[r]:b[r]] = 1 if masked else 2
+                        ends[r] = b[r]
+            return out
+
+        by_q = walked(pa._kv_ranges, num_q, bq, num_kv, block_k=bk)
+        by_kv = walked(pa._q_ranges, num_kv, bk, num_q, block_q=bq).T
+        for got in (by_q, by_kv):
+            assert all_live[got == 2].all()
+            assert not any_live[got == 0].any()
+            # a tile with padding is never all live, so this is the count
+            # of all-live tiles among those that hold none
+            assert (got == 2).sum() == (all_live.sum() if bd else 0)
+        counts = pa._tile_counts(bq, bk, num_q * bq, num_kv * bk, bd, **kw)
+        assert counts["fwd"] == counts["dq"] == (
+            (by_q == 2).sum(), (by_q == 1).sum())
+        assert counts["dkv"] == ((by_kv == 2).sum(), (by_kv == 1).sum())
+
+    def test_whole_and_masked_tiles_at_the_cells_sizes(self):
+        """The seven cells' attention at the default 256 x 512 tiles."""
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        def counts(T, causal=True, window=None, bd=None):
+            by_pass = pa._tile_counts(256, 512, T, T, bd, q_len=T, kv_len=T,
+                                      causal=causal, window=window)
+            assert by_pass["fwd"] == by_pass["dq"] == by_pass["dkv"]
+            return by_pass["fwd"]
+
+        assert counts(16384, causal=False, bd=4) == (480, 96)
+        # the causal cells: every visited tile masked (240 + 32 of them
+        # hold no dead pair at 8,192, 30 + 60 under the window of 1,024)
+        assert counts(8192) == (0, 272)
+        assert counts(8192, window=1024) == (0, 90)
+        assert counts(4096) == (0, 72)
+        assert counts(2048) == (0, 20)
+        assert counts(1024) == (0, 6)
+
+    # Heads of 64: the scale is a power of two, so the CPU backend's fusing
+    # of ``s * scale - m`` into one multiply-add, which the forward's whole
+    # body allows and its masked body (a select in between) does not,
+    # rounds no differently. The chip's vector unit has no such operation
+    # (there heads of 128 are equal bit for bit: PERF.md section 6, PR 45).
+    _PARITY_CASES = {
+        "half_in_tiles": dict(T=512, bd=4),
+        "half_in_tiles_blocks_of_32": dict(T=1024, bd=32, bq=256),
+        "half_not_in_tiles": dict(T=1216, bd=32, bk=256),
+        "tiles_pad": dict(T=1160, bd=4, bq=256),
+        "dropout": dict(T=512, bd=4, rate=0.2),
+        "four_heads_on_one": dict(T=1024, bd=4, heads=(4, 1), bk=256),
+        "key_padding_bias": dict(T=512, bd=32, kpad=True),
+    }
+
+    @pytest.mark.parametrize("name", list(_PARITY_CASES))
+    def test_split_walk_is_the_masked_walk_bit_for_bit(self, name,
+                                                       monkeypatch):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        case = dict(self._PARITY_CASES[name])
+        T = S = case.pop("T")
+        H, Hkv = case.pop("heads", (2, 2))
+        causal, window = False, None
+        bd, rate = case.pop("bd"), case.pop("rate", 0.0)
+        bq, bk = pa._clamp_block(case.pop("bq", 128), T), pa._clamp_block(
+            case.pop("bk", 128), S)
+        ks = jax.random.split(jax.random.key(11), 5)
+        q = jax.random.normal(ks[0], (1, T, H, 64))
+        k = jax.random.normal(ks[1], (1, S, Hkv, 64))
+        v = jax.random.normal(ks[2], (1, S, Hkv, 64))
+        g = jax.random.normal(ks[3], (1, T, H, 64))
+        kpad = None
+        if case.pop("kpad", False):
+            kpad = jnp.where(jax.random.bernoulli(ks[4], 0.9, (1, S)),
+                             0.0, -1e30).astype(jnp.float32)
+        assert not case
+        seed = jnp.asarray(5, jnp.int32) if rate else None
+        args = (kpad, seed, 0.125, causal, window, rate, bq, bk, True)
+
+        def run():
+            o, lse = pa._flash_fwd_impl(q, k, v, *args, bd=bd)
+            grads = pa._flash_bwd_impl(q, k, v, o, g, lse, *args, bd=bd)
+            return [np.asarray(x) for x in (o, lse, *grads)]
+
+        whole = pa._tile_counts(
+            bq, bk, -(-T // bq) * bq, -(-S // bk) * bk, bd, q_len=T,
+            kv_len=S, causal=causal, window=window)
+        assert all(n > 0 for n, _ in whole.values())
+        split = run()
+        monkeypatch.setattr(pa, "_split", _no_tile_is_whole)
+        for a, b in zip(split, run()):
+            np.testing.assert_array_equal(a, b)
+
+    def test_every_static_mask_sets_the_two_gauges_and_the_ids_mode_none(
+            self):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+        from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+        def gauges():
+            metrics = telemetry.report()["metrics"]
+            return {
+                kind: {s["labels"]["pass"]: s["value"]
+                       for s in metrics[f"smp_flash_tiles_{kind}"]["series"]}
+                for kind in ("whole", "masked")
+                if f"smp_flash_tiles_{kind}" in metrics}
+
+        q, k, v = _rand_qkv(jax.random.key(5), (1, 512, 2, 32))
+        telemetry.reset()
+        jax.grad(lambda *a: jnp.sum(_flash(*a)), (0, 1, 2))(q, k, v)
+        assert gauges() == {
+            "whole": {"fwd": 0, "dq": 0, "dkv": 0},
+            "masked": {"fwd": 10, "dq": 10, "dkv": 10}}
+        telemetry.reset()
+        jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, block_diffusion=4, block_q=128, block_k=128,
+            interpret=True)), (0, 1, 2))(q, k, v)
+        assert gauges() == {
+            "whole": {"fwd": 2, "dq": 2, "dkv": 2},
+            "masked": {"fwd": 6, "dq": 6, "dkv": 6}}
+        telemetry.reset()
+        ids = jnp.arange(512)
+        pa.flash_fwd_with_ids(q, k, v, None, ids, ids, scale=0.2,
+                              causal=True, block_q=128, block_k=128,
+                              interpret=True)
+        assert gauges() == {}
 
 
 class TestFlashFeatures:
