@@ -74,8 +74,15 @@ class ModelParallelState:
         from smdistributed_modelparallel_tpu.nn.huggingface import (
             register_predefined_hooks,
         )
+        from smdistributed_modelparallel_tpu.utils.telemetry import (
+            record_hf_hooks_resolved,
+        )
 
-        register_predefined_hooks(self.tp_registry)
+        # Hugging Face classes are registered when first looked up, not
+        # here: registering them all imports transformers, torch and
+        # tensorflow, half a minute that no Flax or smp model needs.
+        self.tp_registry.late_resolver = register_predefined_hooks
+        record_hf_hooks_resolved(0)
         if cfg.fp16:
             from smdistributed_modelparallel_tpu.fp16.loss_scaler import (
                 DynamicLossScaler,

@@ -2,8 +2,10 @@
 
 Parity target: reference ``torch/nn/predefined_hooks.py:56-168``
 (``PredefinedHookManager``): maps HF classes to distributed classes with
-init-hook argument translation and bidirectional state-dict translators,
-registered into the tp_registry at init.
+init-hook argument translation and bidirectional state-dict translators.
+The reference registers them all at init; here the tp_registry asks for a
+class's hook when it first meets that class (``register_predefined_hooks``),
+so that ``smp.init`` imports ``transformers`` for nobody.
 
 TPU-native flow: HF models are torch modules, so "re-instantiation" means
 building the equivalent ``smp.nn.DistributedTransformerLMHead`` from the HF
@@ -13,11 +15,15 @@ one-call entry point; full (non-partial) checkpoints translate back to HF
 naming through the registered ``translate_state_dict_to_hf``.
 """
 
+import sys
 from dataclasses import dataclass
 from typing import Callable, Optional
 
 from smdistributed_modelparallel_tpu.utils.exceptions import SMPValidationError
 from smdistributed_modelparallel_tpu.utils.logger import get_logger
+from smdistributed_modelparallel_tpu.utils.telemetry import (
+    record_hf_hooks_resolved,
+)
 
 logger = get_logger()
 
@@ -244,48 +250,28 @@ def translate_model(model_or_config, **overrides):
     return module, flat, fam
 
 
-def register_predefined_hooks(registry):
-    """Register HF classes in the tp_registry (parity: reference
-    ``PredefinedHookManager``). Lazy: transformers is imported only if
-    present; absence is not an error."""
-    try:
-        import transformers
-    except Exception:  # pragma: no cover - transformers always in image
-        logger.debug("transformers unavailable; HF hooks not registered.")
+_T5_BLOCK = ("transformers.models.t5.modeling_t5", "T5Block")
+
+
+def register_predefined_hooks(registry, origin_cls):
+    """The tp_registry's late resolver (parity: reference
+    ``PredefinedHookManager``, which registers every class at init): asked
+    about one class that says it comes from ``transformers``, register the
+    predefined hook of that class alone, if it has one.
+
+    Never imports ``transformers``: a class that exists was imported by
+    whoever made it, so ``sys.modules`` holds its module, and the class is
+    taken only if that module defines it under its name (a class that
+    merely claims such a module is left alone)."""
+    name = origin_cls.__name__
+    module = sys.modules.get(origin_cls.__module__)
+    if module is None or vars(module).get(name) is not origin_cls:
         return
-
-    for fam in families().values():
-        target_cls = _target_class(fam.target)
-        for arch in fam.architectures:
-            hf_cls = getattr(transformers, arch, None)
-            if hf_cls is None:
-                continue
-
-            def _init_hook(config, _fam=fam, **kw):
-                out = _fam.config_to_smp(config)
-                out.update(kw)
-                return (), out
-
-            # translate_functions deliberately NOT registered here: the
-            # registry keys them by distributed class, and the families
-            # share their target classes — the accurate channel is the
-            # per-instance functions smp.from_hf installs.
-            registry.register(
-                hf_cls,
-                target_cls,
-                init_hook=_init_hook,
-            )
-
-    # T5 layer-level hook (reference-parity surface, kept alongside the
-    # full-model family above): T5Block -> DistributedTransformerLayer;
-    # the relative-attention-bias block is declined by the hook returning
-    # None, as in the reference.
-    t5_block = getattr(
-        getattr(getattr(transformers, "models", None), "t5", None),
-        "modeling_t5", None,
-    )
-    t5_block = getattr(t5_block, "T5Block", None)
-    if t5_block is not None:
+    if (origin_cls.__module__, name) == _T5_BLOCK:
+        # T5 layer-level hook (reference-parity surface, kept alongside
+        # the full-model family): T5Block -> DistributedTransformerLayer;
+        # the relative-attention-bias block is declined by the hook
+        # returning None, as in the reference.
         from smdistributed_modelparallel_tpu.nn.huggingface import t5
         from smdistributed_modelparallel_tpu.nn.transformer import (
             DistributedTransformerLayer,
@@ -298,9 +284,26 @@ def register_predefined_hooks(registry):
             out.update(kw)
             return (), out
 
-        registry.register(
-            t5_block, DistributedTransformerLayer, init_hook=_t5_init_hook
+        target_cls, init_hook = DistributedTransformerLayer, _t5_init_hook
+    else:
+        fam = next(
+            (f for f in families().values() if name in f.architectures), None
         )
+        if fam is None:
+            return
+
+        def _init_hook(config, **kw):
+            out = fam.config_to_smp(config)
+            out.update(kw)
+            return (), out
+
+        target_cls, init_hook = _target_class(fam.target), _init_hook
+    # translate_functions deliberately NOT registered here: the registry
+    # keys them by distributed class, and the families share their target
+    # classes — the accurate channel is the per-instance functions
+    # smp.from_hf installs.
+    registry.register(origin_cls, target_cls, init_hook=init_hook)
+    record_hf_hooks_resolved(1)
 
 
 def from_hf(model_or_config, rngs=("dropout",), **overrides):

@@ -20,6 +20,10 @@ class TensorParallelismRegistry:
         # original class -> (distributed class, init_hook, forward_hook, return_hook)
         self._map = {}
         self._translate_functions = {}  # dist class -> (to_hf, from_hf) state translators
+        # ``resolver(registry, origin_cls)``, installed by the state at
+        # smp.init: may register a ``transformers`` class that ``_map``
+        # does not hold yet (nn/huggingface.register_predefined_hooks).
+        self.late_resolver = None
 
     def register(self, origin_cls, dist_cls, init_hook=None, forward_hook=None,
                  return_hook=None, translate_functions=None):
@@ -29,10 +33,25 @@ class TensorParallelismRegistry:
         if translate_functions is not None:
             self._translate_functions[dist_cls] = translate_functions
 
+    def _resolve_late(self, origin_cls):
+        """Offer a class ``_map`` misses to the late resolver, if the class
+        says it comes from ``transformers``. Any other class costs this
+        one miss and one string test."""
+        if (
+            origin_cls not in self._map
+            and self.late_resolver is not None
+            and (getattr(origin_cls, "__module__", None) or "").startswith(
+                "transformers."
+            )
+        ):
+            self.late_resolver(self, origin_cls)
+
     def is_supported(self, origin_cls):
+        self._resolve_late(origin_cls)
         return origin_cls in self._map
 
     def distributed_class(self, origin_cls):
+        self._resolve_late(origin_cls)
         try:
             return self._map[origin_cls][0]
         except KeyError:
@@ -42,6 +61,7 @@ class TensorParallelismRegistry:
             )
 
     def hooks(self, origin_cls):
+        self._resolve_late(origin_cls)
         _, init_hook, forward_hook, return_hook = self._map[origin_cls]
         return init_hook, forward_hook, return_hook
 
@@ -54,6 +74,7 @@ class TensorParallelismRegistry:
         (parity: reference ``DistributedModule.__call__``,
         ``torch/nn/dist_module.py:5-32``).
         """
+        self._resolve_late(origin_cls)
         dist_cls, init_hook, forward_hook, return_hook = self._map[origin_cls]
         if init_hook is not None:
             hooked = init_hook(*args, **kwargs)

@@ -958,6 +958,18 @@ def record_flash_v_head_dim(v_head_dim):
     ).set(v_head_dim)
 
 
+def record_hf_hooks_resolved(n):
+    """``smp_hf_hooks_resolved``: Hugging Face classes whose predefined
+    hook the tp_registry registered when it first met the class
+    (``nn/huggingface.register_predefined_hooks``). smp.init records 0, so
+    the series reads 0 in a program that holds no such class."""
+    telemetry.counter(
+        "smp_hf_hooks_resolved",
+        "Hugging Face classes registered in the tp_registry on first "
+        "look-up",
+    ).inc(n)
+
+
 def record_loss_scale(event, scale):
     """One fp16 loss-scale event ("overflow" | "growth" | "static_overflow"):
     counter + current-scale gauge + a flight-recorder health event — the

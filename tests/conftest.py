@@ -190,6 +190,21 @@ def _reset_smp():
     smp.reset()
 
 
+@pytest.fixture
+def fresh_tp_registry():
+    """The tp registry as a fresh process's ``smp.init({})`` makes it (the
+    state keeps its registry over reset and init); the session's own is put
+    back afterwards."""
+    import smdistributed_modelparallel_tpu as smp
+    from smdistributed_modelparallel_tpu.backend.state import state
+
+    kept, state.tp_registry = state.tp_registry, None
+    smp.reset()
+    smp.init({})
+    yield state.tp_registry
+    state.tp_registry = kept
+
+
 # -- committed smp.xray golden fingerprints (tests/goldens/) ------------
 # Shared by the HLO regression gates in test_pipeline_1f1b.py and
 # test_pipeline_zero_bubble.py; regenerate with

@@ -264,6 +264,147 @@ class TestRoundTrip:
         assert state.tp_registry.is_supported(transformers.ViTModel)
 
 
+def _resolved():
+    from smdistributed_modelparallel_tpu.utils.telemetry import telemetry
+
+    return telemetry.counter("smp_hf_hooks_resolved").value
+
+
+def _hf_classes_held(registry):
+    return {c for c in registry._map if c.__module__.startswith("transformers.")}
+
+
+def _all_architectures():
+    from smdistributed_modelparallel_tpu.nn import huggingface as hfmod
+
+    return [(fam.name, arch) for fam in hfmod.families().values()
+            for arch in fam.architectures]
+
+
+class TestHooksResolvedOnFirstLookUp:
+    """smp.init registers no Hugging Face class: the registry resolves a
+    ``transformers`` class's predefined hook when first asked about it
+    (``nn/huggingface.register_predefined_hooks``), and what it registers
+    is what the eager loop registered."""
+
+    @pytest.fixture
+    def registry(self, fresh_tp_registry):
+        return fresh_tp_registry
+
+    def test_look_up_registers_that_class_alone(self, registry):
+        assert not _hf_classes_held(registry) and _resolved() == 0
+        assert registry.is_supported(transformers.GPT2LMHeadModel)
+        assert _hf_classes_held(registry) == {transformers.GPT2LMHeadModel}
+        assert _resolved() == 1
+        assert registry.is_supported(transformers.GPT2LMHeadModel)
+        registry.hooks(transformers.GPT2LMHeadModel)
+        assert _resolved() == 1
+
+    def test_counter_reads_the_distinct_classes_asked_about(self, registry):
+        asked = (transformers.GPT2LMHeadModel, transformers.BertModel,
+                 transformers.GPTNeoXForCausalLM, transformers.BertModel)
+        for cls in asked:
+            assert registry.is_supported(cls)
+        # Every reader of the map resolves, not is_supported alone.
+        registry.distributed_class(transformers.ViTModel)
+        registry.hooks(transformers.RobertaModel)
+        assert registry.distribute(
+            transformers.GPTJForCausalLM, (_tiny_configs()["gptj"],), {})
+        assert _resolved() == len(set(asked)) + 3
+        assert len(_hf_classes_held(registry)) == len(set(asked)) + 3
+        # Classes of transformers with no predefined hook stay a miss.
+        assert not registry.is_supported(transformers.GPT2Config)
+        assert not registry.is_supported(transformers.LlamaModel)
+        assert _resolved() == len(set(asked)) + 3
+
+    @pytest.mark.parametrize("family,arch", _all_architectures())
+    def test_every_architecture_resolves_as_registered_before(
+            self, registry, family, arch):
+        from smdistributed_modelparallel_tpu.nn import huggingface as hfmod
+
+        hf_cls = getattr(transformers, arch, None)
+        if hf_cls is None:
+            pytest.skip(f"transformers {transformers.__version__} has no {arch}")
+        fam = hfmod.families()[family]
+        assert registry.distributed_class(hf_cls) is hfmod._target_class(fam.target)
+        init_hook, forward_hook, return_hook = registry.hooks(hf_cls)
+        assert forward_hook is None and return_hook is None
+        config = _tiny_configs().get(family) or hf_cls.config_class()
+        assert init_hook(config) == ((), fam.config_to_smp(config))
+        assert init_hook(config, deterministic=True) == (
+            (), {**fam.config_to_smp(config), "deterministic": True})
+        assert registry.translate_functions(registry.distributed_class(hf_cls)) is None
+        assert _resolved() == 1
+
+    def test_distribute_builds_the_module_config_to_smp_describes(self, registry):
+        from smdistributed_modelparallel_tpu.nn import huggingface as hfmod
+        from smdistributed_modelparallel_tpu.nn.transformer import (
+            DistributedTransformerLMHead,
+        )
+
+        config = _tiny_configs()["gpt2"]
+        built = registry.distribute(
+            transformers.GPT2LMHeadModel, (config,), {"deterministic": True})
+        assert built == DistributedTransformerLMHead(
+            **hfmod.families()["gpt2"].config_to_smp(config), deterministic=True)
+
+    def test_t5_block_resolves_and_declines_a_relative_bias_block(self, registry):
+        from transformers.models.t5.modeling_t5 import T5Block
+
+        from smdistributed_modelparallel_tpu.nn.huggingface import t5
+        from smdistributed_modelparallel_tpu.nn.transformer import (
+            DistributedTransformerLayer,
+        )
+
+        config = transformers.T5Config(
+            d_model=32, d_kv=8, num_heads=4, d_ff=64, num_layers=2,
+            vocab_size=64, dropout_rate=0.0)
+        assert registry.distributed_class(T5Block) is DistributedTransformerLayer
+        assert _hf_classes_held(registry) == {T5Block} and _resolved() == 1
+        assert registry.distribute(
+            T5Block, (config,), {"has_relative_attention_bias": True}) is None
+        assert registry.distribute(T5Block, (config,), {}) == (
+            DistributedTransformerLayer(**t5.config_to_smp_layer(config)))
+
+    def test_a_class_of_the_same_name_outside_transformers_is_not_taken(
+            self, registry):
+        from smdistributed_modelparallel_tpu.utils.exceptions import (
+            TensorParallelismError,
+        )
+
+        real = transformers.GPT2LMHeadModel
+        mine = type("GPT2LMHeadModel", (), {})
+        assert mine.__module__ == __name__
+        # One that claims the real class's module is asked about, and
+        # declined: that module defines another object under the name.
+        claims = type("GPT2LMHeadModel", (), {"__module__": real.__module__})
+        for cls in (mine, claims):
+            assert not registry.is_supported(cls)
+            with pytest.raises(TensorParallelismError):
+                registry.distributed_class(cls)
+        assert _resolved() == 0 and not _hf_classes_held(registry)
+        assert registry.is_supported(real)
+
+    @pytest.mark.parametrize("asked_first", [False, True],
+                             ids=["registered_first", "looked_up_first"])
+    def test_a_users_registration_is_what_distribute_uses(
+            self, registry, asked_first):
+        import flax.linen as nn
+
+        class Mine(nn.Module):
+            width: int
+
+        if asked_first:
+            assert registry.is_supported(transformers.GPTJModel)
+        smp.tp_register_with_module(
+            transformers.GPTJModel, Mine,
+            init_hook=lambda config: ((), {"width": config.n_embd}))
+        assert registry.distributed_class(transformers.GPTJModel) is Mine
+        assert registry.distribute(
+            transformers.GPTJModel, (_tiny_configs()["gptj"],), {}) == Mine(width=32)
+        assert _resolved() == int(asked_first)
+
+
 @pytest.mark.slow
 class TestEndToEnd:
     def test_gpt2_tp4_train_save_full_reload(self, tmp_path):
