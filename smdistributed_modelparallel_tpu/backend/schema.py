@@ -471,7 +471,7 @@ SCHEMA = {
         "multiple_of": 128,
         "description": "TPU extension: flash-attention q-tile rows "
         "(default 256; Mosaic lane alignment requires multiples of 128). "
-        "Tune per TPU generation with the bench's breakdown mode.",
+        "Tune per TPU generation.",
     },
     "pallas_attn_block_k": {
         "type": (int, type(None)),
@@ -525,7 +525,7 @@ SCHEMA = {
         "it as quant_states.pt). Canonicalizes back to bf16 under "
         "pipeline_parallel_degree > 1 or sharded_params: zero3 (warn "
         "once). On CPU/interpret XLA upcasts the f8 dots — CPU runs "
-        "prove parity, not speed (BENCH_NOTES Round 20).",
+        "prove parity, not speed (not measured on the chip).",
     },
     "fused_qkv": {
         "type": bool,

@@ -70,7 +70,7 @@ semantically correct but pays dp gather traffic + replicated activation
 memory — making the rings batch-sharded (lead-dim axes in the specs and
 axis_names) is the ROADMAP follow-up before ring defaults on for dp x tp
 jobs. The CPU tier additionally serializes the ring hops, so CPU A/B
-timings only prove plumbing (BENCH_NOTES Round 15).
+timings only prove plumbing; the rings have not been measured on the chip.
 """
 
 import functools

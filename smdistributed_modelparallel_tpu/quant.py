@@ -43,7 +43,7 @@ CPU/interpret note: XLA:CPU upcasts f8 dot operands to f32 inside the
 compiled program (the dots remain *fp8-origin*: their operands are
 converts from f8 — the X-ray ``quant`` census counts both forms), so
 CPU smoke runs prove plumbing + numerics parity only; the fp8 speed
-claim is a TPU criterion (BENCH_NOTES Round 20).
+claim has not been measured on the chip.
 """
 
 import functools

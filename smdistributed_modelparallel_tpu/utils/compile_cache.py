@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside.
 
-The entry points that compile for the chip (``chip_smoke.py``, ``bench.py``)
-call ``configure()`` once, before their first compile. Where
+The entry point that compiles for the chip (``chip_smoke.py``) calls
+``configure()`` once, before its first compile. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set the directory is JAX's own business and
 no directory is set in code; where it is not, the cache lives at the fixed
 path ``<checkout>/.jax_cache`` (git-ignored) — never a temp dir, a pid or

@@ -698,18 +698,6 @@ class GoodputLedger:
         return self.forensics.trigger(reason, detail=detail,
                                       context=context)
 
-    def bench_block(self, now=None):
-        """The ``"goodput"`` block bench.py stamps into BENCH_r*.json."""
-        now = self._clock() if now is None else now
-        secs = self.seconds(now)
-        return {
-            "fraction": round(self.goodput_fraction(now), 4),
-            "wall_s": round(self.wall_seconds(now), 3),
-            "seconds": {s: round(v, 3) for s, v in sorted(secs.items())},
-            "sentinel": list(self.sentinel.verdicts),
-            "forensics": list(self.forensics.bundles),
-        }
-
 
 class GoodputController:
     """Process-wide singleton (``smp.goodput``): owns the ledger's
@@ -808,10 +796,6 @@ class GoodputController:
     def window_block(self):
         led = self.ledger
         return led.window_block() if led is not None else None
-
-    def bench_block(self):
-        led = self.ledger
-        return led.bench_block() if led is not None else None
 
 
 _NULL_SCOPE = contextlib.nullcontext()

@@ -1927,23 +1927,6 @@ def of_step_function(step_fn):
     )
 
 
-def bench_summary(audit):
-    """The compact block bench.py stamps into BENCH_r*.json."""
-    if audit is None:
-        return None
-    return {
-        "fingerprint": audit.fingerprint_hash,
-        "collective_ops": {
-            op: ent["count"] for op, ent in sorted(audit.census.items())
-        },
-        "collective_bytes": {
-            op: ent["bytes"] for op, ent in sorted(audit.census.items())
-        },
-        "remat_fraction": audit.remat.get("fraction", 0.0),
-        "replicated_bytes": audit.replicated_bytes,
-    }
-
-
 # ----------------------------------------------------------------------
 # Fingerprint diff
 # ----------------------------------------------------------------------

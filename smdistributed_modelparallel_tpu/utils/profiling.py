@@ -5,9 +5,8 @@ The reference library ships profiling hooks as a first-class surface
 (herring timers + the ``smp_timeline_*`` C API around every server
 action); this module is the TPU build's equivalent, designed around the
 fact that chip windows on this image are rare and flaky: when one opens,
-a single run must capture a trace, attribute the MFU gap, and land in a
-tracked trajectory (``scripts/perf_ledger.py``) without anyone re-running
-ad-hoc probes. Four cooperating pieces:
+a single run must capture a trace and attribute the MFU gap without
+anyone re-running ad-hoc probes. Three cooperating pieces:
 
 1. **Named regions** — one vocabulary for every profiling surface.
    ``region(name)`` brackets a host-side phase with
@@ -77,12 +76,6 @@ ad-hoc probes. Four cooperating pieces:
    measured to the end of the device's work: the step engine blocks no
    step to take one.
 
-4. **Breakdown API** — ``StepBreakdown`` collects named component
-   timings and emits them in the same one-JSON-object-per-line schema
-   ``bench.py`` writes to stderr (``{"component": ..., "ms": ...}``), so
-   ``scripts/perf_probe.py`` / ``scripts/step_breakdown.py`` results land
-   in the shape the perf ledger ingests.
-
 Import-hygiene contract: importing this module must never initialize an
 accelerator backend (``jax.profiler``/``jax.named_scope`` are pure-host
 imports; ``jax.devices()`` is only touched from ``device_peaks`` at
@@ -93,7 +86,6 @@ import atexit
 import json
 import os
 import signal
-import sys
 import threading
 import time
 
@@ -728,8 +720,7 @@ def write_scope_report(trace_dir, window=None):
 # ----------------------------------------------------------------------
 
 # Peak dense bf16 TFLOP/s and HBM GB/s per chip, by device_kind fragment
-# (public spec sheets). Single source of truth — bench.py's MFU
-# denominator reads THIS table through device_peaks.
+# (public spec sheets), read through device_peaks.
 _PEAK_TFLOPS = (
     ("v6", 918.0),
     ("v5p", 459.0),
@@ -992,69 +983,3 @@ def _publish(r):
             "smp_roofline_compute_bound",
             "1 when arithmetic intensity sits above the ridge point",
         ).labels(**lab).set(1.0 if r.bound == "compute" else 0.0)
-
-
-# ----------------------------------------------------------------------
-# Breakdown API (scripts/perf_probe.py, scripts/step_breakdown.py, bench)
-# ----------------------------------------------------------------------
-
-
-class StepBreakdown:
-    """Named component timings, emitted one JSON object per line in the
-    exact schema ``bench.py`` writes to stderr:
-    ``{"component": <name>, "ms": <float>, ...extras}``.
-
-    ``record`` takes seconds (the JSON carries ms, like bench); every
-    component also lands in the ``smp_breakdown_ms`` telemetry gauge so a
-    probe run's breakdown rides in its telemetry dump.
-    """
-
-    def __init__(self, context=None):
-        self._rows = []
-        self._context = dict(context or {})
-
-    @property
-    def rows(self):
-        return list(self._rows)
-
-    def record(self, component, seconds, **extras):
-        row = dict(self._context)
-        row.update(extras)
-        row["component"] = component
-        row["ms"] = round(float(seconds) * 1e3, 3)
-        self._rows.append(row)
-        telemetry.gauge(
-            "smp_breakdown_ms", "perf-probe component wall time (ms)"
-        ).labels(component=component).set(float(seconds) * 1e3)
-        return row
-
-    def time(self, component, fn, *args, iters=10, readback=None, **extras):
-        """Warmup call + timed loop; records the mean per-iteration wall
-        time. ``readback`` forces a device->host sync (defaults to
-        ``jax.block_until_ready``). Not for donating functions — those
-        must thread their own state and call ``record`` directly."""
-        out = fn(*args)
-        self._force(out, readback)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        self._force(out, readback)
-        dt = (time.perf_counter() - t0) / iters
-        self.record(component, dt, iters=iters, **extras)
-        return out, dt
-
-    @staticmethod
-    def _force(out, readback):
-        if readback is not None:
-            readback(out)
-        else:
-            jax.block_until_ready(out)
-
-    def emit(self, stream=None):
-        """Write every recorded row as one JSON line (bench schema).
-        Returns the rows."""
-        stream = sys.stderr if stream is None else stream
-        for row in self._rows:
-            stream.write(json.dumps(row) + "\n")
-        stream.flush()
-        return self.rows

@@ -1291,8 +1291,7 @@ def record_serve_latency(kind, seconds):
 
 def serve_latency_summary(kind, qs=(0.5, 0.9, 0.99)):
     """``{"count", "mean_s", "quantiles_s": {q: seconds}}`` of one
-    serving latency distribution, or None before its first sample
-    (bench.py stamps the serve probe's percentile columns from this)."""
+    serving latency distribution, or None before its first sample."""
     with telemetry._lock:
         fam = telemetry._families.get("smp_serve_latency_seconds")
     if fam is None:

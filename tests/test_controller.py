@@ -6,7 +6,7 @@ directions, the cooldown latch, min/max clamps, flap suppression),
 router units over fake handles (least-loaded dispatch, deterministic
 version splits, availability fallback), controller scale events over
 fake handles (phase accounting, the JSONL feed, the drain/reroute
-path), the chaos seams, and the slo_report/perf_ledger tool gates —
+path), the chaos seams, and the slo_report tool gate —
 none of which compile anything. One compiled-engine composite carries
 every behavioral claim that needs real programs (drain token parity,
 zero-recompile adoption, canary promote + chaos-corrupted rollback
@@ -54,7 +54,6 @@ _SCRIPTS = os.path.join(
 if _SCRIPTS not in sys.path:
     sys.path.insert(0, _SCRIPTS)
 
-import perf_ledger  # noqa: E402
 import slo_report  # noqa: E402
 
 
@@ -457,7 +456,7 @@ class TestChaosSeams:
 
 
 # ---------------------------------------------------------------------------
-# tool gates: slo_report --controller, perf_ledger autoscale schema
+# tool gate: slo_report --controller
 # ---------------------------------------------------------------------------
 
 
@@ -520,32 +519,6 @@ class TestControllerReportScript:
             [str(tmp_path), "--controller", "--check"]) == 1
         out = capsys.readouterr().out
         assert "never promoted" in out
-
-
-class TestAutoscaleLedgerSchema:
-    def _block(self, **kw):
-        b = {"component": "autoscale", "scale_events": 2,
-             "p99_ttft_ms_static": 590.0, "p99_ttft_ms_auto": 410.0,
-             "weight_update_s": 0.0001, "canary_verdict": "promoted",
-             "fresh_compiles": 0, "token_parity": True}
-        b.update(kw)
-        return b
-
-    def test_valid_and_absent(self):
-        assert perf_ledger._autoscale_schema_problem(None) is None
-        assert perf_ledger._autoscale_schema_problem(self._block()) is None
-
-    def test_rejections(self):
-        bad = [
-            self._block(scale_events=0),
-            self._block(canary_verdict="maybe"),
-            self._block(token_parity=False),
-            self._block(weight_update_s=-1.0),
-            dict(self._block(), p99_ttft_ms_auto="fast"),
-            [1, 2],
-        ]
-        for block in bad:
-            assert perf_ledger._autoscale_schema_problem(block), block
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """PR-17 fleet metrics plane: the canonical cross-rank merge (shared by
 the live aggregator and the offline scripts), aggregator election +
 fleet windows, the three fleet detectors under fake clocks, the scrape
-endpoint, the disabled-constructs-nothing contract, slo_report --fleet,
-and the perf_ledger fleet-block schema.
+endpoint, the disabled-constructs-nothing contract, and
+slo_report --fleet.
 
 Everything here is tier-1 host-only: planes are built with ``bus=None``
 and injected ``alive_fn``/clock; peer snapshots are ingested directly.
@@ -43,7 +43,6 @@ _SCRIPTS = os.path.join(
 if _SCRIPTS not in sys.path:
     sys.path.insert(0, _SCRIPTS)
 
-import perf_ledger  # noqa: E402
 import slo_report  # noqa: E402
 import telemetry_report  # noqa: E402
 import trace_fuse  # noqa: E402
@@ -649,41 +648,11 @@ class TestSloReportFleet:
 
 
 # ----------------------------------------------------------------------
-# perf_ledger fleet block schema + trace_fuse naming
+# trace_fuse naming
 # ----------------------------------------------------------------------
 
 
 class TestFleetTooling:
-    def _probe(self, fleet=None):
-        probe = {
-            "component": "serving", "ttft_ms": 5.0, "itl_ms": 2.0,
-            "tokens_per_sec": 100.0, "speedup": 2.0,
-            "static_tokens_per_sec": 50.0, "token_parity": True,
-        }
-        if fleet is not None:
-            probe["fleet"] = fleet
-        return probe
-
-    def test_fleet_block_schema(self):
-        ok = {"windows": 3, "ranks": 1, "stragglers": [],
-              "endpoint_roundtrip_ms": 1.5}
-        assert perf_ledger._serve_probe_schema_problem(
-            self._probe(ok)) is None
-        assert perf_ledger._serve_probe_schema_problem(
-            self._probe()) is None  # absent block is fine
-        bad = perf_ledger._serve_probe_schema_problem(
-            self._probe({"windows": 0, "stragglers": []}))
-        assert bad and "windows" in bad
-        bad = perf_ledger._serve_probe_schema_problem(
-            self._probe({"windows": 2, "stragglers": "1"}))
-        assert bad and "stragglers" in bad
-        bad = perf_ledger._serve_probe_schema_problem(
-            self._probe({"windows": 2, "stragglers": [],
-                         "endpoint_roundtrip_ms": "fast"}))
-        assert bad and "endpoint_roundtrip_ms" in bad
-        bad = perf_ledger._serve_probe_schema_problem(self._probe([1]))
-        assert bad and "object" in bad
-
     def test_trace_fuse_names_fleet_events(self):
         stream = trace_fuse.Stream(path="flight.json", kind="recorder",
                                    rank=0)

@@ -3,7 +3,7 @@
 sentinel's change-point latch, the auto-forensics engine's cooldown /
 cap rate limiting, the zero-cost-off contract, and the folds into the
 watchdog dump, the time-series windows, the fleet windows, and the
-slo_report / perf_ledger script gates.
+slo_report script gate.
 
 Everything here is tier-1 host-only: ledgers are built with injected
 fake clocks and fresh ``TelemetryRegistry`` instances, never the
@@ -12,6 +12,7 @@ process singletons.
 
 import json
 import os
+import random
 import sys
 
 import pytest
@@ -43,7 +44,6 @@ _SCRIPTS = os.path.join(
 if _SCRIPTS not in sys.path:
     sys.path.insert(0, _SCRIPTS)
 
-import perf_ledger  # noqa: E402
 import slo_report  # noqa: E402
 
 
@@ -83,6 +83,33 @@ def _counter(reg, name, **labels):
     return None
 
 
+def _short_walk(led, clk):
+    clk.t += 3.0                              # startup
+    led.observe_phase("step_0/trace")
+    clk.t += 2.0
+    led.observe_phase("step_0")
+    clk.t += 4.0
+    with led.scope("data_wait"):
+        clk.t += 1.5
+
+
+def _random_walk(led, clk):
+    rng = random.Random(18)
+    phases = ["step_1/trace", "step_1", "compile/x", "barrier/y",
+              "init/mesh", "initialized", "unclassified/noise"]
+    for _ in range(200):
+        clk.t += rng.uniform(0.0, 3.0)
+        op = rng.random()
+        if op < 0.6:
+            led.observe_phase(rng.choice(phases))
+        elif op < 0.8:
+            with led.scope(rng.choice(("ckpt_save", "data_wait",
+                                       "preempt_drain"))):
+                clk.t += rng.uniform(0.0, 2.0)
+        else:
+            led.enter(rng.choice(("wedged", "recovery_first_step")))
+
+
 # ----------------------------------------------------------------------
 # The attribution state machine
 # ----------------------------------------------------------------------
@@ -113,25 +140,35 @@ class TestLedgerInvariant:
         assert set(secs) <= set(STATES)
 
     def test_invariant_holds_under_random_walk(self):
-        import random
-
-        rng = random.Random(18)
         led, clk = _ledger()
-        phases = ["step_1/trace", "step_1", "compile/x", "barrier/y",
-                  "init/mesh", "initialized", "unclassified/noise"]
-        for _ in range(200):
-            clk.t += rng.uniform(0.0, 3.0)
-            op = rng.random()
-            if op < 0.6:
-                led.observe_phase(rng.choice(phases))
-            elif op < 0.8:
-                with led.scope(rng.choice(("ckpt_save", "data_wait",
-                                           "preempt_drain"))):
-                    clk.t += rng.uniform(0.0, 2.0)
-            else:
-                led.enter(rng.choice(("wedged", "recovery_first_step")))
+        _random_walk(led, clk)
         assert sum(led.seconds().values()) == pytest.approx(
             led.wall_seconds(), abs=1e-9
+        )
+
+    # What a reader of the published blocks may rely on, whatever the
+    # rounding: a fraction in [0, 1] and seconds that add up to the wall.
+
+    @pytest.mark.parametrize("walk", [_short_walk, _random_walk])
+    def test_snapshot_seconds_sum_to_its_wall(self, walk):
+        led, clk = _ledger()
+        walk(led, clk)
+        snap = led.snapshot()
+        assert 0.0 <= snap["goodput_fraction"] <= 1.0
+        assert set(snap["seconds"]) <= set(STATES)
+        assert sum(snap["seconds"].values()) == pytest.approx(
+            snap["wall_s"], rel=0.01
+        )
+
+    @pytest.mark.parametrize("walk", [_short_walk, _random_walk])
+    def test_window_block_badput_is_the_rest_of_the_wall(self, walk):
+        led, clk = _ledger()
+        walk(led, clk)
+        block = led.window_block()
+        assert 0.0 <= block["fraction"] <= 1.0
+        assert not set(block["badput"]) & set(PRODUCTIVE)
+        assert sum(block["badput"].values()) == pytest.approx(
+            (1.0 - block["fraction"]) * led.wall_seconds(), rel=0.01
         )
 
     def test_scope_restores_enclosing_state(self):
@@ -500,13 +537,6 @@ class TestLedgerClosedLoops:
         assert doc["goodput"]["state"]        # snapshot attached
         assert doc["sentinel"]["verdicts"]
 
-    def test_bench_block_shape(self):
-        led, clk = _ledger()
-        led.observe_phase("step_0")
-        clk.t += 5.0
-        block = led.bench_block()
-        assert perf_ledger._goodput_schema_problem(block) is None
-
     def test_maybe_tick_rate_limited(self):
         led, clk = _ledger(tick_seconds=5.0)
         led.observe_phase("step_0")
@@ -551,7 +581,6 @@ class TestController:
         assert ctl.trigger_forensics("r") is None
         assert ctl.snapshot() is None
         assert ctl.window_block() is None
-        assert ctl.bench_block() is None
 
     def test_start_chains_phase_listener_and_stop_restores(self, clean_env):
         clean_env.setenv(GOODPUT_ENV, "1")
@@ -631,21 +660,6 @@ class TestScriptGates:
             [feed, "--fleet", "--check", "--min-train-goodput", "0.9"]
         ) == 1
         capsys.readouterr()
-
-    def test_perf_ledger_goodput_schema(self):
-        good = {"fraction": 0.9, "wall_s": 100.0,
-                "seconds": {"step": 90.0, "data_wait": 10.0},
-                "sentinel": [], "forensics": []}
-        assert perf_ledger._goodput_schema_problem(None) is None
-        assert perf_ledger._goodput_schema_problem(good) is None
-        bad = dict(good, fraction=1.5)
-        assert "fraction" in perf_ledger._goodput_schema_problem(bad)
-        leak = dict(good, seconds={"step": 50.0})
-        assert "sum" in perf_ledger._goodput_schema_problem(leak)
-        assert perf_ledger._goodput_schema_problem([1]) is not None
-        assert perf_ledger._goodput_schema_problem(
-            dict(good, sentinel="no")
-        ) is not None
 
 
 # ----------------------------------------------------------------------

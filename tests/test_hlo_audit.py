@@ -1048,20 +1048,30 @@ def _train_pp(cfg, step_fn=None):
 
 
 class TestEndToEnd:
-    def test_census_persistence_and_reports(self, tmp_path, monkeypatch):
-        """One pp=2 compile exercises the whole surface: stored audit,
-        per-axis census, gauges, SMP_HLO_AUDIT_PATH persistence, the
-        flight-recorder fingerprint, and both report CLIs."""
-        dump = tmp_path / "xray.json"
-        monkeypatch.setenv("SMP_HLO_AUDIT_PATH", str(dump))
-        step_fn = _train_pp({
-            "pipeline_parallel_degree": 2, "microbatches": 4, "ddp": True,
-        })
+    @pytest.fixture(scope="class")
+    def pp2(self, tmp_path_factory):
+        """The class's one pp=2 compile: its audit and where it was
+        persisted. The first test to ask finds the telemetry and the
+        flight recorder as the compile left them."""
+        dump = tmp_path_factory.mktemp("xray") / "xray.json"
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("SMP_HLO_AUDIT_PATH", str(dump))
+            step_fn = _train_pp({
+                "pipeline_parallel_degree": 2, "microbatches": 4,
+                "ddp": True,
+            })
         runner = list(step_fn._cache.values())[0]
         if runner.holder.get("compiled") is None:
             pytest.skip("AOT step executable unavailable on this backend")
         audit = runner.hlo_audit
         assert audit is not None, "post-compile audit did not run"
+        return audit, dump
+
+    def test_census_persistence_and_reports(self, tmp_path, pp2):
+        """One pp=2 compile exercises the whole surface: stored audit,
+        per-axis census, gauges, SMP_HLO_AUDIT_PATH persistence, the
+        flight-recorder fingerprint, and both report CLIs."""
+        audit, dump = pp2
         # The PR-5 guard, structured: pp-axis permutes present, detector
         # clean.
         assert audit.collective_count("collective-permute", axis="pp") > 0
@@ -1135,6 +1145,25 @@ class TestEndToEnd:
         )
         assert diff_dirty.returncode == 1, diff_dirty.stdout
         assert "collectives.collective-permute.pp.count" in diff_dirty.stdout
+
+    # What a reader of the audit may rely on without a schema check of
+    # its own: a hash to compare, and a census it can sum.
+
+    def test_the_fingerprint_hash_is_a_non_empty_string(self, pp2):
+        audit, _ = pp2
+        assert isinstance(audit.fingerprint_hash, str)
+        assert audit.fingerprint_hash
+        assert audit.as_dict()["fingerprint"] == audit.fingerprint_hash
+
+    def test_every_census_entry_has_a_count_and_bytes(self, pp2):
+        audit, _ = pp2
+        assert audit.census
+        for op, ent in audit.census.items():
+            rows = [ent, *ent["axes"].values()]
+            for row in rows:
+                assert isinstance(row["count"], int) and row["count"] > 0, op
+                assert isinstance(row["bytes"], int) and row["bytes"] >= 0, op
+            assert sum(r["count"] for r in rows[1:]) == ent["count"], op
 
     def test_detector_flags_replicated_tick_loop(self, monkeypatch):
         """The acceptance gate for the detector: compile the pp=2/v=2

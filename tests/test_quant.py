@@ -22,9 +22,8 @@ Coverage map:
   int8 engine vs ``smp.generate`` parity incl. both knobs together
   (slow tier);
 - satellites: step-cache/exec-cache quant knob facts (defaults omitted,
-  stored-meta flip -> reject_version), the telemetry_report
-  "-- quant --" section goldens (single dump + cross-rank dir mode),
-  and the perf-ledger ``quant`` component schema/carry/render.
+  stored-meta flip -> reject_version) and the telemetry_report
+  "-- quant --" section goldens (single dump + cross-rank dir mode).
 """
 
 import glob
@@ -624,105 +623,3 @@ class TestQuantReportSection:
         out = io.StringIO()
         mod.render({"meta": {}, "metrics": {}}, out=out)
         assert "-- quant --" not in out.getvalue()
-
-
-# ----------------------------------------------------------------------
-# perf_ledger quant component
-# ----------------------------------------------------------------------
-
-
-def _quant_probe_block(**over):
-    block = {
-        "component": "quant",
-        "train": {
-            "bf16_ms": 5.4, "fp8_ms": 8.5, "speedup_fp8": 0.6353,
-            "loss_rel_diff": 9.6e-05, "steps_compared": 10,
-            "quant_xray": {
-                "native_f8_dots": 0, "fp8_origin_dots": 0,
-                "f8_casts": {"e4m3": 79, "e5m2": 4},
-            },
-        },
-        "decode": {
-            "bf16_tokens_per_sec": 120.0,
-            "int8_kv_tokens_per_sec": 110.0, "speedup_kv": 0.9167,
-            "kv_block_bytes_bf16": 8192, "kv_block_bytes_int8": 2112,
-            "kv_bytes_ratio": 0.2578, "token_parity": True,
-            "requests": 6,
-        },
-        "on_tpu": False,
-    }
-    block.update(over)
-    return block
-
-
-class TestLedgerQuantProbe:
-    @pytest.fixture()
-    def ledger_mod(self):
-        return _load_script("perf_ledger")
-
-    def test_schema_accepts_and_rejects(self, ledger_mod):
-        check = ledger_mod._quant_probe_schema_problem
-        assert check(None) is None
-        assert check(_quant_probe_block()) is None
-        # Either leg alone is a valid block; neither is not.
-        assert check(_quant_probe_block(decode=None)) is None
-        assert check(_quant_probe_block(train=None)) is None
-        assert "neither" in check(
-            _quant_probe_block(train=None, decode=None)
-        )
-        assert "component" in check(_quant_probe_block(component="nope"))
-        blk = _quant_probe_block()
-        blk["train"]["fp8_ms"] = None
-        assert "fp8_ms" in check(blk)
-        blk = _quant_probe_block()
-        blk["train"]["speedup_fp8"] = 9.0
-        assert "inconsistent" in check(blk)
-        blk = _quant_probe_block()
-        blk["train"]["quant_xray"] = "not-a-dict"
-        assert "quant_xray" in check(blk)
-        blk = _quant_probe_block()
-        blk["decode"]["kv_bytes_ratio"] = 0.9
-        assert "inconsistent" in check(blk)
-        blk = _quant_probe_block()
-        blk["decode"]["token_parity"] = False
-        assert "token_parity" in check(blk)
-
-    def test_carried_and_rendered(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "tokens/sec/chip GPT-2-124M train step",
-                  "value": 50000.0, "vs_baseline": 1.0,
-                  "quant": _quant_probe_block()}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert ledger["ok"], ledger["problems"]
-        assert ledger["rounds"][0]["quant"]["train"]["fp8_ms"] == 8.5
-        out = io.StringIO()
-        ledger_mod.render_table(ledger, out=out)
-        text = out.getvalue()
-        assert "quant train:" in text
-        assert "speedup 0.64x" in text
-        assert "loss drift 0.01%" in text
-        assert "f8 casts e4m3=79 e5m2=4" in text
-        assert "quant decode:" in text
-        assert "kv bytes/block 8,192B -> 2,112B (0.26x)" in text
-        assert "parity ok" in text
-
-    def test_malformed_block_is_a_problem(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "m", "value": 1.0, "vs_baseline": 1.0,
-                  "quant": {"component": "quant"}}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert not ledger["ok"]
-        assert any("quant" in p for p in ledger["problems"])
-        assert ledger["rounds"][0]["quant"] is None

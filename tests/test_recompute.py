@@ -11,7 +11,7 @@ validation through ``tests/schedule_checker.py`` across the existing
 12-config sweep; the committed ``zero_bubble_stash_weight_pp2_mb4``
 golden; knob plumbing (config/env aliases, step-key and exec-cache
 canonicalization, checkpoint-policy mapping for non-pipeline paths); and
-the telemetry-report / perf-ledger surfaces.
+the telemetry-report surface.
 """
 
 import importlib.util
@@ -690,79 +690,3 @@ class TestRecomputeReportSection:
         out = io.StringIO()
         mod.render_cross_rank(reports, out=out)
         assert self.GOLDEN in out.getvalue()
-
-
-# ----------------------------------------------------------------------
-# perf_ledger pipeline_probe block (satellite)
-# ----------------------------------------------------------------------
-
-
-class TestLedgerPipelineProbe:
-    def _probe(self, **over):
-        probe = {
-            "component": "pipeline_schedule",
-            "schedules": {"1f1b": 10.0, "interleaved_v2": 9.0,
-                          "zb_h1": 8.5},
-            "remat_fraction": {"1f1b": 0.22, "interleaved_v2": 0.58,
-                               "zb_h1": 0.33},
-            "schedule_best": "zb_h1",
-        }
-        probe.update(over)
-        return probe
-
-    def test_schema_accepts_valid_and_absent(self):
-        mod = _load_script("perf_ledger")
-        assert mod._pipeline_probe_schema_problem(None) is None
-        assert mod._pipeline_probe_schema_problem(self._probe()) is None
-        # remat_fraction is optional (rounds predating the stamp).
-        p = self._probe()
-        del p["remat_fraction"]
-        assert mod._pipeline_probe_schema_problem(p) is None
-
-    def test_schema_rejects_malformed(self):
-        mod = _load_script("perf_ledger")
-        assert "component" in mod._pipeline_probe_schema_problem(
-            self._probe(component="something")
-        )
-        assert "schedules" in mod._pipeline_probe_schema_problem(
-            self._probe(schedules={"1f1b": "fast"})
-        )
-        assert "remat_fraction" in mod._pipeline_probe_schema_problem(
-            self._probe(remat_fraction={"1f1b": 1.5})
-        )
-        assert "did not time" in mod._pipeline_probe_schema_problem(
-            self._probe(remat_fraction={"mystery": 0.2})
-        )
-        assert "schedule_best" in mod._pipeline_probe_schema_problem(
-            self._probe(schedule_best="mystery")
-        )
-
-    def test_ledger_renders_and_gates(self, tmp_path):
-        mod = _load_script("perf_ledger")
-        (tmp_path / "BASELINE.json").write_text(
-            json.dumps({"metric": "tok/s"})
-        )
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "n": 1, "rc": 0,
-            "parsed": {"metric": "x (CPU smoke, reduced model)",
-                       "value": 1.0, "vs_baseline": 1.0,
-                       "pipeline_probe": self._probe()},
-        }))
-        ledger = mod.build_ledger(str(tmp_path))
-        assert ledger["ok"], ledger["problems"]
-        assert ledger["rounds"][0]["pipeline_probe"]["schedule_best"] == "zb_h1"
-        out = io.StringIO()
-        mod.render_table(ledger, out=out)
-        text = out.getvalue()
-        assert "pipeline_probe:" in text
-        assert "zb_h1 8.5ms (remat 33%)" in text
-        # A malformed block is a ledger problem (schema gate).
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-            "n": 2, "rc": 0,
-            "parsed": {"metric": "x (CPU smoke, reduced model)",
-                       "value": 1.0, "vs_baseline": 1.0,
-                       "pipeline_probe": self._probe(component="nope")},
-        }))
-        ledger = mod.build_ledger(str(tmp_path))
-        assert not ledger["ok"]
-        assert any("pipeline_probe" in p for p in ledger["problems"])

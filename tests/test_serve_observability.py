@@ -48,7 +48,6 @@ _SCRIPTS = os.path.join(
 if _SCRIPTS not in sys.path:
     sys.path.insert(0, _SCRIPTS)
 
-import perf_ledger  # noqa: E402
 import slo_report  # noqa: E402
 import telemetry_report  # noqa: E402
 import trace_fuse  # noqa: E402
@@ -511,7 +510,7 @@ class TestServeSpans:
 
 
 # ---------------------------------------------------------------------------
-# report rendering + perf_ledger schema
+# report rendering
 # ---------------------------------------------------------------------------
 
 
@@ -565,27 +564,6 @@ class TestReportRendering:
         assert "latency (ms)" in text
         # 8 merged samples across both ranks on one row.
         assert "ttft" in text
-
-    def test_perf_ledger_percentile_schema(self):
-        probe = {
-            "component": "serving", "ttft_ms": 10.0, "itl_ms": 2.0,
-            "tokens_per_sec": 100.0, "speedup": 2.0,
-            "static_tokens_per_sec": 50.0, "token_parity": True,
-            "ttft_p50_ms": 8.0, "ttft_p95_ms": 20.0, "ttft_p99_ms": 30.0,
-            "itl_p50_ms": 1.5, "itl_p95_ms": 3.0, "itl_p99_ms": 4.0,
-        }
-        assert perf_ledger._serve_probe_schema_problem(probe) is None
-        # Percentiles optional (older rounds predate them)...
-        legacy = {k: v for k, v in probe.items() if "p5" not in k
-                  and "p9" not in k}
-        assert perf_ledger._serve_probe_schema_problem(legacy) is None
-        # ...but must be numeric and monotonic when present.
-        bad = dict(probe, ttft_p99_ms=1.0)
-        assert "not monotonic" in perf_ledger._serve_probe_schema_problem(
-            bad)
-        bad = dict(probe, itl_p95_ms="fast")
-        assert "must be numeric" in (
-            perf_ledger._serve_probe_schema_problem(bad))
 
 
 # ---------------------------------------------------------------------------

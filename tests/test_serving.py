@@ -7,7 +7,7 @@ composite engine end-to-end (greedy + stochastic sampling parity against
 release, chunked prefill interleaving, exactly-two-programs, telemetry +
 report rendering — all on a single pair of compiled programs), the X-ray
 golden gate for the tp2 decode program (zero replicated-KV findings),
-and the pure-python probe/report tool checks. Heavy extra-compile cases
+and the pure-python recovery-report tool check. Heavy extra-compile cases
 (replicated-pool detector, exec-cache warm start) are slow-tiered in
 conftest; the 2-process replica-failover E2E lives in
 tests/test_multiprocess.py.
@@ -467,34 +467,7 @@ class TestChaosKillReplica:
         chaos_mod.chaos.reset()
 
 
-class TestProbeAndLedgerTools:
-    def test_serve_probe_schema(self):
-        import sys
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts",
-        ))
-        import perf_ledger
-
-        good = {
-            "component": "serving", "ttft_ms": 5.0, "itl_ms": 2.5,
-            "tokens_per_sec": 500.0, "static_tokens_per_sec": 250.0,
-            "speedup": 2.0, "token_parity": True,
-        }
-        assert perf_ledger._serve_probe_schema_problem(None) is None
-        assert perf_ledger._serve_probe_schema_problem(good) is None
-        bad = dict(good, speedup=9.0)
-        assert "inconsistent" in perf_ledger._serve_probe_schema_problem(bad)
-        assert "numeric" in perf_ledger._serve_probe_schema_problem(
-            {"component": "serving", "ttft_ms": "fast"}
-        )
-        assert "token_parity" in perf_ledger._serve_probe_schema_problem(
-            dict(good, token_parity=False)
-        )
-        assert "component" in perf_ledger._serve_probe_schema_problem(
-            dict(good, component="svc")
-        )
-
+class TestRecoveryReportTool:
     def test_recovery_report_parses_serving_failover(self, tmp_path):
         """resilience_probe --recovery understands the serving phase
         vocabulary (detect/readmit/first_token) and holds it to the same

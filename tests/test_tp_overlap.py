@@ -25,9 +25,8 @@ Coverage map:
   ``shard_activation`` re-constraining an already-sharded activation
   inserts ZERO tp all-gathers (nn/linear.py module docstring);
 - satellites: step-cache/exec-cache knob facts (defaults omitted,
-  stored-meta flip -> reject), the telemetry_report "-- tp overlap --"
-  section golden, and the perf-ledger ``tp_overlap`` component
-  schema/carry/render.
+  stored-meta flip -> reject) and the telemetry_report
+  "-- tp overlap --" section golden.
 """
 
 import importlib.util
@@ -748,82 +747,3 @@ class TestTpReportSection:
         out = io.StringIO()
         mod.render({"meta": {}, "metrics": {}}, out=out)
         assert "-- tp overlap --" not in out.getvalue()
-
-
-# ----------------------------------------------------------------------
-# perf_ledger tp_overlap component
-# ----------------------------------------------------------------------
-
-
-def _tp_probe_block(**over):
-    block = {
-        "component": "tp_overlap", "tp": 2,
-        "off_ms": 50.0, "ring_ms": 40.0, "ring_fused_ms": 36.0,
-        "speedup_ring": 1.25, "speedup_fused": 1.3889,
-        "tp_overlap": {
-            "ring_permute_ops": 11, "parked_hops": 6,
-            "tp_allgather_ops": 0, "overlap_evidence": True,
-        },
-        "fused_engaged": True, "blocks": 3, "on_tpu": True,
-    }
-    block.update(over)
-    return block
-
-
-class TestLedgerTpProbe:
-    @pytest.fixture()
-    def ledger_mod(self):
-        return _load_script("perf_ledger")
-
-    def test_schema_accepts_and_rejects(self, ledger_mod):
-        assert ledger_mod._tp_probe_schema_problem(None) is None
-        assert ledger_mod._tp_probe_schema_problem(_tp_probe_block()) is None
-        assert "component" in ledger_mod._tp_probe_schema_problem(
-            _tp_probe_block(component="nope")
-        )
-        assert "ring_ms" in ledger_mod._tp_probe_schema_problem(
-            _tp_probe_block(ring_ms=None)
-        )
-        assert "inconsistent" in ledger_mod._tp_probe_schema_problem(
-            _tp_probe_block(speedup_ring=9.0)
-        )
-        assert "X-ray" in ledger_mod._tp_probe_schema_problem(
-            _tp_probe_block(tp_overlap="not-a-dict")
-        )
-
-    def test_carried_and_rendered(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "tokens/sec/chip GPT-2-124M train step",
-                  "value": 50000.0, "vs_baseline": 1.0,
-                  "tp_overlap": _tp_probe_block()}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert ledger["ok"], ledger["problems"]
-        assert ledger["rounds"][0]["tp_overlap"]["speedup_ring"] == 1.25
-        out = io.StringIO()
-        ledger_mod.render_table(ledger, out=out)
-        text = out.getvalue()
-        assert "tp_overlap:" in text
-        assert "speedup 1.25x/1.39x" in text
-        assert "overlap proven" in text
-        assert "11 ring hop(s)" in text
-
-    def test_malformed_block_is_a_problem(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "m", "value": 1.0, "vs_baseline": 1.0,
-                  "tp_overlap": {"component": "tp_overlap"}}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert not ledger["ok"]
-        assert any("tp_overlap" in p for p in ledger["problems"])
-        assert ledger["rounds"][0]["tp_overlap"] is None

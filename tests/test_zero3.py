@@ -15,9 +15,8 @@ Coverage map:
 - composition (slow tier): pp2 x zero3 parity, the GSPMD fallback path
   with prefetch off, and the elastic round trips across world shapes
   (zero3 -> plain dp and back, bitwise);
-- satellites: exec-cache knob facts (flip -> verified miss), the
-  telemetry_report "-- zero --" section golden, and the perf-ledger
-  ``zero_probe`` component schema/carry/render.
+- satellites: exec-cache knob facts (flip -> verified miss) and the
+  telemetry_report "-- zero --" section golden.
 """
 
 import importlib.util
@@ -648,85 +647,6 @@ class TestZeroReportSection:
         out = io.StringIO()
         mod.render({"meta": {}, "metrics": {}}, out=out)
         assert "-- zero --" not in out.getvalue()
-
-
-# ----------------------------------------------------------------------
-# perf_ledger zero_probe component
-# ----------------------------------------------------------------------
-
-
-def _zero_probe_block(**over):
-    block = {
-        "component": "zero_probe", "rdp": 8,
-        "zero2d_ms": 44.7, "zero3_ms": 40.1, "speedup": 1.1147,
-        "memory": {
-            "zero2d": {"param_bytes_per_device": 26720,
-                       "param_bytes_total": 213760},
-            "zero3": {"param_bytes_per_device": 26720,
-                      "param_bytes_total": 213760},
-        },
-        "zero": {"overlap_fraction": 1.0},
-        "blocks": 3, "on_tpu": True,
-    }
-    block.update(over)
-    return block
-
-
-class TestLedgerZeroProbe:
-    @pytest.fixture()
-    def ledger_mod(self):
-        return _load_script("perf_ledger")
-
-    def test_schema_accepts_and_rejects(self, ledger_mod):
-        assert ledger_mod._zero_probe_schema_problem(None) is None
-        assert ledger_mod._zero_probe_schema_problem(
-            _zero_probe_block()
-        ) is None
-        assert "component" in ledger_mod._zero_probe_schema_problem(
-            _zero_probe_block(component="nope")
-        )
-        assert "zero3_ms" in ledger_mod._zero_probe_schema_problem(
-            _zero_probe_block(zero3_ms=None)
-        )
-        assert "inconsistent" in ledger_mod._zero_probe_schema_problem(
-            _zero_probe_block(speedup=9.0)
-        )
-
-    def test_carried_and_rendered(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "tokens/sec/chip GPT-2-124M train step",
-                  "value": 50000.0, "vs_baseline": 1.0,
-                  "zero_probe": _zero_probe_block()}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert ledger["ok"], ledger["problems"]
-        assert ledger["rounds"][0]["zero_probe"]["speedup"] == 1.1147
-        out = io.StringIO()
-        ledger_mod.render_table(ledger, out=out)
-        text = out.getvalue()
-        assert "zero_probe:" in text
-        assert "speedup 1.11x" in text
-        assert "overlap 100%" in text
-
-    def test_malformed_block_is_a_problem(self, tmp_path, ledger_mod):
-        repo = str(tmp_path)
-        with open(os.path.join(repo, "BASELINE.json"), "w") as f:
-            json.dump({"metric": "m"}, f)
-        parsed = {"metric": "m", "value": 1.0, "vs_baseline": 1.0,
-                  "zero_probe": {"component": "zero_probe"}}
-        payload = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                   "parsed": parsed}
-        with open(os.path.join(repo, "BENCH_r01.json"), "w") as f:
-            json.dump(payload, f)
-        ledger = ledger_mod.build_ledger(repo)
-        assert not ledger["ok"]
-        assert any("zero_probe" in p for p in ledger["problems"])
-        assert ledger["rounds"][0]["zero_probe"] is None
 
 
 # ----------------------------------------------------------------------
