@@ -48,6 +48,11 @@ from smdistributed_modelparallel_tpu.nn.diffusion import (
     record_diffusion_stats,
     two_copy_stream,
 )
+from smdistributed_modelparallel_tpu.nn.exit_gate import (
+    exit_gated_loss,
+    next_token_targets,
+    record_exit_stats,
+)
 from smdistributed_modelparallel_tpu.nn.moe import (
     DistributedDroplessMoE,
     DistributedMoE,

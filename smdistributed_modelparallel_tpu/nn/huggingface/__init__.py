@@ -61,8 +61,8 @@ def _target_class(target):
 
 def _families():
     from smdistributed_modelparallel_tpu.nn.huggingface import (
-        bert, gpt2, gptj, gptneo, gptneox, laguna, lfm2_moe, mellum, roberta,
-        sdar, t5, vit, xing4,
+        bert, gpt2, gptj, gptneo, gptneox, laguna, lfm2_moe, mellum, ouro,
+        roberta, sdar, t5, vit, xing4,
     )
 
     fams = {}
@@ -71,6 +71,7 @@ def _families():
         ("gptneox", gptneox), ("bert", bert), ("roberta", roberta),
         ("vit", vit), ("t5", t5), ("laguna", laguna), ("mellum", mellum),
         ("sdarmoe", sdar), ("lfm2moe", lfm2_moe), ("xing40", xing4),
+        ("ouro", ouro),
     ):
         fams[name] = HFFamily(
             name=name,

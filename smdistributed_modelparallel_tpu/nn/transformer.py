@@ -850,6 +850,10 @@ class DistributedTransformerLayer(nn.Module):
     pre_layernorm: bool = False
     post_layernorm: bool = True
     single_pre_layernorm: bool = False
+    # A norm on each branch's output before the residual add, the layer's
+    # own kind (``<site>/branch_layernorm``): with ``pre_layernorm`` the
+    # "sandwich" ``x + N(f(N(x)))``; ``post_layernorm`` norms after the add.
+    branch_layernorm: bool = False
     attention_in_fp32: bool = False
     query_key_layer_scaling: bool = False
     scale_attention_scores: bool = True
@@ -1129,6 +1133,21 @@ class DistributedTransformerLayer(nn.Module):
         connect = self._connector(res_dtype, hidden.dtype)
         x = hidden
 
+        def branch(site, out):
+            """A branch's output as the residual add takes it."""
+            if not self.branch_layernorm:
+                return out
+            with jax.named_scope("smp/layer/branch_norm"):
+                return ln(f"{site}/branch_layernorm")(out)
+
+        if self.branch_layernorm and (
+                self.parallel_attn_output or self.add_cross_attention):
+            raise SMPValidationError(
+                "branch_layernorm norms the attention's and the MLP's "
+                "output, each before its own residual add: "
+                "parallel_attn_output has one add for both, and the norm "
+                "of an add_cross_attention branch is not written."
+            )
         if self.parallel_attn_output:
             # Parallel residual: GPT-J style shares one LN
             # (single_pre_layernorm); GPT-NeoX style (pre_layernorm, two
@@ -1144,9 +1163,9 @@ class DistributedTransformerLayer(nn.Module):
             return x
 
         pre_ln = self.pre_layernorm or self.single_pre_layernorm
-        x = connect(mixer, x, lambda u: attn(
+        x = connect(mixer, x, lambda u: branch(mixer, attn(
             ln(f"{mixer}/layernorm")(u) if pre_ln else u,
-            attention_mask=attention_mask, xs=xs))
+            attention_mask=attention_mask, xs=xs)))
         if self.post_layernorm:
             x = ln(f"{mixer}/post_layernorm")(x)
 
@@ -1177,8 +1196,8 @@ class DistributedTransformerLayer(nn.Module):
                 x = ln("crossattention/post_layernorm")(x)
 
         own_ln = self.pre_layernorm and not self.single_pre_layernorm
-        x = connect("output", x, lambda u: mlp(
-            ln("output/layernorm")(u) if own_ln else u))
+        x = connect("output", x, lambda u: branch("output", mlp(
+            ln("output/layernorm")(u) if own_ln else u)))
         if self.post_layernorm:
             x = ln("output/post_layernorm")(x)
         return x
@@ -1329,6 +1348,7 @@ class DistributedTransformer(nn.Module):
     pre_layernorm: bool = False
     post_layernorm: bool = True
     single_pre_layernorm: bool = False
+    branch_layernorm: bool = False
     attention_in_fp32: bool = False
     query_key_layer_scaling: bool = False
     scale_attention_scores: bool = True
@@ -1350,6 +1370,12 @@ class DistributedTransformer(nn.Module):
     attention_layers_type: Optional[tuple] = None
     layer_pattern: Optional[tuple] = None
     layer_kinds: Optional[Any] = None
+    # Passes of the whole stack over its own output with one set of
+    # parameters. With n > 1 a norm of the stack's kind (``loop_norm``)
+    # follows every pass, its output is the next pass's input, and the
+    # stack returns all n normed states, [n, B, T, D]; 1: one pass, no
+    # norm, [B, T, D], as before.
+    loop_steps: int = 1
     # Every layer's residual path (DistributedTransformerLayer's field of
     # the name): with n > 1 streams the stack copies its input to n
     # streams, its scans carry [B, T, n, D], and it returns their sum.
@@ -1389,6 +1415,7 @@ class DistributedTransformer(nn.Module):
             pre_layernorm=self.pre_layernorm,
             post_layernorm=self.post_layernorm,
             single_pre_layernorm=self.single_pre_layernorm,
+            branch_layernorm=self.branch_layernorm,
             attention_in_fp32=self.attention_in_fp32,
             query_key_layer_scaling=self.query_key_layer_scaling,
             scale_attention_scores=self.scale_attention_scores,
@@ -1436,6 +1463,8 @@ class DistributedTransformer(nn.Module):
         return xs
 
     def setup(self):
+        if self.loop_steps > 1:
+            self._setup_loop()
         body = _LayerScanBody
         if self.activation_checkpointing:
             from smdistributed_modelparallel_tpu.parallel.memory import remat_policy
@@ -1449,6 +1478,30 @@ class DistributedTransformer(nn.Module):
             return
         ScanLayers = _scan_layers(body, self.num_layers)
         self.seq_layers = ScanLayers(self._layer_kwargs(), name="seq_layers")
+
+    def _setup_loop(self):
+        """What ``loop_steps`` > 1 adds to the stack: the norm after every
+        pass. A layer here runs once a pass, not once a forward, which the
+        decode cache (one entry a layer), the pipeline executors (a stage
+        sees a microbatch once) and the fp8 scales' drain (one scan deep)
+        do not know yet."""
+        _refuse_loop_under_pipeline(self.loop_steps)
+        if self.decode or _fp8_active():
+            raise SMPValidationError(
+                f"loop_steps={self.loop_steps} runs every layer once a "
+                "pass: a decode cache with an entry for every (pass, layer) "
+                "and fp8 scales drained through the loop over passes are "
+                "not written; it takes neither decode=True nor fp8 matmuls."
+            )
+        from smdistributed_modelparallel_tpu.utils.telemetry import (
+            record_loop_passes,
+        )
+
+        record_loop_passes(self.loop_steps, self.num_layers)
+        rms = self.layernorm_type == "rms"
+        self.loop_norm = DistributedLayerNorm(
+            epsilon=self.layernorm_epsilon, rms=rms, use_bias=not rms,
+            name="loop_norm")
 
     @nn.nowrap
     def _pattern_segments(self, body):
@@ -1504,10 +1557,37 @@ class DistributedTransformer(nn.Module):
         return built
 
     def __call__(self, hidden, cross_states=None, attention_mask=None):
+        if self.loop_steps > 1:
+            return self._loop(hidden, cross_states, attention_mask)
         # Innermost on the scans' own work alone: a layer's slice of the
         # stacked parameters, the residuals stacked for the backward pass.
         with jax.named_scope("smp/model/stack"):
             return self._stack(hidden, cross_states, attention_mask)
+
+    def _loop(self, hidden, cross_states, attention_mask):
+        """``loop_steps`` passes as one scan with the parameters broadcast
+        and the layers' scans inside: one pass in the program's text, and
+        the gradient of a weight every pass reads summed in the scan's own
+        backward carry. Returns the normed states, [passes, B, T, D]."""
+
+        def one_pass(stack, h, _):
+            with jax.named_scope("smp/model/stack"):
+                out = stack._stack(h, cross_states, attention_mask)
+            # Rematerialized: the backward pass keeps a pass's state as
+            # the layers gave it, not the norm's float32 intermediates.
+            out = nn.remat(lambda mdl, x: mdl.loop_norm(x),
+                           prevent_cse=False)(stack, out)
+            return out, out
+
+        # Innermost on the passes' own work: the norm after a pass, the
+        # carried state, the states stacked for the head.
+        with jax.named_scope("smp/model/loop"):
+            _, states = nn.scan(
+                one_pass, variable_broadcast="params",
+                variable_axes={"intermediates": 0},
+                split_rngs={"params": False, "dropout": True},
+                length=self.loop_steps)(self, hidden, None)
+        return states
 
     def _stack(self, hidden, cross_states, attention_mask):
         if self._streams() > 1:
@@ -1548,6 +1628,7 @@ class DistributedTransformer(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
+        _refuse_loop_under_pipeline(self.loop_steps)
         if self.layer_pattern is not None or self._streams() > 1:
             # Two kinds of layer in a stage, or a carry of several streams
             # (the executors' embed and head see one): not yet.
@@ -1558,6 +1639,16 @@ class DistributedTransformer(nn.Module):
             layer_module=DistributedTransformerLayer(**self._layer_kwargs()),
             layer_xs=self.layer_xs(),
             carry_is_tuple=True,
+        )
+
+
+def _refuse_loop_under_pipeline(loop_steps):
+    if (loop_steps > 1 and state.cfg is not None
+            and state.cfg.pipeline_parallel_degree > 1):
+        raise SMPValidationError(
+            f"loop_steps={loop_steps} with pipeline_parallel_degree > 1: a "
+            "pipeline executor whose stages see a microbatch once a pass is "
+            "not written; run a looped stack at pp = 1."
         )
 
 
@@ -1652,6 +1743,23 @@ class DistributedTransformerLMHead(nn.Module):
     final_layernorm: bool = False
     tie_input_output_embedding: bool = True
     single_pre_layernorm: bool = False
+    # The layer's field of the name (a norm on each branch's output).
+    branch_layernorm: bool = False
+    # Passes of the stack over its own output (DistributedTransformer's
+    # field). With n > 1 the final norm is the stack's, after every pass;
+    # the head runs on each pass's state, one pass at a time and
+    # rematerialized, beside an exit gate (``exit_gate``: one linear layer
+    # to a logit a position, the same for every pass); the model returns
+    # ``(logits [n, B, T, V], gate logits [n, B, T])``, with ``targets``
+    # ``(losses [n, B, T], gate logits)``, both float32, for
+    # ``nn.exit_gate.exit_gated_loss``.
+    loop_steps: int = 1
+    # The most positions of a pass the looped head takes at a time (None:
+    # all; the pieces are equal, so the largest divisor of the sequence
+    # under it). A piece's logits are what the head keeps alive, [B,
+    # positions, V] once in the compute dtype and once in float32, so
+    # whoever knows the chip sets it.
+    loop_head_positions: Optional[int] = None
     scale_attention_scores: bool = True
     scale_attn_by_layer_idx: bool = False
     activation_checkpointing: bool = False
@@ -1700,7 +1808,15 @@ class DistributedTransformerLMHead(nn.Module):
         self.transformer = DistributedTransformer(
             **self._transformer_kwargs(), name="transformer"
         )
-        if self.final_layernorm or self.pre_layernorm:
+        if self.loop_steps > 1:
+            # In float32 whatever the compute dtype, as a router's product
+            # is: its weight's gradient is a sum over every position of
+            # terms that nearly cancel.
+            self.exit_gate = nn.Dense(
+                1, kernel_init=_init(self.initializer_range),
+                dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                name="exit_gate")
+        elif self.final_layernorm or self.pre_layernorm:
             rms = (
                 {"rms": True, "use_bias": False}
                 if self.layernorm_type == "rms" else {}
@@ -1744,6 +1860,7 @@ class DistributedTransformerLMHead(nn.Module):
             pre_layernorm=self.pre_layernorm,
             post_layernorm=self.post_layernorm,
             single_pre_layernorm=self.single_pre_layernorm,
+            branch_layernorm=self.branch_layernorm,
             attention_in_fp32=self.attention_in_fp32,
             query_key_layer_scaling=self.query_key_layer_scaling,
             scale_attention_scores=self.scale_attention_scores,
@@ -1765,6 +1882,7 @@ class DistributedTransformerLMHead(nn.Module):
             attention_layers_type=self.attention_layers_type,
             layer_pattern=self.layer_pattern,
             layer_kinds=self.layer_kinds,
+            loop_steps=self.loop_steps,
             hyper_connection=self.hyper_connection,
             activation_checkpointing=self.activation_checkpointing,
             num_experts=self.num_experts,
@@ -1836,11 +1954,53 @@ class DistributedTransformerLMHead(nn.Module):
                 record_lm_head_positions,
             )
 
-            T = x.shape[1]
+            T = x.shape[-2]
             n = int(round(T * self.head_positions))
             record_lm_head_positions(n, T)
-            x = x[:, :n]
-        if self.final_layernorm or self.pre_layernorm:
+            x = x[..., :n, :]
+        if self.loop_steps > 1:
+            return self._loop_head(x, targets)
+        return self._head(x, targets)
+
+    def _loop_head(self, states, targets):
+        """Head and exit gate on each pass's normed state [passes, B, T,
+        D], as one scan over the passes with the parameters broadcast and
+        its body rematerialized: the backward pass makes a pass's logits
+        again from its state, so no two passes' logits are alive
+        together."""
+
+        n, B, T, D = states.shape
+        pieces = next(c for c in range(1, T + 1) if T % c == 0
+                      and T // c <= (self.loop_head_positions or T))
+        size = T // pieces
+
+        def one_piece(model, _, piece):
+            h, t = piece
+            out = model._head(h, t, normed=True)
+            with jax.named_scope("smp/head/exit_gate"):
+                gate = model.exit_gate(h)[..., 0]
+            return None, (out, gate)
+
+        def whole(y):
+            """[n * pieces, B, size, ...] -> [n, B, T, ...]"""
+            y = jnp.moveaxis(y.reshape(n, pieces, B, size, *y.shape[3:]), 1, 2)
+            return y.reshape(n, B, T, *y.shape[4:])
+
+        xs = jnp.moveaxis(states.reshape(n, B, pieces, size, D), 2, 1)
+        ts = None
+        if targets is not None:
+            ts = jnp.tile(
+                jnp.moveaxis(targets.reshape(B, pieces, size), 1, 0),
+                (n, 1, 1))
+        _, out = nn.scan(
+            nn.remat(one_piece, prevent_cse=False),
+            variable_broadcast="params", split_rngs={"params": False},
+            length=n * pieces)(
+                self, None, (xs.reshape(n * pieces, B, size, D), ts))
+        return jax.tree_util.tree_map(whole, out)
+
+    def _head(self, x, targets, normed=False):
+        if not normed and (self.final_layernorm or self.pre_layernorm):
             with jax.named_scope("smp/head/norm"):
                 x = self.ln_f(x)
         if not self.add_lm_head:
@@ -1891,7 +2051,9 @@ class DistributedTransformerLMHead(nn.Module):
         per-token fp32 losses via the fused LM-head CE. Loss mode
         requires pp == 1 (the pipeline head protocol carries no
         targets). With ``head_positions`` set, logits (or losses) for the
-        leading positions it names only; the stack still runs them all."""
+        leading positions it names only; the stack still runs them all.
+        With ``loop_steps`` > 1, a pair: either of those for every pass,
+        stacked on a leading axis, and the exit gate's logits."""
         if targets is not None:
             if state.cfg is not None and state.cfg.pipeline_parallel_degree > 1:
                 raise SMPValidationError(
@@ -1905,6 +2067,7 @@ class DistributedTransformerLMHead(nn.Module):
 
     @nn.nowrap
     def pipeline_spec(self):
+        _refuse_loop_under_pipeline(self.loop_steps)
         if (self.layer_pattern is not None
                 or dict(self.hyper_connection or {}).get("streams", 1) > 1):
             return None     # see DistributedTransformer.pipeline_spec
@@ -1920,6 +2083,7 @@ class DistributedTransformerLMHead(nn.Module):
                         "attention_layers_type",
                         "layer_pattern",
                         "layer_kinds",
+                        "loop_steps",
                         "activation_checkpointing",
                     )
                 }

@@ -446,6 +446,32 @@ def _bd_compiler_params(length, hd_pad, itemsize):
             _bd_vmem_bytes(length, hd_pad, itemsize), _VMEM_CAP))}
 
 
+def _whole_operand_params(length, hd_pad, hdv_pad, itemsize, block, tile,
+                          bd, backward=False):
+    """``pallas_call`` arguments for a call that holds two whole operands
+    of a head over ``length`` positions (K and V, or Q and dO) in two
+    buffers each. Under the block-diffusion mask always what the stream
+    takes. Otherwise nothing, and the compiled call is what it was, while
+    the operands and what the call holds beside them fit the default
+    scoped limit: its blocks of ``block`` rows in two buffers, read in
+    ``itemsize`` and written in up to float32, and a tile's (``tile``
+    elements) float32 scores and probabilities, with their gradients in a
+    ``backward`` call. That is an upper bound: the compiler's own count
+    differs by the call and by the number of heads. Past it, the operands
+    and the limit's worth of room: 8,192 positions of 128 in float32, the
+    eager init pass of a model whose step runs in bfloat16, fill the limit
+    with the operands alone."""
+    if bd is not None:
+        return _bd_compiler_params(length, hd_pad, itemsize)
+    whole = 2 * length * (hd_pad + hdv_pad) * itemsize
+    beside = (2 * block * (hd_pad + hdv_pad) * (itemsize + 4)
+              + (4 if backward else 2) * tile * 4)
+    if whole + beside <= _VMEM_TILES:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(whole + _VMEM_TILES, _VMEM_CAP))}
+
+
 # ----------------------------------------------------------------------
 # Forward
 # ----------------------------------------------------------------------
@@ -942,13 +968,15 @@ def _flash_fwd_impl(q, k, v, kpad_bias, seed, scale, causal, window,
     if has_ids:
         id_in, id_specs = _ids_extra(q_ids, kv_ids, t_pad, s_pad)
         extra, extra_specs = extra + id_in, extra_specs + id_specs
-    more, more_call = {}, {}
+    more = {}
     if not has_ids:
         _record_tiles(("fwd",), bd, block_q, block_k, t_pad, s_pad, q_len=T,
                       kv_len=S, causal=causal, window=window)
     if bd is not None:
         more = {"bd": bd}
-        more_call = _bd_compiler_params(s_pad, hd_pad, qt.dtype.itemsize)
+    more_call = _whole_operand_params(
+        s_pad, hd_pad, hdv_pad, qt.dtype.itemsize, block_q,
+        block_q * block_k, bd)
     grid = (B * H, t_pad // block_q)
     kv_whole, _ = _kv_index(_group_of(q, k))
     kern = functools.partial(
@@ -1023,14 +1051,15 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         has_ids=has_ids, h_local=H, head_total=head_total or H,
         has_head0=has_head0,
     )
-    more_call = {}
     if not has_ids:
         _record_tiles(("dq", "dkv"), bd, block_q, block_k, t_pad, s_pad,
                       q_len=T, kv_len=S, causal=causal, window=window)
     if bd is not None:
         common["bd"] = bd
-        more_call = _bd_compiler_params(
-            max(s_pad, t_pad), hd_pad, qt.dtype.itemsize)
+    held = functools.partial(
+        _whole_operand_params, hd_pad=hd_pad, hdv_pad=hdv_pad,
+        itemsize=qt.dtype.itemsize, tile=block_q * block_k, bd=bd,
+        backward=True)
     res_spec_q = pl.BlockSpec((1, t_pad, hd_pad), lambda b, i: (b, 0, 0))
     res_spec_do = pl.BlockSpec((1, t_pad, hdv_pad), lambda b, i: (b, 0, 0))
     row_spec = pl.BlockSpec((1, 1, t_pad), lambda b, i: (b, 0, 0))
@@ -1058,7 +1087,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         ),
         name="smp_flash_bwd_dq",
         interpret=interpret or FORCE_INTERPRET,
-        **more_call,
+        **held(max(s_pad, t_pad) if bd else s_pad, block=block_q),
     )(qt, kt, vt, gt, lse, delta, *extra)
 
     dk, dv = pl.pallas_call(
@@ -1091,7 +1120,7 @@ def _flash_bwd_impl(q, k, v, o, g, lse, kpad_bias, seed, scale, causal,
         ],
         name="smp_flash_bwd_dkv",
         interpret=interpret or FORCE_INTERPRET,
-        **more_call,
+        **held(max(s_pad, t_pad) if bd else t_pad, block=block_k),
     )(qt, kt, vt, gt, lse, delta, *extra)
 
     def from_bht(x, L, hd=hd):

@@ -38,9 +38,9 @@ anyone re-running ad-hoc probes. Three cooperating pieces:
    whose name lands in the compiled HLO's op metadata. ``SCOPES`` lists
    every one the package writes (``smp/<subsystem>/<name>``) with the
    round it is put: the user's step function outermost
-   (``smp/step/user``), embeddings, head and loss, each layer, its
-   attention and its parts, the dense feed-forward, the expert layer's
-   parts, the pipeline executors' segments and per-tick sub-steps (with
+   (``smp/step/user``), embeddings, a looped stack's passes, head, exit
+   gate and loss, each layer, its branch norms, its attention and its
+   parts, the dense feed-forward, the expert layer's parts, the pipeline executors' segments and per-tick sub-steps (with
    the pass coordinate under split-backward schedules: ``tick_bwd`` vs
    ``tick_bwd_input`` / ``tick_bwd_weight``), gradient accumulation, the
    half-precision parameter cast and the optimizer update.
@@ -132,13 +132,20 @@ SCOPES = {
                        "scans' own work (a layer's slice of the stacked "
                        "parameters, the residuals stacked for the "
                        "backward pass)",
+    "smp/model/loop": "a looped stack's passes; innermost on the passes' "
+                      "own work (the norm after a pass, the carried "
+                      "state, the states stacked for the head)",
     "smp/head/norm": "the final norm",
     "smp/head/logits": "the LM head's product (tied attend, untied "
                        "lm_head, its vocabulary split)",
     "smp/head/loss": "nn/cross_entropy's entry points and "
                      "nn/diffusion.masked_diffusion_loss",
+    "smp/head/exit_gate": "a looped model's exit gate: its product after "
+                          "each pass, and nn/exit_gate.exit_gated_loss",
     "smp/layer/<kind>": "a layer of a patterned stack, by its kind's name",
     "smp/layer/block": "a layer of a stack with no kind",
+    "smp/layer/branch_norm": "inside a layer: the norm of a branch's "
+                             "output before the residual add",
     "smp/attn/full": "a layer's attention with no window",
     "smp/attn/window": "a layer's attention under a window",
     "smp/attn/block_diffusion": "a layer's attention under the "

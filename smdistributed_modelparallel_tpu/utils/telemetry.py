@@ -12,7 +12,13 @@ histograms, all with optional labels) that every layer of the stack feeds —
   -> measured pipeline bubble fraction vs the theoretical
   ``(pp-1)/(mb+pp-1)``;
 - ``step.py`` / ``utils/metrics.py``: compile-cache hits/misses, compile
-  wall time, XLA ``cost_analysis`` FLOPs/bytes, per-step peak HBM.
+  wall time, XLA ``cost_analysis`` FLOPs/bytes, per-step peak HBM;
+- ``nn/``: what a stack is built of, set while it is built or traced (the
+  ``record_*`` functions below; a looped stack's passes and layer passes
+  among them, ``record_loop_passes``), and what a step returned beside its
+  loss, read back outside any timed path (``nn.record_moe_stats``,
+  ``nn.record_diffusion_stats``, ``nn.record_exit_stats``: exit shares,
+  entropy and the passes' losses of a looped model).
 
 Exports: ``smp.telemetry.report()`` (plain dict), ``render_prometheus()``
 (text exposition format), and a JSON dump — written on demand, at
@@ -911,6 +917,23 @@ def record_conv_mixers(kind, layers):
         "layers of a patterned stack whose mixer is a short convolution, "
         "by layer kind",
     ).labels(kind=kind).set(layers)
+
+
+def record_loop_passes(passes, layers):
+    """``smp_loop_passes`` and ``smp_loop_layer_passes``: passes a looped
+    stack (``DistributedTransformer.loop_steps`` > 1) makes over its own
+    output a forward, and layers run a forward (passes x layers; a step
+    runs that many a microbatch). Set while the stack is built. A step's
+    own exit shares, entropy and per-pass losses are
+    ``nn.exit_gate.record_exit_stats``'s ``smp_exit_*`` gauges."""
+    telemetry.gauge(
+        "smp_loop_passes",
+        "passes of a looped layer stack over its own output, a forward",
+    ).set(passes)
+    telemetry.gauge(
+        "smp_loop_layer_passes",
+        "layers a looped stack runs a forward: passes x layers",
+    ).set(passes * layers)
 
 
 def record_mhc_streams(kind, streams):

@@ -91,6 +91,44 @@ def test_flash_attention_forward_backward(one_chip, shape):
         assert name in text
 
 
+@pytest.mark.parametrize("positions,dtype,asks", [
+    (8192, jnp.bfloat16, False), (8192, jnp.float32, True),
+    (7680, jnp.float32, True), (7168, jnp.float32, False)],
+    ids=["bf16_step", "f32_init_pass", "f32_15MiB", "f32_14MiB"])
+def test_flash_attention_at_8k_with_every_head_its_own_kv_head(
+        one_chip, positions, dtype, asks):
+    """16 heads of 128 on 16 KV heads (the looped cell's attention). In
+    bfloat16 at 8,192 positions, the compiled step, the call is what it
+    was (no scoped limit asked for); in float32, the eager init pass of a
+    model whose step runs in bfloat16, a head's K and V in two buffers
+    fill the default scoped limit and the compiler refused the call by
+    0.3 MiB, and at 7,680 positions (15 MiB) by 0.4: the call asks for
+    what it holds. At 7,168 (14 MiB) it compiles unasked."""
+    from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+    shape = ((1, positions, 16, 128), dtype)
+    params = pa._whole_operand_params(
+        positions, 128, 128, jnp.dtype(dtype).itemsize, 256, 256 * 512, None)
+    assert bool(params) is asks
+    text = _compile(
+        jax.grad(lambda q, k, v: _sum32(pa.flash_attention(
+            q, k, v, causal=True)), argnums=(0, 1, 2)),
+        one_chip, shape, shape, shape)
+    for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert name in text
+
+
+def test_which_flash_calls_ask_for_scoped_memory():
+    """A call under the block-diffusion mask asks whatever its size; the
+    float32 forward of 4 heads of 192 on values of 128 at 4,096 positions
+    (an accepted cell's init pass: 12 MiB of operands) does not."""
+    from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+    assert pa._whole_operand_params(256, 128, 128, 2, 256, 256 * 256, 4)
+    assert not pa._whole_operand_params(
+        4096, 256, 128, 4, 256, 256 * 512, None)
+
+
 def test_flash_attention_with_value_heads_of_their_own_size(one_chip):
     """Latent attention's heads at their published sizes (4 heads a chip,
     T 4096): query and key heads of 192 run at 256 lanes, value heads of

@@ -320,7 +320,9 @@ def _step_scopes(cfg, module, loss_mode=False, seq=8):
 
     @smp.step
     def step_fn(model, ids):
-        if loss_mode:
+        if getattr(module, "loop_steps", 1) > 1:
+            loss = smp.nn.exit_gated_loss(*model(ids, targets=ids), 0.05)[0]
+        elif loss_mode:
             loss = jnp.mean(model(ids, targets=ids))
         else:
             logits = model(ids).astype(jnp.float32)
@@ -342,8 +344,9 @@ def _step_scopes(cfg, module, loss_mode=False, seq=8):
 @pytest.fixture(scope="module")
 def compiled_scopes():
     """The scopes of a handful of compiled tiny steps: both stacks at
-    pp = 1 (a patterned one, and one of latent-attention layers on two
-    residual streams), the CPU mesh's pp = 2 under each executor."""
+    pp = 1 (a patterned one, one of latent-attention layers on two
+    residual streams, and one run twice over its own output with branch
+    norms and an exit gate), the CPU mesh's pp = 2 under each executor."""
     from smdistributed_modelparallel_tpu.models.transformer_lm import (
         TransformerLM,
     )
@@ -385,6 +388,11 @@ def compiled_scopes():
                         q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
                         qk_rope_head_dim=4, v_head_dim=8,
                         softmax_scale=0.3))})),
+        "looped": _step_scopes(
+            {"microbatches": 2, "bf16": True}, tp_stack(
+                layernorm_type="rms", tie_input_output_embedding=False,
+                branch_layernorm=True, loop_steps=2,
+                activation_checkpointing=True), loss_mode=True),
         "1f1b": _step_scopes(pp2, tp_stack()),
         "virtual": _step_scopes(
             dict(pp2, virtual_pipeline_degree=2), tp_stack(num_layers=4)),
@@ -428,9 +436,13 @@ class TestScopeVocabulary:
         if scope.startswith("smp/pipeline/"):
             assert where <= {"1f1b", "virtual", "zero_bubble", "simple"}
         if scope == "smp/model/stack":      # the executors run the layers
-            assert where == {"zoo", "tp_stack", "patterned", "streams"}
-        if scope.startswith(("smp/attn/q", "smp/attn/core", "smp/attn/out",
-                             "smp/head/", "smp/model/", "smp/mlp/")):
+            assert where == {"zoo", "tp_stack", "patterned", "streams",
+                             "looped"}
+        if scope in ("smp/model/loop", "smp/head/exit_gate",
+                     "smp/layer/branch_norm"):
+            assert where == {"looped"}      # and no other stack's step
+        elif scope.startswith(("smp/attn/q", "smp/attn/core", "smp/attn/out",
+                               "smp/head/", "smp/model/", "smp/mlp/")):
             # both stacks write the parts of a layer and the head
             assert {"zoo", "tp_stack"} <= where or scope == "smp/attn/qk_norm"
 
