@@ -61,7 +61,24 @@ Supported feature surface (all combinations):
 Backward: two passes — dq (grid over q blocks, kv streamed) and dk/dv
 (grid over kv blocks, q streamed) — using the forward's saved per-row
 logsumexp and the precomputed ``delta = rowsum(dO * O)``, the standard
-flash-attention backward decomposition.
+flash-attention backward decomposition. Each pass lays its tile so that
+the products it accumulates take the tile as their left operand, as it
+comes. The forward and the dq pass hold rows down the tile, [block_q,
+block_k]: ``s = q k^T``, then ``acc += p v`` and ``dq += ds k``
+contract over the keys, the tile's lanes. The dkv pass holds keys down
+the tile, [block_k, block_q]: ``s^T = k q^T`` and ``dp^T = v dO^T`` (the
+form ``s`` has), then ``dv += p^T dO`` and ``dk += ds^T q`` contract over
+the rows, again the tile's lanes. Rows-major there, both would contract
+over their left operand's rows, and the compiler turned ``p`` and ``ds``
+in the transpose unit for it: 1,090 operations a [256, 512] tile, a
+sixth of the pass's time on the chip (PERF.md section 6, PR 50). ``lse``
+and ``delta`` lie in HBM as rows of lanes, [B*H, 1, T]: the dkv pass
+reads its [1, block_q] slice as it lies, where the dq pass turns its own
+into a [block_q, 1] column once a program; what belongs to a dkv
+program's own keys (its slice of the key-padding bias, its global ids)
+is made a [block_k, 1] column once a program. Every mask and the dropout
+hash are functions of the pair (row, key), so the keys-major tile's are
+the rows-major tile's transposed (``keys_major`` in ``_tile_keep``).
 
 Layout: inputs [B, T, H, hd]; kernels run on [B*H, T, hd].
 
@@ -185,12 +202,20 @@ def _q_bounds(k_lo, k_hi, *, q_len, kv_len, causal, window, block_q, num_q,
 #     clean on noisy:  never.
 
 
-def _bd_mask(q_offset, k_offset, block_q, block_k, *, half, blk):
+def _bd_mask(q_offset, k_offset, block_q, block_k, *, half, blk,
+             keys_major=False):
     """The [block_q, block_k] mask of the tile whose first row and first
     key stand at ``q_offset`` and ``k_offset`` (whatever lies at or past
-    2 x half is padding)."""
-    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    2 x half is padding); ``keys_major``: its transpose, [block_k,
+    block_q], from the same arithmetic on a [1, block_q] row of query
+    indices and a [block_k, 1] column of key indices."""
+    q_axis = int(keys_major)
+    q_shape, k_shape = (
+        ((1, block_q), (block_k, 1)) if keys_major
+        else ((block_q, 1), (1, block_k)))
+    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
+    cols = k_offset + jax.lax.broadcasted_iota(
+        jnp.int32, k_shape, 1 - q_axis)
     r_clean, c_clean = rows >= half, cols >= half
     r_pos = jnp.where(r_clean, rows - half, rows)
     c_pos = jnp.where(c_clean, cols - half, cols)
@@ -507,18 +532,27 @@ def _ids_cmin(kid_ref, k_offset, block_k, kv_len):
 
 
 def _tile_keep(masked, hashed, q_offset, k_offset, shape, q_ids, kv_ids,
-               bd, *, q_len, kv_len, causal, window):
+               bd, *, q_len, kv_len, causal, window, keys_major=False):
     """``(keep, hrows, hcols)`` of the [block_q, block_k] tile at
     ``q_offset``, ``k_offset``: its mask, and the coordinates the dropout
     hash counts by (the global ids where the call carries them). ``keep``
     is None for a whole tile (``masked`` false), the coordinates unless
-    ``hashed``: a whole tile of a call with no dropout builds neither."""
+    ``hashed``: a whole tile of a call with no dropout builds neither.
+
+    ``keys_major`` (the dkv pass): the tile is ``shape`` = [block_k,
+    block_q], keys down axis 0 and rows along axis 1, and the ids come as
+    they broadcast there, a [1, block_q] row and a [block_k, 1] column.
+    Every mask and the hash are functions of the pair (row, key), so what
+    is returned is the other orientation's transpose."""
     if not (masked or hashed):
         return None, None, None
-    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    q_axis = int(keys_major)
+    rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     hrows, hcols = rows, cols
-    if q_ids is not None:
+    if q_ids is not None and keys_major:
+        hrows, hcols = q_ids, kv_ids
+    elif q_ids is not None:
         hrows, hcols = q_ids[:, None], kv_ids[None, :]
     if not masked:
         return None, hrows, hcols
@@ -526,7 +560,8 @@ def _tile_keep(masked, hashed, q_offset, k_offset, shape, q_ids, kv_ids,
         keep = _ids_mask(rows, cols, hrows, hcols, q_len=q_len,
                          kv_len=kv_len, causal=causal, window=window)
     elif bd is not None:
-        keep = _bd_mask(q_offset, k_offset, *shape, half=q_len // 2, blk=bd)
+        keep = _bd_mask(q_offset, k_offset, shape[q_axis], shape[1 - q_axis],
+                        half=q_len // 2, blk=bd, keys_major=keys_major)
     else:
         keep = _tile_mask(rows, cols, q_len=q_len, kv_len=kv_len,
                           causal=causal, window=window)
@@ -738,13 +773,15 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     v_blk = v_ref[0]
     k_offset = j * block_k
     inv_keep = 1.0 / (1.0 - rate) if rate > 0.0 else 1.0
-    # kpm is indexed per kv block here (the block is this program's slice).
-    kpm_blk = None
+    # The tile is keys-major, [bk, bq]: what belongs to the program's own
+    # keys (its slice of the bias, its global ids) is turned into a
+    # [bk, 1] column here, once a program and not once a tile.
+    kpm_col = None
     if kpm_ref is not None:
-        kpm_blk = kpm_ref[0, pl.ds(k_offset, block_k)][None, :]
+        kpm_col = kpm_ref[0, pl.ds(k_offset, block_k)][:, None]
     kv_ids = None
     if has_ids:
-        kv_ids = kid_ref[0, pl.ds(k_offset, block_k)]
+        kv_ids = kid_ref[0, pl.ds(k_offset, block_k)][:, None]
         c_min = _ids_cmin(kid_ref, k_offset, block_k, kv_len)
 
     mask = dict(q_len=q_len, kv_len=kv_len, causal=causal, window=window)
@@ -753,42 +790,42 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
         dk_acc, dv_acc = carry
         q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
         do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
+        lse = lse_ref[0, :, pl.ds(i * block_q, block_q)]      # [1, bq]
+        delta = delta_ref[0, :, pl.ds(i * block_q, block_q)]
+        st = jax.lax.dot_general(
+            k_blk, q_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                              # [bq, bk]
+        )                                              # s^T, [bk, bq]
         if scale != 1.0:
-            s = s * scale
-        if kpm_blk is not None:
-            s = s + kpm_blk
+            st = st * scale
+        if kpm_col is not None:
+            st = st + kpm_col
         keep, hrows, hcols = _tile_keep(
-            masked, rate > 0.0, i * block_q, k_offset, s.shape,
-            qid_ref[0, pl.ds(i * block_q, block_q)] if has_ids else None,
-            kv_ids, bd, **mask)
-        p = jnp.exp(s - lse)
+            masked, rate > 0.0, i * block_q, k_offset, st.shape,
+            qid_ref[:, pl.ds(i * block_q, block_q)] if has_ids else None,
+            kv_ids, bd, keys_major=True, **mask)
+        pt = jnp.exp(st - lse)
         if masked:
-            p = jnp.where(keep, p, 0.0)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
+            pt = jnp.where(keep, pt, 0.0)
+        dpt = jax.lax.dot_general(
+            v_blk, do_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        )                                              # dp^T = v.dO^T
         if rate > 0.0:
             bh = _bh_remap(b, h_local, head_total, head0_ref)
             dkeep = _dropout_keep(seed_ref[0, 0], bh, hrows, hcols,
                                   s_total, rate)
-            p_drop = jnp.where(dkeep, p * inv_keep, 0.0)
-            dp = jnp.where(dkeep, dp * inv_keep, 0.0)
+            pt_drop = jnp.where(dkeep, pt * inv_keep, 0.0)
+            dpt = jnp.where(dkeep, dpt * inv_keep, 0.0)
         else:
-            p_drop = p
+            pt_drop = pt
         dv_acc = dv_acc + jax.lax.dot_general(
-            p_drop.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
+            pt_drop.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                              # [bk, hd]
-        ds = p * (dp - delta) * scale
+        )                                              # [bk, hd_v]
+        dst = pt * (dpt - delta) * scale               # d(k.q^T)
         dk_acc = dk_acc + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
+            dst.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return dk_acc, dv_acc
@@ -812,8 +849,8 @@ def _bwd_dkv_kernel(*refs, scale, block_q, block_k, q_len, kv_len, causal,
     zv = (z if v_blk.shape[-1] == k_blk.shape[-1]
           else jnp.zeros((block_k, v_blk.shape[-1]), jnp.float32))
     dk, dv = _walk(ranges, body_of, (z, zv))
-    # ds carries exactly one *scale factor and q_blk is raw (unscaled), so
-    # dk = ds^T.q is already correct.
+    # ds^T carries exactly one *scale factor and q_blk is raw (unscaled),
+    # so dk = ds^T.q is already correct.
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 

@@ -71,8 +71,8 @@ def _sum32(x):
 
 
 @pytest.mark.parametrize(
-    "shape", [(2, 1024, 12, 64), (1, 2048, 16, 256)],
-    ids=["gpt2_124m", "gptj_6b"],
+    "shape", [(2, 1024, 12, 64), (1, 2048, 16, 256), (2, 1024, 25, 64)],
+    ids=["gpt2_124m", "gptj_6b", "gpt2_xl_microbatch"],
 )
 def test_flash_attention_forward_backward(one_chip, shape):
     from smdistributed_modelparallel_tpu.ops.pallas_attention import (
@@ -115,6 +115,32 @@ def test_flash_attention_at_8k_with_every_head_its_own_kv_head(
             q, k, v, causal=True)), argnums=(0, 1, 2)),
         one_chip, shape, shape, shape)
     for name in ("smp_flash_fwd", "smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
+        assert name in text
+
+
+def test_flash_backward_by_global_ids_with_bias_and_dropout(one_chip):
+    """The cp ring's backward block pair (ids mode, fp32 out) with a
+    key-padding bias, dropout and a window of the global heads. The dkv
+    pass's tile is keys-major, so what belongs to a program's own keys (its
+    global ids, its slice of the bias) is turned from a row of lanes into a
+    [block_k, 1] column: a relayout interpret mode takes whatever its
+    shape, this compiler only where it can make it."""
+    from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+    T = 2048
+
+    def backward(q, k, v, o, g, lse, q_ids, kv_ids, kpad, seed, head0):
+        return pa.flash_bwd_with_ids(
+            q, k, v, o, g, lse, kpad, q_ids, kv_ids, scale=0.088,
+            causal=True, seed=seed, dropout_rate=0.1, counter_len=4 * T,
+            head0=head0, head_total=16)
+
+    heads = (1, T, 4, 128)
+    text = _compile(
+        backward, one_chip, heads, heads, heads, heads, heads,
+        ((1, 4, T), jnp.float32), ((T,), jnp.int32), ((T,), jnp.int32),
+        ((1, T), jnp.float32), ((), jnp.int32), ((), jnp.int32))
+    for name in ("smp_flash_bwd_dq", "smp_flash_bwd_dkv"):
         assert name in text
 
 

@@ -98,9 +98,14 @@ def test_heads_must_divide():
 # ``_tile_keep`` for the three kernels' mask lines) the forward and the dq
 # body multiply the tile index by ``block_k`` before the rows' iota, not
 # after it, and the variables in between are renamed: no other line
-# differs from PR 40's text (6d521284...cb19).
+# differs from PR 40's text (6d521284...cb19). Since PR 50 the dkv pass's
+# tile body is keys-major (``lse`` and ``delta`` read as [1, 128] rows,
+# ``k q^T`` and ``v dO^T``, the iotas' dimensions swapped, the dv and dk
+# products contracting their left operand's dimension 1) and the variables
+# after it are renamed: every line before that body, the forward and the
+# dq kernel among them, is PR 45's (9f0f0b3e...93fe).
 _MHA_JAXPR_SHA256 = (
-    "9f0f0b3e50f8e8ca049d9dd521d120df2b07c987c7e65bb22c5ae9b435f693fe")
+    "21e3b7608819a16c9d824213c80c4f8430356469f2ba57d2e606adb0afc61993")
 
 
 def test_multi_head_traces_as_before():

@@ -15,26 +15,36 @@ from smdistributed_modelparallel_tpu.ops.attention import attention_core
 from smdistributed_modelparallel_tpu.ops.pallas_attention import flash_attention
 
 
-def _naive(q, k, v, scale=None, causal=True, window=None, kpad=None):
-    """jnp reference mirroring the kernel's feature surface."""
+def _naive(q, k, v, scale=None, causal=True, window=None, kpad=None,
+           keep=None, drop=None):
+    """jnp reference mirroring the kernel's feature surface. ``keep``: the
+    [T, S] mask itself in place of ``causal`` / ``window`` (global ids);
+    ``drop``: ``(keep [B, H, T, S], rate)`` of the dropout; KV heads
+    shared by a group of query heads are repeated."""
     hd = q.shape[-1]
     scale = scale or 1.0 / np.sqrt(hd)
     T, S = q.shape[1], k.shape[1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32) * scale
     if kpad is not None:
         s = s + kpad[:, None, None, :]
-    rows = jnp.arange(T)[:, None]
-    cols = jnp.arange(S)[None, :]
-    offset = S - T
-    keep = jnp.ones((T, S), bool)
-    if causal:
-        keep &= cols <= rows + offset
-        if window is not None:
-            keep &= rows + offset - cols < window
-    elif window is not None:
-        keep &= jnp.abs(rows + offset - cols) < window
+    if keep is None:
+        rows = jnp.arange(T)[:, None]
+        cols = jnp.arange(S)[None, :]
+        offset = S - T
+        keep = jnp.ones((T, S), bool)
+        if causal:
+            keep &= cols <= rows + offset
+            if window is not None:
+                keep &= rows + offset - cols < window
+        elif window is not None:
+            keep &= jnp.abs(rows + offset - cols) < window
     s = jnp.where(keep[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
+    if drop is not None:
+        p = jnp.where(drop[0], p / (1.0 - drop[1]), 0.0)
     return jnp.einsum("bhts,bshd->bthd", p.astype(v.dtype), v)
 
 
@@ -498,21 +508,83 @@ class TestFlashFeatures:
         ref = _naive(q, k, v, kpad=kpad)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
-    def test_gradients_all_features(self):
-        B, T, S = 1, 128, 256
-        q, k, v = _rand_qkv(jax.random.key(8), (B, T, 2, 32), (B, S, 2, 32))
-        keep = jax.random.bernoulli(jax.random.key(9), 0.9, (B, S))
-        kpad = jnp.where(keep, 0.0, -1e30).astype(jnp.float32)
+    # Where the orientation of the dkv pass's tile could go wrong (it is
+    # keys-major, [block_k, block_q]; the forward's and the dq pass's is
+    # rows-major): more tiles than one each way, lengths neither block
+    # divides, a bias on the keys, a window, value heads of their own
+    # width, eight query heads on one KV head. (q shape, kv shape, value
+    # width, mask arguments, key-padding bias, tile of rows, tile of keys.)
+    _GRAD_CASES = {
+        "window_kpad_more_keys": (
+            (1, 128, 2, 32), (1, 256, 2, 32), 32,
+            dict(causal=True, window=200), True, 128, 128),
+        "kpad_more_keys_in_no_tiles": (
+            (2, 200, 2, 32), (2, 300, 2, 32), 32,
+            dict(causal=True), True, 128, 128),
+        "band_in_no_tiles": (
+            (1, 300, 2, 32), (1, 300, 2, 32), 32,
+            dict(causal=False, window=90), False, 128, 256),
+        "value_heads_of_128_on_192": (
+            (1, 256, 2, 192), (1, 256, 2, 192), 128,
+            dict(causal=True), False, 128, 128),
+        "eight_heads_on_one_kv_head": (
+            (1, 256, 8, 32), (1, 256, 1, 32), 32,
+            dict(causal=True, window=100), False, 128, 128),
+        "more_rows_than_keys_kpad": (
+            (1, 384, 2, 32), (1, 200, 1, 32), 32,
+            dict(causal=False), True, 256, 128),
+    }
+
+    @pytest.mark.parametrize("name", list(_GRAD_CASES))
+    def test_gradients_all_features(self, name):
+        qshape, kvshape, hdv, mask, with_kpad, bq, bk = self._GRAD_CASES[name]
+        q, k, v = _rand_qkv(jax.random.key(8), qshape, kvshape)
+        v = v[..., :hdv]
+        kpad = None
+        if with_kpad:
+            keep = jax.random.bernoulli(
+                jax.random.key(9), 0.9, (kvshape[0], kvshape[1]))
+            kpad = jnp.where(keep, 0.0, -1e30).astype(jnp.float32)
+        probe = jax.random.normal(jax.random.key(10), (*qshape[:3], hdv))
 
         def loss_flash(q, k, v):
-            return jnp.sum(_flash(q, k, v, kpad=kpad, causal=True, window=200) ** 2)
+            return jnp.sum(
+                _flash(q, k, v, kpad=kpad, bq=bq, bk=bk, **mask) * probe)
 
         def loss_naive(q, k, v):
-            return jnp.sum(_naive(q, k, v, kpad=kpad, causal=True, window=200) ** 2)
+            return jnp.sum(_naive(q, k, v, kpad=kpad, **mask) * probe)
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gn = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gn):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+    def test_gradients_by_global_ids_of_zigzag_rows(self):
+        """Ids mode (the cp ring's block pair): rows and keys are two
+        chunks each of a sequence cut in four, out of order and of a length
+        no block divides, and the causal relation is by their global ids.
+        dq, dk and dv (fp32 out of the kernels) against the jnp reference
+        under the same mask."""
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        q, k, v = _rand_qkv(jax.random.key(12), (2, 200, 2, 32))
+        q_ids = jnp.concatenate([jnp.arange(100, 200), jnp.arange(300, 400)])
+        kv_ids = jnp.concatenate([jnp.arange(0, 100), jnp.arange(200, 300)])
+        g = jax.random.normal(jax.random.key(13), q.shape)
+        scale = 1.0 / np.sqrt(32)
+        blocks = dict(block_q=128, block_k=128, interpret=True)
+        o, lse = pa.flash_fwd_with_ids(
+            q, k, v, None, q_ids, kv_ids, scale=scale, causal=True, **blocks)
+        got = pa.flash_bwd_with_ids(
+            q, k, v, o, g, lse, None, q_ids, kv_ids, scale=scale,
+            causal=True, **blocks)
+        keep = kv_ids[None, :] <= q_ids[:, None]
+        ref, vjp = jax.vjp(
+            lambda q, k, v: _naive(q, k, v, keep=keep), q, k, v)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
+        for a, b in zip(got, vjp(g)):
+            assert a.dtype == jnp.float32 and a.shape == b.shape
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
     def test_dropout_deterministic_and_effective(self):
@@ -526,35 +598,47 @@ class TestFlashFeatures:
         # Inverted-dropout scaling keeps the output magnitude comparable.
         assert np.abs(np.asarray(a)).mean() < 3 * np.abs(np.asarray(c)).mean()
 
-    def test_dropout_gradients_match_same_mask_reference(self):
+    # (batch, local heads, T, tile, head0, heads in all): one tile of one
+    # head, then four tiles a head with the local heads a window of the
+    # global ones (Ulysses), where a hash counted by (key, row) or by the
+    # local head would replay another mask in the dkv pass.
+    _DROPOUT_CASES = {
+        "one_head_one_tile": (1, 1, 128, 128, None, None),
+        "head0_of_eight_four_tiles": (2, 2, 256, 128, 3, 8),
+    }
+
+    @pytest.mark.parametrize("name", list(_DROPOUT_CASES))
+    def test_dropout_gradients_match_same_mask_reference(self, name):
         """Backward with dropout vs a jnp reference using the exact same
         hash-derived keep mask (the kernels replay it bit-identically)."""
         from smdistributed_modelparallel_tpu.ops.pallas_attention import (
             _dropout_keep,
         )
 
-        B, T, H, hd = 1, 128, 1, 32
+        B, H, T, tile, head0, head_total = self._DROPOUT_CASES[name]
+        hd = 32
         q, k, v = _rand_qkv(jax.random.key(11), (B, T, H, hd))
         seed = jnp.int32(7)
         rate = 0.25
-        scale = 1.0 / np.sqrt(hd)
         rows = jnp.arange(T)[:, None] * jnp.ones((1, T), jnp.int32)
         cols = jnp.arange(T)[None, :] * jnp.ones((T, 1), jnp.int32)
-        keep = _dropout_keep(seed, jnp.int32(0), rows, cols, T, rate)
+        bh = (jnp.arange(B)[:, None] * (head_total or H) + (head0 or 0)
+              + jnp.arange(H)[None, :])
+        keep = jax.vmap(jax.vmap(
+            lambda i: _dropout_keep(seed, i, rows, cols, T, rate)))(
+                bh.astype(jnp.int32))
 
-        def ref(q, k, v):
-            s = jnp.einsum("bthd,bshd->bhts", q * scale, k).astype(jnp.float32)
-            m = jnp.tril(jnp.ones((T, T), bool))
-            s = jnp.where(m[None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            pd = jnp.where(keep, p / (1 - rate), 0.0)
-            return jnp.einsum("bhts,bshd->bthd", pd.astype(v.dtype), v)
+        def flash(q, k, v):
+            return flash_attention(
+                q, k, v, None, seed,
+                None if head0 is None else jnp.int32(head0), None, True,
+                None, rate, tile, tile, True, head_total)
 
         def loss_flash(q, k, v):
-            return jnp.sum(_flash(q, k, v, seed=seed, rate=rate) ** 2)
+            return jnp.sum(flash(q, k, v) ** 2)
 
         def loss_ref(q, k, v):
-            return jnp.sum(ref(q, k, v) ** 2)
+            return jnp.sum(_naive(q, k, v, drop=(keep, rate)) ** 2)
 
         np.testing.assert_allclose(
             float(loss_flash(q, k, v)), float(loss_ref(q, k, v)), rtol=1e-5
@@ -565,15 +649,88 @@ class TestFlashFeatures:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
 
 
+def _pallas_calls(jaxpr, name):
+    """The ``pallas_call`` equations named ``name`` in a jaxpr and the
+    jaxprs inside it, each once (a scan's body counts once whatever its
+    length)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and str(eqn.params["name"]) == name):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, name)
+    return found
+
+
 def _kernel_calls(jaxpr, name):
-    """``pallas_call``s named ``name`` in a jaxpr and the jaxprs inside it,
-    each once (a scan's body counts once whatever its length)."""
-    return sum(
-        int(eqn.primitive.name == "pallas_call"
-            and str(eqn.params["name"]) == name)
-        + sum(_kernel_calls(sub, name)
-              for sub in jax.core.jaxprs_in_params(eqn.params))
-        for eqn in jaxpr.eqns)
+    """How many ``pallas_call``s named ``name`` a jaxpr holds."""
+    return len(_pallas_calls(jaxpr, name))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (loops,
+    branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+# The masks the dkv pass takes: every static kind, and the global ids of
+# the cp ring (a backward block pair, differentiated by the ring itself).
+_DKV_FORMS = {
+    "causal": dict(causal=True),
+    "window": dict(causal=True, window=200),
+    "band": dict(causal=False, window=200),
+    "block_diffusion": dict(block_diffusion=4),
+    "none": dict(causal=False),
+    "ids": None,
+    "dropout_key_padding": dict(causal=True),
+}
+
+
+class TestFlashDkvKeysMajor:
+    """The dkv pass forms its tile keys-major (``s^T = k q^T``, [block_k,
+    block_q]) so that ``p^T`` and ``ds^T`` are the left operands of the dv
+    and dk products as they come: what says the mechanism engages is the
+    kernel's own body."""
+
+    @pytest.mark.parametrize("name", list(_DKV_FORMS))
+    def test_no_transpose_and_no_product_contracting_its_left_rows(
+            self, name):
+        from smdistributed_modelparallel_tpu.ops import pallas_attention as pa
+
+        q, k, v = _rand_qkv(jax.random.key(5), (1, 512, 4, 32),
+                            (1, 512, 2, 32))
+        blocks = dict(block_q=128, block_k=256, interpret=True)
+        if name == "ids":
+            ids = jnp.arange(512)
+            lse = jnp.zeros((1, 4, 512), jnp.float32)
+            jaxpr = jax.make_jaxpr(lambda q, k, v: pa.flash_bwd_with_ids(
+                q, k, v, q, q, lse, None, ids, ids[::-1], scale=0.2,
+                causal=True, **blocks))(q, k, v).jaxpr
+        else:
+            extra = {}
+            if name == "dropout_key_padding":
+                extra = dict(kpad_bias=jnp.zeros((1, 512), jnp.float32),
+                             seed=jnp.int32(3), dropout_rate=0.1)
+            jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+                flash_attention(q, k, v, **extra, **_DKV_FORMS[name],
+                                **blocks)), (0, 1, 2)))(q, k, v).jaxpr
+        call, = _pallas_calls(jaxpr, "smp_flash_bwd_dkv")
+        body = list(_equations(call.params["jaxpr"]))
+        assert not [e for e in body if e.primitive.name == "transpose"]
+        products = [e.params["dimension_numbers"][0] for e in body
+                    if e.primitive.name == "dot_general"]
+        # s^T = k q^T and dp^T = v dO^T (the form s = q k^T has in the
+        # other passes), then dv += p^T dO and dk += ds^T q, once for each
+        # tile body the walk holds: the left operand's rows are never the
+        # contraction (the rows-major body this replaced had ((0,), (0,))
+        # in the last two)
+        per_body = [((1,), (1,)), ((1,), (1,)), ((1,), (0,)), ((1,), (0,))]
+        assert products and len(products) % 4 == 0
+        assert products == per_body * (len(products) // 4)
 
 
 # Mask and heads of the stack below: (query heads, KV heads, the mask's
